@@ -1,5 +1,13 @@
 """Scale-from-zero cold-start benchmark: submit -> first-token, by stage.
 
+A CPU COUNT-CHECK, NOT A DEVICE MEASUREMENT: the parent builds a
+checkpoint with JAX while its server children are pinned to
+JAX_PLATFORMS=cpu, so it refuses to start unless JAX_PLATFORMS=cpu is
+exported (utils/devices.py). What carries over to a chip are its counts
+(programs built by warmup, cache hits on a warm boot, compiles after
+ready); its seconds are XLA's CPU backend. It needs per-chip process
+placement before it can become a benchmark cell (ROADMAP D7).
+
 Each arm boots the native model server (examples/deployment/native) as a
 fresh subprocess — the same thing a scale-from-zero replica does — and
 decomposes its time-to-first-token into the stages the cold-start fast
@@ -65,6 +73,8 @@ from pathlib import Path
 
 import httpx
 
+from dstack_tpu.utils.devices import require_cpu_request
+
 REPO = Path(__file__).resolve().parent
 SERVER = REPO / "examples" / "deployment" / "native" / "server.py"
 STAGE_PREFIX = "::dstack-tpu-stage::"
@@ -108,6 +118,11 @@ class ServerProc:
             # bench impersonates one to get the marker timeline.
             "DSTACK_RUN_NAME": "bench-coldstart",
         }
+        # The cache's placement IS this bench's independent variable
+        # (--compile-cache-dir per arm): an inherited
+        # JAX_COMPILATION_CACHE_DIR would win over the flag
+        # (compile_cache.enable) and turn every arm into the same arm.
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         self.t_spawn = time.perf_counter()
         self.proc = subprocess.Popen(
             cmd, env=env, cwd=REPO, text=True,
@@ -268,6 +283,7 @@ def main() -> int:
                              " stage) is reported — warm boots are cheap"
                              " and min-of-N estimates the noise floor")
     args = parser.parse_args()
+    require_cpu_request("bench_coldstart.py")
 
     work = tempfile.mkdtemp(prefix="bench_coldstart_")
     cache_dir = os.path.join(work, "compile-cache")

@@ -1,6 +1,14 @@
 """Prefix-affinity fleet routing benchmark: cache-aware replica selection
 vs plain least-outstanding, measured on REAL serving engines.
 
+A CPU COUNT-CHECK, NOT A DEVICE MEASUREMENT: every replica and the
+data-plane worker are children pinned to JAX_PLATFORMS=cpu, so it
+refuses to start unless JAX_PLATFORMS=cpu is exported
+(utils/devices.py). What carries over to a chip are its counts (prefill
+tokens computed, forced adapter loads); its TTFTs are XLA's CPU backend.
+It needs per-chip process placement — one replica per chip — before it
+can become a benchmark cell (ROADMAP D7, R4c).
+
 An N-replica fleet of native model servers (tiny preset, real prefix
 caches) sits behind one real `python -m dstack_tpu.dataplane` worker.
 Each arm runs twice — affinity routing on (the shipped default) and off
@@ -45,6 +53,8 @@ import time
 from pathlib import Path
 
 import httpx
+
+from dstack_tpu.utils.devices import require_cpu_request
 
 REPO = Path(__file__).resolve().parent
 MODEL = "tiny-rt"
@@ -527,6 +537,7 @@ def main() -> None:
     parser.add_argument("--arms", default="",
                         help="comma-separated arm subset (skips summary)")
     args = parser.parse_args()
+    require_cpu_request("bench_routing.py")
     results = asyncio.get_event_loop().run_until_complete(_run_all(args))
     with open(args.out, "w") as f:
         json.dump(results, f, indent=2)

@@ -258,11 +258,6 @@ jax.config.update("jax_platforms", "cpu")
 # fleet; CPU async dispatch can still touch freed buffers from its
 # dispatch thread (observed SIGSEGV / malloc corruption under load).
 jax.config.update("jax_cpu_enable_async_dispatch", False)
-try:
-    import jax.extend.backend as _jb
-    _jb.clear_backends()
-except Exception:
-    pass
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.train import (
     init_train_state, make_train_step, synthetic_batch, install_drain_handler,
@@ -449,11 +444,6 @@ jax.config.update("jax_platforms", "cpu")
 # fleet; CPU async dispatch can still touch freed buffers from its
 # dispatch thread (observed SIGSEGV / malloc corruption under load).
 jax.config.update("jax_cpu_enable_async_dispatch", False)
-try:
-    import jax.extend.backend as _jb
-    _jb.clear_backends()
-except Exception:
-    pass
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.train import (
     init_train_state, make_train_step, synthetic_batch, install_drain_handler,
@@ -614,11 +604,6 @@ jax.config.update("jax_platforms", "cpu")
 # fleet; CPU async dispatch can still touch freed buffers from its
 # dispatch thread (observed SIGSEGV / malloc corruption under load).
 jax.config.update("jax_cpu_enable_async_dispatch", False)
-try:
-    import jax.extend.backend as _jb
-    _jb.clear_backends()
-except Exception:
-    pass
 from dstack_tpu.parallel.mesh import rescale_accum_steps
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.sharding import make_mesh

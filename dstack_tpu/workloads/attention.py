@@ -20,9 +20,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -142,6 +142,7 @@ def _ring_attention_local(
     *,
     axis_name: str,
     causal: bool,
+    traced_paths: set,
 ) -> jnp.ndarray:
     """Per-device body run under shard_map: q/k/v are local seq shards."""
     n = lax.psum(1, axis_name)
@@ -150,6 +151,7 @@ def _ring_attention_local(
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     flash_impl = _ring_block_impl(sq, sk, hd, q.dtype)
+    traced_paths.add("ring_jnp" if flash_impl is None else "ring_flash")
 
     # Block-level causal masks, selected per ring step by traced scalars:
     # kv block strictly after my queries -> fully masked; same block ->
@@ -245,19 +247,55 @@ def make_attention_fn(
     block-divisible seq — workloads/flash_attention.py), else plain fused
     attention (XLA shards heads/batch itself from the surrounding
     constraints). Otherwise -> ring attention under shard_map over seq.
-    """
-    if mesh is None or seq_axis not in mesh.axis_names or mesh.shape[seq_axis] == 1:
 
-        def single_device(q, k, v):
-            from dstack_tpu.workloads.flash_attention import (
-                flash_attention,
-                use_flash,
+    The returned function carries `traced_paths`: the set of
+    implementations its traces actually took ("flash" / "plain" /
+    "ring_flash" / "ring_jnp"), filled in as jit traces it — what ran,
+    not what a dispatch rule would predict.
+    """
+    traced_paths = set()
+    axis_names = mesh.axis_names if mesh is not None else ()
+    batch = tuple(a for a in batch_axes if a in axis_names)
+    heads = heads_axis if heads_axis in axis_names else None
+    if seq_axis not in axis_names or mesh.shape[seq_axis] == 1:
+        # A Pallas call has no SPMD partitioning rule: inside a jit that
+        # GSPMD partitions over several devices its lowering is refused
+        # ("Mosaic kernels cannot be automatically partitioned"). On a
+        # multi-device mesh the kernel therefore runs under shard_map,
+        # each device attending its own batch rows and heads — attention
+        # never mixes either, so no collective is needed.
+        def flash(q, k, v):
+            from dstack_tpu.workloads.flash_attention import flash_attention
+
+            return flash_attention(q, k, v, causal=causal)
+
+        batch_shards = head_shards = 1
+        if mesh is not None and mesh.size > 1:
+            batch_shards = int(np.prod([mesh.shape[a] for a in batch]))
+            head_shards = mesh.shape[heads] if heads else 1
+            spec = P(batch if batch else None, None, heads, None)
+            flash = jax.shard_map(
+                flash, mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False,
             )
 
-            if q.shape[1] == k.shape[1] and use_flash(
-                q.shape[1], q.shape[3], dtype_bytes=q.dtype.itemsize
+        def single_device(q, k, v):
+            from dstack_tpu.workloads.flash_attention import use_flash
+
+            # Flash needs equal q/kv lengths, a shape the kernel takes,
+            # and — on a multi-device mesh — rows and KV heads that split
+            # evenly over the axes they are sharded on.
+            if (
+                q.shape[1] == k.shape[1]
+                and use_flash(
+                    q.shape[1], q.shape[3], dtype_bytes=q.dtype.itemsize
+                )
+                and q.shape[0] % batch_shards == 0
+                and k.shape[2] % head_shards == 0
             ):
-                return flash_attention(q, k, v, causal=causal)
+                traced_paths.add("flash")
+                return flash(q, k, v)
+            traced_paths.add("plain")
             return plain_attention(q, k, v, causal=causal)
 
         def _quadratic(seq_len: int, head_dim: int, dtype_bytes: int = 2) -> bool:
@@ -268,20 +306,20 @@ def make_attention_fn(
             return not use_flash(seq_len, head_dim, dtype_bytes=dtype_bytes)
 
         single_device.memory_is_quadratic = _quadratic
+        single_device.traced_paths = traced_paths
         return single_device
 
-    batch = tuple(a for a in batch_axes if a in mesh.axis_names)
-    heads = heads_axis if heads_axis in mesh.axis_names else None
     spec = P(batch if batch else None, seq_axis, heads, None)
     body = functools.partial(
-        _ring_attention_local, axis_name=seq_axis, causal=causal
+        _ring_attention_local, axis_name=seq_axis, causal=causal,
+        traced_paths=traced_paths,
     )
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
     def ring(q, k, v):
@@ -306,4 +344,5 @@ def make_attention_fn(
         )
 
     ring.memory_is_quadratic = _ring_quadratic
+    ring.traced_paths = traced_paths
     return ring

@@ -4,19 +4,30 @@ The scale-from-zero cold-start budget (docs/guides/serving-tuning.md,
 "cold start") is dominated by XLA compiling the engine's jitted program
 set on first boot. JAX's persistent compilation cache keys entries on
 the HLO, so a repeat boot of the same model retrieves executables from
-disk instead of recompiling — IF the cache directory survives the
-container. The server's volume plumbing mounts one per durable volume
-(`JAX_COMPILATION_CACHE_DIR`, process_running_jobs.py); workloads opt in
-locally with `DSTACK_TPU_COMPILE_CACHE` or the native server's
-`--compile-cache-dir`.
+disk instead of recompiling — IF the cache directory survives and does
+not move (the directory is part of the key).
 
-VERSION KEYING IS LOAD-BEARING: the serialized executables are jaxlib-
-and backend-specific, and deserializing a foreign entry does not fail
-cleanly — it segfaults (observed on the PR 14 subprocess drills, which
-is why tests/conftest.py long refused to export its cache to children).
-`cache_dir_for` therefore nests every cache under a
-``jax<ver>-jaxlib<ver>-<backend>`` leaf, so one shared volume (or one
-shared /tmp dir) can serve heterogeneous workers: a version bump lands
+WHERE THE CACHE LIVES is decided from outside the program, in this order
+(`enable`):
+
+1. `JAX_COMPILATION_CACHE_DIR` is exported: JAX itself reads it. That
+   directory is used as configured and nothing here points JAX anywhere
+   else — not a flag, not the other variable.
+2. `--compile-cache-dir` / `DSTACK_TPU_COMPILE_CACHE` (the orchestrator
+   exports the latter for a run with a durable volume,
+   process_running_jobs.py): a version-keyed leaf under that base.
+3. Neither: a version-keyed leaf under `.jax-compile-cache/` at the root
+   of the checkout this package was imported from (git-ignored). Always
+   the same path for the same checkout — never a temp name, pid or time —
+   so every process of one machine (trainer, server, tests, chip_smoke.py
+   children) shares it.
+
+VERSION KEYING IS LOAD-BEARING for the directories this module names:
+the serialized executables are jaxlib- and backend-specific, and
+deserializing a foreign entry does not fail cleanly — it segfaults
+(observed on the PR 14 subprocess drills). `cache_dir_for` therefore
+nests every cache under a ``jax<ver>-jaxlib<ver>-<backend>`` leaf, so
+one shared volume can serve heterogeneous workers: a version bump lands
 in a fresh leaf instead of poisoning the old one.
 
 Counters ride JAX's monitoring seam and power the warmup-gated
@@ -33,11 +44,17 @@ import threading
 from typing import Dict, Optional
 
 ENV_VAR = "DSTACK_TPU_COMPILE_CACHE"
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+# Rule 3's base: <checkout>/.jax-compile-cache (this file sits at
+# <checkout>/dstack_tpu/workloads/compile_cache.py).
+DEFAULT_BASE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax-compile-cache",
+)
 
-# Monitoring event names (stable across jax 0.4.x; verified against the
-# pinned jaxlib). backend_compile_duration fires for fresh compiles AND
-# persistent-cache retrievals; the hit/miss events only fire when the
-# persistent cache is enabled.
+# Monitoring event names. backend_compile_duration fires for fresh
+# compiles AND persistent-cache retrievals; the hit/miss events only
+# fire when the persistent cache is enabled.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -111,45 +128,35 @@ def install_counters() -> None:
     monitoring.register_event_duration_secs_listener(_on_duration)
 
 
-def enable(base_dir: str, backend: Optional[str] = None) -> str:
-    """Point JAX's persistent compilation cache at the version-keyed
-    leaf under `base_dir` (created if absent) and install the counters.
-    min_compile_time is forced to 0 so even the tiny programs (table-row
-    setters, block copies) cache — a warm boot must retrieve the WHOLE
-    program set or the first request still pays a compile. Returns the
-    leaf directory."""
-    import jax
+def enable(base_dir: Optional[str] = None) -> str:
+    """Turn the persistent compilation cache on where the module
+    docstring's precedence puts it, install the counters, and return
+    the directory in use. `base_dir` is the caller's flag value (rule 2;
+    empty/None means "not given").
 
+    Under rules 2 and 3 min_compile_time is forced to 0 so even the tiny
+    programs (table-row setters, block copies) cache — a warm boot must
+    retrieve the WHOLE program set or the first request still pays a
+    compile. Under rule 1 the user's JAX configuration is left exactly
+    as exported."""
     global _enabled_dir
-    d = cache_dir_for(base_dir, backend)
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     install_counters()
+    d = os.environ.get(JAX_ENV_VAR)
+    if not d:
+        import jax
+
+        d = cache_dir_for(base_dir or os.environ.get(ENV_VAR) or DEFAULT_BASE)
+        os.makedirs(d, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", d)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     with _lock:
         _enabled_dir = d
     return d
 
 
-def enable_from_env() -> Optional[str]:
-    """`enable()` from DSTACK_TPU_COMPILE_CACHE when set (no-op
-    otherwise). JAX_COMPILATION_CACHE_DIR wins if the user exported it —
-    that path is already live inside JAX and is NOT version-keyed by us;
-    we leave it exactly as configured."""
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        install_counters()
-        with _lock:
-            return _enabled_dir
-    base = os.environ.get(ENV_VAR)
-    if not base:
-        return None
-    return enable(base)
-
-
 def enabled_dir() -> Optional[str]:
-    """The active version-keyed cache leaf, or None when this module
-    never enabled one (a user-exported JAX_COMPILATION_CACHE_DIR does
-    not count — it is not ours to report as version-keyed)."""
+    """The cache directory `enable` last put in use, or None when it was
+    never called in this process."""
     with _lock:
         return _enabled_dir
 

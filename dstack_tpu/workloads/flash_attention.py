@@ -68,8 +68,10 @@ NEG_INF = -1e30
 # back. Empirically verified on v5e: every admitted bf16/hd-128 shape up to
 # the budget boundary (seq 16384, KV exactly 8MB) compiles and runs — as a
 # STANDALONE kernel. Inside a multi-layer model, 1024-wide tiles at
-# seq 8192+ crash the AOT compile helper, which is why _pick_block caps
-# long-sequence tiles at 512 (see its docstring before raising the cap).
+# seq 8192+ crashed the AOT compile helper of the platform in use then,
+# which is why _pick_block caps long-sequence tiles at 512 (see its
+# docstring before raising the cap; the reason has not been re-verified
+# against the current libtpu — ROADMAP S8).
 KV_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
@@ -99,9 +101,10 @@ def use_flash(
     global n_rep of 2 can be per-shard n_rep 1, or fractional). Pass the
     GLOBAL counts plus `model_shards`; the per-shard division happens
     here. `model_shards > 1` currently always answers False: pallas_call
-    carries no SPMD partitioning rule, so inside a GSPMD-partitioned
-    program the kernel would force a full gather of the sharded pools —
-    the lax fallback is what partitions cleanly.
+    carries no SPMD partitioning rule, and inside a GSPMD-partitioned
+    program its lowering is refused outright ("Mosaic kernels cannot be
+    automatically partitioned") — the lax path is what partitions. (The
+    trainer's flash call runs under shard_map instead, attention.py.)
     """
     import os
 

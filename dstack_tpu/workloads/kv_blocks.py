@@ -411,6 +411,14 @@ class BlockAllocator:
 # pools sharded on the KV-head dim, control state replicated — and GSPMD
 # partitions the SAME traced logic; there are no sharded/unsharded code
 # forks. When None (the default), jit behaves exactly as before.
+#
+# `attn_impl` names the ragged-attention implementation the program
+# traces ("pallas" / "lax_ragged"). The engine decides it ONCE from its
+# geometry, backend and shard count (paged_attention.dispatch_path) and
+# hands the same string to every factory and to its dispatch counter, so
+# the path an engine reports is the path its programs traced. None lets
+# `ragged_attention` decide from the operand shapes alone (unsharded
+# library callers and tests).
 
 
 def _jit_shardings(in_shardings, out_shardings):
@@ -420,7 +428,7 @@ def _jit_shardings(in_shardings, out_shardings):
 
 
 def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
-                       lora: bool = False):
+                       lora: bool = False, attn_impl: Optional[str] = None):
     """chunk_prefill(params, state, slot, table_row (MB,), tokens (1, C),
     n_valid, start, budget, temp, top_p, rng, finalize) ->
     (state, first_token ()).
@@ -499,7 +507,9 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
             # masks whatever garbage their attention rows read.
             ck = ck.at[blk, off].set(k[0].astype(ck.dtype), mode="drop")
             cv = cv.at[blk, off].set(v[0].astype(cv.dtype), mode="drop")
-            attn = ragged_attention(q, ck, cv, table_row[None], valid_len[None])
+            attn = ragged_attention(
+                q, ck, cv, table_row[None], valid_len[None], impl=attn_impl
+            )
             x = x + linear(attn, p["wo"])
             if c.n_experts > 0:
                 from dstack_tpu.workloads.moe import moe_block
@@ -560,7 +570,8 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
 
 
 def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
-                           lora: bool = False):
+                           lora: bool = False,
+                           attn_impl: Optional[str] = None):
     """decode_steps(params, state, rng) -> (state, tokens (B, steps),
     active) over a PagedDecodeState — the paged twin of
     serving.make_decode_step. With `lora=True` the program takes a
@@ -626,7 +637,9 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
                 )
             ck = ck.at[blk, off].set(k[:, 0].astype(ck.dtype), mode="drop")
             cv = cv.at[blk, off].set(v[:, 0].astype(cv.dtype), mode="drop")
-            attn = ragged_attention(q, ck, cv, state.block_tables, valid_len)
+            attn = ragged_attention(
+                q, ck, cv, state.block_tables, valid_len, impl=attn_impl
+            )
             x = x + linear(attn, p["wo"])
             if c.n_experts > 0:
                 from dstack_tpu.workloads.moe import moe_block
@@ -725,7 +738,8 @@ def _sampling_probs(logits, temps, top_ps):
     return jax.nn.softmax(filtered, axis=-1)
 
 
-def make_spec_draft(config: ModelConfig, k: int, shardings=None):
+def make_spec_draft(config: ModelConfig, k: int, shardings=None,
+                    attn_impl: Optional[str] = None):
     """spec_draft(params, draft_k, draft_v, block_tables, lengths,
     last_token, active, temps, top_ps, rng) ->
     (draft_k', draft_v', drafts (B, k), qlogits (B, k, V)).
@@ -782,7 +796,9 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None):
                 q, kk, vv = project_qkv(c, x, p, pos[:, None])
                 ck = ck.at[blk, off].set(kk[:, 0].astype(ck.dtype), mode="drop")
                 cv = cv.at[blk, off].set(vv[:, 0].astype(cv.dtype), mode="drop")
-                attn = ragged_attention(q, ck, cv, block_tables, pos[:, None] + 1)
+                attn = ragged_attention(
+                    q, ck, cv, block_tables, pos[:, None] + 1, impl=attn_impl
+                )
                 x = x + linear(attn, p["wo"])
                 if c.n_experts > 0:
                     from dstack_tpu.workloads.moe import moe_block
@@ -815,7 +831,7 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None):
 
 
 def make_spec_verify(config: ModelConfig, k: int, shardings=None,
-                     lora: bool = False):
+                     lora: bool = False, attn_impl: Optional[str] = None):
     """spec_verify(params, state, drafts (B, k), qlogits (B, k, V), rng)
     -> (state', emitted (B, k+1), accepted (B,), active (B,)).
 
@@ -907,7 +923,7 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
             ck = ck.at[blk, off].set(kk.astype(ck.dtype), mode="drop")
             cv = cv.at[blk, off].set(vv.astype(cv.dtype), mode="drop")
             attn = ragged_attention(
-                q, ck, cv, state.block_tables, positions + 1
+                q, ck, cv, state.block_tables, positions + 1, impl=attn_impl
             )
             x = x + linear(attn, p["wo"])
             if c.n_experts > 0:

@@ -17,18 +17,22 @@ Two implementations behind one dispatch seam (`ragged_attention`):
 - `_ragged_attention_pallas`: a Pallas TPU kernel. Block tables ride in
   as scalar-prefetch operands (pallas_guide: PrefetchScalarGridSpec) so
   each grid step's BlockSpec index_map resolves `tables[b, j]` into the
-  pool's block axis and the DMA engine streams exactly that
-  `(block_size, hd)` K/V tile HBM→VMEM — the gather IS the index_map.
-  Softmax state (running max m, denominator l, unnormalized output o)
-  accumulates in VMEM scratch across the innermost grid axis, the
-  standard flash accumulation (same math as `attention._block_attend`).
-  Pad-sentinel table entries (== num_blocks) clamp to a real block in
-  the index_map and are masked out of the logits, as are rows at or
-  beyond each query's `valid_len`. Validated on CPU via interpret=True.
+  pool's block axis and the DMA engine streams exactly that block's
+  `(block_size * KV, hd)` K/V slab HBM→VMEM — the gather IS the
+  index_map. Softmax state (running max m, denominator l, unnormalized
+  output o) accumulates in VMEM scratch across the innermost grid axis,
+  the standard flash accumulation (same math as
+  `attention._block_attend`). Pad-sentinel table entries (== num_blocks)
+  clamp to a real block in the index_map and are masked out of the
+  logits, as are rows at or beyond each query's `valid_len`. The block
+  shapes are chosen for the TPU (8, 128) tiling rule (see the note above
+  the kernel); tests/test_tpu_lowering.py lowers it for platform "tpu"
+  at the engine's shapes and chip_smoke.py compiles and checks it on the
+  chip.
 
-- `_ragged_attention_lax`: pure-lax fallback for CPU tests and
-  bench_serving. Two `lax.fori_loop` passes walk the table columns —
-  softmax stats first (running max + rescaled denominator), then the PV
+- `_ragged_attention_lax`: pure-lax path for CPU, sharded engines and
+  geometries the kernel does not take. Two `lax.fori_loop` passes walk
+  the table columns — softmax stats first (running max + rescaled denominator), then the PV
   accumulation with probabilities normalized at the final stats and
   quantized to q.dtype, reproducing the flat softmax's rounding profile
   (see the function docstring: temperature-0 bit-exactness against the
@@ -87,7 +91,8 @@ def dispatch_path(
     the rule judges the per-shard geometry each partitioned program
     actually sees (and answers "lax_ragged" whenever model_shards > 1 —
     pallas_call has no SPMD partitioning rule; the lax fallback is the
-    path GSPMD partitions).
+    path GSPMD partitions). The engine passes the answer to every
+    program factory as `attn_impl`, so this is also the path it traces.
     """
     from dstack_tpu.workloads.flash_attention import use_flash
 
@@ -217,22 +222,40 @@ def _ragged_attention_lax(q, k_pool, v_pool, tables, valid_len):
 
 
 # ------------------------------------------------------------ pallas kernel
+#
+# TPU block shapes must tile the LAST TWO array dims by (8, 128) — (16, 128)
+# for bf16 — or span them whole. The pool's last two dims are (KV, hd), so a
+# one-head K tile `(bs, 1, hd)` is not a legal block. The kernel instead
+# takes every operand as a 2-D slab whose trailing dims are whole:
+#
+#   pool  (NB, bs, KV, hd) -> (NB, bs*KV, hd)   rows ordered (t, g)
+#   q     (B, S, H, hd)    -> (B, S*H, hd)      rows ordered (s, h)
+#
+# (both reshapes keep the row-major order; with KV a multiple of the
+# sublane tile they are layout bitcasts, not copies). One grid step then
+# multiplies a tile of query rows against ALL of a block's (t, g) rows in a
+# single matmul and masks the pairs whose query head does not belong to the
+# column's KV head — KV times the needed MXU work, spent to keep every
+# load a plain aligned tile: no strided sublane reads, no in-kernel
+# transposes. ROADMAP S2 owns making it fast.
 
 
 def _paged_kernel(
     t_ref,  # scalar prefetch: (B, MB) block tables in SMEM
-    q_ref,  # (1, S, 1, hd)
-    vlen_ref,  # (1, 1, S)
-    k_ref,  # (1, bs, 1, hd) — the block the index_map resolved for step j
-    v_ref,  # (1, bs, 1, hd)
-    o_ref,  # (1, S, 1, hd), revisited across the innermost grid axis
-    acc_ref,  # VMEM scratch (S, hd) f32
-    m_ref,  # VMEM scratch (S, 1) f32
-    l_ref,  # VMEM scratch (S, 1) f32
+    nc_ref,  # scalar prefetch: (B,) table columns row b actually needs
+    q_ref,  # (TQ, hd) query rows, ordered (s, h)
+    vlen_ref,  # (TQ, 1) valid_len of each query row
+    k_ref,  # (bs*KV, hd) — the block the index_map resolved for step j
+    v_ref,  # (bs*KV, hd)
+    o_ref,  # (TQ, hd), revisited across the innermost grid axis
+    acc_ref,  # VMEM scratch (TQ, hd) f32
+    m_ref,  # VMEM scratch (TQ, 1) f32
+    l_ref,  # VMEM scratch (TQ, 1) f32
     *,
-    n_cols: int,
     block_size: int,
     num_pool_blocks: int,
+    num_heads: int,
+    num_kv_heads: int,
     scale: float,
 ):
     b = pl.program_id(0)
@@ -244,42 +267,72 @@ def _paged_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF / 2)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Storage-dtype operands with f32 accumulation, scale applied to the
-    # f32 logits — the same placement as attention._block_attend.
-    q = q_ref[0, :, 0, :]  # (S, hd)
-    k = k_ref[0, :, 0, :]  # (bs, hd)
-    v = v_ref[0, :, 0, :]
-    logits = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (S, bs)
-    # 2D iota (TPU requires >= 2D): key positions per logits column.
-    pos = j * block_size + lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    ok = pos < vlen_ref[0, 0, :][:, None]
-    # Pad-sentinel columns clamp to block NB-1 in the index_map; mask
-    # everything they contributed.
-    ok &= t_ref[b, j] < num_pool_blocks
-    logits = jnp.where(ok, logits, NEG_INF)
+    # Columns past the row's live context hold nothing it may see: the
+    # index_map parks their DMA on the last live block and the body is
+    # skipped, so a short context in an MB-wide table costs grid steps,
+    # not bandwidth or MXU time.
+    @pl.when(j < nc_ref[b])
+    def _attend():
+        # Storage-dtype operands with f32 accumulation, scale applied to
+        # the f32 logits — the same placement as attention._block_attend.
+        q = q_ref[...]
+        k = k_ref[...]
+        v = v_ref[...]
+        logits = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (TQ, bs*KV)
+        # 2D iotas (TPU requires >= 2D). TQ is a multiple of H, so a
+        # tile-local row index resolves the query head.
+        row = lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+        col = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        q_group = lax.div(lax.rem(row, num_heads), num_heads // num_kv_heads)
+        pos = j * block_size + lax.div(col, num_kv_heads)
+        ok = (q_group == lax.rem(col, num_kv_heads)) & (pos < vlen_ref[...])
+        # Pad-sentinel columns clamp to block NB-1 in the index_map; mask
+        # everything they contributed.
+        ok &= t_ref[b, j] < num_pool_blocks
+        logits = jnp.where(ok, logits, NEG_INF)
 
-    blk_m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), NEG_INF / 2)
-    p = jnp.exp(logits - blk_m)
-    blk_l = jnp.sum(p, axis=-1, keepdims=True)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, blk_m)
-    alpha = jnp.exp(m_prev - m_new)
-    beta = jnp.exp(blk_m - m_new)
-    m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * alpha + blk_l * beta
-    pv = lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (S, hd)
-    acc_ref[...] = acc_ref[...] * alpha + beta * pv
+        blk_m = jnp.maximum(
+            jnp.max(logits, axis=-1, keepdims=True), NEG_INF / 2
+        )
+        p = jnp.exp(logits - blk_m)
+        blk_l = jnp.sum(p, axis=-1, keepdims=True)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, blk_m)
+        alpha = jnp.exp(m_prev - m_new)
+        beta = jnp.exp(blk_m - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + blk_l * beta
+        pv = lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (TQ, hd)
+        acc_ref[...] = acc_ref[...] * alpha + beta * pv
 
-    @pl.when(j == n_cols - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _emit():
-        o_ref[0, :, 0, :] = (
+        o_ref[...] = (
             acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         ).astype(o_ref.dtype)
+
+
+# Query rows per grid step: bounds the kernel's VMEM (q/o tiles, the f32
+# accumulators and one (TQ, bs*KV) logits tile) independently of the
+# prefill chunk length.
+_MAX_Q_ROWS = 512
+
+
+def _q_tile_positions(s: int, h: int) -> int:
+    """Query positions per tile: the largest divisor of S whose rows
+    (positions x heads) fit _MAX_Q_ROWS and tile the sublanes; the whole
+    of S when no divisor does (a full-extent block is always legal)."""
+    if s * h <= _MAX_Q_ROWS:
+        return s
+    for ts in range(_MAX_Q_ROWS // h, 0, -1):
+        if s % ts == 0 and (ts * h) % 16 == 0:
+            return ts
+    return s
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -287,43 +340,59 @@ def _ragged_attention_pallas(q, k_pool, v_pool, tables, valid_len, *, interpret=
     b, s, h, hd = q.shape
     nb, bs, kv, _ = k_pool.shape
     mb = tables.shape[1]
-    n_rep = h // kv
-    grid = (b, h, mb)
+    ts = _q_tile_positions(s, h)
+    tq = ts * h
+    grid = (b, s // ts, mb)
 
-    def _table_block(bi, hi, ji, t):
+    valid_len = valid_len.astype(jnp.int32)
+    n_cols = jnp.clip((jnp.max(valid_len, axis=1) + bs - 1) // bs, 1, mb)
+
+    def _table_block(bi, qi, ji, t, nc):
         # The gather IS the index_map: scalar-prefetched tables steer the
         # DMA straight at the slot's j-th block (sentinel clamps in-range;
-        # the kernel masks its rows).
-        return (jnp.minimum(t[bi, ji], nb - 1), 0, hi // n_rep, 0)
+        # the kernel masks its rows). Past the row's last live column the
+        # index stays put, and an unchanged block index is not re-fetched.
+        live = jnp.minimum(ji, nc[bi] - 1)
+        return (jnp.minimum(t[bi, live], nb - 1), 0, 0)
+
+    def _q_rows(bi, qi, ji, t, nc):
+        return (bi, qi, 0)
 
     kernel = functools.partial(
         _paged_kernel,
-        n_cols=mb,
         block_size=bs,
         num_pool_blocks=nb,
+        num_heads=h,
+        num_kv_heads=kv,
         scale=hd ** -0.5,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, s, 1, hd), lambda bi, hi, ji, t: (bi, 0, hi, 0)),
-                pl.BlockSpec((1, 1, s), lambda bi, hi, ji, t: (bi, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd), _table_block),
-                pl.BlockSpec((1, bs, 1, hd), _table_block),
+                pl.BlockSpec((None, tq, hd), _q_rows),
+                pl.BlockSpec((None, tq, 1), _q_rows),
+                pl.BlockSpec((None, bs * kv, hd), _table_block),
+                pl.BlockSpec((None, bs * kv, hd), _table_block),
             ],
-            out_specs=pl.BlockSpec(
-                (1, s, 1, hd), lambda bi, hi, ji, t: (bi, 0, hi, 0)
-            ),
+            out_specs=pl.BlockSpec((None, tq, hd), _q_rows),
             scratch_shapes=[
-                pltpu.VMEM((s, hd), jnp.float32),
-                pltpu.VMEM((s, 1), jnp.float32),
-                pltpu.VMEM((s, 1), jnp.float32),
+                pltpu.VMEM((tq, hd), jnp.float32),
+                pltpu.VMEM((tq, 1), jnp.float32),
+                pltpu.VMEM((tq, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s * h, hd), q.dtype),
         interpret=interpret,
-    )(tables, q, valid_len[:, None, :].astype(jnp.int32), k_pool, v_pool)
+        name="ragged_paged_attention",
+    )(
+        tables,
+        n_cols,
+        q.reshape(b, s * h, hd),
+        jnp.repeat(valid_len, h, axis=1)[:, :, None],
+        k_pool.reshape(nb, bs * kv, hd),
+        v_pool.reshape(nb, bs * kv, hd),
+    )
     return out.reshape(b, s, h * hd)

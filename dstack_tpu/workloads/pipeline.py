@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dstack_tpu.workloads.attention import make_attention_fn
@@ -273,12 +272,12 @@ def make_pipeline_train_step(
                 P(), _param_specs(state.params), _param_specs(state.opt_state)
             )
             batch_specs = {k: P("data") for k in batch}
-            inner = shard_map(
+            inner = jax.shard_map(
                 step,
                 mesh=mesh,
                 in_specs=(state_specs, batch_specs),
                 out_specs=(state_specs, {"loss": P(), "grad_norm": P()}),
-                check_rep=False,
+                check_vma=False,
             )
             _cache[key] = jax.jit(inner, donate_argnums=0)
         return _cache[key](state, batch)

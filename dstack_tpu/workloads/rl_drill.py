@@ -1,5 +1,12 @@
 """Headless Sebulba RL gang drill (`make drill-rl`).
 
+A CPU COUNT-CHECK, NOT A DEVICE MEASUREMENT: the learner (this parent
+process) holds the JAX device while every actor subprocess is pinned to
+JAX_PLATFORMS=cpu, so the drill refuses to start unless
+JAX_PLATFORMS=cpu is exported (utils/devices.py). It needs per-chip
+process placement — learner and actors each on their own chip — before
+it can become a benchmark cell (ROADMAP D7).
+
 Topology: this (parent) process is the LEARNER — PPO updates, the
 WeightRefreshServer, the TrajectorySink, a /metrics endpoint rendering
 the RL metric series — and each ACTOR is a real OS subprocess
@@ -47,6 +54,8 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
+from dstack_tpu.utils.devices import require_cpu_request
+
 _REPO_ROOT = str(Path(__file__).resolve().parents[2])
 
 RUN_NAME = "rl-drill"
@@ -54,7 +63,6 @@ PROMPT_LEN = 4
 HORIZON = 8
 BATCH = 4
 TARGET = 7
-CACHE_DIR = "/tmp/rl_drill_jax_cache"
 
 
 def _free_port() -> int:
@@ -72,7 +80,9 @@ def actor_main(args) -> int:
     os.environ.setdefault("DSTACK_RUN_NAME", RUN_NAME)
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    from dstack_tpu.workloads import compile_cache
+
+    compile_cache.enable()
     from dstack_tpu.workloads.rl import (
         Actor, TargetTokenEnv, TrajectoryClient, WeightRefreshClient,
         tiny_rl_config,
@@ -162,10 +172,11 @@ def _spawn_actor(actor_id: int, *, seed: int, refresh_port: int,
 
 def run_drill(*, seed: int = 0, updates_per_phase: int = 2,
               echo: bool = False, timeout_s: float = 420.0) -> Dict:
+    require_cpu_request("rl_drill")
     os.environ["DSTACK_RUN_NAME"] = RUN_NAME
-    import jax
+    from dstack_tpu.workloads import compile_cache
 
-    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    compile_cache.enable()
     from dstack_tpu.workloads.rl import (
         Learner, RLStats, TrajectorySink, WeightRefreshServer,
         rl_prometheus_metrics, tiny_rl_config,

@@ -315,9 +315,8 @@ def make_decode_step(config: ModelConfig, steps: int = 1):
 
     `steps` tokens for every active slot per call — the inner scan stays on
     device, so one host sync delivers a chunk of tokens per slot. Larger
-    chunks amortize dispatch/readback latency (critical over tunneled
-    transports, still a win locally) at the cost of up-to-`steps`-step
-    admission latency for new requests. Sampling is per SLOT from
+    chunks amortize dispatch/readback latency at the cost of
+    up-to-`steps`-step admission latency for new requests. Sampling is per SLOT from
     `state.temperature` (0 = greedy argmax, else categorical at that
     temperature — requests with different temperatures share one decode
     batch; the engine assigns its default to requests that don't
@@ -357,6 +356,13 @@ class EngineOverloadedError(RuntimeError):
         )
         self.pending = pending
         self.retry_after = retry_after
+
+
+class EngineBusyError(RuntimeError):
+    """warmup() refused because the engine is not idle: a request was
+    admitted first. Its own type so that a caller can tell this benign
+    refusal from a program that failed to build — compiler and runtime
+    errors are RuntimeErrors too."""
 
 
 class _Request(NamedTuple):
@@ -485,14 +491,12 @@ class ServingEngine:
         max_resident_slots: Optional[int] = None,
         qos_weights: Optional[Dict[str, float]] = None,
     ):
-        # Persistent compile cache (workloads/compile_cache.py): honors
-        # DSTACK_TPU_COMPILE_CACHE before any jitted program below is
-        # built, so a repeat boot of the same model retrieves its whole
-        # program set from disk instead of recompiling. The monitoring
-        # counters back warmup()'s zero-post-ready-compile contract and
-        # are installed even when no cache dir is configured.
-        self._compile_cache_dir = compile_cache.enable_from_env()
-        compile_cache.install_counters()
+        # Persistent compile cache, placed by compile_cache.enable()'s
+        # precedence, live before any jitted program below is built: a
+        # repeat boot of the same model retrieves its whole program set
+        # from disk instead of recompiling. The monitoring counters it
+        # installs back warmup()'s zero-post-ready-compile contract.
+        self._compile_cache_dir = compile_cache.enable()
         self.config = config
         self.params = params
         self.slots = slots
@@ -628,6 +632,8 @@ class ServingEngine:
                 mesh, self.params, self.state
             )
             self.state = jax.device_put(self.state, self._shardings.state)
+        else:
+            self.state = self._commit(self.state)
         # -- multi-tenant LoRA (lora_max_adapters > 0) --------------------
         # A refcounted host registry over a device-side adapter pool; the
         # jitted programs below are built with lora=True so every batched
@@ -653,9 +659,16 @@ class ServingEngine:
         # _release_adapter pops exactly once per request (guarded by
         # _lock like all scheduler state).
         self._adapter_holds: Dict[Any, str] = {}
+        # Which ragged-attention implementation this engine runs: one
+        # static decision (shape + backend + shard count) that every
+        # jitted program below is built with AND that labels
+        # dstack_tpu_serving_attn_dispatch_total{path=...} — the reported
+        # path is the traced path, sharded or not.
+        self._attn_path = self._resolve_attn_path(config)
+        self._attn_dispatch = {"pallas": 0, "lax_ragged": 0}
         self._step = make_paged_decode_step(
             config, steps=steps_per_sync, shardings=self._shardings,
-            lora=self._lora is not None,
+            lora=self._lora is not None, attn_impl=self._attn_path,
         )
         # Plain twin for LoRA engines: while no request holds an adapter
         # ref the loop dispatches this instead — the LoRA program's
@@ -665,19 +678,9 @@ class ServingEngine:
         self._step_base = self._step if self._lora is None else \
             make_paged_decode_step(
                 config, steps=steps_per_sync, shardings=self._shardings,
+                attn_impl=self._attn_path,
             )
         self._copy_block = make_copy_block(shardings=self._shardings)
-        # Which ragged-attention implementation this engine's geometry
-        # dispatches (static per engine: shape + backend decide), and
-        # how many jitted-program dispatches ran it — exposed as
-        # dstack_tpu_serving_attn_dispatch_total{path=...}.
-        self._attn_path = attn_dispatch_path(
-            self.max_len, config.head_dim, kv_block_size,
-            dtype_bytes=jnp.dtype(config.activation_dtype).itemsize,
-            num_heads=config.n_heads, num_kv_heads=config.n_kv_heads,
-            model_shards=self._model_shards,
-        )
-        self._attn_dispatch = {"pallas": 0, "lax_ragged": 0}
         # -- speculative decoding (drafter proposes k, target verifies
         # k+1 in one forward; see kv_blocks.make_spec_draft/_verify).
         self._spec = bool(spec_enable)
@@ -694,6 +697,8 @@ class ServingEngine:
                     * jnp.dtype(cfg.activation_dtype).itemsize)
 
         self._draft_config = spec_draft_config or config
+        # The drafter's programs attend over its own pool geometry.
+        self._draft_attn_path = self._resolve_attn_path(self._draft_config)
         # Exposed so deployment surfaces (and tests) can size
         # kv_budget_bytes against the actual pool footprint.
         self._pool_bytes_target = _pool_bytes(config)
@@ -761,6 +766,8 @@ class ServingEngine:
                 self._draft_state = jax.device_put(
                     self._draft_state, self._draft_shardings.state
                 )
+            else:
+                self._draft_state = self._commit(self._draft_state)
             self._copy_draft_block = make_copy_block(
                 shardings=self._draft_shardings
             )
@@ -1029,6 +1036,29 @@ class ServingEngine:
             self._ttft_cold_hist.observe(dt)
             self._cold_over = True
 
+    def _commit(self, state):
+        """Place a fresh (unsharded) state on the params' device as
+        COMMITTED arrays. Every program returns committed state once any
+        input is committed — and checkpoint-restored params are — while
+        `init_paged_state` hands out uncommitted arrays. jit keys its
+        cache on that difference, so a state that starts uncommitted
+        makes the first live dispatch of whatever program warmup ran
+        FIRST re-trace and re-build it after /readyz."""
+        leaf = jax.tree_util.tree_leaves(self.params)[0]
+        device = (
+            next(iter(leaf.devices())) if isinstance(leaf, jax.Array)
+            else jax.devices()[0]
+        )
+        return jax.device_put(state, device)
+
+    def _resolve_attn_path(self, config: ModelConfig) -> str:
+        return attn_dispatch_path(
+            self.max_len, config.head_dim, self._block_size,
+            dtype_bytes=jnp.dtype(config.activation_dtype).itemsize,
+            num_heads=config.n_heads, num_kv_heads=config.n_kv_heads,
+            model_shards=self._model_shards,
+        )
+
     def _warmup_idle_check(self) -> None:
         """Raise unless the engine is at the idle boundary warmup needs
         (same invariant as refresh_params: warmup invokes the real
@@ -1041,7 +1071,7 @@ class ServingEngine:
             or not self._pending.empty()
         )
         if busy:
-            raise RuntimeError(
+            raise EngineBusyError(
                 "warmup requires an idle engine: call it before serving"
                 " traffic (readiness gating) or after a drain"
             )
@@ -1075,7 +1105,7 @@ class ServingEngine:
         markers for the run timeline, and reports the compile-counter
         delta (workloads/compile_cache.py) so callers can tell fresh
         compiles from persistent-cache retrievals. Only legal on an idle
-        engine (RuntimeError otherwise); admission stays held for the
+        engine (EngineBusyError otherwise); admission stays held for the
         duration. Returns {"seconds", "programs", "compiles",
         "cache_hits", "cache_misses", "compile_seconds"}.
         """
@@ -1785,7 +1815,7 @@ class ServingEngine:
         if fn is None:
             fn = make_chunk_prefill(
                 self.config, n_padded, shardings=self._shardings,
-                lora=lora,
+                lora=lora, attn_impl=self._attn_path,
             )
             self._chunk_cache[(n_padded, lora)] = fn
         return fn
@@ -1798,6 +1828,7 @@ class ServingEngine:
             fn = make_chunk_prefill(
                 self._draft_config, n_padded,
                 shardings=self._draft_shardings,
+                attn_impl=self._draft_attn_path,
             )
             self._draft_chunk_cache[n_padded] = fn
         return fn
@@ -1806,7 +1837,8 @@ class ServingEngine:
         fn = self._spec_draft_fns.get(k)
         if fn is None:
             fn = make_spec_draft(
-                self._draft_config, k, shardings=self._draft_shardings
+                self._draft_config, k, shardings=self._draft_shardings,
+                attn_impl=self._draft_attn_path,
             )
             self._spec_draft_fns[k] = fn
         return fn
@@ -1816,7 +1848,7 @@ class ServingEngine:
         if fn is None:
             fn = make_spec_verify(
                 self.config, k, shardings=self._shardings,
-                lora=lora,
+                lora=lora, attn_impl=self._attn_path,
             )
             self._spec_verify_fns[(k, lora)] = fn
         return fn
@@ -2015,7 +2047,7 @@ class ServingEngine:
                     self._draft_params, self._draft_state, *chunk_args,
                     dsub, jnp.asarray(final, bool),
                 )
-                self._attn_dispatch[self._attn_path] += 1
+                self._attn_dispatch[self._draft_attn_path] += 1
             task.pos += n
             budget -= n
             self._prefill_chunks += 1
@@ -3150,7 +3182,8 @@ class ServingEngine:
         still = jax.device_get(active)
         acc = jax.device_get(accepted)
         t_sync = time.monotonic()
-        self._attn_dispatch[self._attn_path] += 2  # draft + verify programs
+        self._attn_dispatch[self._draft_attn_path] += 1
+        self._attn_dispatch[self._attn_path] += 1
         self._chunk_s = self._ewma(self._chunk_s, t_sync - t_pf)
         self._t_decode += t_sync - t_pf
         self._last_chunk_s = t_sync - t_pf

@@ -1,5 +1,13 @@
 """Two-process prefill/decode disaggregation drill.
 
+A CPU COUNT-CHECK, NOT A DEVICE MEASUREMENT: the parent builds its
+unified reference engine with JAX while both workers are pinned to
+JAX_PLATFORMS=cpu on virtual devices, so the drill refuses to start
+unless JAX_PLATFORMS=cpu is exported (utils/devices.py). It needs
+per-chip process placement — prefill and decode tier each on their own
+chips, no JAX in the parent — before it can become a benchmark cell
+(ROADMAP D7).
+
 `python -m dstack_tpu.workloads.serving_disagg` spawns a DECODE worker
 and a PREFILL worker as separate OS processes (each optionally
 tensor-parallel over a virtual CPU mesh via
@@ -37,6 +45,7 @@ import time
 from pathlib import Path
 from typing import Any, Dict, List, Optional
 
+from dstack_tpu.utils.devices import require_cpu_request
 from dstack_tpu.workloads.kv_transfer import recv_msg, send_msg
 
 _REPO_ROOT = str(Path(__file__).resolve().parents[2])
@@ -469,6 +478,7 @@ def run_drill(mesh_model: int = 2, spec: bool = False,
         {"prompt": list(range(2, 50)), "max_new": 47},    # long decode
     ]
 
+    require_cpu_request("serving_disagg drill")
     log(f"reference: unified single-process engine (spec={spec})")
     import jax
 

@@ -7,6 +7,7 @@ ppermutes (seq ring attention). Optimizer is AdamW with f32 moments sharded
 exactly like their params, so optimizer memory scales down with fsdp.
 """
 
+import functools
 import signal as _signal
 import sys as _sys
 from typing import Any, Dict, NamedTuple, Optional, Tuple
@@ -24,7 +25,6 @@ from dstack_tpu.workloads.config import ModelConfig
 from dstack_tpu.workloads.sharding import (
     BATCH_SPEC,
     param_shardings,
-    shard_tree,
 )
 from dstack_tpu.workloads.transformer import forward, init_params, logits_linear
 
@@ -60,6 +60,43 @@ def make_optimizer(
     )
 
 
+@functools.lru_cache(maxsize=16)
+def _make_init(
+    config: ModelConfig,
+    mesh: Optional[Mesh],
+    learning_rate: float,
+    warmup_steps: int,
+    decay_steps: int,
+):
+    """`init(key) -> TrainState` for one (config, mesh, schedule): built
+    once and memoized, so a process that initializes the same state
+    again (a resize, a test) re-traces nothing.
+
+    With a mesh the state is BORN SHARDED: the init is jitted with the
+    state's target `out_shardings`, so each device materializes only its
+    own shard of the params and Adam moments. Building the state
+    unsharded and then device_put-ing it parks the whole train state on
+    device 0 first — most of a 16 GB chip for smol-1b at full depth.
+    Threefry's partitionable mode (JAX's default) makes the values
+    independent of the layout."""
+    optimizer = make_optimizer(
+        learning_rate, warmup_steps=warmup_steps, decay_steps=decay_steps
+    )
+
+    def init(key):
+        params = init_params(config, key)
+        return TrainState(
+            jnp.zeros((), jnp.int32), params, optimizer.init(params)
+        )
+
+    if mesh is None:
+        return init
+    shardings = param_shardings(
+        mesh, jax.eval_shape(init, jax.random.PRNGKey(0))
+    )
+    return jax.jit(init, out_shardings=shardings)
+
+
 def init_train_state(
     config: ModelConfig,
     key: jax.Array,
@@ -73,18 +110,13 @@ def init_train_state(
     # a different opt-state structure than a constant-lr one.
     # First touch of the accelerator in a typical trainer: the timeline's
     # env_ready -> tpu_init gap is import + device-discovery cost.
-    # Persistent-cache opt-in must land before anything compiles, so the
+    # The persistent cache must be live before anything compiles, so the
     # train_step build below can be a disk retrieval on a repeat boot.
-    compile_cache.enable_from_env()
+    compile_cache.enable()
     auto_stage("tpu_init")
-    params = init_params(config, key)
-    opt_state = make_optimizer(
-        learning_rate, warmup_steps=warmup_steps, decay_steps=decay_steps
-    ).init(params)
-    state = TrainState(jnp.zeros((), jnp.int32), params, opt_state)
-    if mesh is not None:
-        state = shard_tree(mesh, state)
-    return state
+    return _make_init(
+        config, mesh, learning_rate, warmup_steps, decay_steps
+    )(key)
 
 
 def ce_from_logits(
@@ -267,7 +299,9 @@ def make_train_step(
         return new_state, {"loss": loss, "grad_norm": gnorm, "router_aux": aux}
 
     if mesh is None:
-        return _staged_step(jax.jit(train_step, donate_argnums=0))
+        return _staged_step(
+            jax.jit(train_step, donate_argnums=0), attention_fn.traced_paths
+        )
 
     def shardings_of(tree):
         return param_shardings(mesh, tree)
@@ -300,15 +334,17 @@ def make_train_step(
             )
         return _cache[key](state, batch)
 
-    return _staged_step(jitted)
+    return _staged_step(jitted, attention_fn.traced_paths)
 
 
-def _staged_step(step_fn):
+def _staged_step(step_fn, attention_paths):
     """Bracket the FIRST invocation with compile_start/compile_end and
     first_step timeline markers (no-ops outside an orchestrated run). The
     first call is synced with block_until_ready so compile_end measures the
     actual compile+first-execute wall, not async dispatch; later calls go
-    through untouched."""
+    through untouched. The returned step carries `attention_paths`, the
+    attention implementations its traces took (attention.make_attention_fn)
+    — empty until the first call has traced."""
     holder = {"first": True}
 
     def stepped(state, batch):
@@ -322,6 +358,7 @@ def _staged_step(step_fn):
         auto_stage("first_step")
         return out
 
+    stepped.attention_paths = attention_paths
     return stepped
 
 

@@ -17,8 +17,12 @@ import argparse
 import codecs
 import itertools
 import json
+import math
+import os
+import sys
 import threading
 import time
+import traceback
 from collections import defaultdict
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -39,6 +43,7 @@ from dstack_tpu.workloads.lora_serving import (
     AdapterPoolFullError,
 )
 from dstack_tpu.workloads.serving import (
+    EngineBusyError,
     EngineOverloadedError,
     ServingEngine,
     prometheus_metrics,
@@ -65,8 +70,10 @@ class Engine:
                  qos_burst: float = 20.0, qos_tenant_cap: int = 64,
                  qos_weights=None, kv_host_budget_mb: int = 0,
                  max_resident_slots: int = 0,
-                 trace_ring: int = 256, trace_slow_ms=None):
+                 trace_ring: int = 256, trace_slow_ms=None, layers: int = 0):
         self.config = PRESETS[preset]
+        if layers > 0:
+            self.config = self.config.with_(n_layers=layers)
         if max_new_tokens >= self.config.max_seq_len:
             raise SystemExit(
                 f"--max-new-tokens {max_new_tokens} must be <"
@@ -358,7 +365,7 @@ class Engine:
                 # max(0.0, nan) is 0.0 — NaN would silently mean GREEDY
                 # instead of "malformed: engine default" (the engine
                 # itself rejects NaN with 400; match the top_p branch).
-                if v == v and v != float("inf"):
+                if math.isfinite(v):
                     temp = max(0.0, v)
             except (TypeError, ValueError):
                 pass  # malformed: engine default
@@ -520,6 +527,10 @@ class Engine:
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--preset", default="smol-1b", choices=sorted(PRESETS))
+    parser.add_argument("--layers", type=int, default=0,
+                        help="serve the preset cut to this many layers"
+                             " (0 = the preset's depth); must match the"
+                             " checkpoint's")
     parser.add_argument("--port", type=int, default=9000)
     parser.add_argument("--model-name", default="dstack-tpu-native")
     parser.add_argument("--max-new-tokens", type=int, default=64)
@@ -534,8 +545,10 @@ def main() -> None:
                              " compiled programs from disk instead of"
                              " recompiling. Keyed by jax+jaxlib version"
                              " and backend under the base, so one volume"
-                             " serves heterogeneous workers. Defaults to"
-                             " $DSTACK_TPU_COMPILE_CACHE when unset")
+                             " serves heterogeneous workers. An exported"
+                             " JAX_COMPILATION_CACHE_DIR wins over this"
+                             " flag; unset, $DSTACK_TPU_COMPILE_CACHE,"
+                             " then .jax-compile-cache/ in the checkout")
     parser.add_argument("--no-warmup", action="store_true",
                         help="skip the warmup pass that pre-compiles every"
                              " jitted engine program before /readyz flips"
@@ -684,12 +697,8 @@ def main() -> None:
             )
     # The cache must be live before the Engine constructor touches the
     # accelerator — weight init and the warmup pass below both compile.
-    if args.compile_cache_dir:
-        leaf = compile_cache.enable(args.compile_cache_dir)
-    else:
-        leaf = compile_cache.enable_from_env()
-    if leaf:
-        print(f"compile cache: {leaf}", flush=True)
+    print(f"compile cache: {compile_cache.enable(args.compile_cache_dir)}",
+          flush=True)
     engine = Engine(args.preset, args.max_new_tokens, args.checkpoint_dir,
                     quantize=args.quantize, max_pending=args.max_pending,
                     slots=args.slots, steps_per_sync=args.steps_per_sync,
@@ -710,7 +719,7 @@ def main() -> None:
                     kv_host_budget_mb=args.kv_host_budget_mb,
                     max_resident_slots=args.max_resident_slots,
                     trace_ring=args.trace_ring,
-                    trace_slow_ms=args.trace_slow_ms)
+                    trace_slow_ms=args.trace_slow_ms, layers=args.layers)
 
     # Warmup-gated readiness: /readyz answers 503 until the engine's
     # warmup pass has built every jitted program, so an orchestrator that
@@ -1144,11 +1153,20 @@ def main() -> None:
         def _warm() -> None:
             try:
                 r = engine.serving.warmup()
-            except RuntimeError as e:
+            except EngineBusyError as e:
                 # A request raced admission before warmup started (the
                 # idle-check refused). Readiness still flips — the racer
                 # is paying the compiles warmup would have.
                 print(f"warmup skipped: {e}", flush=True)
+            except Exception:
+                # A program that did not build (lowering error, Mosaic
+                # refusal, VMEM limit, OOM): a server that cannot decode
+                # must not stay up answering /healthz 200 and /readyz 503
+                # forever, let alone turn ready. This is a daemon thread,
+                # so raising would only end the thread — end the process.
+                traceback.print_exc()
+                print("warmup failed: exiting", file=sys.stderr, flush=True)
+                os._exit(1)
             else:
                 print(
                     f"warmup: {r['programs']} programs in"

@@ -26,9 +26,28 @@ from dstack_tpu.workloads.train import (
 )
 
 
+def _print_device_memory(when: str) -> None:
+    """bytes_in_use per local device — shows at a glance whether the
+    train state is spread over the mesh or parked on one chip. Backends
+    without memory stats (CPU) print nothing."""
+    used = [
+        (d.memory_stats() or {}).get("bytes_in_use")
+        for d in jax.local_devices()
+    ]
+    if any(u is not None for u in used):
+        gib = " ".join(f"{(u or 0) / 2**30:.2f}" for u in used)
+        print(f"device memory {when}: {gib} GiB in use per device")
+
+
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--preset", default="smol-1b", choices=sorted(PRESETS))
+    parser.add_argument(
+        "--layers", type=int, default=0,
+        help="train the preset cut to this many layers (0 = the preset's"
+             " depth): full width on a chip whose memory the full depth's"
+             " train state does not fit",
+    )
     parser.add_argument("--steps", type=int, default=100)
     parser.add_argument("--batch-size", type=int, default=8)
     parser.add_argument("--seq-len", type=int, default=2048)
@@ -56,10 +75,14 @@ def main() -> None:
         jax.distributed.initialize()
     print(
         f"process {jax.process_index()}/{jax.process_count()} sees"
-        f" {jax.local_device_count()} local / {jax.device_count()} global devices"
+        f" {jax.local_device_count()} local / {jax.device_count()} global"
+        f" devices (platform {jax.devices()[0].platform},"
+        f" {jax.devices()[0].device_kind})"
     )
 
     config = PRESETS[args.preset]
+    if args.layers > 0:
+        config = config.with_(n_layers=args.layers)
     if args.seq_len > config.max_seq_len:
         raise SystemExit(f"--seq-len > {config.max_seq_len} for {args.preset}")
     if args.expert_parallel > 1 and config.n_experts % args.expert_parallel:
@@ -68,6 +91,15 @@ def main() -> None:
         jax.devices(), model=args.model_parallel, seq=args.seq_parallel,
         expert=args.expert_parallel,
     )
+    if jax.process_index() == 0:
+        print(
+            f"model {args.preset}: layers={config.n_layers}"
+            f" d_model={config.d_model} heads={config.n_heads}x{config.head_dim}"
+            f" kv_heads={config.n_kv_heads} d_ff={config.d_ff}"
+            f" vocab={config.vocab_size} dtype={config.dtype};"
+            f" batch={args.batch_size} seq={args.seq_len}"
+            f" mesh={dict(mesh.shape)}"
+        )
     # One state + one step either way; LoRA swaps in the tiny adapter
     # state and a step closed over the frozen base — data, checkpoints,
     # and the loop below are shared.
@@ -135,11 +167,18 @@ def main() -> None:
     else:
         batch = synthetic_batch(config, batch_size, args.seq_len, mesh=mesh)
 
+    _print_device_memory("after init")
     start = int(state.step)  # nonzero after a resume
     for i in range(start, args.steps):
         if loader is not None:
             batch = next(loader)
         state, metrics = step(state, batch)
+        if i == start:
+            # What the first step's trace actually ran, not a prediction.
+            paths = getattr(step, "attention_paths", None)
+            if paths and jax.process_index() == 0:
+                print(f"attention path: {','.join(sorted(paths))}")
+            _print_device_memory("after first step")
         if i % 10 == 0 or i == args.steps - 1:
             loss = float(metrics["loss"])
             if jax.process_index() == 0:

@@ -15,7 +15,6 @@ import sys
 from pathlib import Path
 
 from dstack_tpu.server.http import response_json
-from tests.conftest import _SHARED_CACHE_LEAF
 from tests.server.conftest import make_server
 
 REPO = Path(__file__).resolve().parent.parent.parent
@@ -49,12 +48,10 @@ async def test_native_model_serving_end_to_end():
                         "env": {
                             "PYTHONPATH": str(REPO),
                             "JAX_PLATFORMS": "cpu",
-                            # Warm the replica's warmup pass from the
-                            # suite's shared compile cache: a cold one
-                            # holds admission ~30s (tests/conftest.py).
-                            **({"JAX_COMPILATION_CACHE_DIR":
-                                _SHARED_CACHE_LEAF}
-                               if _SHARED_CACHE_LEAF else {}),
+                            # Boot from the suite's shared compile cache
+                            # (tests/conftest.py exports it).
+                            "JAX_COMPILATION_CACHE_DIR":
+                                os.environ["JAX_COMPILATION_CACHE_DIR"],
                         },
                         "resources": {"cpu": "1..", "memory": "0.1.."},
                     },

@@ -16,7 +16,7 @@ import pytest
 
 from dstack_tpu.workloads import compile_cache
 from dstack_tpu.workloads.config import PRESETS
-from dstack_tpu.workloads.serving import ServingEngine
+from dstack_tpu.workloads.serving import EngineBusyError, ServingEngine
 from dstack_tpu.workloads.transformer import init_params
 
 CFG = PRESETS["tiny"].with_(remat=False)
@@ -51,9 +51,13 @@ def _burst(engine):
 
 
 def test_warmup_then_burst_compiles_nothing(params):
+    # COMMITTED params, as a checkpoint restore hands them out (init_params
+    # returns uncommitted arrays): every program then returns committed
+    # state, and an engine whose state started uncommitted re-built the
+    # program warmup ran first on the first live dispatch.
     engine = ServingEngine(
-        CFG, params, slots=2, max_len=64, prefill_chunk_tokens=16,
-        kv_block_size=8,
+        CFG, jax.device_put(params, jax.devices()[0]), slots=2, max_len=64,
+        prefill_chunk_tokens=16, kv_block_size=8,
     )
     try:
         stats = engine.stats()
@@ -113,7 +117,9 @@ def test_warmup_requires_idle_engine(params):
     engine = ServingEngine(CFG, params, slots=1, max_len=64)
     try:
         q = engine.submit([5, 7, 11], max_new_tokens=30)
-        with pytest.raises(RuntimeError, match="idle"):
+        # Its own type: a caller must be able to tell this refusal from
+        # a program that failed to build (also a RuntimeError).
+        with pytest.raises(EngineBusyError, match="idle"):
             engine.warmup()
         _drain(q)
     finally:
