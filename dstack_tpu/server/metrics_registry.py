@@ -132,6 +132,12 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # Ragged paged attention: jitted-program dispatches per
     # implementation (path = "pallas" | "lax_ragged").
     "dstack_tpu_serving_attn_dispatch_total": ("counter", ("path",)),
+    # Work at the scheduler's boundary: steps of every launched decode
+    # chunk or speculation round, the same times the slots live at
+    # launch, and the tokens those steps emitted.
+    "dstack_tpu_serving_decode_slot_steps_total": ("counter", ()),
+    "dstack_tpu_serving_decode_steps_total": ("counter", ()),
+    "dstack_tpu_serving_decode_tokens_total": ("counter", ()),
     "dstack_tpu_serving_kv_blocks_cached": ("gauge", ()),
     "dstack_tpu_serving_kv_blocks_in_use": ("gauge", ()),
     "dstack_tpu_serving_kv_cow_copies_total": ("counter", ()),
@@ -156,6 +162,13 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_kv_transfer_bytes_total": ("counter", ()),
     "dstack_tpu_serving_kv_transfer_queue_depth": ("gauge", ()),
     "dstack_tpu_serving_kv_transfer_seconds": ("histogram", ("role",)),
+    # The engine loop's own time (utils/flight_recorder.PhaseClock): host
+    # wall seconds per phase (wait/admit/grow/dispatch/sync/barrier/
+    # fan_out, and admit's children as "admit/match"), whole cycles, and
+    # the cycles of a second or more.
+    "dstack_tpu_serving_loop_cycles_total": ("counter", ()),
+    "dstack_tpu_serving_loop_phase_seconds_total": ("counter", ("phase",)),
+    "dstack_tpu_serving_loop_slow_cycles_total": ("counter", ()),
     "dstack_tpu_serving_pending_requests": ("gauge", ()),
     # Per-request phase breakdown (PR 15 flight recorder): telescoping
     # phase durations — queue_wait/prefill/kv_ship/kv_adopt/decode/... —

@@ -24,6 +24,11 @@ design constraints come from where it sits:
   Dapper insight that the interesting traces live in the tail. The tail
   store is itself a bounded overwrite-oldest ring.
 
+`PhaseClock` applies the same telescoping construction to the engine
+*loop* that serves those requests: one `mark(phase)` feeds an always-on
+counter and, when the engine hands it an annotation factory, a span of
+the same name on the profiler's timeline.
+
 The module is stdlib-only (plus the server's histogram primitive) so the
 dataplane worker can import it without pulling in JAX.
 """
@@ -52,6 +57,25 @@ PHASES = (
 )
 
 _TERMINAL = ("ok", "error", "shed", "cancelled")
+
+# Phases of one engine-loop iteration, in order (the loop's counters,
+# Prometheus labels and `engine/<phase>` span names are generated from
+# these). `wait` is the only phase outside a cycle.
+LOOP_PHASES = (
+    "wait",      # nothing to do (or a starved pool's 1 ms sleep): device idle, rightly
+    "admit",     # readmit / preempt / prefill chunks / adopt: idle until a chunk launches
+    "grow",      # decode-block growth; a launched prefill chunk runs meanwhile
+    "dispatch",  # rng split + decode launch (spec: draft launch -> verify launch)
+    "sync",      # device_get: the host is blocked on a busy device
+    "barrier",   # first-token order barrier: device idle
+    "fan_out",   # tokens to consumers, retire slots: device idle
+)
+# Nested work inside a phase, as "<phase>/<child>".
+LOOP_CHILDREN = ("admit/match", "admit/chunk_args", "admit/chunk_launch")
+# A cycle this long is a stall (the longest healthy cycle on the benchmark
+# ledger is ~0.27 s): counted, and the last few kept whole.
+SLOW_CYCLE_SECONDS = 1.0
+SLOW_CYCLES_KEPT = 8
 
 
 class RequestTrace:
@@ -310,3 +334,181 @@ class FlightRecorder:
     def phase_histograms(self) -> Dict[str, Dict[str, Any]]:
         with self._lock:
             return {p: h.to_dict() for p, h in self.phase_hist.items()}
+
+
+class PhaseClock:
+    """The engine loop's own timeline: telescoping phases per cycle.
+
+    `begin(phase)` opens a cycle and its first phase, `mark(phase)` closes
+    the open phase and opens the next, `end()` closes both — so a cycle's
+    phases sum to the cycle exactly (integer nanoseconds). `mark()` outside
+    a cycle opens a free-standing phase (`wait`) that the next
+    `mark()`/`begin()` closes. `child(name)` times nested work inside the
+    open phase of a cycle.
+
+    Each interval is two things at once: nanoseconds added to a counter
+    that is always on, and — when `annotate` is given (the engine passes
+    `jax.profiler.TraceAnnotation`; this module stays stdlib-only) — an
+    annotation `engine/<phase>` entered and left around the same
+    interval, which costs a flag test while no profiler runs and lands
+    on the profiler's timeline while one does.
+
+    Single writer (the loop thread). Readers on other threads take
+    `snapshot()`, which is replaced whole at the end of each cycle (and of
+    each free-standing phase), so it always holds whole cycles: the
+    phases that ran inside cycles sum to `cycle_seconds`. Cycles of
+    `slow_seconds` or more are counted and the last `keep` of them kept
+    with their phases — tail capture, as `TailStore` does for requests.
+    """
+
+    def __init__(self, *, annotate: Optional[Callable[..., Any]] = None,
+                 slow_seconds: float = SLOW_CYCLE_SECONDS,
+                 keep: int = SLOW_CYCLES_KEPT,
+                 clock: Callable[[], int] = time.monotonic_ns):
+        self._annotate = annotate
+        self._slow_ns = int(slow_seconds * 1e9)
+        self._keep = max(1, keep)
+        self._now = clock
+        # Totals in ns, keyed by phase and by "<phase>/<child>".
+        self._totals: Dict[str, int] = dict.fromkeys(
+            LOOP_PHASES + LOOP_CHILDREN, 0)
+        self._cycles = 0
+        self._cycle_ns = 0
+        self._slow_cycles = 0
+        self._slow_cycle_ns = 0
+        self._slow: List[Dict[str, Any]] = []
+        # The open cycle: its closed phases and children so far (ns).
+        self.cycle: Dict[str, int] = {}
+        self._cycle_t0: Optional[int] = None
+        self._cycle_kw: Dict[str, Any] = {}
+        self._phase: Optional[str] = None
+        self._phase_t0 = 0
+        self._spans: List[Any] = []  # entered annotations, outermost first
+        self._publish()
+
+    def _publish(self) -> None:
+        self._snapshot = {
+            "seconds": {k: ns / 1e9 for k, ns in self._totals.items()},
+            "cycles": self._cycles,
+            "cycle_seconds": self._cycle_ns / 1e9,
+            "slow_cycles": self._slow_cycles,
+            "slow_cycle_seconds": self._slow_cycle_ns / 1e9,
+            "slow": self._slow,
+        }
+
+    def snapshot(self) -> Dict[str, Any]:
+        """Totals as of the last whole cycle (never mutated afterwards)."""
+        return self._snapshot
+
+    # -- spans ---------------------------------------------------------------
+
+    def _enter(self, name: str, kw: Dict[str, Any]) -> None:
+        if self._annotate is not None:
+            span = self._annotate(f"engine/{name}", **kw)
+            span.__enter__()
+            self._spans.append(span)
+
+    def _leave(self) -> None:
+        if self._annotate is not None:
+            self._spans.pop().__exit__(None, None, None)
+
+    # -- phases --------------------------------------------------------------
+
+    def _open_phase(self, phase: str, t: int) -> None:
+        self._phase = phase
+        self._phase_t0 = t
+        self._enter(phase, {})
+
+    def _close_phase(self, t: int) -> None:
+        phase = self._phase
+        if phase is None:
+            return
+        self._phase = None
+        self._leave()
+        dt = t - self._phase_t0
+        if self._cycle_t0 is not None:
+            self.cycle[phase] = self.cycle.get(phase, 0) + dt
+        else:
+            self._totals[phase] = self._totals.get(phase, 0) + dt
+            self._publish()
+
+    def begin(self, phase: str, **kw: Any) -> None:
+        """Open a cycle and its first phase at one instant (closing a
+        free-standing phase). `kw` goes on the cycle's span and, should
+        the cycle turn out slow, in its record."""
+        t = self._now()
+        self._close_phase(t)
+        self.cycle = {}
+        self._cycle_t0 = t
+        self._cycle_kw = kw
+        self._enter("cycle", dict(kw, n=self._cycles))
+        self._open_phase(phase, t)
+
+    def mark(self, phase: str) -> int:
+        """Open `phase` (closing the previous one) now; returns now (ns)."""
+        t = self._now()
+        self._close_phase(t)
+        self._open_phase(phase, t)
+        return t
+
+    def child(self, name: str, **kw: Any) -> "_ChildSpan":
+        """`with clock.child("match", request_id=..., tokens=...)`: nested
+        work inside the open phase, counted and shown as `<phase>/<name>`."""
+        return _ChildSpan(self, f"{self._phase}/{name}", kw)
+
+    def end(self) -> int:
+        """Close the open phase and the cycle at one instant and publish;
+        returns the cycle's nanoseconds (0 when no cycle was open)."""
+        t = self._now()
+        self._close_phase(t)
+        if self._cycle_t0 is None:
+            return 0
+        self._leave()
+        ns = t - self._cycle_t0
+        for key, dt in self.cycle.items():
+            self._totals[key] = self._totals.get(key, 0) + dt
+        if ns >= self._slow_ns:
+            self._slow_cycles += 1
+            self._slow_cycle_ns += ns
+            self._slow = (self._slow + [{
+                "t": self._cycle_t0 / 1e9, "seconds": ns / 1e9,
+                "phases": {k: v / 1e9 for k, v in self.cycle.items()},
+                **self._cycle_kw,
+            }])[-self._keep:]
+        self._cycles += 1
+        self._cycle_ns += ns
+        self._cycle_t0 = None
+        self._publish()
+        return ns
+
+    def close(self) -> None:
+        """Leave whatever is still entered (the loop is exiting, possibly
+        mid-cycle after a failure); the open cycle is not counted."""
+        while self._spans:
+            self._spans.pop().__exit__(None, None, None)
+        self._phase = None
+        self._cycle_t0 = None
+
+    def cycle_seconds(self, *phases: str) -> float:
+        """Seconds the open cycle has spent in `phases` (closed ones)."""
+        return sum(self.cycle.get(p, 0) for p in phases) / 1e9
+
+
+class _ChildSpan:
+    __slots__ = ("_clock", "_key", "_kw", "_t0")
+
+    def __init__(self, clock: PhaseClock, key: str, kw: Dict[str, Any]):
+        self._clock = clock
+        self._key = key
+        self._kw = kw
+
+    def __enter__(self) -> "_ChildSpan":
+        self._clock._enter(self._key, self._kw)
+        self._t0 = self._clock._now()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        clock = self._clock
+        dt = clock._now() - self._t0
+        clock._leave()
+        clock.cycle[self._key] = clock.cycle.get(self._key, 0) + dt

@@ -53,7 +53,12 @@ import numpy as np
 from jax import lax
 
 from dstack_tpu.server.tracing import HistogramData
-from dstack_tpu.utils.flight_recorder import FlightRecorder
+from dstack_tpu.utils.flight_recorder import (
+    LOOP_CHILDREN,
+    LOOP_PHASES,
+    FlightRecorder,
+    PhaseClock,
+)
 from dstack_tpu.utils.stagemarkers import auto_stage
 from dstack_tpu.workloads import compile_cache
 from dstack_tpu.workloads.attention import decode_attention
@@ -363,6 +368,20 @@ class EngineBusyError(RuntimeError):
     admitted first. Its own type so that a caller can tell this benign
     refusal from a program that failed to build — compiler and runtime
     errors are RuntimeErrors too."""
+
+
+# stats() key of each loop phase ("admit") and child ("admit/match").
+_LOOP_SECONDS_KEYS = {
+    key: f"loop_{key.replace('/', '_')}_seconds_total"
+    for key in LOOP_PHASES + LOOP_CHILDREN
+}
+
+
+def _span_id(req: "_Request") -> Any:
+    """What names a request on an `engine/*` span: the flight recorder's
+    id of it (the key of GET /v1/requests/<id>/trace)."""
+    rid = req.trace.request_id if req.trace is not None else req.request_id
+    return "" if rid is None else rid
 
 
 class _Request(NamedTuple):
@@ -851,12 +870,18 @@ class ServingEngine:
         # One first_token timeline marker per engine lifetime (stage
         # markers ride stdout; see utils/stagemarkers.py).
         self._first_token_emitted = False
-        # Wall-time accounting for the utilization gauges: cumulative
-        # seconds the loop spent blocked on decode chunks, doing
-        # prefill/admission host work, and idle-waiting.
-        self._t_decode = 0.0
-        self._t_prefill = 0.0
-        self._t_idle = 0.0
+        # The loop's own time: every phase of every cycle goes through
+        # this one clock, which feeds the loop_* counters of stats() and,
+        # while a profiler runs, `engine/<phase>` spans on its timeline.
+        self._clock = PhaseClock(annotate=jax.profiler.TraceAnnotation)
+        # Barrier seconds of cycles with nothing live: admission's, in
+        # the prefill_seconds_total derivation (stats()).
+        self._admission_barrier_s = 0.0
+        # Work at the scheduler's boundary: decode steps launched, the
+        # same weighted by live slots, and the tokens they emitted.
+        self._decode_steps = 0
+        self._decode_slot_steps = 0
+        self._decode_tokens = 0
         # Chunked-prefill / paging counters (monotonic, for /metrics and
         # the prefix-reuse acceptance measurement: tokens_computed for a
         # cache-hit request drops by the reused prefix).
@@ -1557,9 +1582,10 @@ class ServingEngine:
         """Live load snapshot (feeds /metrics and autoscaler signals).
 
         Beyond queue/shed counters and the scheduler gauges (`ttft_
-        seconds_ewma` with its queue-wait/prefill breakdown, the
-        util_decode/util_prefill/util_idle wall-time split), this now
-        reports the paged-KV view: pool occupancy (`kv_blocks_in_use` /
+        seconds_ewma` with its queue-wait/prefill breakdown, the loop's
+        own time per phase as `loop_*_seconds_total` with the decode
+        step/slot-step/token counters), this now reports the paged-KV
+        view: pool occupancy (`kv_blocks_in_use` /
         `kv_blocks_cached` of `kv_blocks_total`), prefix-cache hit
         counters with `prefix_tokens_reused_total` (prompt tokens whose
         prefill was skipped), copy-on-write and eviction counters, and
@@ -1567,7 +1593,8 @@ class ServingEngine:
         `prefill_tokens_computed_total` — diff the latter across a
         window against submitted prompt tokens to measure the prefill
         compute saved by sharing)."""
-        busy = self._t_decode + self._t_prefill + self._t_idle
+        loop = self._clock.snapshot()
+        phase_s = loop["seconds"]
         a = self._alloc
         tier = (
             self._host_tier.stats() if self._host_tier is not None else {}
@@ -1622,14 +1649,36 @@ class ServingEngine:
             "ttft_seconds_ewma": round(self._ttft_s, 4),
             "queue_wait_seconds_ewma": round(self._queue_wait_s, 4),
             "prefill_seconds_ewma": round(self._prefill_s, 4),
-            "util_decode": round(self._t_decode / busy, 4) if busy else 0.0,
-            "util_prefill": round(self._t_prefill / busy, 4) if busy else 0.0,
-            "util_idle": round(self._t_idle / busy, 4) if busy else 0.0,
-            # Raw monotonic counters behind the fractions (Prometheus
-            # counter style) so scrapers/benches can diff per window.
-            "decode_seconds_total": round(self._t_decode, 4),
-            "prefill_seconds_total": round(self._t_prefill, 4),
-            "idle_seconds_total": round(self._t_idle, 4),
+            # The loop's own time (PhaseClock): host wall seconds per
+            # phase, whole cycles only and unrounded, so at any read the
+            # phases but `wait` sum to loop_cycle_seconds_total.
+            # Monotonic; diff two snapshots for a window's shares.
+            "loop_cycles_total": loop["cycles"],
+            "loop_cycle_seconds_total": loop["cycle_seconds"],
+            **{name: phase_s[key]
+               for key, name in _LOOP_SECONDS_KEYS.items()},
+            # Cycles of SLOW_CYCLE_SECONDS or more, and the last few of
+            # them whole ({t, seconds, phases, live, tasks, pending}):
+            # where a stalled run is to be read.
+            "loop_slow_cycles_total": loop["slow_cycles"],
+            "loop_slow_cycle_seconds_total": loop["slow_cycle_seconds"],
+            "loop_slow_cycles": loop["slow"],
+            # Work at the scheduler's boundary: steps of every launched
+            # decode chunk or speculation round, the same times the slots
+            # live at launch, and the tokens those steps emitted.
+            "decode_steps_total": self._decode_steps,
+            "decode_slot_steps_total": self._decode_slot_steps,
+            "decode_tokens_total": self._decode_tokens,
+            # The older three-way split, derived from the same clock:
+            # launch to readback; admission host work (with the barrier
+            # of a cycle that had nothing live); waiting for work. They
+            # leave out barrier and fan-out of decoding cycles.
+            "decode_seconds_total": round(
+                phase_s["dispatch"] + phase_s["sync"], 6),
+            "prefill_seconds_total": round(
+                phase_s["admit"] + phase_s["grow"]
+                + self._admission_barrier_s, 6),
+            "idle_seconds_total": round(phase_s["wait"], 6),
             # Summary-style sum/count behind the latency EWMAs: diff two
             # snapshots for an exact per-window mean (the EWMAs carry
             # compile-spike history across windows; these don't).
@@ -1982,9 +2031,11 @@ class ServingEngine:
                     break
             with self._lock:
                 self._admitting.append(req)
-                blocks, matched = self._alloc.match(
-                    req.tokens, namespace=(req.adapter or "").encode()
-                )
+                with self._clock.child("match", request_id=_span_id(req),
+                                       tokens=len(req.tokens)):
+                    blocks, matched = self._alloc.match(
+                        req.tokens, namespace=(req.adapter or "").encode()
+                    )
             slot = free[0]
             t_pop = time.monotonic()
             self._slot_t0[slot] = t_pop
@@ -2013,41 +2064,46 @@ class ServingEngine:
             final = task.pos + n == len(task.req.tokens)
             n_padded = self._pad_chunk(n)
             chunk = task.req.tokens[task.pos:task.pos + n]
-            self._rng, sub = jax.random.split(self._rng)
-            chunk_args = (
-                jnp.asarray(task.slot, jnp.int32),
-                jnp.asarray(self._pad_table(task.table), jnp.int32),
-                jnp.asarray([chunk + [0] * (n_padded - n)], jnp.int32),
-                jnp.asarray(n, jnp.int32),
-                jnp.asarray(task.pos, jnp.int32),
-                jnp.asarray(task.req.max_new_tokens, jnp.int32),
-                jnp.asarray(task.req.temperature, jnp.float32),
-                jnp.asarray(task.req.top_p, jnp.float32),
-            )
-            if self._lora is not None and task.req.adapter_ix >= 0:
-                # Target-only: the drafter below never applies LoRA.
-                self.state, first = self._chunk_fn(n_padded, lora=True)(
-                    self.params, self.state, *chunk_args, sub,
-                    jnp.asarray(final, bool),
-                    jnp.asarray(task.req.adapter_ix, jnp.int32),
-                    self._lora.bank,
+            span = {"request_id": _span_id(task.req), "tokens": n}
+            lora = self._lora is not None and task.req.adapter_ix >= 0
+            with self._clock.child("chunk_args", **span):
+                self._rng, sub = jax.random.split(self._rng)
+                chunk_args = (
+                    jnp.asarray(task.slot, jnp.int32),
+                    jnp.asarray(self._pad_table(task.table), jnp.int32),
+                    jnp.asarray([chunk + [0] * (n_padded - n)], jnp.int32),
+                    jnp.asarray(n, jnp.int32),
+                    jnp.asarray(task.pos, jnp.int32),
+                    jnp.asarray(task.req.max_new_tokens, jnp.int32),
+                    jnp.asarray(task.req.temperature, jnp.float32),
+                    jnp.asarray(task.req.top_p, jnp.float32),
                 )
-            else:
-                self.state, first = self._chunk_fn(n_padded)(
-                    self.params, self.state, *chunk_args, sub,
-                    jnp.asarray(final, bool),
-                )
-            self._attn_dispatch[self._attn_path] += 1
-            if self._spec:
-                # The drafter prefills the same chunk into ITS pool
-                # through the same table — prefix-cache hits skip both
-                # models' prefill identically (same task.pos start).
-                self._rng_draft, dsub = jax.random.split(self._rng_draft)
-                self._draft_state, _ = self._draft_chunk_fn(n_padded)(
-                    self._draft_params, self._draft_state, *chunk_args,
-                    dsub, jnp.asarray(final, bool),
-                )
-                self._attn_dispatch[self._draft_attn_path] += 1
+                final_arg = jnp.asarray(final, bool)
+                if lora:
+                    adapter_arg = jnp.asarray(task.req.adapter_ix, jnp.int32)
+                if self._spec:
+                    self._rng_draft, dsub = jax.random.split(self._rng_draft)
+            with self._clock.child("chunk_launch", **span):
+                if lora:
+                    # Target-only: the drafter below never applies LoRA.
+                    self.state, first = self._chunk_fn(n_padded, lora=True)(
+                        self.params, self.state, *chunk_args, sub,
+                        final_arg, adapter_arg, self._lora.bank,
+                    )
+                else:
+                    self.state, first = self._chunk_fn(n_padded)(
+                        self.params, self.state, *chunk_args, sub, final_arg,
+                    )
+                self._attn_dispatch[self._attn_path] += 1
+                if self._spec:
+                    # The drafter prefills the same chunk into ITS pool
+                    # through the same table — prefix-cache hits skip both
+                    # models' prefill identically (same task.pos start).
+                    self._draft_state, _ = self._draft_chunk_fn(n_padded)(
+                        self._draft_params, self._draft_state, *chunk_args,
+                        dsub, final_arg,
+                    )
+                    self._attn_dispatch[self._draft_attn_path] += 1
             task.pos += n
             budget -= n
             self._prefill_chunks += 1
@@ -3039,33 +3095,37 @@ class ServingEngine:
     # -- loop ----------------------------------------------------------------
 
     def _loop(self) -> None:
+        clock = self._clock
         while not self._stop:
             try:
-                has_live = any(r is not None for r in self._live)
-                if not has_live and not self._tasks:
+                live = sum(r is not None for r in self._live)
+                if not live and not self._tasks:
                     with self._lock:
                         queued_handoffs = bool(self._prefilled_pending)
                         waiting = (bool(self._swapped)
                                    or self._next_req is not None)
                     if (self._pending.empty() and not queued_handoffs
                             and not waiting):
-                        t_w = time.monotonic()
+                        clock.mark("wait")
                         self._wake.wait(timeout=0.2)
                         self._wake.clear()
-                        self._t_idle += time.monotonic() - t_w
                         continue
-                if not has_live:
+                clock.begin("admit", live=live, tasks=len(self._tasks),
+                            pending=self._pending.qsize())
+                if not live:
                     # Nothing decoding: admission runs alone; the next
                     # iteration dispatches the first decode chunk for the
                     # freshly activated slots. Swapped-out requests get
                     # first claim on the free capacity.
-                    t_p = time.monotonic()
                     progressed = self._readmit_swapped()
                     progressed |= self._advance_prefills()
                     progressed |= self._admit_prefilled()
+                    clock.mark("barrier")
                     self._wait_activations()
-                    self._t_prefill += time.monotonic() - t_p
+                    clock.end()
+                    self._admission_barrier_s += clock.cycle_seconds("barrier")
                     if not progressed and (self._tasks or self._swapped):
+                        clock.mark("wait")
                         time.sleep(0.001)  # pool starved, nothing live
                     continue
                 # 1) Dispatch PREFILL chunks FIRST: their programs run
@@ -3078,20 +3138,22 @@ class ServingEngine:
                 #    covers the prompt — growing first would let the
                 #    chunk's writes past the last prompt block hit the
                 #    pad sentinel and silently drop.
-                t0 = time.monotonic()
                 self._readmit_swapped()
                 self._process_preempt_requests()
                 self._advance_prefills()
                 self._admit_prefilled()
+                clock.mark("grow")
                 spec_now = self._spec and self._spec_cooldown == 0
                 if spec_now:
-                    toks, still, t_pf = self._spec_round(t0)
+                    toks, still = self._spec_round()
                     if toks is None:
+                        clock.end()
                         continue  # every slot force-retired mid-round
                 else:
                     self._ensure_decode_blocks()
-                    t_pf = time.monotonic()
+                    clock.mark("dispatch")
                     # 2) Dispatch the decode chunk (async), sync on it.
+                    self._count_decode_launch(self._steps_per_sync)
                     self._rng, sub = jax.random.split(self._rng)
                     if self._lora is not None and self._lora.inflight > 0:
                         self.state, tokens, active = self._step(
@@ -3102,12 +3164,11 @@ class ServingEngine:
                             self.params, self.state, sub
                         )
                     self._attn_dispatch[self._attn_path] += 1
+                    clock.mark("sync")
                     toks = jax.device_get(tokens)  # (B, steps_per_sync)
                     still = jax.device_get(active)
-                    t_sync = time.monotonic()
-                    self._chunk_s = self._ewma(self._chunk_s, t_sync - t_pf)
-                    self._t_decode += t_sync - t_pf
-                    self._last_chunk_s = t_sync - t_pf
+                    clock.mark("barrier")
+                    self._observe_chunk_seconds()
                     if self._spec and self._spec_cooldown > 0:
                         self._spec_fallback_rounds += 1
                         self._spec_cooldown -= 1
@@ -3117,12 +3178,14 @@ class ServingEngine:
                             self._slot_k = [1] * self.slots
                             self._accept_ewma = [None] * self.slots
                             self._spec_low_streak = 0
-                self._t_prefill += t_pf - t0
                 # 3) First-token order barrier, then fan out the chunk.
                 self._wait_activations()
+                clock.mark("fan_out")
                 self._fan_out(toks, still)
+                clock.end()
             except Exception as e:  # device/compile error: fail loudly, not
                 # by wedging every consumer on a dead queue.
+                clock.close()
                 if self._stop:
                     # close() raced the in-flight step (donated buffers /
                     # deleted arrays are expected then); consumers were
@@ -3140,14 +3203,31 @@ class ServingEngine:
                     "serving engine loop failed"
                 )
                 return
+        clock.close()
 
-    def _spec_round(self, t0: float):
+    def _count_decode_launch(self, steps: int) -> None:
+        """One decode chunk (or speculation round) of `steps` steps is
+        about to launch over the slots live right now."""
+        self._decode_steps += steps
+        self._decode_slot_steps += steps * sum(
+            r is not None for r in self._live
+        )
+
+    def _observe_chunk_seconds(self) -> None:
+        """Launch-to-readback wall time of the chunk whose `sync` phase
+        just closed (the cadence gauges and the TPT series read it)."""
+        self._last_chunk_s = self._clock.cycle_seconds("dispatch", "sync")
+        self._chunk_s = self._ewma(self._chunk_s, self._last_chunk_s)
+
+    def _spec_round(self):
         """One speculation boundary: drafter proposes k tokens per
         slot, the target verifies all k+1 positions in one forward, and
         the host adapts per-slot draft lengths from what survived.
-        Returns (toks, still, t_pf) shaped exactly like a decode chunk
-        (toks (B, k+1) with -1 padding) so the fan-out is shared, or
-        (None, None, t) when no slot survived block provisioning."""
+        Entered in the cycle's `grow` phase, left in `barrier`. Returns
+        (toks, still) shaped exactly like a decode chunk (toks (B, k+1)
+        with -1 padding) so the fan-out is shared, or (None, None) when
+        no slot survived block provisioning."""
+        clock = self._clock
         k_cur = max(
             (self._slot_k[s] for s in range(self.slots)
              if self._live[s] is not None),
@@ -3156,8 +3236,9 @@ class ServingEngine:
         self._ensure_decode_blocks(k_cur + 1)
         self._ensure_spec_writable(k_cur)
         if not any(r is not None for r in self._live):
-            return None, None, time.monotonic()
-        t_pf = time.monotonic()
+            return None, None
+        t_pf = clock.mark("dispatch")
+        self._count_decode_launch(k_cur + 1)
         self._rng_draft, dsub = jax.random.split(self._rng_draft)
         self._rng, vsub = jax.random.split(self._rng)
         dk, dv, drafts, qlogits = self._spec_draft_fn(k_cur)(
@@ -3168,7 +3249,7 @@ class ServingEngine:
         )
         self._draft_state = self._draft_state._replace(k=dk, v=dv)
         drafts.block_until_ready()  # draft/verify timing split
-        t_draft = time.monotonic()
+        t_draft = time.monotonic_ns()
         if self._lora is not None and self._lora.inflight > 0:
             self.state, emitted, accepted, active = self._spec_verify_fn(
                 k_cur, lora=True
@@ -3178,17 +3259,16 @@ class ServingEngine:
             self.state, emitted, accepted, active = self._spec_verify_fn(
                 k_cur
             )(self.params, self.state, drafts, qlogits, vsub)
+        clock.mark("sync")
         toks = jax.device_get(emitted)     # (B, k_cur + 1), -1 padded
         still = jax.device_get(active)
         acc = jax.device_get(accepted)
-        t_sync = time.monotonic()
+        t_sync = clock.mark("barrier")
         self._attn_dispatch[self._draft_attn_path] += 1
         self._attn_dispatch[self._attn_path] += 1
-        self._chunk_s = self._ewma(self._chunk_s, t_sync - t_pf)
-        self._t_decode += t_sync - t_pf
-        self._last_chunk_s = t_sync - t_pf
-        self._t_spec_draft += t_draft - t_pf
-        self._t_spec_verify += t_sync - t_draft
+        self._observe_chunk_seconds()
+        self._t_spec_draft += (t_draft - t_pf) / 1e9
+        self._t_spec_verify += (t_sync - t_draft) / 1e9
         # Acceptance bookkeeping + per-slot draft-length adaptation.
         self._spec_rounds += 1
         live_rates = []
@@ -3235,7 +3315,7 @@ class ServingEngine:
                     self._spec_cooldown = 50
             else:
                 self._spec_low_streak = 0
-        return toks, still, t_pf
+        return toks, still
 
     def _fan_out(self, toks, still) -> None:
         """Deliver one chunk's tokens (decode or speculation round —
@@ -3301,6 +3381,7 @@ class ServingEngine:
             for tok in toks[slot]:
                 if tok >= 0:
                     req.out.put(int(tok))
+        self._decode_tokens += total_emitted
         if total_emitted:
             # One TPT sample per chunk: decode wall time amortized over
             # the tokens it emitted (the decode-isolation measurement
@@ -3359,6 +3440,18 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
          stats["prefill_tokens_computed_total"]),
         ("dstack_tpu_serving_admitted_total", "counter",
          stats["admitted_total"]),
+        # The engine loop's own accounting (.get defaults keep snapshots
+        # from before the phase clock renderable).
+        ("dstack_tpu_serving_loop_cycles_total", "counter",
+         stats.get("loop_cycles_total", 0)),
+        ("dstack_tpu_serving_loop_slow_cycles_total", "counter",
+         stats.get("loop_slow_cycles_total", 0)),
+        ("dstack_tpu_serving_decode_steps_total", "counter",
+         stats.get("decode_steps_total", 0)),
+        ("dstack_tpu_serving_decode_slot_steps_total", "counter",
+         stats.get("decode_slot_steps_total", 0)),
+        ("dstack_tpu_serving_decode_tokens_total", "counter",
+         stats.get("decode_tokens_total", 0)),
         ("dstack_tpu_serving_rejected_total", "counter",
          stats["rejected_total"]),
         # Speculative decoding (all zero when --spec-enable is off;
@@ -3422,6 +3515,12 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
             f'{attn}{{path="{path}"}}'
             f' {stats.get(f"attn_dispatch_{path}_total", 0)}'
         )
+    # Host wall seconds of the engine loop per phase (children as
+    # "admit/match"): the phases but `wait` sum to the loop's busy time.
+    loop = "dstack_tpu_serving_loop_phase_seconds_total"
+    lines.append(f"# TYPE {loop} counter")
+    for key, name in _LOOP_SECONDS_KEYS.items():
+        lines.append(f'{loop}{{phase="{key}"}} {stats.get(name, 0.0)}')
     # Latency histograms, labeled with the engine role: a split
     # request's prefill leg (submit -> handoff acked), decode leg
     # (receipt -> first delivery) and a unified engine's full TTFT are

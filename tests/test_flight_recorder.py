@@ -7,9 +7,14 @@ with the slot), a disabled recorder must retain nothing, and the
 tail-capture threshold must be inclusive at the boundary.
 """
 
+import pytest
+
 from dstack_tpu.utils.flight_recorder import (
+    LOOP_CHILDREN,
+    LOOP_PHASES,
     PHASES,
     FlightRecorder,
+    PhaseClock,
     RequestTrace,
     TailStore,
 )
@@ -198,3 +203,204 @@ def test_get_coerces_digit_strings():
     tr = rec.begin(42, t0=0.0)
     rec.finish(tr, "ok", t_end=1.0)
     assert rec.get("42")["request_id"] == 42
+
+
+# -- PhaseClock: the engine loop's timeline ----------------------------------
+
+
+class Spans:
+    """An annotation factory that logs what was entered and left."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **kw):
+        log = self.log
+
+        class Span:
+            def __enter__(self):
+                log.append(("enter", name, kw))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return Span()
+
+
+def _one_cycle(clock, now, child=True):
+    """admit 3 (match 1 inside) + grow 2 + sync 40 + fan_out 5 = 50 ns."""
+    now.t = 100
+    clock.begin("admit", live=2, tasks=1)
+    if child:
+        now.t = 101
+        with clock.child("match", request_id="r0", tokens=7):
+            now.t = 102
+    now.t = 103
+    clock.mark("grow")
+    now.t = 105
+    clock.mark("sync")
+    now.t = 145
+    clock.mark("fan_out")
+    now.t = 150
+    return clock.end()
+
+
+@pytest.mark.parametrize("factory", [None, "spans"])
+def test_phase_clock_telescopes(factory):
+    """A cycle's phases sum to the cycle exactly; a child never exceeds
+    its parent; the same holds with and without an annotation factory."""
+    now = Clock(0)
+    clock = PhaseClock(annotate=Spans() if factory else None, clock=now)
+    assert _one_cycle(clock, now) == 50
+    assert clock.cycle == {"admit": 3, "admit/match": 1, "grow": 2,
+                           "sync": 40, "fan_out": 5}
+    snap = clock.snapshot()
+    assert snap["cycles"] == 1
+    assert snap["cycle_seconds"] == 50 / 1e9
+    in_cycle = sum(snap["seconds"][p] for p in LOOP_PHASES if p != "wait")
+    assert in_cycle == pytest.approx(snap["cycle_seconds"], abs=1e-15)
+    assert sum(v for k, v in clock.cycle.items() if "/" not in k) == 50
+    assert snap["seconds"]["admit/match"] <= snap["seconds"]["admit"]
+    assert set(snap["seconds"]) == set(LOOP_PHASES + LOOP_CHILDREN)
+    assert snap["slow_cycles"] == 0 and snap["slow"] == []
+
+
+def test_phase_clock_spans_enter_and_leave_in_order():
+    now = Clock(0)
+    spans = Spans()
+    clock = PhaseClock(annotate=spans, clock=now)
+    _one_cycle(clock, now)
+    assert [(e[0], e[1]) for e in spans.log] == [
+        ("enter", "engine/cycle"), ("enter", "engine/admit"),
+        ("enter", "engine/admit/match"), ("exit", "engine/admit/match"),
+        ("exit", "engine/admit"),
+        ("enter", "engine/grow"), ("exit", "engine/grow"),
+        ("enter", "engine/sync"), ("exit", "engine/sync"),
+        ("enter", "engine/fan_out"), ("exit", "engine/fan_out"),
+        ("exit", "engine/cycle"),
+    ]
+    assert spans.log[0][2] == {"live": 2, "tasks": 1, "n": 0}
+    assert spans.log[2][2] == {"request_id": "r0", "tokens": 7}
+
+
+def test_phase_clock_wait_is_outside_cycles():
+    """`mark()` outside a cycle is a free-standing phase: counted, on no
+    cycle, and closed by whatever comes next."""
+    now = Clock(0)
+    spans = Spans()
+    clock = PhaseClock(annotate=spans, clock=now)
+    clock.mark("wait")
+    now.t = 200
+    clock.mark("wait")
+    now.t = 300
+    clock.begin("admit")
+    now.t = 310
+    assert clock.end() == 10
+    snap = clock.snapshot()
+    assert snap["seconds"]["wait"] == 300 / 1e9
+    assert snap["cycles"] == 1 and snap["cycle_seconds"] == 10 / 1e9
+    assert [e[1] for e in spans.log if e[0] == "enter"] == [
+        "engine/wait", "engine/wait", "engine/cycle", "engine/admit"]
+    assert clock.end() == 0  # nothing open: a no-op
+
+
+def test_phase_clock_snapshot_holds_whole_cycles_only():
+    now = Clock(0)
+    clock = PhaseClock(clock=now)
+    _one_cycle(clock, now)
+    before = clock.snapshot()
+    now.t = 200
+    clock.begin("admit")
+    now.t = 260
+    clock.mark("sync")
+    assert clock.snapshot() is before  # mid-cycle: nothing published yet
+    assert clock.cycle_seconds("admit") == 60 / 1e9
+    now.t = 300
+    clock.end()
+    assert clock.snapshot()["cycles"] == 2
+    assert before["cycles"] == 1  # a taken snapshot is never mutated
+
+
+def test_phase_clock_keeps_the_last_slow_cycles():
+    now = Clock(0)
+    clock = PhaseClock(clock=now, slow_seconds=1e-6, keep=2)
+    for i in range(4):
+        now.t = i * 10_000
+        clock.begin("admit", live=i, tasks=0, pending=3)
+        now.t += 400
+        clock.mark("sync")
+        now.t += 600 + i  # 1000 + i ns >= 1000 ns: slow, inclusive at i=0
+        clock.end()
+    now.t = 90_000
+    clock.begin("admit")
+    now.t += 999  # under the threshold
+    clock.end()
+    snap = clock.snapshot()
+    assert snap["cycles"] == 5 and snap["slow_cycles"] == 4
+    assert snap["slow_cycle_seconds"] == pytest.approx(4006 / 1e9)
+    assert [c["live"] for c in snap["slow"]] == [2, 3]  # the last two
+    last = snap["slow"][-1]
+    assert last["t"] == 30_000 / 1e9 and last["pending"] == 3
+    assert last["phases"] == {"admit": 400 / 1e9, "sync": 603 / 1e9}
+    assert sum(last["phases"].values()) == pytest.approx(last["seconds"])
+
+
+def test_phase_clock_close_leaves_open_spans_uncounted():
+    now = Clock(0)
+    spans = Spans()
+    clock = PhaseClock(annotate=spans, clock=now)
+    clock.begin("admit")
+    child = clock.child("chunk_launch")
+    child.__enter__()
+    now.t = 50
+    clock.close()  # the loop died mid-cycle
+    assert [e[1] for e in spans.log if e[0] == "exit"] == [
+        "engine/admit/chunk_launch", "engine/admit", "engine/cycle"]
+    assert clock.snapshot()["cycles"] == 0
+    assert clock.end() == 0
+
+
+def test_phase_clock_readers_never_see_a_torn_cycle():
+    """One writer, many readers, no lock: every snapshot a reader takes
+    holds whole cycles (phases in cycles sum to the cycle total)."""
+    import sys
+    import threading
+    import time
+
+    clock = PhaseClock()
+    stop = threading.Event()
+    torn = []
+
+    def reader():
+        while not stop.is_set():
+            snap = clock.snapshot()
+            in_cycles = sum(v for k, v in snap["seconds"].items()
+                            if "/" not in k and k != "wait")
+            if abs(in_cycles - snap["cycle_seconds"]) > 1e-9:
+                torn.append(snap)
+
+    readers = [threading.Thread(target=reader) for _ in range(8)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in readers:
+            t.start()
+        deadline = time.monotonic() + 0.5
+        cycles = 0
+        while time.monotonic() < deadline:
+            clock.begin("admit")
+            with clock.child("match"):
+                pass
+            for phase in ("grow", "dispatch", "sync", "barrier", "fan_out"):
+                clock.mark(phase)
+            clock.end()
+            clock.mark("wait")
+            cycles += 1
+    finally:
+        stop.set()
+        for t in readers:
+            t.join(timeout=10)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in readers)
+    assert not torn
+    assert clock.snapshot()["cycles"] == cycles > 0

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from dstack_tpu.server.metrics_registry import METRICS
+from dstack_tpu.utils.flight_recorder import LOOP_CHILDREN, LOOP_PHASES
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.generate import generate
 from dstack_tpu.workloads.serving import ServingEngine, prometheus_metrics
@@ -224,10 +225,12 @@ def test_prometheus_metrics_matches_registry(params):
             assert name in METRICS, f"undeclared series {name}"
             assert METRICS[name][0] == mtype, name
             # Serving series carry no labels, except the r12 attention
-            # dispatch counter (path=pallas|lax_ragged) and the r13/r16
-            # role-labeled latency histograms — their samples are
-            # checked against the declared label sets below.
+            # dispatch counter (path=pallas|lax_ragged), the loop's
+            # per-phase seconds and the r13/r16 role-labeled latency
+            # histograms — their samples are checked against the
+            # declared label sets below.
             if name not in ("dstack_tpu_serving_attn_dispatch_total",
+                            "dstack_tpu_serving_loop_phase_seconds_total",
                             "dstack_tpu_serving_ttft_seconds",
                             "dstack_tpu_serving_tpt_seconds",
                             "dstack_tpu_serving_kv_transfer_seconds",
@@ -244,6 +247,9 @@ def test_prometheus_metrics_matches_registry(params):
                 assert name in (
                     base + '{path="pallas"}', base + '{path="lax_ragged"}'
                 ), name
+            if base == "dstack_tpu_serving_loop_phase_seconds_total":
+                phase = name[len(base) + len('{phase="'):-len('"}')]
+                assert phase in LOOP_PHASES + LOOP_CHILDREN, name
             if base.startswith("dstack_tpu_serving_phase_seconds"):
                 # r15 flight-recorder histograms: every sample carries
                 # the declared (phase, role) pair.

@@ -19,7 +19,12 @@ import pytest
 
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.generate import generate
-from dstack_tpu.workloads.serving import ServingEngine
+from dstack_tpu.utils.flight_recorder import (
+    LOOP_CHILDREN,
+    LOOP_PHASES,
+    SLOW_CYCLE_SECONDS,
+)
+from dstack_tpu.workloads.serving import ServingEngine, prometheus_metrics
 from dstack_tpu.workloads.transformer import init_params
 
 CFG = PRESETS["tiny"].with_(remat=False)
@@ -135,8 +140,8 @@ def test_stats_exposes_scheduler_gauges(params):
         assert len(_drain(q)) == 4
         s = engine.stats()
         for key in ("ttft_seconds_ewma", "queue_wait_seconds_ewma",
-                    "prefill_seconds_ewma", "util_decode", "util_prefill",
-                    "util_idle", "decode_seconds_total",
+                    "prefill_seconds_ewma", "loop_cycles_total",
+                    "loop_cycle_seconds_total", "decode_seconds_total",
                     "prefill_seconds_total", "idle_seconds_total",
                     "admitted_total", "ttft_seconds_sum",
                     "queue_wait_seconds_sum", "prefill_seconds_sum",
@@ -153,11 +158,156 @@ def test_stats_exposes_scheduler_gauges(params):
         assert s["prefill_seconds_ewma"] > 0
         assert s["prefill_tokens_computed_total"] == 3
         assert s["prefill_chunks_total"] == 1
-        util = s["util_decode"] + s["util_prefill"] + s["util_idle"]
-        assert util == pytest.approx(1.0, abs=2e-3)
-        assert s["util_decode"] > 0  # at least one chunk ran
+        # The loop accounts for all of its own time: the phases of its
+        # cycles sum to the cycles, `wait` is everything else.
+        in_cycles = sum(s[f"loop_{p}_seconds_total"]
+                        for p in LOOP_PHASES if p != "wait")
+        assert in_cycles == pytest.approx(
+            s["loop_cycle_seconds_total"], abs=1e-9)
+        assert s["loop_sync_seconds_total"] > 0  # at least one chunk ran
+        assert not any(k.startswith("util_") for k in s)
     finally:
         engine.close()
+
+
+def _settled_stats(engine):
+    """stats() once the loop has gone back to waiting (the last cycle's
+    counters are published when it ends, after the last token is out)."""
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        before = engine.stats()
+        time.sleep(0.05)
+        s = engine.stats()
+        if (s["active"] == 0
+                and s["loop_cycles_total"] == before["loop_cycles_total"]
+                and s["loop_wait_seconds_total"]
+                > before["loop_wait_seconds_total"]):
+            return s
+    raise AssertionError("the engine loop never went idle")
+
+
+def test_loop_counters_account_for_the_loop(params):
+    """The phase clock's counters on a live engine: phases sum to the
+    cycles, children stay inside their parent, the step / slot-step /
+    token counters are coherent with what consumers received, and the
+    three older totals are the documented sums of phases."""
+    engine = ServingEngine(CFG, params, slots=2, max_len=64,
+                           steps_per_sync=4)
+    try:
+        prompts = [[5, 7, 11], [3, 1, 4, 1, 5], [2, 7, 1, 8], [9, 9]]
+        outs = [engine.submit(p, max_new_tokens=6) for p in prompts]
+        delivered = sum(len(_drain(q)) for q in outs)
+        assert delivered == 6 * len(prompts)
+        s = _settled_stats(engine)
+        phase = {p: s[f"loop_{p}_seconds_total"] for p in LOOP_PHASES}
+        assert all(v >= 0 for v in phase.values())
+        assert sum(phase.values()) - phase["wait"] == pytest.approx(
+            s["loop_cycle_seconds_total"], abs=1e-9)
+        children = [s[f"loop_{c.replace('/', '_')}_seconds_total"]
+                    for c in LOOP_CHILDREN]
+        assert all(c > 0 for c in children)
+        assert sum(children) <= phase["admit"]
+        # Every token but each request's first came out of a decode step.
+        assert s["decode_tokens_total"] == delivered - len(prompts)
+        assert s["decode_steps_total"] % 4 == 0
+        assert (s["decode_tokens_total"] <= s["decode_slot_steps_total"]
+                <= s["decode_steps_total"] * s["slots"])
+        assert s["loop_cycles_total"] >= s["decode_steps_total"] // 4
+        assert s["decode_seconds_total"] == pytest.approx(
+            phase["dispatch"] + phase["sync"], abs=1e-5)
+        assert s["idle_seconds_total"] == pytest.approx(
+            phase["wait"], abs=1e-5)
+        # admit + grow, plus the barrier of cycles with nothing live.
+        lo = phase["admit"] + phase["grow"]
+        assert lo - 1e-5 <= s["prefill_seconds_total"] <= (
+            lo + phase["barrier"] + 1e-5)
+        text = prometheus_metrics(s)
+        assert 'dstack_tpu_serving_loop_phase_seconds_total{phase="sync"}' \
+            in text
+        assert f"dstack_tpu_serving_decode_tokens_total " \
+            f"{s['decode_tokens_total']}" in text
+    finally:
+        engine.close()
+
+
+def test_slow_cycle_is_kept_with_its_phases(params):
+    """A cycle of SLOW_CYCLE_SECONDS or more is counted and kept whole:
+    here the clock jumps two seconds inside a chunk launch."""
+    engine = ServingEngine(CFG, params, slots=2, max_len=32)
+    real_now = engine._clock._now
+    jump = [0]
+    engine._clock._now = lambda: real_now() + jump[0]
+
+    def stall(n_padded, e):
+        if not jump[0]:
+            jump[0] = 2 * 10**9
+
+    _spy_chunks(engine, stall)
+    try:
+        assert len(_drain(engine.submit([5, 7, 11], max_new_tokens=4))) == 4
+        s = _settled_stats(engine)
+        assert s["loop_slow_cycles_total"] == 1
+        assert s["loop_slow_cycle_seconds_total"] >= SLOW_CYCLE_SECONDS
+        (slow,) = s["loop_slow_cycles"]
+        assert slow["seconds"] >= 2.0
+        assert slow["phases"]["admit"] >= slow["phases"]["admit/chunk_launch"] \
+            >= 2.0
+        top = sum(v for k, v in slow["phases"].items() if "/" not in k)
+        assert top == pytest.approx(slow["seconds"], abs=1e-9)
+        assert {"t", "live", "tasks", "pending"} <= set(slow)
+        assert slow["pending"] == 1  # the request that was about to stall
+    finally:
+        engine.close()
+
+
+def test_engine_spans_land_in_a_profile(params, tmp_path):
+    """Under jax.profiler the same marks are spans on the profiler's
+    timeline: engine/cycle with its phases nested inside, chunk children
+    carrying the request's id."""
+    from jax.profiler import ProfileData
+
+    engine = ServingEngine(CFG, params, slots=2, max_len=32)
+    try:
+        _drain(engine.submit([5, 7, 11], max_new_tokens=4))  # compile first
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            outs = [engine.submit([5, 7, 11 + i], max_new_tokens=4,
+                                  request_id=700 + i) for i in range(3)]
+            for q in outs:
+                _drain(q)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        engine.close()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            found = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                      dict(e.stats))
+                     for e in line.events if e.name.startswith("engine/")]
+            if found:
+                assert plane.name == "/host:CPU"
+                spans.append(found)
+    (spans,) = spans  # every engine/ span is on one line: the loop thread's
+    cycles = [e for e in spans if e[0] == "engine/cycle"]
+    assert len(cycles) >= 2
+    assert all({"n", "live", "tasks"} <= set(c[3]) for c in cycles)
+    for name, lo, hi, _ in spans:
+        if name in ("engine/cycle", "engine/wait"):
+            continue
+        assert any(c[1] <= lo and hi <= c[2] for c in cycles), name
+    launches = [e for e in spans if e[0] == "engine/admit/chunk_launch"]
+    assert {e[3]["request_id"] for e in launches} == {700, 701, 702}
+    assert all(e[3]["tokens"] == 3 for e in launches)
+    admits = [e for e in spans if e[0] == "engine/admit"]
+    for _, lo, hi, _ in launches:
+        assert any(a[1] <= lo and hi <= a[2] for a in admits)
+    assert {"engine/sync", "engine/fan_out", "engine/barrier",
+            "engine/admit/match", "engine/admit/chunk_args"} <= {
+                e[0] for e in spans}
 
 
 def test_cancel_during_prefill_overlap_leaves_no_leak(params):
