@@ -247,9 +247,14 @@ def child_kernels(rehearsal: bool) -> dict:
         check(f"flash_block_{tag}_lse", m + jnp.log(l), rm + jnp.log(rl))
 
     # -- ragged paged kernel vs the lax path ------------------------------
+    # The kernel addresses one layer inside the stacked pool: three
+    # layers, the middle one attended.
     nb, mb = slots * (max_len // block), max_len // block
-    k_pool = rand(keys[7], (nb, block, kv, hd))
-    v_pool = rand(jax.random.fold_in(keys[7], 1), (nb, block, kv, hd))
+    n_pool_layers, layer = 3, jnp.int32(1)
+    k_pool = rand(keys[7], (n_pool_layers, nb, block, kv, hd))
+    v_pool = rand(
+        jax.random.fold_in(keys[7], 1), (n_pool_layers, nb, block, kv, hd)
+    )
     rng = np.random.default_rng(0)
 
     def tables_for(b):
@@ -273,7 +278,7 @@ def child_kernels(rehearsal: bool) -> dict:
                 [rng.integers(1, n_blk[i] * block + 1, s) for i in range(b)]
             ).astype(np.int32)
         qq = rand(jax.random.fold_in(keys[0], b * 1000 + s), (b, s, h, hd))
-        args = (qq, k_pool, v_pool, jnp.asarray(t), jnp.asarray(vlen))
+        args = (qq, k_pool, v_pool, layer, jnp.asarray(t), jnp.asarray(vlen))
         check(
             name,
             _ragged_attention_pallas(*args, interpret=interpret),

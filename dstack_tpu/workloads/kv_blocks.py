@@ -22,16 +22,20 @@ table* row mapping its logical cache positions to pool blocks — plus:
 
 Since r12, every attention in this module goes through
 `paged_attention.ragged_attention`: it attends STRAIGHT against the
-`(num_blocks, block_size, KV, hd)` pool indexed by the block tables,
-with a streaming softmax that walks one table column (one block) at a
-time. No program here materializes a dense `(max_len, ...)` per-slot
-view any more — the whole-pool `jnp.take(pool, block_tables, ...)`
+stacked pool at a layer index, through the block tables, with a
+streaming softmax that walks one table column (one block) at a time.
+No program here materializes a dense `(max_len, ...)` per-slot view
+any more — the whole-pool `jnp.take(pool, block_tables, ...)`
 gather, the matching full-view scatter, and the engine's cross-chunk
 view cache that existed to amortize them are all gone (the static
 analyzer's KVB01 check keeps them gone). Each program's writes shrink
 to the handful of rows it actually produced, scattered by
-`(block, offset)` before the layer's attention so in-flight rows see
-themselves and their predecessors exactly as the dense body would.
+`(layer, block, offset)` before the layer's attention so in-flight rows
+see themselves and their predecessors exactly as the dense body would.
+The pool rides every program's layer loop as a CARRY (`_layer_loop`),
+so those scatters update it in place; it is never an `xs`/`ys` of the
+layer scan, which would copy each layer's slab out and back per
+layer-step and the whole pool once per decode step.
 
 Correctness leans on two XLA facts (pallas_guide: gather/scatter modes):
 garbage in unwritten or stale pool blocks is harmless because attention
@@ -427,6 +431,71 @@ def _jit_shardings(in_shardings, out_shardings):
     return {"in_shardings": in_shardings, "out_shardings": out_shardings}
 
 
+def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
+                blk, off, tables, valid_len, *, bank=None, adapter_ix=None,
+                has_lora=None, attn_impl: Optional[str] = None):
+    """The layer loop of every paged program -> (x, k_pool, v_pool).
+
+    x (B, S, d) at `positions` runs through the layers; layer l writes
+    its new K/V rows into the STACKED pool (L, num_blocks, block_size,
+    KV, hd) at `[l, blk, off]` (blk/off (B, S); lanes pointed at the
+    sentinel block `num_blocks` drop) and then attends raggedly over
+    `tables` with per-row `valid_len`, so in-flight rows see themselves
+    and their predecessors. With a LoRA `bank` the q/k/v projection adds
+    each request's unmerged delta (lora_serving.project_qkv_lora):
+    `adapter_ix` is a scalar for the one-request prefill program, (B,)
+    for decode/verify, -1 = none; `has_lora` gates the LoRA math.
+
+    The pool is a carry of the scan, never an `xs`/`ys`: a scan cannot
+    alias `xs` to `ys`, so the stacked form sliced each layer's K and V
+    slab out into a fresh buffer and wrote it back into a second stack
+    every layer-step, and the step loop around it copied the whole pool
+    once per decode step — 64% of the chat cell's device time on the v5e
+    (PERF.md §6, PR 26). As a carry the two scatters update the donated
+    pool in place and nothing of pool or slab shape is moved
+    (tests/test_tpu_lowering.py reads the compiled HLO). Do not flatten
+    (L, num_blocks) for the WRITE: sentinel + l * num_blocks would land
+    in layer l+1 instead of out of bounds.
+    """
+    if bank is None:
+        project = lambda x, p, lp: project_qkv(c, x, p, positions)
+    else:
+        from dstack_tpu.workloads.lora_serving import project_qkv_lora
+
+        pool = bank["scale"].shape[0] - 1            # the all-zero slot
+        safe = jnp.where(adapter_ix >= 0, adapter_ix, pool).astype(jnp.int32)
+        scale = jnp.take(bank["scale"], safe)
+        project = lambda x, p, lp: project_qkv_lora(
+            c, x, p, positions, lp, safe, scale, has_lora
+        )
+    xs = (
+        params["layers"],
+        jnp.arange(k_pool.shape[0], dtype=jnp.int32),
+        None if bank is None else bank["layers"],
+    )
+
+    def body(carry, layer):
+        x, kp, vp = carry
+        p, l, lp = layer
+        q, k, v = project(x, p, lp)
+        kp = kp.at[l, blk, off].set(k.astype(kp.dtype), mode="drop")
+        vp = vp.at[l, blk, off].set(v.astype(vp.dtype), mode="drop")
+        attn = ragged_attention(
+            q, kp, vp, l, tables, valid_len, impl=attn_impl
+        )
+        x = x + linear(attn, p["wo"])
+        if c.n_experts > 0:
+            from dstack_tpu.workloads.moe import moe_block
+
+            x, _ = moe_block(c, x, p)
+        else:
+            x = mlp_block(c, x, p)
+        return (x, kp, vp), None
+
+    (x, k_pool, v_pool), _ = lax.scan(body, (x, k_pool, v_pool), xs)
+    return x, k_pool, v_pool
+
+
 def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
                        lora: bool = False, attn_impl: Optional[str] = None):
     """chunk_prefill(params, state, slot, table_row (MB,), tokens (1, C),
@@ -477,49 +546,17 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
         valid_len = start + 1 + offs
 
         x = jnp.take(params["embed"], tokens, axis=0)  # (1, C, d)
-
-        if bank is None:
-            qkv = lambda x, p: project_qkv(c, x, p, positions)
-            ops = (params["layers"], state.k, state.v)
-        else:
-            from dstack_tpu.workloads.lora_serving import project_qkv_lora
-
-            pool = bank["scale"].shape[0] - 1        # the all-zero slot
-            safe = jnp.where(aix >= 0, aix, pool).astype(jnp.int32)
-            scale = bank["scale"][safe]
-            has_lora = aix >= 0
-            qkv = lambda x, layer: project_qkv_lora(
-                c, x, layer[0], positions, layer[1], safe, scale, has_lora
-            )
-            ops = (params["layers"], bank["layers"], state.k, state.v)
-
-        def body(x, layer):
-            if bank is None:
-                p, ck, cv = layer  # ck/cv: (num_blocks, block_size, KV, hd)
-                q, k, v = qkv(x, p)
-            else:
-                p, lp, ck, cv = layer
-                q, k, v = qkv(x, (p, lp))
-            # Write the chunk's rows into the pool FIRST, then attend
-            # raggedly over the slot's blocks: row i sees cache
-            # positions <= start + i, including the rows just written.
-            # Padded lanes hit the sentinel block and drop; valid_len
-            # masks whatever garbage their attention rows read.
-            ck = ck.at[blk, off].set(k[0].astype(ck.dtype), mode="drop")
-            cv = cv.at[blk, off].set(v[0].astype(cv.dtype), mode="drop")
-            attn = ragged_attention(
-                q, ck, cv, table_row[None], valid_len[None], impl=attn_impl
-            )
-            x = x + linear(attn, p["wo"])
-            if c.n_experts > 0:
-                from dstack_tpu.workloads.moe import moe_block
-
-                x, _ = moe_block(c, x, p)
-            else:
-                x = mlp_block(c, x, p)
-            return x, (ck, cv)
-
-        x, (new_k, new_v) = lax.scan(body, x, ops)
+        # Each layer writes the chunk's rows into the pool FIRST, then
+        # attends raggedly over the slot's blocks: row i sees cache
+        # positions <= start + i, including the rows just written.
+        # Padded lanes hit the sentinel block and drop; valid_len masks
+        # whatever garbage their attention rows read.
+        x, new_k, new_v = _layer_loop(
+            c, params, x, positions, state.k, state.v,
+            blk[None], off[None], table_row[None], valid_len[None],
+            bank=bank, adapter_ix=aix, has_lora=aix >= 0,
+            attn_impl=attn_impl,
+        )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         h_last = jnp.take(
             h[0], jnp.clip(n_valid - 1, 0, C - 1), axis=0, mode="clip"
@@ -580,14 +617,20 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
     live adapters skips the LoRA math behind one `lax.cond`.
 
     Each of the `steps` per-token iterations writes the new row's K/V
-    straight into the slot's current block — one O(B)-row scatter — and
-    attends raggedly over the block tables
-    (`paged_attention.ragged_attention`). The whole-pool gather, the
-    full-view write-back, and the carried cross-chunk view cache of
-    r08-r10 are gone: steady-state decode touches only the blocks each
-    slot actually owns, and there is no cached view for boundary events
-    (prefill chunks, CoW copies, table growth, spec rounds) to
-    invalidate.
+    straight into the slot's current block — one O(B)-row scatter per
+    layer into the carried pool (`_layer_loop`) — and attends raggedly
+    over the block tables (`paged_attention.ragged_attention`). The
+    whole-pool gather, the full-view write-back, and the carried
+    cross-chunk view cache of r08-r10 are gone, and there is no cached
+    view for boundary events (prefill chunks, CoW copies, table growth,
+    spec rounds) to invalidate. Steady-state decode touches only the
+    blocks each slot actually owns — true on the chip since PR 26 only:
+    until then the layer scan took the pool as `xs` and returned it as
+    `ys`, and the traced runs of PR 22 and PR 24 (mistral-7b.chat) show
+    every layer-step slicing out and writing back the layer's whole K
+    and V slab (four ops of 0.44 ms over bf16[4608,16,8,128]) and every
+    step copying the whole pool twice (`copy.92/.93`, 5.2 ms each):
+    31.5 ms of a 50.7 ms step to write 16 rows.
 
     Sampling and retirement share `serving._select_next_token` — the
     SAME traced tail as the dense `_decode_body` — so the two paths
@@ -617,44 +660,14 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
         off = state.lengths % bs
         valid_len = (state.lengths + 1)[:, None]     # (B, 1)
 
-        if bank is not None:
-            from dstack_tpu.workloads.lora_serving import project_qkv_lora
-
-            pool = bank["scale"].shape[0] - 1        # the all-zero slot
-            aix = state.adapter_ix
-            safe = jnp.where(aix >= 0, aix, pool).astype(jnp.int32)
-            scale = jnp.take(bank["scale"], safe)
-            has_lora = jnp.any(state.active & (aix >= 0))
-
-        def body(x, layer):
-            if bank is None:
-                p, ck, cv = layer  # ck/cv: (num_blocks, block_size, KV, hd)
-                q, k, v = project_qkv(c, x, p, positions)
-            else:
-                p, lp, ck, cv = layer
-                q, k, v = project_qkv_lora(
-                    c, x, p, positions, lp, safe, scale, has_lora
-                )
-            ck = ck.at[blk, off].set(k[:, 0].astype(ck.dtype), mode="drop")
-            cv = cv.at[blk, off].set(v[:, 0].astype(cv.dtype), mode="drop")
-            attn = ragged_attention(
-                q, ck, cv, state.block_tables, valid_len, impl=attn_impl
-            )
-            x = x + linear(attn, p["wo"])
-            if c.n_experts > 0:
-                from dstack_tpu.workloads.moe import moe_block
-
-                x, _ = moe_block(c, x, p)
-            else:
-                x = mlp_block(c, x, p)
-            return x, (ck, cv)
-
-        ops = (
-            (params["layers"], state.k, state.v)
-            if bank is None
-            else (params["layers"], bank["layers"], state.k, state.v)
+        aix = state.adapter_ix
+        x, new_k, new_v = _layer_loop(
+            c, params, x, positions, state.k, state.v,
+            blk[:, None], off[:, None], state.block_tables, valid_len,
+            bank=bank, adapter_ix=aix,
+            has_lora=jnp.any(state.active & (aix >= 0)),
+            attn_impl=attn_impl,
         )
-        x, (new_k, new_v) = lax.scan(body, x, ops)
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         logits = logits_linear(h[:, -1], params["lm_head"])
         next_token = _serving._select_next_token(state, logits, rng)
@@ -791,24 +804,11 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None,
             blk = jnp.where(write_ok, blk, nb)
             off = pos % bs
 
-            def body(x, layer):
-                p, ck, cv = layer           # ck (num_blocks, bs, KV, hd)
-                q, kk, vv = project_qkv(c, x, p, pos[:, None])
-                ck = ck.at[blk, off].set(kk[:, 0].astype(ck.dtype), mode="drop")
-                cv = cv.at[blk, off].set(vv[:, 0].astype(cv.dtype), mode="drop")
-                attn = ragged_attention(
-                    q, ck, cv, block_tables, pos[:, None] + 1, impl=attn_impl
-                )
-                x = x + linear(attn, p["wo"])
-                if c.n_experts > 0:
-                    from dstack_tpu.workloads.moe import moe_block
-
-                    x, _ = moe_block(c, x, p)
-                else:
-                    x = mlp_block(c, x, p)
-                return x, (ck, cv)
-
-            x, (dk, dv) = lax.scan(body, x, (params["layers"], dk, dv))
+            x, dk, dv = _layer_loop(
+                c, params, x, pos[:, None], dk, dv,
+                blk[:, None], off[:, None], block_tables, pos[:, None] + 1,
+                attn_impl=attn_impl,
+            )
             h = rms_norm(x, params["final_norm"], c.norm_eps)
             logits = logits_linear(h[:, -1], params["lm_head"])  # (B, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -902,44 +902,13 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
 
         x = jnp.take(params["embed"], tokens, axis=0)        # (B, S, d)
 
-        if bank is not None:
-            from dstack_tpu.workloads.lora_serving import project_qkv_lora
-
-            pool = bank["scale"].shape[0] - 1        # the all-zero slot
-            aix = state.adapter_ix
-            safe = jnp.where(aix >= 0, aix, pool).astype(jnp.int32)
-            scale = jnp.take(bank["scale"], safe)
-            has_lora = jnp.any(act0 & (aix >= 0))
-
-        def body(x, layer):
-            if bank is None:
-                p, ck, cv = layer                # ck (num_blocks, bs, KV, hd)
-                q, kk, vv = project_qkv(c, x, p, positions)
-            else:
-                p, lp, ck, cv = layer
-                q, kk, vv = project_qkv_lora(
-                    c, x, p, positions, lp, safe, scale, has_lora
-                )
-            ck = ck.at[blk, off].set(kk.astype(ck.dtype), mode="drop")
-            cv = cv.at[blk, off].set(vv.astype(cv.dtype), mode="drop")
-            attn = ragged_attention(
-                q, ck, cv, state.block_tables, positions + 1, impl=attn_impl
-            )
-            x = x + linear(attn, p["wo"])
-            if c.n_experts > 0:
-                from dstack_tpu.workloads.moe import moe_block
-
-                x, _ = moe_block(c, x, p)
-            else:
-                x = mlp_block(c, x, p)
-            return x, (ck, cv)
-
-        ops = (
-            (params["layers"], state.k, state.v)
-            if bank is None
-            else (params["layers"], bank["layers"], state.k, state.v)
+        aix = state.adapter_ix
+        x, new_k, new_v = _layer_loop(
+            c, params, x, positions, state.k, state.v,
+            blk, off, state.block_tables, positions + 1,
+            bank=bank, adapter_ix=aix, has_lora=jnp.any(act0 & (aix >= 0)),
+            attn_impl=attn_impl,
         )
-        x, (new_k, new_v) = lax.scan(body, x, ops)
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         logits = logits_linear(h, params["lm_head"])         # (B, S, V)
 

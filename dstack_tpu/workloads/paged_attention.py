@@ -12,6 +12,15 @@ it. This module deletes that trade entirely: attention runs directly
 against the pool, vLLM-PagedAttention-style, one block at a time with a
 streaming softmax, and the dense view is never materialized.
 
+`ragged_attention` takes the STACKED pool and a layer index, never one
+layer's `(num_blocks, block_size, KV, hd)` slab: the paged programs
+carry the whole pool through their layer loop (kv_blocks._layer_loop),
+and a slab cut out of it to feed this module would be a copy of every
+block of the layer per layer-step — the traced runs of PR 22 / PR 24
+read 0.44 ms per slab at a 151 MB layer, four per layer-step, 64% of the
+chat cell's device time. Both paths below address layer l's block j
+inside the stack instead.
+
 Two implementations behind one dispatch seam (`ragged_attention`):
 
 - `_ragged_attention_pallas`: a Pallas TPU kernel. Block tables ride in
@@ -19,9 +28,12 @@ Two implementations behind one dispatch seam (`ragged_attention`):
   each grid step's BlockSpec index_map resolves `tables[b, j]` into the
   pool's block axis and the DMA engine streams exactly that block's
   `(block_size * KV, hd)` K/V slab HBM→VMEM — the gather IS the
-  index_map. Softmax state (running max m, denominator l, unnormalized
-  output o) accumulates in VMEM scratch across the innermost grid axis,
-  the standard flash accumulation (same math as
+  index_map. The pool goes in as `(L * num_blocks, block_size * KV, hd)`
+  (a bitcast of the stack) and the layer index as a third
+  scalar-prefetch operand, so the index_map lands on row
+  `layer * num_blocks + tables[b, j]`. Softmax state (running max m,
+  denominator l, unnormalized output o) accumulates in VMEM scratch
+  across the innermost grid axis, the standard flash accumulation (same math as
   `attention._block_attend`). Pad-sentinel table entries (== num_blocks)
   clamp to a real block in the index_map and are masked out of the
   logits, as are rows at or beyond each query's `valid_len`. The block
@@ -40,7 +52,8 @@ Two implementations behind one dispatch seam (`ragged_attention`):
   `(B, block_size)` block column — O(B·block_size) transient memory,
   never a dense `(max_len)` view. Both loops are capped at the number
   of columns any live row actually needs, so short contexts don't pay
-  for the table tail.
+  for the table tail. The block column is gathered from the flattened
+  `(L * num_blocks, ...)` stack at `layer * num_blocks + block`.
 
 Both paths mask, scale, and accumulate identically, so the
 interpret-mode parity test (tests/test_paged_attention.py) pins them
@@ -49,8 +62,8 @@ on the test's f32 inputs the quantization casts are no-ops).
 
 Semantics: query row (b, i) attends cache positions `p < valid_len[b, i]`
 in slot b's context; position p lives at block `tables[b, p // bs]`, row
-`p % bs` of the pool. Garbage in masked rows (unwritten blocks, pad
-sentinels, stale reuse) never reaches the softmax.
+`p % bs` of layer `layer` of the pool. Garbage in masked rows (unwritten
+blocks, pad sentinels, stale reuse) never reaches the softmax.
 """
 
 import functools
@@ -113,47 +126,52 @@ def ragged_attention(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
+    layer: jnp.ndarray,
     tables: jnp.ndarray,
     valid_len: jnp.ndarray,
     *,
     impl: Optional[str] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Ragged paged attention over one layer's block pool.
+    """Ragged paged attention over one layer of the stacked block pool.
 
-    q:        (B, S, H, hd)      queries (S=1 decode, S=k+1 verify, S=C chunk)
-    k_pool:   (NB, bs, KV, hd)   one layer of the shared block pool
-    v_pool:   (NB, bs, KV, hd)
-    tables:   (B, MB) int32      per-slot block tables, pad sentinel == NB
-    valid_len:(B, S) int32       row (b, i) attends positions < valid_len[b, i]
+    q:        (B, S, H, hd)       queries (S=1 decode, S=k+1 verify, S=C chunk)
+    k_pool:   (L, NB, bs, KV, hd) the whole shared block pool, all layers
+    v_pool:   (L, NB, bs, KV, hd)
+    layer:    () int32            the layer whose blocks are attended, < L
+    tables:   (B, MB) int32       per-slot block tables, pad sentinel == NB
+    valid_len:(B, S) int32        row (b, i) attends positions < valid_len[b, i]
 
     Returns (B, S, H*hd) in q.dtype, matching the dense consumers' shape.
     """
     if impl is None:
         impl = dispatch_path(
-            tables.shape[1] * k_pool.shape[1],
+            tables.shape[1] * k_pool.shape[2],
             q.shape[-1],
-            k_pool.shape[1],
+            k_pool.shape[2],
             dtype_bytes=k_pool.dtype.itemsize,
             interpret=interpret,
         )
     if impl == "pallas":
         return _ragged_attention_pallas(
-            q, k_pool, v_pool, tables, valid_len, interpret=interpret
+            q, k_pool, v_pool, layer, tables, valid_len, interpret=interpret
         )
-    return _ragged_attention_lax(q, k_pool, v_pool, tables, valid_len)
+    return _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len)
 
 
 # ------------------------------------------------------------- lax fallback
 
 
-def _ragged_attention_lax(q, k_pool, v_pool, tables, valid_len):
+def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len):
     """Gather-free fallback: two fori_loop passes over table columns.
 
-    Per step the only gather is `jnp.take(pool, tables[:, j])` — one
-    (B, bs, KV, hd) block column, clip-guarded against the pad sentinel
-    and masked before the softmax. Pass 1 streams the softmax stats
-    (running max, rescaled denominator); pass 2 accumulates the PV
+    Per step the only gather is `jnp.take(pool, layer * NB + tables[:, j])`
+    over the stack flattened to (L * NB, bs, KV, hd) — one (B, bs, KV, hd)
+    block column, clip-guarded against the pad sentinel (BEFORE the layer
+    offset, so a sentinel never reaches into layer l+1) and masked before
+    the softmax; the layer's slab is never cut out of the stack. Pass 1
+    streams the softmax stats (running max, rescaled denominator); pass
+    2 accumulates the PV
     product with the probabilities normalized at the FINAL (m, l) and
     quantized to q.dtype first. That quantization is deliberate: the
     dense consumers this path replaced (generate._cached_attention,
@@ -166,24 +184,28 @@ def _ragged_attention_lax(q, k_pool, v_pool, tables, valid_len):
     buys exactness without any (max_len)-sized scratch.
     """
     b, s, h, hd = q.shape
-    nb, bs, kv, _ = k_pool.shape
+    n_layers, nb, bs, kv, _ = k_pool.shape
     mb = tables.shape[1]
     n_rep = h // kv
     scale = hd ** -0.5
+    k_pool = k_pool.reshape(n_layers * nb, bs, kv, hd)
+    v_pool = v_pool.reshape(n_layers * nb, bs, kv, hd)
+    base = jnp.asarray(layer, jnp.int32) * nb
 
     # Columns any live row needs: garbage-masked steps past this are pure
     # no-ops, so skip them (short contexts in a MB-wide table).
     n_cols = jnp.minimum((jnp.max(valid_len) + bs - 1) // bs, mb)
 
     def _block(j):
-        """Masked logits for table column j plus the clamped block ids.
+        """Masked logits for table column j plus the clamped rows of
+        the flattened stack its blocks live in.
 
         Same dtype/scale placement as the flat reference: the einsum
         takes q/k in storage dtype with an f32 accumulator, scale lands
         on the f32 logits.
         """
         col = lax.dynamic_index_in_dim(tables, j, axis=1, keepdims=False)
-        safe = jnp.clip(col, 0, nb - 1)
+        safe = base + jnp.clip(col, 0, nb - 1)
         kb = _repeat_kv(jnp.take(k_pool, safe, axis=0), n_rep)
         logits = jnp.einsum(
             "bshd,bthd->bhst", q, kb, preferred_element_type=jnp.float32
@@ -228,8 +250,8 @@ def _ragged_attention_lax(q, k_pool, v_pool, tables, valid_len):
 # one-head K tile `(bs, 1, hd)` is not a legal block. The kernel instead
 # takes every operand as a 2-D slab whose trailing dims are whole:
 #
-#   pool  (NB, bs, KV, hd) -> (NB, bs*KV, hd)   rows ordered (t, g)
-#   q     (B, S, H, hd)    -> (B, S*H, hd)      rows ordered (s, h)
+#   pool  (L, NB, bs, KV, hd) -> (L*NB, bs*KV, hd)   rows ordered (t, g)
+#   q     (B, S, H, hd)       -> (B, S*H, hd)        rows ordered (s, h)
 #
 # (both reshapes keep the row-major order; with KV a multiple of the
 # sublane tile they are layout bitcasts, not copies). One grid step then
@@ -243,6 +265,7 @@ def _ragged_attention_lax(q, k_pool, v_pool, tables, valid_len):
 def _paged_kernel(
     t_ref,  # scalar prefetch: (B, MB) block tables in SMEM
     nc_ref,  # scalar prefetch: (B,) table columns row b actually needs
+    layer_ref,  # scalar prefetch: (1,) layer index — the index_maps' alone
     q_ref,  # (TQ, hd) query rows, ordered (s, h)
     vlen_ref,  # (TQ, 1) valid_len of each query row
     k_ref,  # (bs*KV, hd) — the block the index_map resolved for step j
@@ -336,9 +359,11 @@ def _q_tile_positions(s: int, h: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _ragged_attention_pallas(q, k_pool, v_pool, tables, valid_len, *, interpret=False):
+def _ragged_attention_pallas(
+    q, k_pool, v_pool, layer, tables, valid_len, *, interpret=False
+):
     b, s, h, hd = q.shape
-    nb, bs, kv, _ = k_pool.shape
+    n_layers, nb, bs, kv, _ = k_pool.shape
     mb = tables.shape[1]
     ts = _q_tile_positions(s, h)
     tq = ts * h
@@ -347,15 +372,17 @@ def _ragged_attention_pallas(q, k_pool, v_pool, tables, valid_len, *, interpret=
     valid_len = valid_len.astype(jnp.int32)
     n_cols = jnp.clip((jnp.max(valid_len, axis=1) + bs - 1) // bs, 1, mb)
 
-    def _table_block(bi, qi, ji, t, nc):
+    def _table_block(bi, qi, ji, t, nc, lyr):
         # The gather IS the index_map: scalar-prefetched tables steer the
-        # DMA straight at the slot's j-th block (sentinel clamps in-range;
-        # the kernel masks its rows). Past the row's last live column the
-        # index stays put, and an unchanged block index is not re-fetched.
+        # DMA straight at the slot's j-th block of this layer (sentinel
+        # clamps in-range of the layer's own NB blocks BEFORE the layer
+        # offset; the kernel masks its rows). Past the row's last live
+        # column the index stays put, and an unchanged block index is not
+        # re-fetched.
         live = jnp.minimum(ji, nc[bi] - 1)
-        return (jnp.minimum(t[bi, live], nb - 1), 0, 0)
+        return (lyr[0] * nb + jnp.minimum(t[bi, live], nb - 1), 0, 0)
 
-    def _q_rows(bi, qi, ji, t, nc):
+    def _q_rows(bi, qi, ji, t, nc, lyr):
         return (bi, qi, 0)
 
     kernel = functools.partial(
@@ -369,7 +396,7 @@ def _ragged_attention_pallas(q, k_pool, v_pool, tables, valid_len, *, interpret=
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((None, tq, hd), _q_rows),
@@ -390,9 +417,10 @@ def _ragged_attention_pallas(q, k_pool, v_pool, tables, valid_len, *, interpret=
     )(
         tables,
         n_cols,
+        jnp.asarray(layer, jnp.int32).reshape(1),
         q.reshape(b, s * h, hd),
         jnp.repeat(valid_len, h, axis=1)[:, :, None],
-        k_pool.reshape(nb, bs * kv, hd),
-        v_pool.reshape(nb, bs * kv, hd),
+        k_pool.reshape(n_layers * nb, bs * kv, hd),
+        v_pool.reshape(n_layers * nb, bs * kv, hd),
     )
     return out.reshape(b, s, h * hd)

@@ -1,13 +1,15 @@
 """BlockAllocator unit tests: refcounts, prefix cache, CoW, eviction.
 
 The allocator is pure host-side Python (the engine serializes it under
-its own lock), so these tests pin its invariants without touching JAX:
+its own lock), so these tests pin its invariants without touching JAX
+(the last test of the file, on the jitted programs' writes, apart):
 a block leaves the free list only via alloc(), returns only at refcount
 zero, cache retention counts as a reference, and the sha1-chained match
 walk never covers the last prompt token (the prefill must compute the
 last position's logits to sample the first output token).
 """
 
+import numpy as np
 import pytest
 
 from dstack_tpu.workloads.kv_blocks import BlockAllocator, init_paged_state
@@ -277,3 +279,95 @@ def test_drop_cache_empty_is_noop():
     a = BlockAllocator(num_blocks=2, block_size=BS)
     assert a.drop_cache() == 0
     assert a.drop_cache() == 0  # idempotent
+
+
+# -- the jitted programs write rows, not slabs ---------------------------------
+
+
+def _random_pool_state(cfg, slots, max_len, block, seed):
+    """A paged state whose pool holds random bits in every layer, so an
+    untouched block is one that still holds exactly those bits."""
+    import jax
+    import jax.numpy as jnp
+
+    nb = slots * (max_len // block)
+    st = init_paged_state(cfg, slots, max_len, block, nb)
+    kk, kv = jax.random.split(jax.random.PRNGKey(seed))
+    return st._replace(
+        k=jax.random.normal(kk, st.k.shape, jnp.float32).astype(st.k.dtype),
+        v=jax.random.normal(kv, st.v.shape, jnp.float32).astype(st.v.dtype),
+    )
+
+
+def _assert_only_rows_written(before, after, rows):
+    """Every layer of the pool is bit-identical outside `rows`, a list of
+    (block, offset), and differs in each of them."""
+    before, after = np.asarray(before), np.asarray(after)
+    written = np.zeros(before.shape[:3], bool)
+    for blk, off in rows:
+        written[:, blk, off] = True
+    changed = (before != after).any(axis=(3, 4))
+    assert (changed == written).all(), (
+        "layers/blocks/rows that differ from the rows written: "
+        f"{np.argwhere(changed != written)[:8].tolist()}"
+    )
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_paged_program_writes_only_its_rows_in_every_layer(program):
+    """The pool is carried through the layer loop and written at
+    [layer, block, offset]: a lane pointed at the sentinel block drops
+    (it must not spill into block 0 of layer l+1, which is where
+    sentinel + l * num_blocks would land on a flattened pool), and no
+    layer's other blocks change by a bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from dstack_tpu.workloads.kv_blocks import (
+        make_chunk_prefill,
+        make_paged_decode_step,
+    )
+    from dstack_tpu.workloads.transformer import init_params
+
+    cfg = PRESETS["tiny"].with_(remat=False, n_layers=3)
+    slots, max_len, block = 3, 32, 8
+    mb = max_len // block
+    st = _random_pool_state(cfg, slots, max_len, block, seed=1)
+    nb = st.k.shape[1]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    k0, v0 = np.asarray(st.k), np.asarray(st.v)
+
+    if program == "decode_step":
+        # Slot 0 writes row 10 = block 7, offset 2; slot 1 is inactive
+        # with a STALE table row (its lane must drop); slot 2 is active
+        # with a full cache (lengths == max_len: the write is refused).
+        tables = np.full((slots, mb), nb, np.int32)
+        tables[0, :2] = [5, 7]
+        tables[1, :2] = [0, 1]
+        tables[2] = [2, 3, 4, 6]
+        st = st._replace(
+            block_tables=jnp.asarray(tables),
+            lengths=jnp.asarray([10, 9, max_len], jnp.int32),
+            last_token=jnp.asarray([3, 4, 5], jnp.int32),
+            active=jnp.asarray([True, False, True]),
+            remaining=jnp.asarray([4, 4, 4], jnp.int32),
+        )
+        step = make_paged_decode_step(cfg, steps=1)
+        out, _, _ = step(params, st, jax.random.PRNGKey(2))
+        rows = [(7, 2)]
+    else:
+        # A chunk of 8 lanes, 5 real, at positions 6..10: rows 6, 7 of
+        # block 9 and rows 0..2 of block 4; three padded lanes drop.
+        row = np.full((mb,), nb, np.int32)
+        row[:2] = [9, 4]
+        chunk = make_chunk_prefill(cfg, 8)
+        out, _ = chunk(
+            params, st, jnp.int32(1), jnp.asarray(row),
+            jnp.asarray([[7, 8, 9, 10, 11, 0, 0, 0]], jnp.int32),
+            jnp.int32(5), jnp.int32(6), jnp.int32(4), jnp.float32(0.0),
+            jnp.float32(1.0), jax.random.PRNGKey(2), jnp.bool_(True),
+        )
+        rows = [(9, 6), (9, 7), (4, 0), (4, 1), (4, 2)]
+
+    _assert_only_rows_written(k0, out.k, rows)
+    _assert_only_rows_written(v0, out.v, rows)

@@ -97,8 +97,10 @@ def test_pallas_interpret_matches_lax_fallback(shape):
     interpret-mode kernel output must match the fallback bit-tightly on
     identical inputs (sentinel-padded tables, ragged valid lengths)."""
     q, kp, vp, tables, vlen = _ragged_inputs(7, *shape)
-    got_lax = _ragged_attention_lax(q, kp, vp, tables, vlen)
-    got_pal = _ragged_attention_pallas(q, kp, vp, tables, vlen, interpret=True)
+    got_lax = _ragged_attention_lax(q, kp[None], vp[None], 0, tables, vlen)
+    got_pal = _ragged_attention_pallas(
+        q, kp[None], vp[None], 0, tables, vlen, interpret=True
+    )
     np.testing.assert_allclose(
         np.asarray(got_pal), np.asarray(got_lax), rtol=1e-6, atol=1e-6
     )
@@ -110,9 +112,47 @@ def test_ragged_matches_flat_softmax_reference(shape):
     densified view (the pre-r12 semantics) to f32 accuracy."""
     q, kp, vp, tables, vlen = _ragged_inputs(11, *shape)
     ref = _flat_softmax_reference(q, kp, vp, tables, vlen)
-    got = ragged_attention(q, kp, vp, tables, vlen)  # lax path on CPU
+    got = ragged_attention(q, kp[None], vp[None], 0, tables, vlen)  # lax on CPU
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref), rtol=1e-5, atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("impl", ["lax", "pallas"])
+@pytest.mark.parametrize("shape", SHAPES)
+def test_layer_index_addresses_one_layer_of_the_stack(shape, impl):
+    """Over a stacked (L, NB, ...) pool with a layer index, both paths
+    equal the per-slab call on `pool[l]` (a one-layer stack, layer 0) for
+    a layer in the middle — with pad-sentinel table entries, and one lane
+    whose whole table row is the sentinel (an inactive or padded slot).
+    Every OTHER layer's V is NaN: a sentinel clamped after the layer
+    offset instead of before it would read block 0 of layer l+1, and a
+    masked NaN row survives the PV product as 0 * NaN."""
+    B, S, H, KV, hd, NB, bs, MB = shape
+    n_layers, layer = 4, 2
+    q, _, _, tables, vlen = _ragged_inputs(5, *shape)
+    tables = tables.at[B - 1].set(NB)  # the padded lane: nothing to attend
+    vlen = vlen.at[B - 1].set(1)
+    rng = np.random.default_rng(13)
+    kp = rng.standard_normal((n_layers, NB, bs, KV, hd)).astype(np.float32)
+    vp = rng.standard_normal((n_layers, NB, bs, KV, hd)).astype(np.float32)
+    slab_k, slab_v = jnp.asarray(kp[layer])[None], jnp.asarray(vp[layer])[None]
+    vp[np.arange(n_layers) != layer] = np.nan
+    kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+
+    if impl == "lax":
+        fn = _ragged_attention_lax
+    else:
+        fn = lambda *a: _ragged_attention_pallas(*a, interpret=True)
+    got = fn(q, kp, vp, jnp.int32(layer), tables, vlen)
+    want = fn(q, slab_k, slab_v, jnp.int32(0), tables, vlen)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ref = _flat_softmax_reference(q, slab_k[0], slab_v[0], tables, vlen)
+    # The flat softmax averages clamped garbage on the all-masked lane.
+    live = np.arange(B) != B - 1
+    np.testing.assert_allclose(
+        np.asarray(got)[live], np.asarray(ref)[live], rtol=1e-5, atol=1e-5
     )
 
 
@@ -127,7 +167,7 @@ def test_ragged_rows_never_see_masked_garbage():
     # Poison rows past each row's valid length inside used blocks too.
     vlen_np = np.asarray(vlen)
     out = ragged_attention(
-        q, jnp.asarray(poison), vp, tables, jnp.minimum(vlen, 9)
+        q, jnp.asarray(poison)[None], vp[None], 0, tables, jnp.minimum(vlen, 9)
     )
     assert np.isfinite(np.asarray(out)).all()
 

@@ -11,6 +11,8 @@ lowering. Neither runs a kernel: numerics on the chip are chip_smoke.py's
 phase b.
 """
 
+import math
+import re
 import types
 
 import jax
@@ -19,6 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from dstack_tpu.workloads import flash_attention as fa
+from dstack_tpu.workloads import kv_blocks
 from dstack_tpu.workloads.attention import make_attention_fn
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.paged_attention import _ragged_attention_pallas
@@ -33,6 +36,9 @@ H, KV, HD = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
 SLOTS, CHUNK, BLOCK, MAX_DRAFT = 8, 128, 16, 4
 MAX_BLOCKS = CFG.max_seq_len // BLOCK
 POOL_BLOCKS = SLOTS * MAX_BLOCKS
+# The kernel addresses one layer inside the stacked pool; the programs'
+# HLO guard below runs at this depth too (a middle layer exists).
+POOL_LAYERS = 3
 # The engine's own bucketing rule, so this list cannot drift from it.
 PREFILL_BUCKETS = sorted({
     ServingEngine._pad_chunk(types.SimpleNamespace(prefill_chunk_tokens=CHUNK), n)
@@ -63,9 +69,10 @@ def _kernels():
     for kind, b, s in PAGED_SHAPES:
         out.append((
             f"paged_{kind}_b{b}_s{s}", _ragged_attention_pallas,
-            [((b, s, H, HD), bf16), ((POOL_BLOCKS, BLOCK, KV, HD), bf16),
-             ((POOL_BLOCKS, BLOCK, KV, HD), bf16), ((b, MAX_BLOCKS), i32),
-             ((b, s), i32)],
+            [((b, s, H, HD), bf16),
+             ((POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD), bf16),
+             ((POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD), bf16), ((), i32),
+             ((b, MAX_BLOCKS), i32), ((b, s), i32)],
         ))
     # The trainer's shape (bench.py: S=2048) and one ring step's shard.
     q, kv = ((2, 2048, H, HD), bf16), ((2, 2048, KV, HD), bf16)
@@ -113,6 +120,117 @@ def test_kernel_builds_for_tpu(name, fn, args, v5e):
         lowered = jax.jit(fn).lower(*specs)
         lowered.compile()
     assert "tpu_custom_call" in lowered.as_text()
+
+
+# ------------------------------------------- the pool rides the layer loop
+#
+# The four paged programs carry the stacked KV pool through their layer
+# loop (kv_blocks._layer_loop). Handed to the layer scan as `xs` and
+# returned as `ys` instead, XLA slices each layer's K and V slab out and
+# writes it back every layer-step and copies the whole pool once per
+# decode step: 64% of a serving cell's device time on the v5e (PERF.md
+# §6, PR 26). The compiled HLO shows either form, so it is read here.
+
+PROGRAM_STEPS = 4  # the engine's steps_per_sync
+# An op that moves the pool or one layer's slab of it. A scatter that
+# updates the pool in place is the program's own write and is allowed.
+_MOVES = re.compile(
+    r"= \w+\[([\d,]+)\]\S* (copy|dynamic-slice|dynamic-update-slice)\("
+)
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A program compiled for a described chip is written to the
+    persistent cache but cannot be read back without one: keep the
+    whole-program compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _paged_program(name, cfg, attn_impl):
+    """(jitted program, argument shapes) at the engine's geometry."""
+    i32, f32 = jnp.int32, jnp.float32
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    st = jax.eval_shape(
+        lambda: kv_blocks.init_paged_state(
+            cfg, SLOTS, cfg.max_seq_len, BLOCK, POOL_BLOCKS
+        )
+    )
+    rng = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt)
+    if name == "decode_steps":
+        fn = kv_blocks.make_paged_decode_step(
+            cfg, PROGRAM_STEPS, attn_impl=attn_impl
+        )
+        return fn, (params, st, rng)
+    if name == "chunk_prefill":
+        fn = kv_blocks.make_chunk_prefill(cfg, CHUNK, attn_impl=attn_impl)
+        return fn, (
+            params, st, scalar(i32),
+            jax.ShapeDtypeStruct((MAX_BLOCKS,), i32),
+            jax.ShapeDtypeStruct((1, CHUNK), i32),
+            scalar(i32), scalar(i32), scalar(i32), scalar(f32), scalar(f32),
+            rng, scalar(jnp.bool_),
+        )
+    if name == "spec_draft":
+        fn = kv_blocks.make_spec_draft(cfg, MAX_DRAFT, attn_impl=attn_impl)
+        return fn, (
+            params, st.k, st.v, st.block_tables, st.lengths, st.last_token,
+            st.active, st.temperature, st.top_p, rng,
+        )
+    fn = kv_blocks.make_spec_verify(cfg, MAX_DRAFT, attn_impl=attn_impl)
+    return fn, (
+        params, st,
+        jax.ShapeDtypeStruct((SLOTS, MAX_DRAFT), i32),
+        jax.ShapeDtypeStruct((SLOTS, MAX_DRAFT, cfg.vocab_size), f32),
+        rng,
+    )
+
+
+@pytest.mark.parametrize(
+    "name", ["decode_steps", "chunk_prefill", "spec_draft", "spec_verify"]
+)
+def test_paged_program_moves_no_pool_or_slab(name, v5e, no_compile_cache):
+    """The optimized HLO of each paged program holds no copy,
+    dynamic-slice or dynamic-update-slice that produces an array of the
+    pool's or one layer slab's size. For the v5e with the Pallas kernel
+    where libtpu describes one, and there its scratch is also smaller
+    than one pool (K and V): updated in place, not held twice. Else for
+    the backend at hand on the lax path."""
+    cfg = CFG.with_(n_layers=POOL_LAYERS, remat=False)
+    fn, args = _paged_program(
+        name, cfg, "pallas" if v5e is not None else "lax_ragged"
+    )
+    if v5e is not None:
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            args,
+        )
+    compiled = fn.lower(*args).compile()
+
+    pool = (POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD)
+    sizes = {math.prod(pool): "pool", math.prod(pool[1:]): "slab"}
+    # Sizes are compared, not shapes (a bitcast keeps the size): no
+    # weight may share one, or its re-layout would read as pool traffic.
+    weights = {math.prod(a.shape) for a in jax.tree.leaves(args[0])}
+    weights |= {n // POOL_LAYERS for n in weights}
+    assert not weights & set(sizes)
+    moved = [
+        f"{m.group(2)} of the {sizes[n]} [{m.group(1)}]"
+        for m in _MOVES.finditer(compiled.as_text())
+        if (n := math.prod(int(d) for d in m.group(1).split(","))) in sizes
+    ]
+    assert not moved, moved
+    if v5e is not None:  # XLA:CPU's buffer assignment is not the chip's
+        itemsize = jnp.dtype(cfg.activation_dtype).itemsize
+        one_pool = 2 * math.prod(pool) * itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < one_pool
 
 
 @pytest.mark.parametrize("axes", [{}, {"model": 2}], ids=["fsdp4", "model2"])
