@@ -152,6 +152,7 @@ def child_kernels(rehearsal: bool) -> dict:
     """Compile every Pallas kernel at the shapes the engine and trainer
     use and compare it with the repo's own plain/lax path. Returns
     {name: max_abs_err}; raises on a tolerance miss."""
+    import functools
     import types
 
     import jax
@@ -169,6 +170,7 @@ def child_kernels(rehearsal: bool) -> dict:
     from dstack_tpu.workloads.paged_attention import (
         _ragged_attention_lax,
         _ragged_attention_pallas,
+        ragged_attention,
     )
     from dstack_tpu.workloads.serving import ServingEngine
 
@@ -296,6 +298,29 @@ def child_kernels(rehearsal: bool) -> dict:
             paged(f"paged_prefill_s{s}", 1, s, causal_chunk=True)
     for draft in range(1, max_draft + 1):
         paged(f"paged_verify_k{draft}", slots, draft + 1, causal_chunk=False)
+
+    # -- latent paged kernel vs the lax path -------------------------------
+    # One row a position for all heads, the values its leading columns
+    # (GLM-4.7-Flash: 512 + 64 padded to 640, 20 heads); the engine's two
+    # shapes for it, decode and a prefill chunk.
+    latent_pool = rand(keys[7], (n_pool_layers, nb, block, 1, 640))
+    no_v = jnp.zeros((n_pool_layers, nb, block, 1, 0), latent_pool.dtype)
+
+    def latent(name, b, s):
+        t, n_blk = tables_for(b)
+        start = rng.integers(0, n_blk * block - s + 1)
+        vlen = (start[:, None] + 1 + np.arange(s)[None]).astype(np.int32)
+        qq = rand(jax.random.fold_in(keys[1], b * 1000 + s), (b, s, 20, 640))
+        args = (qq, latent_pool, no_v, layer, jnp.asarray(t), jnp.asarray(vlen))
+        kw = {"latent_values": 512, "scale": 256 ** -0.5}
+        check(
+            name,
+            ragged_attention(*args, impl="pallas", interpret=interpret, **kw),
+            jax.jit(functools.partial(_ragged_attention_lax, **kw))(*args),
+        )
+
+    latent(f"latent_decode_b{slots}", slots, 1)
+    latent(f"latent_prefill_s{buckets[-1]}", 1, buckets[-1])
 
     if failed:
         raise PhaseFailed("kernel tolerance: " + "; ".join(failed))

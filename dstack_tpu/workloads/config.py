@@ -7,7 +7,7 @@ cleanly onto the 128x128 MXU (pallas_guide: Tiling Constraints).
 
 import os
 from dataclasses import dataclass, replace
-from typing import Dict, Optional, Union
+from typing import Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
 
@@ -52,10 +52,91 @@ class ModelConfig:
     # bytes/token of saved residuals, which is what lets the flagship
     # bench shape run the remat-free rung (docs/design/perf.md).
     ce_chunk: int = 0
+    # Latent attention (MLA), absent at 0: queries go through a rank-
+    # `q_lora_rank` bottleneck, keys and values come from ONE latent row a
+    # token of `kv_lora_rank` values plus `qk_rope_head_dim` rotary key
+    # values shared by every head (transformer.project_latent). A head
+    # scores over `qk_nope_head_dim + qk_rope_head_dim` values and emits
+    # `v_head_dim`; none of them is d_model // n_heads. n_kv_heads is not
+    # read: the cache keeps no per-head rows (kv_row_shapes).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Expert models whose first `n_dense_layers` blocks are plain SwiGLU at
+    # width `dense_d_ff` (params["dense_layers"]); the n_layers -
+    # n_dense_layers that follow carry the expert bank at width d_ff.
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # Shared experts: a SwiGLU of width n_shared_experts * d_ff that every
+    # token passes through beside its routed experts.
+    n_shared_experts: int = 0
+    # Router (moe.route_assignments). "softmax": scores are a softmax over
+    # the experts, top-k by score. "sigmoid": scores are sigmoids, top-k by
+    # score + a per-expert bias that chooses and does not weigh (the
+    # `router_bias` leaf). The chosen weights are renormalised to sum to 1
+    # and multiplied by routed_scaling.
+    router_score: str = "softmax"
+    routed_scaling: float = 1.0
+
+    def __post_init__(self):
+        if self.kv_lora_rank > 0 and not (
+            self.q_lora_rank > 0 and self.qk_rope_head_dim > 0
+            and self.qk_nope_head_dim > 0 and self.v_head_dim > 0
+        ):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank,"
+                " qk_nope_head_dim, qk_rope_head_dim and v_head_dim"
+            )
+        if self.n_dense_layers and not (
+            self.n_experts > 0 and self.dense_d_ff > 0
+            and self.n_dense_layers < self.n_layers
+        ):
+            raise ValueError(
+                "n_dense_layers leads an expert model: it needs n_experts,"
+                " dense_d_ff and at least one expert layer after it"
+            )
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_score={self.router_score!r}: expected 'softmax' or"
+                " 'sigmoid'"
+            )
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def head_dim(self) -> int:
+        """Values a query head scores over."""
+        if self.latent:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def latent_row(self) -> int:
+        """Values a token keeps per layer under latent attention: the
+        normed latent, then the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def kv_row_shapes(self) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+        """The trailing (heads, width) of one cached token's row in the
+        k pool and in the v pool — the ONE place the cache geometry is
+        derived; pools, transfers, the host tier and budgets read it.
+        GQA keeps a key and a value per KV head. Latent attention keeps
+        one row for all heads and no value row (its values are the first
+        kv_lora_rank columns of the key row, so the v pool is zero wide);
+        the row is padded to whole 128-value lanes, which is what the TPU
+        tiles, and `latent_row` is what the algorithm needs."""
+        if self.latent:
+            return (1, -(-self.latent_row // 128) * 128), (1, 0)
+        return (self.n_kv_heads, self.head_dim), (self.n_kv_heads, self.head_dim)
+
+    def kv_row_bytes(self) -> int:
+        """Bytes one token allocates per layer across both pools."""
+        (kh, kw), (vh, vw) = self.kv_row_shapes()
+        return (kh * kw + vh * vw) * self.dtype_bytes
 
     @property
     def activation_dtype(self):
@@ -68,16 +149,44 @@ class ModelConfig:
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
 
-    def param_count(self) -> int:
-        """Approximate parameter count (embedding + head untied)."""
-        d, f, v = self.d_model, self.d_ff, self.vocab_size
+    def attn_params(self) -> int:
+        """Weights of one block's attention projections (no norms)."""
+        d, h = self.d_model, self.n_heads
+        if self.latent:
+            return (
+                d * self.q_lora_rank + self.q_lora_rank * h * self.head_dim
+                + d * self.latent_row
+                + self.kv_lora_rank
+                * h * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d
+            )
         hd = self.head_dim
-        attn = d * (self.n_heads + 2 * self.n_kv_heads) * hd + self.n_heads * hd * d
-        if self.n_experts > 0:
-            mlp = 3 * d * f * self.n_experts + d * self.n_experts
-        else:
-            mlp = 3 * d * f
-        return self.n_layers * (attn + mlp) + 2 * d * v
+        return d * (h + 2 * self.n_kv_heads) * hd + h * hd * d
+
+    def mlp_params(self, dense: bool = False) -> int:
+        """Weights of one block's MLP: the plain SwiGLU of a leading
+        dense layer (`dense`) or of a model without experts, else the
+        whole expert bank, the shared experts and the router."""
+        d = self.d_model
+        if self.n_experts == 0:
+            return 3 * d * self.d_ff
+        if dense:
+            return 3 * d * self.dense_d_ff
+        return (
+            3 * d * self.d_ff * (self.n_experts + self.n_shared_experts)
+            + d * self.n_experts
+        )
+
+    def param_count(self) -> int:
+        """Approximate parameter count (embedding + head untied; norms
+        and the router's selection bias are not counted)."""
+        nd = self.n_dense_layers
+        return (
+            self.n_layers * self.attn_params()
+            + nd * self.mlp_params(dense=True)
+            + (self.n_layers - nd) * self.mlp_params()
+            + 2 * self.d_model * self.vocab_size
+        )
 
     def resolve_remat(
         self,
@@ -173,17 +282,22 @@ class ModelConfig:
         (a conservative lower bound). MoE counts the k active experts
         per token plus the router matmul, not the full expert bank."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        hd = self.head_dim
-        attn_proj = 2 * d * (self.n_heads + 2 * self.n_kv_heads) * hd + 2 * self.n_heads * hd * d
+        per_layer = 2 * self.attn_params()
+        if seq_len:
+            # causal QK^T + AV: half of 2 * S * (scored + emitted) a head
+            v_dim = self.v_head_dim if self.latent else self.head_dim
+            per_layer += seq_len * self.n_heads * (self.head_dim + v_dim)
         if self.n_experts > 0:
-            mlp = 3 * 2 * d * f * self.experts_per_token + 2 * d * self.n_experts
+            active = self.experts_per_token + self.n_shared_experts
+            mlp = 3 * 2 * d * f * active + 2 * d * self.n_experts
         else:
             mlp = 3 * 2 * d * f
-        per_layer = attn_proj + mlp
-        if seq_len:
-            per_layer += 2 * seq_len * self.n_heads * hd  # causal QK^T + AV
+        nd = self.n_dense_layers
         embed = 2 * d * v
-        fwd = self.n_layers * per_layer + embed
+        fwd = (
+            self.n_layers * per_layer + (self.n_layers - nd) * mlp
+            + nd * 3 * 2 * d * self.dense_d_ff + embed
+        )
         return 3.0 * fwd
 
 
@@ -222,6 +336,18 @@ PRESETS: Dict[str, ModelConfig] = {
         vocab_size=512, d_model=128, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=256, max_seq_len=256, remat=False, n_experts=4,
         experts_per_token=2,
+    ),
+    # Latent attention + a leading dense layer + sigmoid-routed experts
+    # beside a shared one, for tests/dryrun: every mechanism of the
+    # glm4_moe_lite block at a size the CPU runs (1 dense + 2 expert
+    # layers). capacity_factor = experts / experts per token: no drop.
+    "tiny-latent": ModelConfig(
+        vocab_size=512, d_model=128, n_layers=3, n_heads=4, n_kv_heads=4,
+        d_ff=64, max_seq_len=256, remat=False, n_experts=8,
+        experts_per_token=2, capacity_factor=4.0, q_lora_rank=48,
+        kv_lora_rank=32, qk_nope_head_dim=24, qk_rope_head_dim=16,
+        v_head_dim=32, n_dense_layers=1, dense_d_ff=256,
+        n_shared_experts=1, router_score="sigmoid", routed_scaling=1.8,
     ),
     # Mixtral-shaped 8x top-2 at the 1B-active scale.
     "smol-moe": ModelConfig(

@@ -26,9 +26,13 @@ from jax import lax
 from dstack_tpu.workloads.attention import NEG_INF, _repeat_kv
 from dstack_tpu.workloads.config import ModelConfig
 from dstack_tpu.workloads.transformer import (
+    absorb_query,
+    latent_output,
+    layer_stacks,
     linear,
     logits_linear,
     mlp_block,
+    project_latent,
     project_qkv,
     rms_norm,
 )
@@ -37,7 +41,9 @@ Params = Dict[str, Any]
 
 
 class KVCache(NamedTuple):
-    """Static-shape per-layer cache: k/v (L, B, max_len, KV, hd)."""
+    """Static-shape per-layer cache: k/v (L, B, max_len, KV, hd); a
+    latent-attention model keeps one (1, row) latent row a token in k
+    and a zero-wide v (ModelConfig.kv_row_shapes)."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -48,24 +54,27 @@ def init_cache(
     config: ModelConfig, batch: int, max_len: int, dtype=None
 ) -> KVCache:
     c = config
-    shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
+    k_row, v_row = c.kv_row_shapes()
+    shape = (c.n_layers, batch, max_len)
     dtype = dtype or c.activation_dtype
     return KVCache(
-        k=jnp.zeros(shape, dtype),
-        v=jnp.zeros(shape, dtype),
+        k=jnp.zeros(shape + k_row, dtype),
+        v=jnp.zeros(shape + v_row, dtype),
         length=jnp.zeros((), jnp.int32),
     )
 
 
-def _cached_attention(q, ck, cv, valid_len):
+def _cached_attention(q, ck, cv, valid_len, scale=None):
     """q (B, S, H, hd) against cache k/v (B, max_len, KV, hd); positions at
     or beyond valid_len (zero padding) are masked out. Causality inside the
-    new tokens is handled by the caller's masking of valid_len per row."""
+    new tokens is handled by the caller's masking of valid_len per row.
+    `cv` may be narrower than `ck` (latent attention's values are the
+    leading columns of its key rows); `scale` defaults to hd ** -0.5."""
     b, s, h, hd = q.shape
     n_rep = h // ck.shape[2]
     k = _repeat_kv(ck, n_rep)
     v = _repeat_kv(cv, n_rep)
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -77,7 +86,7 @@ def _cached_attention(q, ck, cv, valid_len):
     out = jnp.einsum(
         "bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32
     )
-    return out.astype(q.dtype).reshape(b, s, h * hd)
+    return out.astype(q.dtype).reshape(b, s, h * v.shape[-1])
 
 
 def _forward_cached(
@@ -100,12 +109,30 @@ def _forward_cached(
 
     def body(x, layer):
         p, ck, cv = layer
-        q, k, v = project_qkv(c, x, p, positions)
-        ck = lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, start, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, start, 0, 0))
-        attn = _cached_attention(q, ck, cv, valid_len)
-        x = x + linear(attn, p["wo"])
-        if c.n_experts > 0:
+        if c.latent:
+            # The cache row is [c_kv | k_rope | pad]; decode attends in
+            # the absorbed form (transformer.absorb_query), as the paged
+            # engine does.
+            q, row = project_latent(c, x, p, positions, ck.shape[-1])
+            ck = lax.dynamic_update_slice(
+                ck, row[:, :, None].astype(ck.dtype), (0, start, 0, 0)
+            )
+            o_lat = _cached_attention(
+                absorb_query(c, q, p, ck.shape[-1]), ck,
+                ck[..., : c.kv_lora_rank], valid_len, c.head_dim ** -0.5,
+            )
+            x = x + latent_output(c, o_lat, p)
+        else:
+            q, k, v = project_qkv(c, x, p, positions)
+            ck = lax.dynamic_update_slice(
+                ck, k.astype(ck.dtype), (0, start, 0, 0)
+            )
+            cv = lax.dynamic_update_slice(
+                cv, v.astype(cv.dtype), (0, start, 0, 0)
+            )
+            attn = _cached_attention(q, ck, cv, valid_len)
+            x = x + linear(attn, p["wo"])
+        if "router" in p:
             from dstack_tpu.workloads.moe import moe_block
 
             x, _ = moe_block(c, x, p)
@@ -113,7 +140,18 @@ def _forward_cached(
             x = mlp_block(c, x, p)
         return x, (ck, cv)
 
-    x, (new_k, new_v) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
+    new_k, new_v, first = [], [], 0
+    for stack in layer_stacks(params):
+        n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+        x, (sk, sv) = lax.scan(
+            body, x,
+            (stack, cache.k[first:first + n], cache.v[first:first + n]),
+        )
+        new_k.append(sk)
+        new_v.append(sv)
+        first += n
+    new_k = new_k[0] if len(new_k) == 1 else jnp.concatenate(new_k)
+    new_v = new_v[0] if len(new_v) == 1 else jnp.concatenate(new_v)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     logits = logits_linear(x[:, -1], params["lm_head"])
     return logits, KVCache(k=new_k, v=new_v, length=start + s)

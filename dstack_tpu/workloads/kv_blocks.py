@@ -63,9 +63,13 @@ from dstack_tpu.workloads.generate import (
 )
 from dstack_tpu.workloads.paged_attention import ragged_attention
 from dstack_tpu.workloads.transformer import (
+    absorb_query,
+    latent_output,
+    layer_stacks,
     linear,
     logits_linear,
     mlp_block,
+    project_latent,
     project_qkv,
     rms_norm,
 )
@@ -79,6 +83,9 @@ class PagedDecodeState(NamedTuple):
     (`_any_active_nucleus` / `_any_active_sampling`) and engine-level
     tests work on either."""
 
+    # The pools' trailing (heads, width) is ModelConfig.kv_row_shapes():
+    # (KV, hd) twice for GQA; ONE latent pool (1, row) and a zero-wide v
+    # (no bytes, never read) for latent attention.
     k: jnp.ndarray            # (L, num_blocks, block_size, KV, hd)
     v: jnp.ndarray
     block_tables: jnp.ndarray  # (B, max_blocks) int32; pad = num_blocks
@@ -104,10 +111,11 @@ def init_paged_state(
             f"kv_block_size {block_size} must divide max_len {max_len}"
         )
     max_blocks = max_len // block_size
-    shape = (c.n_layers, num_blocks, block_size, c.n_kv_heads, c.head_dim)
+    k_row, v_row = c.kv_row_shapes()
+    shape = (c.n_layers, num_blocks, block_size)
     return PagedDecodeState(
-        k=jnp.zeros(shape, c.activation_dtype),
-        v=jnp.zeros(shape, c.activation_dtype),
+        k=jnp.zeros(shape + k_row, c.activation_dtype),
+        v=jnp.zeros(shape + v_row, c.activation_dtype),
         block_tables=jnp.full((batch, max_blocks), num_blocks, jnp.int32),
         lengths=jnp.zeros((batch,), jnp.int32),
         last_token=jnp.zeros((batch,), jnp.int32),
@@ -468,23 +476,38 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
         project = lambda x, p, lp: project_qkv_lora(
             c, x, p, positions, lp, safe, scale, has_lora
         )
-    xs = (
-        params["layers"],
-        jnp.arange(k_pool.shape[0], dtype=jnp.int32),
-        None if bank is None else bank["layers"],
-    )
 
-    def body(carry, layer):
-        x, kp, vp = carry
-        p, l, lp = layer
+    def attend(x, p, lp, l, kp, vp):
+        """Write the rows of layer l, attend over the tables -> the
+        block's attention output (before the residual) and the pools."""
+        if c.latent:
+            # One row a token for all heads; the absorbed query scores
+            # straight against cached rows and no step up-projects them.
+            q, row = project_latent(c, x, p, positions, kp.shape[-1])
+            kp = kp.at[l, blk, off].set(
+                row[:, :, None].astype(kp.dtype), mode="drop"
+            )
+            q = absorb_query(c, q, p, kp.shape[-1])
+            with jax.named_scope("mla/attend"):
+                o_lat = ragged_attention(
+                    q, kp, vp, l, tables, valid_len, impl=attn_impl,
+                    latent_values=c.kv_lora_rank, scale=c.head_dim ** -0.5,
+                )
+            return latent_output(c, o_lat, p), kp, vp
         q, k, v = project(x, p, lp)
         kp = kp.at[l, blk, off].set(k.astype(kp.dtype), mode="drop")
         vp = vp.at[l, blk, off].set(v.astype(vp.dtype), mode="drop")
         attn = ragged_attention(
             q, kp, vp, l, tables, valid_len, impl=attn_impl
         )
-        x = x + linear(attn, p["wo"])
-        if c.n_experts > 0:
+        return linear(attn, p["wo"]), kp, vp
+
+    def body(carry, layer):
+        x, kp, vp = carry
+        p, l, lp = layer
+        out, kp, vp = attend(x, p, lp, l, kp, vp)
+        x = x + out
+        if "router" in p:
             from dstack_tpu.workloads.moe import moe_block
 
             x, _ = moe_block(c, x, p)
@@ -492,8 +515,20 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             x = mlp_block(c, x, p)
         return (x, kp, vp), None
 
-    (x, k_pool, v_pool), _ = lax.scan(body, (x, k_pool, v_pool), xs)
-    return x, k_pool, v_pool
+    # The pool keeps ONE layer axis over every kind of block: a model's
+    # leading dense layers take its first indices, the expert layers the
+    # rest, each kind one scan with the pool still the carry.
+    carry, first = (x, k_pool, v_pool), 0
+    for stack in layer_stacks(params):
+        n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+        xs = (
+            stack,
+            jnp.arange(first, first + n, dtype=jnp.int32),
+            None if bank is None else bank["layers"],
+        )
+        carry, _ = lax.scan(body, carry, xs)
+        first += n
+    return carry
 
 
 def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,

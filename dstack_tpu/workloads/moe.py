@@ -43,11 +43,19 @@ def expert_capacity(c: ModelConfig, seq_len: int) -> int:
 
 
 def route_assignments(
-    c: ModelConfig, h: jnp.ndarray, router: jnp.ndarray
+    c: ModelConfig, h: jnp.ndarray, router: jnp.ndarray,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Top-k routing -> (gate_vals (B,S,k) f32, gate_idx (B,S,k) i32,
     slot (B,S,k) i32, sel (B,S,k,E) f32 one-hot, aux scalar).
     slot >= C marks a dropped token.
+
+    Scores are a softmax over the experts, or (router_score "sigmoid") a
+    sigmoid per expert; then the k best are chosen by score + `bias`
+    ((E,) f32, the aux-loss-free router's selection bias: it chooses and
+    does not weigh), their scores renormalised to sum to 1
+    and multiplied by routed_scaling. A sigmoid router has no
+    load-balance term: its aux is 0.
 
     Slot assignment is priority-ordered: every token's first choice is
     placed before any token's second choice (GShard ordering), via one
@@ -59,11 +67,20 @@ def route_assignments(
     logits = jnp.einsum(
         "bsd,de->bse", h, router, preferred_element_type=jnp.float32
     )
-    probs = jax.nn.softmax(logits, axis=-1)  # (B,S,E) f32
-    gate_vals, gate_idx = lax.top_k(probs, k)  # (B,S,k)
+    if c.router_score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)  # (B,S,E) f32
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        gate_vals, gate_idx = lax.top_k(probs, k)  # (B,S,k)
+    else:
+        _, gate_idx = lax.top_k(probs + bias, k)
+        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
     gate_vals = gate_vals / jnp.maximum(
         jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
     )
+    if c.routed_scaling != 1.0:
+        gate_vals = gate_vals * c.routed_scaling
 
     sel = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # (B,S,k,E)
     # Choice-major flatten so cumsum hands out slots first-choices-first.
@@ -72,6 +89,8 @@ def route_assignments(
     pos = pos_flat.reshape(B, k, S, E).transpose(0, 2, 1, 3)  # (B,S,k,E)
     slot = jnp.sum(pos * sel, axis=-1).astype(jnp.int32)  # (B,S,k)
 
+    if c.router_score == "sigmoid":
+        return gate_vals, gate_idx, slot, sel, jnp.float32(0.0)
     # Switch-style load-balance loss: E * sum_e mean_prob_e * top1_share_e.
     mean_prob = jnp.mean(probs, axis=(0, 1))  # (E,)
     top1_share = jnp.mean(sel[:, :, 0, :], axis=(0, 1))  # (E,)
@@ -80,11 +99,12 @@ def route_assignments(
 
 
 def route(
-    c: ModelConfig, h: jnp.ndarray, router: jnp.ndarray
+    c: ModelConfig, h: jnp.ndarray, router: jnp.ndarray,
+    bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Top-k routing -> (dispatch (B,S,E,C), combine (B,S,E,C), aux scalar)."""
     C = expert_capacity(c, h.shape[1])
-    gate_vals, _, slot, sel, aux = route_assignments(c, h, router)
+    gate_vals, _, slot, sel, aux = route_assignments(c, h, router, bias)
     slot_oh = jax.nn.one_hot(slot, C, dtype=jnp.float32)  # 0-row when >= C
     dispatch = jnp.einsum("bske,bskc->bsec", sel, slot_oh)
     combine = jnp.einsum("bsk,bske,bskc->bsec", gate_vals, sel, slot_oh)
@@ -134,7 +154,10 @@ def moe_mlp(
         raise ValueError(
             f'moe_impl={c.moe_impl!r}: expected "einsum" or "gather"'
         )
-    dispatch, combine, aux = route(c, h, p["router"])
+    with jax.named_scope("moe/route"):
+        dispatch, combine, aux = route(
+            c, h, p["router"], p.get("router_bias")
+        )
 
     def constrain(x, spec):
         if mesh is not None and "expert" in mesh.axis_names:
@@ -148,7 +171,8 @@ def moe_mlp(
         "bsec,bsd->ebcd", dispatch.astype(h.dtype), h
     )
     expert_in = constrain(expert_in, P("expert", ("data", "fsdp"), None, None))
-    expert_out = _expert_ffn(h.dtype, expert_in, p)
+    with jax.named_scope("moe/experts"):
+        expert_out = _expert_ffn(h.dtype, expert_in, p)
     expert_out = constrain(
         expert_out, P("expert", ("data", "fsdp"), None, None)
     )
@@ -175,7 +199,9 @@ def _moe_mlp_gather(
     B, S, D = h.shape
     E, k = c.n_experts, c.experts_per_token
     C = expert_capacity(c, S)
-    gate_vals, gate_idx, slot, _, aux = route_assignments(c, h, p["router"])
+    gate_vals, gate_idx, slot, _, aux = route_assignments(
+        c, h, p["router"], p.get("router_bias")
+    )
 
     def constrain(x, spec):
         if mesh is not None and "expert" in mesh.axis_names:
@@ -219,9 +245,15 @@ def moe_block(
     p: Params,
     mesh: Optional[Mesh] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Pre-norm MoE block with residual: x -> (x + moe(norm(x)), aux)."""
-    from dstack_tpu.workloads.transformer import rms_norm
+    """Pre-norm MoE block with residual: x -> (x + moe(norm(x)), aux).
+    With shared experts (`ws_*` weights) every token also passes through
+    their SwiGLU, added beside the routed sum."""
+    from dstack_tpu.workloads.transformer import _silu, linear, rms_norm
 
     h = rms_norm(x, p["mlp_norm"], c.norm_eps)
     out, aux = moe_mlp(c, h, p, mesh)
+    if "ws_gate" in p:
+        with jax.named_scope("moe/shared"):
+            gate = _silu(linear(h, p["ws_gate"]))
+            out = out + linear(gate * linear(h, p["ws_up"]), p["ws_down"])
     return x + out, aux

@@ -60,6 +60,15 @@ interpret-mode parity test (tests/test_paged_attention.py) pins them
 together to f32 rounding (the kernel folds its softmax into one pass;
 on the test's f32 inputs the quantization casts are no-ops).
 
+Latent pools (`latent_values` > 0, kv_blocks' pool of a latent-attention
+model): a token keeps ONE row for all heads, `(bs, 1, width)` a block, and
+its value is the first `latent_values` columns of that same row. Both
+paths then read the k pool alone — the value tile is a lane-aligned slice
+of the key tile already in VMEM, never a second fetch — and emit
+`(B, S, H * latent_values)`; every head shares the row, so the GQA
+kernel's cross-group mask has no counterpart. The caller passes `scale`
+(the model's head size, not the row's width, sets it).
+
 Semantics: query row (b, i) attends cache positions `p < valid_len[b, i]`
 in slot b's context; position p lives at block `tables[b, p // bs]`, row
 `p % bs` of layer `layer` of the pool. Garbage in masked rows (unwritten
@@ -132,6 +141,8 @@ def ragged_attention(
     *,
     impl: Optional[str] = None,
     interpret: bool = False,
+    latent_values: int = 0,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Ragged paged attention over one layer of the stacked block pool.
 
@@ -143,7 +154,12 @@ def ragged_attention(
     valid_len:(B, S) int32        row (b, i) attends positions < valid_len[b, i]
 
     Returns (B, S, H*hd) in q.dtype, matching the dense consumers' shape.
+    With `latent_values` the k pool is (L, NB, bs, 1, width), q is
+    (B, S, H, width), v_pool is not read and the result is
+    (B, S, H*latent_values). `scale` defaults to hd ** -0.5.
     """
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     if impl is None:
         impl = dispatch_path(
             tables.shape[1] * k_pool.shape[2],
@@ -153,16 +169,25 @@ def ragged_attention(
             interpret=interpret,
         )
     if impl == "pallas":
+        if latent_values:
+            return _latent_attention_pallas(
+                q, k_pool, layer, tables, valid_len, interpret=interpret,
+                latent_values=latent_values, scale=scale,
+            )
         return _ragged_attention_pallas(
             q, k_pool, v_pool, layer, tables, valid_len, interpret=interpret
         )
-    return _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len)
+    return _ragged_attention_lax(
+        q, k_pool, v_pool, layer, tables, valid_len,
+        latent_values=latent_values, scale=scale,
+    )
 
 
 # ------------------------------------------------------------- lax fallback
 
 
-def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len):
+def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
+                          latent_values=0, scale=None):
     """Gather-free fallback: two fori_loop passes over table columns.
 
     Per step the only gather is `jnp.take(pool, layer * NB + tables[:, j])`
@@ -187,9 +212,14 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len):
     n_layers, nb, bs, kv, _ = k_pool.shape
     mb = tables.shape[1]
     n_rep = h // kv
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     k_pool = k_pool.reshape(n_layers * nb, bs, kv, hd)
-    v_pool = v_pool.reshape(n_layers * nb, bs, kv, hd)
+    if latent_values:
+        # One pool: a value is the leading columns of its key row, cut
+        # out of the gathered block column, never out of the pool.
+        v_pool, vd = k_pool, latent_values
+    else:
+        v_pool, vd = v_pool.reshape(n_layers * nb, bs, kv, hd), hd
     base = jnp.asarray(layer, jnp.int32) * nb
 
     # Columns any live row needs: garbage-masked steps past this are pure
@@ -233,14 +263,14 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len):
 
     def accum(j, o):
         logits, safe = _block(j)
-        vb = _repeat_kv(jnp.take(v_pool, safe, axis=0), n_rep)
+        vb = _repeat_kv(jnp.take(v_pool, safe, axis=0)[..., :vd], n_rep)
         p = (jnp.exp(logits - m) / l).astype(q.dtype)
         return o + jnp.einsum(
             "bhst,bthd->bhsd", p, vb, preferred_element_type=jnp.float32
         )
 
-    o = lax.fori_loop(0, n_cols, accum, jnp.zeros((b, h, s, hd), jnp.float32))
-    return o.astype(q.dtype).transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+    o = lax.fori_loop(0, n_cols, accum, jnp.zeros((b, h, s, vd), jnp.float32))
+    return o.astype(q.dtype).transpose(0, 2, 1, 3).reshape(b, s, h * vd)
 
 
 # ------------------------------------------------------------ pallas kernel
@@ -260,6 +290,28 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len):
 # column's KV head — KV times the needed MXU work, spent to keep every
 # load a plain aligned tile: no strided sublane reads, no in-kernel
 # transposes. ROADMAP S2 owns making it fast.
+
+
+def _accumulate_block(logits, v, acc_ref, m_ref, l_ref):
+    """One block's step of the streaming softmax, shared by both kernels:
+    fold the masked (TQ, cols) logits and the block's (cols, vd) values
+    into the running max, denominator and unnormalized output."""
+    blk_m = jnp.maximum(
+        jnp.max(logits, axis=-1, keepdims=True), NEG_INF / 2
+    )
+    p = jnp.exp(logits - blk_m)
+    blk_l = jnp.sum(p, axis=-1, keepdims=True)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, blk_m)
+    alpha = jnp.exp(m_prev - m_new)
+    beta = jnp.exp(blk_m - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + blk_l * beta
+    pv = lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # (TQ, vd)
+    acc_ref[...] = acc_ref[...] * alpha + beta * pv
 
 
 def _paged_kernel(
@@ -316,22 +368,7 @@ def _paged_kernel(
         ok &= t_ref[b, j] < num_pool_blocks
         logits = jnp.where(ok, logits, NEG_INF)
 
-        blk_m = jnp.maximum(
-            jnp.max(logits, axis=-1, keepdims=True), NEG_INF / 2
-        )
-        p = jnp.exp(logits - blk_m)
-        blk_l = jnp.sum(p, axis=-1, keepdims=True)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, blk_m)
-        alpha = jnp.exp(m_prev - m_new)
-        beta = jnp.exp(blk_m - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + blk_l * beta
-        pv = lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (TQ, hd)
-        acc_ref[...] = acc_ref[...] * alpha + beta * pv
+        _accumulate_block(logits, v, acc_ref, m_ref, l_ref)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _emit():
@@ -424,3 +461,126 @@ def _ragged_attention_pallas(
         v_pool.reshape(n_layers * nb, bs * kv, hd),
     )
     return out.reshape(b, s, h * hd)
+
+
+# ----------------------------------------------------- latent pallas kernel
+#
+# A latent pool keeps one row a token for all heads, so the slabs are
+#
+#   pool  (L, NB, bs, 1, W) -> (L*NB, bs, W)       one row per position
+#   q     (B, S, H, W)      -> (B, S*H, W)         rows ordered (s, h)
+#
+# and one grid step multiplies a tile of query rows against a block's bs
+# rows: every pair is a wanted pair (no group mask), and the value tile is
+# `k[:, :latent_values]`, a lane-aligned cut of the tile the step already
+# holds. W is whole 128-value lanes (ModelConfig.kv_row_shapes pads the
+# row), the query's padding columns are zero.
+
+
+def _latent_kernel(
+    t_ref,  # scalar prefetch: (B, MB) block tables in SMEM
+    nc_ref,  # scalar prefetch: (B,) table columns row b actually needs
+    layer_ref,  # scalar prefetch: (1,) layer index — the index_maps' alone
+    q_ref,  # (TQ, W) absorbed query rows, ordered (s, h)
+    vlen_ref,  # (TQ, 1) valid_len of each query row
+    k_ref,  # (bs, W) — the block the index_map resolved for step j
+    o_ref,  # (TQ, latent_values), revisited across the innermost grid axis
+    acc_ref,  # VMEM scratch (TQ, latent_values) f32
+    m_ref,  # VMEM scratch (TQ, 1) f32
+    l_ref,  # VMEM scratch (TQ, 1) f32
+    *,
+    block_size: int,
+    num_pool_blocks: int,
+    latent_values: int,
+    scale: float,
+):
+    b = pl.program_id(0)
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF / 2)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j < nc_ref[b])
+    def _attend():
+        q = q_ref[...]
+        k = k_ref[...]
+        logits = lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (TQ, bs)
+        pos = j * block_size + lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        ok = (pos < vlen_ref[...]) & (t_ref[b, j] < num_pool_blocks)
+        logits = jnp.where(ok, logits, NEG_INF)
+
+        _accumulate_block(logits, k[:, :latent_values], acc_ref, m_ref, l_ref)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _emit():
+        o_ref[...] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        ).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "latent_values", "scale")
+)
+def _latent_attention_pallas(
+    q, k_pool, layer, tables, valid_len, *, interpret=False,
+    latent_values, scale,
+):
+    b, s, h, w = q.shape
+    n_layers, nb, bs, _, _ = k_pool.shape
+    mb = tables.shape[1]
+    ts = _q_tile_positions(s, h)
+    tq = ts * h
+    grid = (b, s // ts, mb)
+
+    valid_len = valid_len.astype(jnp.int32)
+    n_cols = jnp.clip((jnp.max(valid_len, axis=1) + bs - 1) // bs, 1, mb)
+
+    def _table_block(bi, qi, ji, t, nc, lyr):
+        # As _ragged_attention_pallas: the gather IS the index_map.
+        live = jnp.minimum(ji, nc[bi] - 1)
+        return (lyr[0] * nb + jnp.minimum(t[bi, live], nb - 1), 0, 0)
+
+    def _q_rows(bi, qi, ji, t, nc, lyr):
+        return (bi, qi, 0)
+
+    kernel = functools.partial(
+        _latent_kernel,
+        block_size=bs,
+        num_pool_blocks=nb,
+        latent_values=latent_values,
+        scale=scale,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((None, tq, w), _q_rows),
+                pl.BlockSpec((None, tq, 1), _q_rows),
+                pl.BlockSpec((None, bs, w), _table_block),
+            ],
+            out_specs=pl.BlockSpec((None, tq, latent_values), _q_rows),
+            scratch_shapes=[
+                pltpu.VMEM((tq, latent_values), jnp.float32),
+                pltpu.VMEM((tq, 1), jnp.float32),
+                pltpu.VMEM((tq, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, s * h, latent_values), q.dtype),
+        interpret=interpret,
+        name="latent_paged_attention",
+    )(
+        tables,
+        n_cols,
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q.reshape(b, s * h, w),
+        jnp.repeat(valid_len, h, axis=1)[:, :, None],
+        k_pool.reshape(n_layers * nb, bs, w),
+    )
+    return out.reshape(b, s, h * latent_values)

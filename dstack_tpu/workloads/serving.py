@@ -83,7 +83,8 @@ from dstack_tpu.workloads.kv_transfer import KVHandoff, StaleEpochError
 from dstack_tpu.workloads.paged_attention import (
     dispatch_path as attn_dispatch_path,
 )
-from dstack_tpu.workloads.quant import quantize_params
+from dstack_tpu.workloads.moe import expert_capacity
+from dstack_tpu.workloads.quant import QTensor, quantize_params
 from dstack_tpu.workloads.sharding import (
     make_serving_shardings,
     serving_param_shardings,
@@ -118,6 +119,12 @@ class DecodeState(NamedTuple):
 
 def init_decode_state(config: ModelConfig, batch: int, max_len: int) -> DecodeState:
     c = config
+    if c.latent or c.n_dense_layers:
+        raise ValueError(
+            "the dense reference engine runs one stack of GQA blocks: latent"
+            " attention and leading dense layers are served by the paged"
+            " engine (ServingEngine) only"
+        )
     shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
     return DecodeState(
         k=jnp.zeros(shape, c.activation_dtype),
@@ -640,6 +647,30 @@ class ServingEngine:
                         f"{what} heads ({mc.n_heads} q / {mc.n_kv_heads} kv)"
                         f" must divide the mesh's model axis ({ms})"
                     )
+        # Engine features that assume GQA rows or wq/wk/wv weights, or one
+        # stack of layers: a latent-attention (or dense-leading) model
+        # names the feature and stops here; none may run and give other
+        # numbers.
+        if config.latent or config.n_dense_layers:
+            quantized = any(
+                isinstance(leaf, QTensor) for leaf in jax.tree_util.tree_leaves(
+                    params, is_leaf=lambda x: isinstance(x, QTensor))
+            )
+            for feature, asked in (
+                ("LoRA adapter banks (lora_max_adapters)", lora_max_adapters > 0),
+                ("int8 weights (--quantize int8)", quantized),
+                ("a model-sharded mesh (--mesh-model > 1)", mesh is not None),
+                ("speculative decoding (--spec-enable)", bool(spec_enable)),
+                ("the prefill/decode split (--role)", role != "unified"),
+                ("the host KV tier (--kv-host-budget-mb)",
+                 bool(kv_host_budget_bytes)),
+            ):
+                if asked:
+                    raise ValueError(
+                        f"{feature} is not supported for latent-attention or"
+                        " dense-leading models: it assumes per-head K/V rows,"
+                        " wq/wk/wv weights or one stack of layers"
+                    )
         self.state = init_paged_state(
             config, slots, self.max_len, kv_block_size, self._num_blocks
         )
@@ -685,6 +716,11 @@ class ServingEngine:
         # path is the traced path, sharded or not.
         self._attn_path = self._resolve_attn_path(config)
         self._attn_dispatch = {"pallas": 0, "lax_ragged": 0}
+        # Expert slots, window-diffable: what routing asked for against
+        # what the capacity dispatch computes, counted from the shapes at
+        # each launch (_count_expert_slots).
+        self._moe_routed_slots = 0
+        self._moe_computed_slots = 0
         self._step = make_paged_decode_step(
             config, steps=steps_per_sync, shardings=self._shardings,
             lora=self._lora is not None, attn_impl=self._attn_path,
@@ -711,9 +747,8 @@ class ServingEngine:
         self._spec_min_accept = spec_min_accept
 
         def _pool_bytes(cfg: ModelConfig) -> int:
-            row = 2 * cfg.n_kv_heads * cfg.head_dim  # k + v
-            return (cfg.n_layers * self._num_blocks * kv_block_size * row
-                    * jnp.dtype(cfg.activation_dtype).itemsize)
+            return (cfg.n_layers * self._num_blocks * kv_block_size
+                    * cfg.kv_row_bytes())
 
         self._draft_config = spec_draft_config or config
         # The drafter's programs attend over its own pool geometry.
@@ -1077,10 +1112,13 @@ class ServingEngine:
         return jax.device_put(state, device)
 
     def _resolve_attn_path(self, config: ModelConfig) -> str:
+        # The kernels see the pool's row: (KV, hd), or latent attention's
+        # one row for all heads.
+        kv_heads, width = config.kv_row_shapes()[0]
         return attn_dispatch_path(
-            self.max_len, config.head_dim, self._block_size,
+            self.max_len, width, self._block_size,
             dtype_bytes=jnp.dtype(config.activation_dtype).itemsize,
-            num_heads=config.n_heads, num_kv_heads=config.n_kv_heads,
+            num_heads=config.n_heads, num_kv_heads=kv_heads,
             model_shards=self._model_shards,
         )
 
@@ -1753,7 +1791,14 @@ class ServingEngine:
             # engine's geometry selects (static) and how many jitted
             # programs ran it (chunk prefills, decode chunks, spec
             # draft/verify forwards).
-            "attn_path": self._attn_path,
+            "attn_path": self._attn_path + (
+                "_latent" if self.config.latent else ""
+            ),
+            # Bytes one cached token allocates per layer (padding
+            # included), and the expert slots routed vs computed.
+            "kv_row_bytes": self.config.kv_row_bytes(),
+            "moe_routed_slots_total": self._moe_routed_slots,
+            "moe_computed_slots_total": self._moe_computed_slots,
             "attn_dispatch_pallas_total": self._attn_dispatch["pallas"],
             "attn_dispatch_lax_ragged_total":
                 self._attn_dispatch["lax_ragged"],
@@ -2095,6 +2140,7 @@ class ServingEngine:
                         self.params, self.state, *chunk_args, sub, final_arg,
                     )
                 self._attn_dispatch[self._attn_path] += 1
+                self._count_expert_slots(n, 1, n_padded)
                 if self._spec:
                     # The drafter prefills the same chunk into ITS pool
                     # through the same table — prefix-cache hits skip both
@@ -2419,14 +2465,18 @@ class ServingEngine:
                 f" {self.max_len}"
             )
         c = self.config
-        want = (c.n_layers, self._block_size, c.n_kv_heads, c.head_dim)
-        got = (handoff.k.shape[0],) + tuple(handoff.k.shape[2:])
-        if got != want or handoff.k.shape != handoff.v.shape:
+        want_k, want_v = (
+            (c.n_layers, self._block_size) + row for row in c.kv_row_shapes()
+        )
+        got_k = (handoff.k.shape[0],) + tuple(handoff.k.shape[2:])
+        got_v = (handoff.v.shape[0],) + tuple(handoff.v.shape[2:])
+        if (got_k, got_v) != (want_k, want_v) \
+                or handoff.k.shape[1] != handoff.v.shape[1]:
             raise ValueError(
                 f"handoff KV geometry {handoff.k.shape} does not match"
                 f" this engine's pool (L, n, bs, KV, hd) ="
-                f" ({c.n_layers}, n, {self._block_size}, {c.n_kv_heads},"
-                f" {c.head_dim})"
+                f" ({c.n_layers}, n, {self._block_size}, {want_k[2]},"
+                f" {want_k[3]})"
             )
         expected = (len(prompt) - 1) // self._block_size + 1
         if handoff.n_blocks != expected:
@@ -3208,9 +3258,22 @@ class ServingEngine:
     def _count_decode_launch(self, steps: int) -> None:
         """One decode chunk (or speculation round) of `steps` steps is
         about to launch over the slots live right now."""
+        live = sum(r is not None for r in self._live)
         self._decode_steps += steps
-        self._decode_slot_steps += steps * sum(
-            r is not None for r in self._live
+        self._decode_slot_steps += steps * live
+        self._count_expert_slots(steps * live, steps * self.slots, 1)
+
+    def _count_expert_slots(self, tokens: int, rows: int, row_len: int) -> None:
+        """A launch routes `tokens` valid tokens through every expert layer
+        and computes a slot for each of experts x rows x capacity(row_len)
+        (moe.expert_capacity), padding and dead rows included."""
+        c = self.config
+        if c.n_experts == 0:
+            return
+        layers = c.n_layers - c.n_dense_layers
+        self._moe_routed_slots += layers * tokens * c.experts_per_token
+        self._moe_computed_slots += (
+            layers * c.n_experts * rows * expert_capacity(c, row_len)
         )
 
     def _observe_chunk_seconds(self) -> None:

@@ -71,12 +71,28 @@ PARAM_SPECS: Dict[str, Any] = {
         "we_gate": P(None, "expert", "fsdp", "model"),
         "we_up": P(None, "expert", "fsdp", "model"),
         "we_down": P(None, "expert", "model", "fsdp"),
+        "router_bias": P(None, None),
+        # Shared experts: a dense SwiGLU beside the bank.
+        "ws_gate": P(None, "fsdp", "model"),
+        "ws_up": P(None, "fsdp", "model"),
+        "ws_down": P(None, "model", "fsdp"),
+        # Latent attention (present instead of wq/wk/wv): the rank
+        # bottlenecks stay whole, the per-head up-projections shard
+        # like wq.
+        "wq_a": P(None, "fsdp", None),
+        "q_norm": P(None, None),
+        "wq_b": P(None, None, "model"),
+        "wkv_a": P(None, "fsdp", None),
+        "kv_norm": P(None, None),
+        "wkv_b": P(None, None, "model"),
         "attn_norm": P(None, None),
         "mlp_norm": P(None, None),
     },
     "final_norm": P(None),
     "lm_head": P("fsdp", "model"),
 }
+# A model's leading dense layers are a second stack of the same blocks.
+PARAM_SPECS["dense_layers"] = PARAM_SPECS["layers"]
 
 # LoRA adapter matrices ride under "layers" as f"{base}_a" (L, in, r) /
 # f"{base}_b" (L, r, out): A is sharded on its input dim like the base
@@ -126,10 +142,13 @@ SERVING_LORA_SPECS: Dict[str, Any] = {
     "_b": P(None, None, "model"),
 }
 
-# Paged KV pools are (L, num_blocks, block_size, KV_heads, head_dim);
+# Paged KV pools are (L, num_blocks, block_size) + ModelConfig.
+# kv_row_shapes(): (KV_heads, head_dim) for the GQA models a mesh serves;
 # shard the KV-head dim over "model" to match the column-parallel wk/wv
-# output shard. Block tables / lengths / sampling params stay replicated
-# (they are host-driven control state).
+# output shard. (A latent pool has one row for all heads and nothing to
+# shard there: the engine refuses a mesh for it, and SERVING_PARAM_SPECS
+# has no entry for its weights.) Block tables / lengths / sampling params
+# stay replicated (they are host-driven control state).
 SERVING_KV_POOL_SPEC = P(None, None, None, "model", None)
 
 # Activations: batch over (data, fsdp), sequence over seq.

@@ -31,9 +31,13 @@ AttentionFn = Callable[..., jnp.ndarray]
 
 
 def init_params(config: ModelConfig, key: jax.Array) -> Params:
-    """Initialise bf16 params. Layer weights are stacked on axis 0 for scan."""
+    """Initialise bf16 params. Layer weights are stacked on axis 0 for scan.
+
+    Blocks of one kind share a stack. A model whose first
+    `n_dense_layers` blocks are plain SwiGLU and the rest carry experts
+    has two: `dense_layers` (run first) and `layers`; every other model
+    has `layers` alone, as before."""
     c = config
-    hd = c.head_dim
     dt = c.activation_dtype
     keys = jax.random.split(key, 8)
 
@@ -43,36 +47,84 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, dtype=jnp.float32) * fan_in**-0.5).astype(dt)
 
-    L, D, F, V = c.n_layers, c.d_model, c.d_ff, c.vocab_size
-    layers = {
-        "wq": dense(keys[1], (L, D, c.n_heads * hd), D),
-        "wk": dense(keys[2], (L, D, c.n_kv_heads * hd), D),
-        "wv": dense(keys[3], (L, D, c.n_kv_heads * hd), D),
-        "wo": dense(keys[4], (L, c.n_heads * hd, D), c.n_heads * hd),
-        "attn_norm": norm_init((L, D)),
-        "mlp_norm": norm_init((L, D)),
-    }
-    if c.n_experts > 0:
-        E = c.n_experts
-        # Router stays f32: tiny, and routing decisions are precision-
-        # sensitive (a bf16 tie flips top-k membership).
-        layers["router"] = (
-            jax.random.normal(keys[5], (L, D, E), dtype=jnp.float32) * D**-0.5
-        )
-        ek = jax.random.split(keys[6], 3)
-        layers["we_gate"] = dense(ek[0], (L, E, D, F), D)
-        layers["we_up"] = dense(ek[1], (L, E, D, F), D)
-        layers["we_down"] = dense(ek[2], (L, E, F, D), F)
-    else:
-        layers["w_gate"] = dense(keys[5], (L, D, F), D)
-        layers["w_up"] = dense(keys[6], (L, D, F), D)
-        layers["w_down"] = dense(keys[7], (L, F, D), F)
-    return {
+    D, V = c.d_model, c.vocab_size
+
+    def stack(keys, L, d_ff, experts):
+        """L blocks of one kind: attention + dense MLP or expert bank."""
+        layers = {
+            "attn_norm": norm_init((L, D)),
+            "mlp_norm": norm_init((L, D)),
+        }
+        if c.latent:
+            H, qr, kvr = c.n_heads, c.q_lora_rank, c.kv_lora_rank
+            qk, kq = jax.random.split(keys[1]), jax.random.split(keys[2])
+            layers["wq_a"] = dense(qk[0], (L, D, qr), D)
+            layers["q_norm"] = norm_init((L, qr))
+            layers["wq_b"] = dense(qk[1], (L, qr, H * c.head_dim), qr)
+            layers["wkv_a"] = dense(kq[0], (L, D, c.latent_row), D)
+            layers["kv_norm"] = norm_init((L, kvr))
+            layers["wkv_b"] = dense(
+                kq[1], (L, kvr, H * (c.qk_nope_head_dim + c.v_head_dim)), kvr
+            )
+            layers["wo"] = dense(
+                keys[4], (L, H * c.v_head_dim, D), H * c.v_head_dim
+            )
+        else:
+            hd = c.head_dim
+            layers["wq"] = dense(keys[1], (L, D, c.n_heads * hd), D)
+            layers["wk"] = dense(keys[2], (L, D, c.n_kv_heads * hd), D)
+            layers["wv"] = dense(keys[3], (L, D, c.n_kv_heads * hd), D)
+            layers["wo"] = dense(keys[4], (L, c.n_heads * hd, D), c.n_heads * hd)
+        F = d_ff
+        if experts:
+            E = c.n_experts
+            # Router stays f32: tiny, and routing decisions are precision-
+            # sensitive (a bf16 tie flips top-k membership).
+            layers["router"] = (
+                jax.random.normal(keys[5], (L, D, E), dtype=jnp.float32) * D**-0.5
+            )
+            ek = jax.random.split(keys[6], 3)
+            layers["we_gate"] = dense(ek[0], (L, E, D, F), D)
+            layers["we_up"] = dense(ek[1], (L, E, D, F), D)
+            layers["we_down"] = dense(ek[2], (L, E, F, D), F)
+            if c.router_score == "sigmoid":
+                # The selection bias of an aux-loss-free router: a buffer
+                # the published models start at zero and move by a rule
+                # outside the gradient.
+                layers["router_bias"] = jnp.zeros((L, E), jnp.float32)
+            if c.n_shared_experts:
+                Fs = F * c.n_shared_experts
+                sk = jax.random.split(keys[7], 3)
+                layers["ws_gate"] = dense(sk[0], (L, D, Fs), D)
+                layers["ws_up"] = dense(sk[1], (L, D, Fs), D)
+                layers["ws_down"] = dense(sk[2], (L, Fs, D), Fs)
+        else:
+            layers["w_gate"] = dense(keys[5], (L, D, F), D)
+            layers["w_up"] = dense(keys[6], (L, D, F), D)
+            layers["w_down"] = dense(keys[7], (L, F, D), F)
+        return layers
+
+    nd = c.n_dense_layers
+    params = {
         "embed": dense(keys[0], (V, D), D),
-        "layers": layers,
+        "layers": stack(keys, c.n_layers - nd, c.d_ff, c.n_experts > 0),
         "final_norm": norm_init((D,)),
         "lm_head": dense(jax.random.fold_in(key, 99), (D, V), D),
     }
+    if nd:
+        params["dense_layers"] = stack(
+            jax.random.split(jax.random.fold_in(key, 98), 8), nd,
+            c.dense_d_ff, False,
+        )
+    return params
+
+
+def layer_stacks(params: Params):
+    """The model's stacks of blocks in the order they run: the leading
+    dense layers (if the model has them), then `layers`."""
+    if "dense_layers" in params:
+        return (params["dense_layers"], params["layers"])
+    return (params["layers"],)
 
 
 def linear(x: jnp.ndarray, w) -> jnp.ndarray:
@@ -136,6 +188,88 @@ def project_qkv(c: ModelConfig, x: jnp.ndarray, p: Params, positions: jnp.ndarra
     k = linear(h, p["wk"]).reshape(b, s, c.n_kv_heads, hd)
     v = linear(h, p["wv"]).reshape(b, s, c.n_kv_heads, hd)
     return _rope(q, positions, c.rope_theta), _rope(k, positions, c.rope_theta), v
+
+
+def project_latent(c: ModelConfig, x: jnp.ndarray, p: Params,
+                   positions: jnp.ndarray, width: Optional[int] = None):
+    """Latent attention's projections -> (q (B, S, H, nope + rope) with its
+    rope columns rotated, row (B, S, kv_lora_rank + rope), zero-padded to
+    `width` values where a cache's row is wider): the query per
+    head, and the ONE row a token keeps for all heads — the normed latent
+    `c_kv`, then the rotated key `k_rope` every head shares. Nothing else
+    of a token is cached; keys and values are up-projections of the
+    latent (`expand_latent`) or folded into the query and the output
+    (`absorb_query`, `latent_output`)."""
+    b, s, _ = x.shape
+    nope, kvr = c.qk_nope_head_dim, c.kv_lora_rank
+    with jax.named_scope("mla/project"):
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
+        cq = rms_norm(linear(h, p["wq_a"]), p["q_norm"], c.norm_eps)
+        q = linear(cq, p["wq_b"]).reshape(b, s, c.n_heads, c.head_dim)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope(q[..., nope:], positions, c.rope_theta)],
+            axis=-1,
+        )
+        kv = linear(h, p["wkv_a"])
+        k_rope = _rope(kv[:, :, None, kvr:], positions, c.rope_theta)[:, :, 0]
+        parts = [rms_norm(kv[..., :kvr], p["kv_norm"], c.norm_eps), k_rope]
+        if width is not None and width > c.latent_row:
+            parts.append(jnp.zeros((b, s, width - c.latent_row), k_rope.dtype))
+        row = jnp.concatenate(parts, axis=-1)
+    return q, row
+
+
+def _wkv_b(c: ModelConfig, p: Params):
+    """The latent's up-projection per head: W_uk (kvr, H, nope) for keys,
+    W_uv (kvr, H, v) for values."""
+    w = p["wkv_b"].reshape(
+        c.kv_lora_rank, c.n_heads, c.qk_nope_head_dim + c.v_head_dim
+    )
+    return w[..., : c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
+
+
+def expand_latent(c: ModelConfig, row: jnp.ndarray, p: Params):
+    """Expanded form: the full key and value of every head from the
+    cached rows, (B, S, H, nope + rope) and (B, S, H, v). What `forward`
+    runs (any attention_fn then applies); never run per decode step."""
+    b, s, _ = row.shape
+    kvr = c.kv_lora_rank
+    w_uk, w_uv = _wkv_b(c, p)
+    k_nope = jnp.einsum("bsc,chn->bshn", row[..., :kvr], w_uk)
+    v = jnp.einsum("bsc,chv->bshv", row[..., :kvr], w_uv)
+    k_rope = jnp.broadcast_to(
+        row[:, :, None, kvr:], (b, s, c.n_heads, c.qk_rope_head_dim)
+    )
+    return jnp.concatenate([k_nope, k_rope], axis=-1), v
+
+
+def absorb_query(c: ModelConfig, q: jnp.ndarray, p: Params, width: int):
+    """Absorbed form, query side: fold W_uk into the query so it scores
+    straight against cached rows — q_lat = q_nope W_uk^T, then
+    [q_lat | q_rope | 0...] of `width` values (the pool row's, padding
+    included). (B, S, H, nope + rope) -> (B, S, H, width)."""
+    nope = c.qk_nope_head_dim
+    with jax.named_scope("mla/project"):
+        w_uk, _ = _wkv_b(c, p)
+        q_lat = jnp.einsum("bshn,chn->bshc", q[..., :nope], w_uk)
+        pad = width - c.latent_row
+        parts = [q_lat, q[..., nope:]]
+        if pad:
+            parts.append(jnp.zeros(q.shape[:3] + (pad,), q.dtype))
+        return jnp.concatenate(parts, axis=-1)
+
+
+def latent_output(c: ModelConfig, o_lat: jnp.ndarray, p: Params):
+    """Absorbed form, output side: o_lat (B, S, H * kvr), each head's
+    weighted sum of latents -> its value through W_uv, then `wo`."""
+    b, s, _ = o_lat.shape
+    with jax.named_scope("mla/project"):
+        _, w_uv = _wkv_b(c, p)
+        o = jnp.einsum(
+            "bshc,chv->bshv",
+            o_lat.reshape(b, s, c.n_heads, c.kv_lora_rank), w_uv,
+        )
+        return linear(o.reshape(b, s, c.n_heads * c.v_head_dim), p["wo"])
 
 
 @jax.custom_vjp
@@ -205,12 +339,19 @@ def _block(
     attention_fn: AttentionFn,
     mesh=None,
 ):
-    """One decoder block -> (x, router_aux). aux is 0.0 for dense models."""
+    """One decoder block -> (x, router_aux). aux is 0.0 for dense models.
+    A block carries experts iff its weights do (`router`): the leading
+    dense layers of an expert model run the plain MLP."""
     b, s, _ = x.shape
-    q, k, v = project_qkv(c, x, p, positions)
-    attn = attention_fn(q, k, v).reshape(b, s, c.n_heads * c.head_dim)
+    if c.latent:
+        q, row = project_latent(c, x, p, positions)
+        k, v = expand_latent(c, row, p)
+        attn = attention_fn(q, k, v).reshape(b, s, c.n_heads * c.v_head_dim)
+    else:
+        q, k, v = project_qkv(c, x, p, positions)
+        attn = attention_fn(q, k, v).reshape(b, s, c.n_heads * c.head_dim)
     x = x + linear(attn, p["wo"])
-    if c.n_experts > 0:
+    if "router" in p:
         from dstack_tpu.workloads.moe import moe_block
 
         return moe_block(c, x, p, mesh)
@@ -257,7 +398,10 @@ def forward(
         body, c, tokens.shape[0] * tokens.shape[1], mesh,
         seq_len=tokens.shape[1], attn_scores=attn_scores,
     )
-    (x, aux), _ = lax.scan(body, (x, jnp.float32(0.0)), params["layers"])
+    carry = (x, jnp.float32(0.0))
+    for stack in layer_stacks(params):
+        carry, _ = lax.scan(body, carry, stack)
+    x, aux = carry
 
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     if return_hidden:

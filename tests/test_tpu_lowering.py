@@ -24,7 +24,10 @@ from dstack_tpu.workloads import flash_attention as fa
 from dstack_tpu.workloads import kv_blocks
 from dstack_tpu.workloads.attention import make_attention_fn
 from dstack_tpu.workloads.config import PRESETS
-from dstack_tpu.workloads.paged_attention import _ragged_attention_pallas
+from dstack_tpu.workloads.paged_attention import (
+    _latent_attention_pallas,
+    _ragged_attention_pallas,
+)
 from dstack_tpu.workloads.serving import ServingEngine
 from dstack_tpu.workloads.sharding import BATCH_SPEC, make_mesh, param_shardings
 from dstack_tpu.workloads.train import loss_fn
@@ -32,6 +35,18 @@ from dstack_tpu.workloads.transformer import init_params
 
 CFG = PRESETS["smol-1b"]
 H, KV, HD = CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
+# The latent-attention block at GLM-4.7-Flash's head geometry (20 heads over
+# one 512 + 64 row a token, padded to 640) with a leading dense layer; fewer
+# experts and a smaller vocabulary than published, to compile quickly, and
+# another hidden size (at 2048 `wo` is as large as a slab of the test's pool).
+LATENT_CFG = CFG.with_(
+    d_model=1536, n_heads=20, n_kv_heads=20, d_ff=1536, n_experts=8, experts_per_token=2,
+    capacity_factor=4.0, q_lora_rank=768, kv_lora_rank=512,
+    qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+    n_dense_layers=1, dense_d_ff=10240, n_shared_experts=1,
+    router_score="sigmoid", routed_scaling=1.8,
+)
+LATENT_W = LATENT_CFG.kv_row_shapes()[0][1]
 # ServingEngine / native server defaults.
 SLOTS, CHUNK, BLOCK, MAX_DRAFT = 8, 128, 16, 4
 MAX_BLOCKS = CFG.max_seq_len // BLOCK
@@ -72,6 +87,17 @@ def _kernels():
             [((b, s, H, HD), bf16),
              ((POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD), bf16),
              ((POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD), bf16), ((), i32),
+             ((b, MAX_BLOCKS), i32), ((b, s), i32)],
+        ))
+    # The latent pool's kernel: decode, the cell's 512-token chunk (a
+    # 320-row query tile), the smallest chunk bucket.
+    for kind, b, s in [("decode", SLOTS, 1), ("prefill", 1, 512), ("prefill", 1, 8)]:
+        out.append((
+            f"latent_{kind}_b{b}_s{s}",
+            lambda *a: _latent_attention_pallas(
+                *a, latent_values=512, scale=256 ** -0.5),
+            [((b, s, 20, LATENT_W), bf16),
+             ((POOL_LAYERS, POOL_BLOCKS, BLOCK, 1, LATENT_W), bf16), ((), i32),
              ((b, MAX_BLOCKS), i32), ((b, s), i32)],
         ))
     # The trainer's shape (bench.py: S=2048) and one ring step's shard.
@@ -193,17 +219,23 @@ def _paged_program(name, cfg, attn_impl):
     )
 
 
-@pytest.mark.parametrize(
-    "name", ["decode_steps", "chunk_prefill", "spec_draft", "spec_verify"]
-)
-def test_paged_program_moves_no_pool_or_slab(name, v5e, no_compile_cache):
+@pytest.mark.parametrize("name,kind", [
+    ("decode_steps", "gqa"), ("chunk_prefill", "gqa"), ("spec_draft", "gqa"),
+    ("spec_verify", "gqa"),
+    # what the engine runs for a latent model (it refuses speculation)
+    ("decode_steps", "latent"), ("chunk_prefill", "latent"),
+])
+def test_paged_program_moves_no_pool_or_slab(name, kind, v5e, no_compile_cache):
     """The optimized HLO of each paged program holds no copy,
     dynamic-slice or dynamic-update-slice that produces an array of the
     pool's or one layer slab's size. For the v5e with the Pallas kernel
     where libtpu describes one, and there its scratch is also smaller
     than one pool (K and V): updated in place, not held twice. Else for
-    the backend at hand on the lax path."""
-    cfg = CFG.with_(n_layers=POOL_LAYERS, remat=False)
+    the backend at hand on the lax path. The latent model's programs run
+    a dense layer and then scan the expert layers, the one latent pool a
+    carry of both loops."""
+    base = CFG if kind == "gqa" else LATENT_CFG
+    cfg = base.with_(n_layers=POOL_LAYERS, remat=False)
     fn, args = _paged_program(
         name, cfg, "pallas" if v5e is not None else "lax_ragged"
     )
@@ -214,12 +246,12 @@ def test_paged_program_moves_no_pool_or_slab(name, v5e, no_compile_cache):
         )
     compiled = fn.lower(*args).compile()
 
-    pool = (POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD)
+    pool = (POOL_LAYERS, POOL_BLOCKS, BLOCK) + cfg.kv_row_shapes()[0]
     sizes = {math.prod(pool): "pool", math.prod(pool[1:]): "slab"}
     # Sizes are compared, not shapes (a bitcast keeps the size): no
     # weight may share one, or its re-layout would read as pool traffic.
     weights = {math.prod(a.shape) for a in jax.tree.leaves(args[0])}
-    weights |= {n // POOL_LAYERS for n in weights}
+    weights |= {math.prod(a.shape[1:]) for a in jax.tree.leaves(args[0])}
     assert not weights & set(sizes)
     moved = [
         f"{m.group(2)} of the {sizes[n]} [{m.group(1)}]"
@@ -227,7 +259,9 @@ def test_paged_program_moves_no_pool_or_slab(name, v5e, no_compile_cache):
         if (n := math.prod(int(d) for d in m.group(1).split(","))) in sizes
     ]
     assert not moved, moved
-    if v5e is not None:  # XLA:CPU's buffer assignment is not the chip's
+    # XLA:CPU's buffer assignment is not the chip's; a latent pool is a
+    # sixteenth of the GQA one and smaller than a chunk's expert scratch.
+    if v5e is not None and kind == "gqa":
         itemsize = jnp.dtype(cfg.activation_dtype).itemsize
         one_pool = 2 * math.prod(pool) * itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < one_pool
