@@ -134,9 +134,13 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_attn_dispatch_total": ("counter", ("path",)),
     # Work at the scheduler's boundary: steps of every launched decode
     # chunk or speculation round, the same times the slots live at
-    # launch, and the tokens those steps emitted.
+    # launch, and the tokens those steps emitted; the blocks the live
+    # slots' contexts filled at those steps against the block table's
+    # columns (what paged attention had to read against what it spans).
+    "dstack_tpu_serving_decode_live_blocks_total": ("counter", ()),
     "dstack_tpu_serving_decode_slot_steps_total": ("counter", ()),
     "dstack_tpu_serving_decode_steps_total": ("counter", ()),
+    "dstack_tpu_serving_decode_table_columns_total": ("counter", ()),
     "dstack_tpu_serving_decode_tokens_total": ("counter", ()),
     "dstack_tpu_serving_kv_blocks_cached": ("gauge", ()),
     "dstack_tpu_serving_kv_blocks_in_use": ("gauge", ()),

@@ -693,7 +693,11 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
         )[:, 0]
         blk = jnp.where(write_ok, blk, nb)
         off = state.lengths % bs
-        valid_len = (state.lengths + 1)[:, None]     # (B, 1)
+        # A retired slot keeps its last length and table on the device:
+        # it sees nothing, so attention walks none of its stale blocks.
+        valid_len = jnp.where(
+            state.active, state.lengths + 1, 0
+        )[:, None]                                   # (B, 1)
 
         aix = state.adapter_ix
         x, new_k, new_v = _layer_loop(
@@ -841,7 +845,8 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None,
 
             x, dk, dv = _layer_loop(
                 c, params, x, pos[:, None], dk, dv,
-                blk[:, None], off[:, None], block_tables, pos[:, None] + 1,
+                blk[:, None], off[:, None], block_tables,
+                jnp.where(active, pos + 1, 0)[:, None],  # dead slots: nothing
                 attn_impl=attn_impl,
             )
             h = rms_norm(x, params["final_norm"], c.norm_eps)
@@ -940,7 +945,8 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
         aix = state.adapter_ix
         x, new_k, new_v = _layer_loop(
             c, params, x, positions, state.k, state.v,
-            blk, off, state.block_tables, positions + 1,
+            blk, off, state.block_tables,
+            jnp.where(act0[:, None], positions + 1, 0),  # dead slots: nothing
             bank=bank, adapter_ix=aix, has_lora=jnp.any(act0 & (aix >= 0)),
             attn_impl=attn_impl,
         )

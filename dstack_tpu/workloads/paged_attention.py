@@ -23,24 +23,25 @@ inside the stack instead.
 
 Two implementations behind one dispatch seam (`ragged_attention`):
 
-- `_ragged_attention_pallas`: a Pallas TPU kernel. Block tables ride in
-  as scalar-prefetch operands (pallas_guide: PrefetchScalarGridSpec) so
-  each grid step's BlockSpec index_map resolves `tables[b, j]` into the
-  pool's block axis and the DMA engine streams exactly that block's
-  `(block_size * KV, hd)` K/V slab HBM→VMEM — the gather IS the
-  index_map. The pool goes in as `(L * num_blocks, block_size * KV, hd)`
-  (a bitcast of the stack) and the layer index as a third
-  scalar-prefetch operand, so the index_map lands on row
-  `layer * num_blocks + tables[b, j]`. Softmax state (running max m,
-  denominator l, unnormalized output o) accumulates in VMEM scratch
-  across the innermost grid axis, the standard flash accumulation (same math as
-  `attention._block_attend`). Pad-sentinel table entries (== num_blocks)
-  clamp to a real block in the index_map and are masked out of the
-  logits, as are rows at or beyond each query's `valid_len`. The block
-  shapes are chosen for the TPU (8, 128) tiling rule (see the note above
-  the kernel); tests/test_tpu_lowering.py lowers it for platform "tpu"
-  at the engine's shapes and chip_smoke.py compiles and checks it on the
-  chip.
+- `_ragged_attention_pallas`: a Pallas TPU kernel whose grid is
+  (slot, query tile) and whose body walks the tile's LIVE table columns.
+  Block tables, the columns each tile may see and the layer index ride in
+  as scalar-prefetch operands (pallas_guide: PrefetchScalarGridSpec); the
+  pool goes in whole, left in HBM (`pl.ANY`), as `(L * num_blocks,
+  block_size * KV, hd)` (a bitcast of the stack). A `fori_loop` with the
+  tile's own trip count fetches row `layer * num_blocks + tables[b, j]`
+  for a group of columns a step with `make_async_copy`, into one half of
+  a two-slot VMEM buffer while the other half is attended: the gather is
+  those DMAs, and a dead slot or the table's unused tail costs nothing.
+  Softmax state (running max m, denominator l, unnormalized output o)
+  accumulates in VMEM scratch block by block, the standard flash
+  accumulation (same math as `attention._block_attend`). Pad-sentinel
+  table entries (== num_blocks) clamp to a real block before the layer
+  offset and are masked out of the logits, as are rows at or beyond each
+  query's `valid_len`. The slab shapes are chosen for the TPU (8, 128)
+  tiling rule (see the note above the kernel); tests/test_tpu_lowering.py
+  compiles it for a v5e at the engine's shapes and holds its grid to
+  slots x query tiles, chip_smoke.py checks it on the chip.
 
 - `_ragged_attention_lax`: pure-lax path for CPU, sharded engines and
   geometries the kernel does not take. Two `lax.fori_loop` passes walk
@@ -72,7 +73,8 @@ kernel's cross-group mask has no counterpart. The caller passes `scale`
 Semantics: query row (b, i) attends cache positions `p < valid_len[b, i]`
 in slot b's context; position p lives at block `tables[b, p // bs]`, row
 `p % bs` of layer `layer` of the pool. Garbage in masked rows (unwritten
-blocks, pad sentinels, stale reuse) never reaches the softmax.
+blocks, pad sentinels, stale reuse) never reaches the softmax. A row with
+`valid_len` 0 (a retired slot) attends nothing and comes out zero.
 """
 
 import functools
@@ -284,12 +286,32 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
 #   q     (B, S, H, hd)       -> (B, S*H, hd)        rows ordered (s, h)
 #
 # (both reshapes keep the row-major order; with KV a multiple of the
-# sublane tile they are layout bitcasts, not copies). One grid step then
-# multiplies a tile of query rows against ALL of a block's (t, g) rows in a
-# single matmul and masks the pairs whose query head does not belong to the
-# column's KV head — KV times the needed MXU work, spent to keep every
+# sublane tile they are layout bitcasts, not copies). One block's step
+# multiplies a tile of query rows against ALL of the block's (t, g) rows in
+# a single matmul and masks the pairs whose query head does not belong to
+# the column's KV head — KV times the needed MXU work, spent to keep every
 # load a plain aligned tile: no strided sublane reads, no in-kernel
-# transposes. ROADMAP S2 owns making it fast.
+# transposes. That all-pairs product is what is left of ROADMAP S2.
+#
+# The grid is (slot, query tile) and nothing else: a grid step costs time
+# whether or not it has work, and a grid over the table's columns made a
+# decode call cost its 16 x 288 steps whatever was live (PERF.md section 6,
+# PR 28). The pool stays in HBM (`pl.ANY`) and the body walks the tile's
+# live table columns itself, a group of blocks a loop step: while one
+# group is attended, the DMAs of the next are in flight into the other
+# half of a two-slot VMEM buffer. The trip count is the tile's own
+# (`n_cols[b, tile]`, scalar-prefetched), so a dead slot makes no trip and
+# a short context pays for its own blocks only.
+
+# Bytes of K (and as many of V) in flight per buffer slot. The group of
+# blocks one loop step fetches is this over a block's bytes: eight 32 KiB
+# blocks at 16-token blocks of 8 KV heads, one block from 256 tokens up.
+_GROUP_BYTES = 256 * 1024
+
+
+def _group_blocks(block_bytes: int, table_cols: int) -> int:
+    """Blocks one loop step of the paged kernel fetches and attends."""
+    return max(1, min(_GROUP_BYTES // block_bytes, table_cols))
 
 
 def _accumulate_block(logits, v, acc_ref, m_ref, l_ref):
@@ -316,13 +338,16 @@ def _accumulate_block(logits, v, acc_ref, m_ref, l_ref):
 
 def _paged_kernel(
     t_ref,  # scalar prefetch: (B, MB) block tables in SMEM
-    nc_ref,  # scalar prefetch: (B,) table columns row b actually needs
-    layer_ref,  # scalar prefetch: (1,) layer index — the index_maps' alone
+    nc_ref,  # scalar prefetch: (B, q tiles) table columns a tile may see
+    layer_ref,  # scalar prefetch: (1,) layer index into the stacked pool
     q_ref,  # (TQ, hd) query rows, ordered (s, h)
     vlen_ref,  # (TQ, 1) valid_len of each query row
-    k_ref,  # (bs*KV, hd) — the block the index_map resolved for step j
-    v_ref,  # (bs*KV, hd)
-    o_ref,  # (TQ, hd), revisited across the innermost grid axis
+    k_hbm,  # (L*NB, bs*KV, hd) the whole K pool, left in HBM
+    v_hbm,  # (L*NB, bs*KV, hd)
+    o_ref,  # (TQ, hd)
+    k_buf,  # VMEM scratch (2, group, bs*KV, hd): two slots of one group
+    v_buf,  # VMEM scratch (2, group, bs*KV, hd)
+    sems,  # DMA semaphores (2, group): one per buffered block
     acc_ref,  # VMEM scratch (TQ, hd) f32
     m_ref,  # VMEM scratch (TQ, 1) f32
     l_ref,  # VMEM scratch (TQ, 1) f32
@@ -331,50 +356,90 @@ def _paged_kernel(
     num_pool_blocks: int,
     num_heads: int,
     num_kv_heads: int,
+    group: int,
     scale: float,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    n_cols = nc_ref[b, pl.program_id(1)]
+    base = layer_ref[0] * num_pool_blocks
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF / 2)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF / 2)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Columns past the row's live context hold nothing it may see: the
-    # index_map parks their DMA on the last live block and the body is
-    # skipped, so a short context in an MB-wide table costs grid steps,
-    # not bandwidth or MXU time.
-    @pl.when(j < nc_ref[b])
-    def _attend():
-        # Storage-dtype operands with f32 accumulation, scale applied to
-        # the f32 logits — the same placement as attention._block_attend.
-        q = q_ref[...]
-        k = k_ref[...]
-        v = v_ref[...]
-        logits = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (TQ, bs*KV)
-        # 2D iotas (TPU requires >= 2D). TQ is a multiple of H, so a
-        # tile-local row index resolves the query head.
-        row = lax.broadcasted_iota(jnp.int32, logits.shape, 0)
-        col = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        q_group = lax.div(lax.rem(row, num_heads), num_heads // num_kv_heads)
-        pos = j * block_size + lax.div(col, num_kv_heads)
-        ok = (q_group == lax.rem(col, num_kv_heads)) & (pos < vlen_ref[...])
-        # Pad-sentinel columns clamp to block NB-1 in the index_map; mask
-        # everything they contributed.
-        ok &= t_ref[b, j] < num_pool_blocks
-        logits = jnp.where(ok, logits, NEG_INF)
+    # What of a block's mask no block changes, made once a grid step: which
+    # (query row, block row) pairs share a KV head, and each block row's
+    # position inside its block. 2D iotas (TPU requires >= 2D); TQ is a
+    # multiple of H, so a tile-local row index resolves the query head.
+    pairs = (q_ref.shape[0], block_size * num_kv_heads)
+    row = lax.broadcasted_iota(jnp.int32, pairs, 0)
+    lane = lax.broadcasted_iota(jnp.int32, pairs, 1)
+    same_head = lax.div(
+        lax.rem(row, num_heads), num_heads // num_kv_heads
+    ) == lax.rem(lane, num_kv_heads)
+    pos_in_block = lax.div(lane, num_kv_heads)
 
-        _accumulate_block(logits, v, acc_ref, m_ref, l_ref)
+    def each_live_block(step, slot, fn):
+        """`fn(col, g, copies)` for the blocks of group `step` that lie
+        inside the tile's live columns, in table order; `copies` are the
+        block's two DMAs into place g of buffer slot `slot`. The last
+        group of a row is cut at `n_cols` here: nothing past it is
+        fetched, waited for or attended. A loop, not `group` copies of the
+        body: tracing the copies cost every engine start seconds."""
+        first = step * group
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[...] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
+        def block(col, carry):
+            g = col - first
+            # The pad sentinel clamps inside the layer's own NB blocks
+            # BEFORE the layer offset; `attend` masks what it fetched.
+            src = base + jnp.minimum(t_ref[b, col], num_pool_blocks - 1)
+            # One semaphore a buffered block, signalled by its K and its
+            # V copy: a semaphore counts bytes, so one shared by a group's
+            # copies in flight could pass a wait on parts of several.
+            fn(col, g, [
+                pltpu.make_async_copy(
+                    pool.at[src], buf.at[slot, g], sems.at[slot, g]
+                )
+                for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))
+            ])
+            return carry
+
+        lax.fori_loop(first, jnp.minimum(first + group, n_cols), block, 0)
+
+    def fetch(col, g, copies):
+        for copy in copies:
+            copy.start()
+
+    def attend_group(step, carry):
+        slot = lax.rem(step, 2)
+        each_live_block(step + 1, 1 - slot, fetch)
+
+        def attend(col, g, copies):
+            for copy in copies:
+                copy.wait()
+            # Storage-dtype operands with f32 accumulation, scale applied
+            # to the f32 logits — the same placement as
+            # attention._block_attend.
+            logits = lax.dot_general(
+                q_ref[...], k_buf[slot, g], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (TQ, bs*KV)
+            ok = same_head & (
+                col * block_size + pos_in_block < vlen_ref[...]
+            )
+            ok &= t_ref[b, col] < num_pool_blocks  # a clamped sentinel
+            logits = jnp.where(ok, logits, NEG_INF)
+            _accumulate_block(logits, v_buf[slot, g], acc_ref, m_ref, l_ref)
+
+        each_live_block(step, slot, attend)
+        return carry
+
+    each_live_block(0, 0, fetch)
+    lax.fori_loop(0, pl.cdiv(n_cols, group), attend_group, 0)
+
+    o_ref[...] = (
+        acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    ).astype(o_ref.dtype)
 
 
 # Query rows per grid step: bounds the kernel's VMEM (q/o tiles, the f32
@@ -404,22 +469,17 @@ def _ragged_attention_pallas(
     mb = tables.shape[1]
     ts = _q_tile_positions(s, h)
     tq = ts * h
-    grid = (b, s // ts, mb)
+    group = _group_blocks(bs * kv * hd * k_pool.dtype.itemsize, mb)
 
     valid_len = valid_len.astype(jnp.int32)
-    n_cols = jnp.clip((jnp.max(valid_len, axis=1) + bs - 1) // bs, 1, mb)
+    # Table columns each query tile may see: up to its own longest row,
+    # none for a tile of dead rows (valid_len 0).
+    n_cols = jnp.clip(
+        (jnp.max(valid_len.reshape(b, s // ts, ts), axis=2) + bs - 1) // bs,
+        0, mb,
+    )
 
-    def _table_block(bi, qi, ji, t, nc, lyr):
-        # The gather IS the index_map: scalar-prefetched tables steer the
-        # DMA straight at the slot's j-th block of this layer (sentinel
-        # clamps in-range of the layer's own NB blocks BEFORE the layer
-        # offset; the kernel masks its rows). Past the row's last live
-        # column the index stays put, and an unchanged block index is not
-        # re-fetched.
-        live = jnp.minimum(ji, nc[bi] - 1)
-        return (lyr[0] * nb + jnp.minimum(t[bi, live], nb - 1), 0, 0)
-
-    def _q_rows(bi, qi, ji, t, nc, lyr):
+    def _q_rows(bi, qi, t, nc, lyr):
         return (bi, qi, 0)
 
     kernel = functools.partial(
@@ -428,21 +488,25 @@ def _ragged_attention_pallas(
         num_pool_blocks=nb,
         num_heads=h,
         num_kv_heads=kv,
+        group=group,
         scale=hd ** -0.5,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=grid,
+            grid=(b, s // ts),
             in_specs=[
                 pl.BlockSpec((None, tq, hd), _q_rows),
                 pl.BlockSpec((None, tq, 1), _q_rows),
-                pl.BlockSpec((None, bs * kv, hd), _table_block),
-                pl.BlockSpec((None, bs * kv, hd), _table_block),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((None, tq, hd), _q_rows),
             scratch_shapes=[
+                pltpu.VMEM((2, group, bs * kv, hd), k_pool.dtype),
+                pltpu.VMEM((2, group, bs * kv, hd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, group)),
                 pltpu.VMEM((tq, hd), jnp.float32),
                 pltpu.VMEM((tq, 1), jnp.float32),
                 pltpu.VMEM((tq, 1), jnp.float32),

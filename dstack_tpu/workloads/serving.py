@@ -917,6 +917,11 @@ class ServingEngine:
         self._decode_steps = 0
         self._decode_slot_steps = 0
         self._decode_tokens = 0
+        # What those steps gave the paged-attention kernel to walk: the
+        # blocks the live slots' contexts fill, against the columns of
+        # the whole block table (slots x max blocks).
+        self._decode_live_blocks = 0
+        self._decode_table_columns = 0
         # Chunked-prefill / paging counters (monotonic, for /metrics and
         # the prefix-reuse acceptance measurement: tokens_computed for a
         # cache-hit request drops by the reused prefix).
@@ -1707,6 +1712,11 @@ class ServingEngine:
             "decode_steps_total": self._decode_steps,
             "decode_slot_steps_total": self._decode_slot_steps,
             "decode_tokens_total": self._decode_tokens,
+            # The live slots' blocks at each launched step over the block
+            # table's columns: the share of the table paged attention has
+            # anything to read in.
+            "decode_live_blocks_total": self._decode_live_blocks,
+            "decode_table_columns_total": self._decode_table_columns,
             # The older three-way split, derived from the same clock:
             # launch to readback; admission host work (with the barrier
             # of a cycle that had nothing live); waiting for work. They
@@ -3258,9 +3268,16 @@ class ServingEngine:
     def _count_decode_launch(self, steps: int) -> None:
         """One decode chunk (or speculation round) of `steps` steps is
         about to launch over the slots live right now."""
-        live = sum(r is not None for r in self._live)
+        bs = self._block_size
+        live_blocks = [
+            -(-self._lengths_host[slot] // bs)
+            for slot, r in enumerate(self._live) if r is not None
+        ]
+        live = len(live_blocks)
         self._decode_steps += steps
         self._decode_slot_steps += steps * live
+        self._decode_live_blocks += steps * sum(live_blocks)
+        self._decode_table_columns += steps * self.slots * self._max_blocks
         self._count_expert_slots(steps * live, steps * self.slots, 1)
 
     def _count_expert_slots(self, tokens: int, rows: int, row_len: int) -> None:
@@ -3515,6 +3532,10 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
          stats.get("decode_slot_steps_total", 0)),
         ("dstack_tpu_serving_decode_tokens_total", "counter",
          stats.get("decode_tokens_total", 0)),
+        ("dstack_tpu_serving_decode_live_blocks_total", "counter",
+         stats.get("decode_live_blocks_total", 0)),
+        ("dstack_tpu_serving_decode_table_columns_total", "counter",
+         stats.get("decode_table_columns_total", 0)),
         ("dstack_tpu_serving_rejected_total", "counter",
          stats["rejected_total"]),
         # Speculative decoding (all zero when --spec-enable is off;

@@ -18,6 +18,7 @@ from dstack_tpu.workloads.attention import _repeat_kv
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.generate import generate
 from dstack_tpu.workloads.paged_attention import (
+    _group_blocks,
     _ragged_attention_lax,
     _ragged_attention_pallas,
     dispatch_path,
@@ -91,6 +92,91 @@ SHAPES = (
 )
 
 
+# The kernel walks a query tile's live table columns a GROUP of blocks at
+# a time (paged_attention._group_blocks). These cases sit where that walk
+# can go wrong; at 64 KiB blocks (f32, bs 16 x KV 8 x hd 128) a group is
+# 4 blocks, so every table below is wider than one group.
+#   name: ((B, S, H, KV, hd, NB, bs, MB), context per slot (0 = dead),
+#          table entries (slot, column) punched out to the pad sentinel)
+WALK_CASES = {
+    # Most slots dead beside one long row: zero trips, zeros out.
+    "dead_rows_beside_a_long_row": (
+        (6, 1, 8, 8, 128, 40, 16, 11), (0, 0, 171, 0, 0, 0), ()),
+    # 5 and 9 live blocks: the last group holds 1 block of 4.
+    "live_blocks_not_a_multiple_of_the_group": (
+        (2, 1, 8, 8, 128, 40, 16, 12), (70, 133), ()),
+    # 10 columns, all live: the last group would reach past the table.
+    "table_width_not_a_multiple_of_the_group": (
+        (2, 1, 8, 8, 128, 40, 16, 10), (160, 150), ()),
+    # Sentinels inside the live range, in a full group and in the cut
+    # last group (7 live columns: groups 0-3, 4-6).
+    "sentinel_inside_the_last_group": (
+        (2, 3, 8, 8, 128, 40, 16, 9), (110, 100), ((0, 5), (1, 2), (1, 6))),
+    # A chunk of two query tiles (2 x 32 positions x 16 heads): the first
+    # ends at column 10, the second at 12.
+    "chunk_tiles_end_at_different_columns": (
+        (1, 64, 16, 8, 128, 40, 16, 14), (200,), ()),
+}
+
+
+def _walk_inputs(seed, shape, ctx, holes):
+    """Inputs of a WALK_CASES entry: slot b holds `ctx[b]` positions in
+    distinct blocks; query row (b, i) is row i of the last S positions
+    (a chunk's causal rows; S=1: the decode row), dead slots see nothing.
+    Also returns the pool blocks some live row owns."""
+    B, S, H, KV, hd, NB, bs, MB = shape
+    rng = np.random.default_rng(seed)
+    q = rng.standard_normal((B, S, H, hd)).astype(np.float32)
+    kp = rng.standard_normal((NB, bs, KV, hd)).astype(np.float32)
+    vp = rng.standard_normal((NB, bs, KV, hd)).astype(np.float32)
+    tables = np.full((B, MB), NB, np.int32)
+    blocks = iter(rng.permutation(NB))
+    for b, n in enumerate(ctx):
+        for j in range(-(-n // bs)):
+            tables[b, j] = next(blocks)
+    for b, j in holes:
+        tables[b, j] = NB
+    vlen = np.stack([
+        np.maximum(n - S + 1 + np.arange(S), 1) if n else np.zeros(S)
+        for n in ctx
+    ]).astype(np.int32)
+    owned = sorted(set(tables[tables < NB].tolist()))
+    return tuple(map(jnp.asarray, (q, kp, vp, tables, vlen))) + (owned,)
+
+
+@pytest.mark.parametrize("case", WALK_CASES)
+def test_pallas_walk_matches_lax_fallback(case):
+    """The group walk against the lax path, in interpret mode, where it
+    can go wrong: dead rows, a cut last group, a table narrower than
+    whole groups, sentinels in a fetched group, tiles of one chunk that
+    stop at different columns."""
+    shape, ctx, holes = WALK_CASES[case]
+    B, S, H, KV, hd, NB, bs, MB = shape
+    group = _group_blocks(bs * KV * hd * 4, MB)
+    live = [-(-n // bs) for n in ctx]
+    assert 1 < group < max(live)  # more than one loop step, several blocks each
+    assert any(n % group for n in live if n) or MB % group
+    q, kp, vp, tables, vlen, _ = _walk_inputs(17, shape, ctx, holes)
+    got_lax = _ragged_attention_lax(q, kp[None], vp[None], 0, tables, vlen)
+    got_pal = _ragged_attention_pallas(
+        q, kp[None], vp[None], 0, tables, vlen, interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(got_pal), np.asarray(got_lax), rtol=1e-6, atol=1e-6
+    )
+    dead = np.asarray(ctx) == 0
+    assert not np.asarray(got_pal)[dead].any()  # a dead slot emits zeros
+
+
+def test_group_size_follows_the_blocks_bytes():
+    """The blocks a loop step fetches come from the block's bytes alone:
+    several at the engine's 16-token blocks, one from 256 tokens up,
+    never more than the table holds."""
+    assert _group_blocks(16 * 8 * 128 * 2, 288) == 8
+    assert _group_blocks(256 * 8 * 128 * 2, 18) == 1
+    assert _group_blocks(8 * 2 * 32 * 4, 6) == 6
+
+
 @pytest.mark.parametrize("shape", SHAPES)
 def test_pallas_interpret_matches_lax_fallback(shape):
     """Both implementations share one streaming-softmax update rule —
@@ -156,20 +242,44 @@ def test_layer_index_addresses_one_layer_of_the_stack(shape, impl):
     )
 
 
-def test_ragged_rows_never_see_masked_garbage():
+@pytest.mark.parametrize(
+    "case,impl", [(None, "lax"), *((c, "pallas") for c in (None, *WALK_CASES))]
+)
+def test_ragged_rows_never_see_masked_garbage(case, impl):
     """NaN planted in unwritten pool blocks and past valid_len must not
-    leak: masking happens before the softmax, not after."""
-    q, kp, vp, tables, vlen = _ragged_inputs(3, 2, 2, 4, 2, 32, 10, 8, 4)
-    tables_np = np.asarray(tables)
-    poison = np.array(kp)
-    unused = sorted(set(range(10)) - set(tables_np[tables_np < 10].tolist()))
-    poison[unused] = np.nan
-    # Poison rows past each row's valid length inside used blocks too.
-    vlen_np = np.asarray(vlen)
-    out = ragged_attention(
-        q, jnp.asarray(poison)[None], vp[None], 0, tables, jnp.minimum(vlen, 9)
-    )
+    leak: masking happens before the softmax, not after. The kernel walks
+    a row's live columns only, so for it EVERY block that no live row
+    owns is NaN in both pools: the output is finite and equal to the
+    output over the clean pools. The lax path reads a clamped block for
+    every row at every column some row needs (0 * NaN in PV), so it keeps
+    the K pool's poison alone."""
+    if case is None:
+        q, kp, vp, tables, vlen = _ragged_inputs(3, 2, 2, 4, 2, 32, 10, 8, 4)
+        vlen = jnp.minimum(vlen, 9)
+        tables_np = np.asarray(tables)
+        owned = set(tables_np[tables_np < 10].tolist())
+    else:
+        shape, ctx, holes = WALK_CASES[case]
+        q, kp, vp, tables, vlen, owned = _walk_inputs(23, shape, ctx, holes)
+        if holes:
+            # A sentinel inside a live range clamps to the layer's last
+            # block, which is fetched and masked: its values must be
+            # finite, as the engine's written-or-zero pool's are.
+            owned = {*owned, kp.shape[0] - 1}
+    unused = sorted(set(range(kp.shape[0])) - set(owned))
+    assert unused
+    poison_k, poison_v = np.array(kp), np.array(vp)
+    poison_k[unused] = np.nan
+    if impl == "lax":
+        fn = _ragged_attention_lax
+    else:
+        poison_v[unused] = np.nan
+        fn = lambda *a: _ragged_attention_pallas(*a, interpret=True)
+    out = fn(q, jnp.asarray(poison_k)[None], jnp.asarray(poison_v)[None],
+             0, tables, vlen)
     assert np.isfinite(np.asarray(out)).all()
+    clean = fn(q, kp[None], vp[None], 0, tables, vlen)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
 
 
 def test_paged_dispatch_rules():

@@ -230,6 +230,39 @@ def test_loop_counters_account_for_the_loop(params):
         engine.close()
 
 
+def test_live_block_counters_match_the_hand_count(params):
+    """decode_live_blocks_total / decode_table_columns_total: what the
+    decode steps gave paged attention to walk over what the block table
+    spans. With one step a launch every live slot emits one token a
+    launch, so a request of P prompt tokens and N new ones is live at
+    N - 1 launches with P, P + 1, ... P + N - 2 positions cached,
+    however the two requests' steps interleave."""
+    bs, max_len, slots = 8, 64, 3
+    engine = ServingEngine(CFG, params, slots=slots, max_len=max_len,
+                           kv_block_size=bs, steps_per_sync=1)
+    try:
+        asks = [(list(range(1, 12)), 14), (list(range(3, 33)), 9)]
+        outs = [engine.submit(p, max_new_tokens=n) for p, n in asks]
+        assert [len(_drain(q)) for q in outs] == [n for _, n in asks]
+        s = _settled_stats(engine)
+    finally:
+        engine.close()
+    by_hand = sum(
+        -(-(len(p) + j) // bs) for p, n in asks for j in range(n - 1)
+    )
+    # 11..23 positions cached: 6 steps of 2 blocks, 7 of 3; 30..37: 3 of 4, 5 of 5.
+    assert by_hand == 6 * 2 + 7 * 3 + 3 * 4 + 5 * 5
+    assert s["decode_slot_steps_total"] == sum(n - 1 for _, n in asks)
+    assert s["decode_steps_total"] < s["decode_slot_steps_total"]  # two at once
+    assert s["decode_live_blocks_total"] == by_hand
+    columns = s["decode_steps_total"] * slots * (max_len // bs)
+    assert s["decode_table_columns_total"] == columns
+    assert 0 < by_hand / columns < 1
+    text = prometheus_metrics(s)
+    assert f"dstack_tpu_serving_decode_live_blocks_total {by_hand}" in text
+    assert f"dstack_tpu_serving_decode_table_columns_total {columns}" in text
+
+
 def test_slow_cycle_is_kept_with_its_phases(params):
     """A cycle of SLOW_CYCLE_SECONDS or more is counted and kept whole:
     here the clock jumps two seconds inside a chunk launch."""

@@ -26,6 +26,7 @@ from dstack_tpu.workloads.attention import make_attention_fn
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.paged_attention import (
     _latent_attention_pallas,
+    _q_tile_positions,
     _ragged_attention_pallas,
 )
 from dstack_tpu.workloads.serving import ServingEngine
@@ -66,6 +67,23 @@ PAGED_SHAPES = (
 )
 
 
+# The benchmark's dense serving cut (Mistral-7B's heads, 16 slots x a
+# 4608-token context): the widest table the kernel walks, and a 128-token
+# chunk of eight 512-row query tiles.
+WIDE_H, WIDE_SLOTS, WIDE_BLOCKS = 32, 16, 4608 // BLOCK
+WIDE_SHAPES = [
+    ("decode", WIDE_SLOTS, 1), ("prefill", 1, CHUNK),
+    ("verify", WIDE_SLOTS, MAX_DRAFT + 1),
+]
+
+
+def _paged_args(b, s, h, slots, max_blocks):
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = ((POOL_LAYERS, slots * max_blocks, BLOCK, KV, HD), bf16)
+    return [((b, s, h, HD), bf16), pool, pool, ((), i32),
+            ((b, max_blocks), i32), ((b, s), i32)]
+
+
 def _flash_fwd(q, k, v):
     return fa.flash_attention(q, k, v, causal=True)
 
@@ -84,10 +102,12 @@ def _kernels():
     for kind, b, s in PAGED_SHAPES:
         out.append((
             f"paged_{kind}_b{b}_s{s}", _ragged_attention_pallas,
-            [((b, s, H, HD), bf16),
-             ((POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD), bf16),
-             ((POOL_LAYERS, POOL_BLOCKS, BLOCK, KV, HD), bf16), ((), i32),
-             ((b, MAX_BLOCKS), i32), ((b, s), i32)],
+            _paged_args(b, s, H, SLOTS, MAX_BLOCKS),
+        ))
+    for kind, b, s in WIDE_SHAPES:
+        out.append((
+            f"paged_{kind}_b{b}_s{s}_mb{WIDE_BLOCKS}", _ragged_attention_pallas,
+            _paged_args(b, s, WIDE_H, WIDE_SLOTS, WIDE_BLOCKS),
         ))
     # The latent pool's kernel: decode, the cell's 512-token chunk (a
     # 320-row query tile), the smallest chunk bucket.
@@ -146,6 +166,36 @@ def test_kernel_builds_for_tpu(name, fn, args, v5e):
         lowered = jax.jit(fn).lower(*specs)
         lowered.compile()
     assert "tpu_custom_call" in lowered.as_text()
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every pallas_call in a jaxpr, nested calls included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.mark.parametrize(
+    "b,s,h,slots,max_blocks",
+    [(b, s, H, SLOTS, MAX_BLOCKS) for _, b, s in PAGED_SHAPES]
+    + [(b, s, WIDE_H, WIDE_SLOTS, WIDE_BLOCKS) for _, b, s in WIDE_SHAPES],
+)
+def test_paged_kernel_grid_has_no_table_column_axis(b, s, h, slots, max_blocks):
+    """The paged kernel is ONE call whose grid is slots x query tiles: a
+    grid step costs time with or without work, so a grid over the table's
+    columns made a call cost its table whatever was live (PERF.md section
+    6, PR 28). The live columns are a loop inside the body."""
+    specs = [
+        jax.ShapeDtypeStruct(shape, dt)
+        for shape, dt in _paged_args(b, s, h, slots, max_blocks)
+    ]
+    grids = _pallas_grids(jax.make_jaxpr(_ragged_attention_pallas)(*specs).jaxpr)
+    assert grids == [(b, s // _q_tile_positions(s, h))]
+    assert math.prod(grids[0]) < max_blocks  # nowhere near rows x columns
 
 
 # ------------------------------------------- the pool rides the layer loop
