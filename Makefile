@@ -4,7 +4,7 @@
 PYTHON ?= python
 PYTEST_ARGS ?= -q -m 'not slow' -p no:cacheprovider
 
-.PHONY: test test-all chaos chaos-fast chaos-replica-kill chaos-worker-kill chaos-outage chaos-shard-kill dataplane lint lint-json capacity capacity-smoke capacity-multi bench-proxy bench-routing bench-serving bench-coldstart drill-disagg drill-rl bench-rl
+.PHONY: test test-all chaos chaos-fast chaos-replica-kill chaos-worker-kill chaos-outage chaos-shard-kill dataplane lint lint-json capacity capacity-smoke capacity-multi bench-proxy bench-routing drill-disagg drill-rl
 
 test:
 	JAX_PLATFORMS=cpu $(PYTHON) -m pytest tests/ $(PYTEST_ARGS)
@@ -75,34 +75,6 @@ bench-proxy:
 bench-routing:
 	JAX_PLATFORMS=cpu $(PYTHON) bench_routing.py --out BENCH_routing_r18.json
 
-# Serving-engine benchmark: chunked prefill + paged KV with prefix
-# sharing, speculative-decoding arms, the r12 ragged-paged-attention
-# cells, the r13 sharded (tensor-parallel bit-exactness/overhead) and
-# disaggregation (prefill-flood decode-isolation) arms, and the r14
-# multi-tenant arms (mixed-adapter LoRA batch vs merged-engine token
-# equality + empty-pool overhead; noisy-neighbor steady-tenant TTFT
-# with QoS on/off/no-flood), the r15 flight-recorder overhead arm
-# (recorder-on vs recorder-off, the <2% tracing-always-on claim; run it
-# alone with --arms recorder), and the r16 hierarchical-KV overcommit
-# arm (host-RAM spill tier + slot preemption at 4x residency
-# overcommit; run it alone with --arms overcommit). Results land in
-# BENCH_serving_r16.json; see docs/guides/serving-tuning.md,
-# docs/guides/multi-tenant.md and docs/guides/observability.md for how
-# to read them.
-bench-serving:
-	JAX_PLATFORMS=cpu $(PYTHON) bench_serving.py --out BENCH_serving_r16.json
-
-# Scale-from-zero cold-start decomposition: boots the native server as a
-# fresh subprocess per arm (no cache / warm persistent compile cache /
-# warm cache + packed parallel weight load / warm standby) and splits
-# submit->first-token into stages from the ::dstack-tpu-stage:: markers.
-# Asserts the warm-cache compile stage is >=5x smaller than cold and
-# that the first post-/readyz request pays zero compiles (per-process
-# compile-counter diff over /metrics). Results land in
-# BENCH_coldstart_r20.json; see docs/guides/serving-tuning.md.
-bench-coldstart:
-	JAX_PLATFORMS=cpu $(PYTHON) bench_coldstart.py --out BENCH_coldstart_r20.json
-
 # Prefill/decode disaggregation drill: two real worker processes over a
 # 2-way model mesh each, KV handoffs over a socket. Asserts token
 # bit-exactness vs a unified engine, end-to-end trace continuity (one
@@ -120,12 +92,6 @@ drill-disagg:
 # the stage-marker timeline, and the RL /metrics series.
 drill-rl:
 	JAX_PLATFORMS=cpu $(PYTHON) -m dstack_tpu.workloads.rl_drill
-
-# RL throughput benchmark: colocated (Anakin) loop, socket weight
-# refresh vs a checkpoint-file refresh baseline. Records env-steps/s,
-# learner step time, and weight-refresh latency in BENCH_rl_r17.json.
-bench-rl:
-	JAX_PLATFORMS=cpu $(PYTHON) bench_rl.py --out BENCH_rl_r17.json
 
 # CI-sized variant: 40 runs in-process, asserts 0 failures + telemetry.
 capacity-smoke:

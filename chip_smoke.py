@@ -106,7 +106,7 @@ def sizes(rehearsal: bool) -> dict:
             max_new_tokens=8, mesh_model=2, platform="cpu",
             serve_flags="--slots 2 --prefill-chunk-tokens 32",
         )
-    # bench.py's one-chip shape: 8 of smol-1b's 16 layers leave room for
+    # The one-chip shape: 8 of smol-1b's 16 layers leave room for
     # the 12 B/param train state on a 16 GB chip; B=6, S=2048. Four chips
     # take all 16 layers; B=4 there because the seq-parallel layout's ring
     # backward (jnp recompute of each step's logits) needs 16.2 GiB of a
@@ -183,7 +183,7 @@ def child_kernels(rehearsal: bool) -> dict:
         train_b, train_s, ring_s = 1, 128, 128
         slots, chunk, block, max_len, max_draft = 2, 16, 8, 32, 1
     else:
-        # bench.py's train shape is B=6; two rows are enough to cross the
+        # The train phase's shape is B=6; two rows are enough to cross the
         # batch*head grid axis. Engine geometry = ServingEngine defaults.
         train_b, train_s, ring_s = 2, 2048, 1024
         slots, chunk, block, max_len, max_draft = 8, 128, 16, cfg.max_seq_len, 4

@@ -1,11 +1,10 @@
 """KVB01: no whole-table gathers of the KV block pool in kv_blocks.py.
 
-The r12 ragged-attention rewrite (workloads/paged_attention.py) exists
-because the paged engine's attention builders used to gather every block
-a slot owns into a dense `(max_len, KV, hd)` scratch view before
-attending — `jnp.take(pool, block_tables, ...)` — which BENCH_serving_r10
-measured at −63.6% single-stream throughput. This checker is the
-regression guard: inside `workloads/kv_blocks.py`, any `jnp.take` /
+Ragged paged attention (workloads/paged_attention.py) exists so that
+no attention builder gathers every block a slot owns into a dense
+`(max_len, KV, hd)` scratch view before attending —
+`jnp.take(pool, block_tables, ...)` — a whole-pool data movement per
+dispatch. This checker is the regression guard: inside `workloads/kv_blocks.py`, any `jnp.take` /
 `jnp.take_along_axis` / `lax.gather` whose *indices* operand is a whole
 block table (a bare name or attribute like `block_tables`, `table_row`,
 `tables`) is flagged. The allowed ragged idiom indexes a single table

@@ -4,8 +4,8 @@ The reference ships its training/serving examples as user YAML + shell
 commands (reference: examples/fine-tuning/*, examples/accelerators/tpu/*);
 the orchestrator itself never touches model code. Here the example workload
 is a first-class library so that (a) the driver's `__graft_entry__` contract
-has a flagship model to compile, (b) `bench.py` can prove the "tokens/s
-within 5% of bare-metal" north star (BASELINE.md), and (c) users get a
+has a flagship model to compile, (b) the benchmark (`benchmarks/`,
+PERF.md) has a trainer and a serving engine to measure, and (c) users get a
 known-good sharded JAX fine-tune to launch via `dstack-tpu apply`.
 
 Everything is pure JAX: bf16 matmuls on the MXU with f32 accumulation,

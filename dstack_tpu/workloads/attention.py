@@ -37,31 +37,6 @@ def _repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     )
 
 
-def decode_attention(q, ck, cv, valid_len):
-    """Single-position decode attention with per-ROW validity: q
-    (B, 1, H, hd) against per-slot caches (B, max_len, KV, hd), each row
-    masked to its own `valid_len` (decode slots sit at different
-    lengths; generate._cached_attention masks per-position instead).
-    The cache may be a GATHERED view of a paged block pool — garbage in
-    rows at or beyond valid_len (unwritten or stale blocks) is discarded
-    by the mask, NaN included, because `jnp.where` selects before the
-    softmax ever sees it."""
-    b, s, h, hd = q.shape
-    k = _repeat_kv(ck, h // ck.shape[2])
-    v = _repeat_kv(cv, h // ck.shape[2])
-    logits = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * (hd ** -0.5)
-    kpos = jnp.arange(ck.shape[1], dtype=jnp.int32)
-    mask = kpos[None, :] < valid_len[:, None]          # (B, max_len)
-    logits = jnp.where(mask[:, None, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    out = jnp.einsum(
-        "bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32
-    )
-    return out.astype(q.dtype).reshape(b, s, h * hd)
-
-
 def plain_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,

@@ -307,7 +307,7 @@ PRESETS: Dict[str, ModelConfig] = {
         vocab_size=512, d_model=128, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=256, max_seq_len=256, remat=False,
     ),
-    # Single v5e/v6e chip fine-tune scale; the bench.py flagship.
+    # Single v5e/v6e chip fine-tune scale; what chip_smoke.py trains.
     "smol-1b": ModelConfig(
         vocab_size=32768, d_model=2048, n_layers=16, n_heads=16, n_kv_heads=8,
         d_ff=5632, max_seq_len=2048,

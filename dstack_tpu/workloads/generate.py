@@ -157,50 +157,6 @@ def _forward_cached(
     return logits, KVCache(k=new_k, v=new_v, length=start + s)
 
 
-def _nucleus_filter(logits: jnp.ndarray, top_p) -> jnp.ndarray:
-    """Nucleus (top-p) filter over one row of logits: strict `<` on the
-    PRECEDING cumulative mass, so the top token always survives and
-    top_p=1 keeps everything. The single source of truth — the jitted
-    decode step vmaps this, prefill first-token sampling calls it
-    directly, and speculative decoding's rejection sampling builds both
-    its target (p) and drafter (q) distributions through it
-    (kv_blocks._sampling_probs), so the boundary rule cannot drift
-    between any of them: distribution-exact speculation requires p and
-    q to share the exact filter semantics."""
-    order = jnp.argsort(-logits)
-    probs = jax.nn.softmax(logits[order])
-    before = jnp.cumsum(probs) - probs
-    keep = jnp.zeros(logits.shape[0], bool).at[order].set(before < top_p)
-    return jnp.where(keep, logits, -jnp.inf)
-
-
-def sample_logits_row(logits, temp, top_p, rng):
-    """First-token sampling over one logits row (V,): greedy argmax when
-    temp == 0, else temperature-scaled categorical behind the shared
-    `_nucleus_filter`. `temp`/`top_p`/`rng` are traced, so callers pay no
-    extra compile entries per sampling config. Shared by the dense
-    whole-prompt prefill (serving.make_prefill) and the chunked paged
-    prefill (kv_blocks.make_chunk_prefill) — the two admission paths
-    must sample identically for the token-exactness contract."""
-
-    def _sample(x):
-        scaled = x / jnp.maximum(temp, 1e-6)
-        filtered = lax.cond(
-            top_p < 1.0,
-            lambda s: _nucleus_filter(s, top_p),
-            lambda s: s,
-            scaled,
-        )
-        return jax.random.categorical(rng, filtered).astype(jnp.int32)
-
-    return lax.cond(
-        temp > 0.0,
-        _sample,
-        lambda x: jnp.argmax(x).astype(jnp.int32),
-        logits,
-    )
-
-
 def generate(
     config: ModelConfig,
     params: Params,

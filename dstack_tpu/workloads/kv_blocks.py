@@ -1,9 +1,9 @@
 """Paged KV cache: block pool + prefix sharing + chunked prefill.
 
-The dense serving layout (one `(max_len, KV, hd)` strip per slot) wastes
+A dense serving layout (one `(max_len, KV, hd)` strip per slot) wastes
 HBM twice: a short request reserves the whole strip, and N requests that
-share a system prompt hold N copies of its KV. This module replaces the
-strip with a vLLM-style *block pool* — `k`/`v` are
+share a system prompt hold N copies of its KV. This module keeps a
+vLLM-style *block pool* instead — `k`/`v` are
 `(L, num_blocks, block_size, KV, hd)` and each slot owns an int32 *block
 table* row mapping its logical cache positions to pool blocks — plus:
 
@@ -16,22 +16,25 @@ table* row mapping its logical cache positions to pool blocks — plus:
 - `make_chunk_prefill`: prefill one budget-bounded token chunk of one
   prompt directly into the pool, so a long prompt interleaves with
   decode chunks instead of monopolizing the device;
-- `make_paged_decode_step`: the per-token decode body against the pool,
-  sharing `serving._select_next_token` with the dense path so sampling
-  semantics cannot drift.
+- `make_paged_decode_step`: the per-token decode body against the pool;
+  it, the prefill's first token and speculation's accept test all take
+  their sampling law from `sampling.py`.
 
-Since r12, every attention in this module goes through
+The reference these programs are held to is `generate.generate` (one
+request, one static strip, no paging): tests/test_serving_paged.py and
+its siblings pin the engine's temperature-0 token streams to it.
+
+Every attention in this module goes through
 `paged_attention.ragged_attention`: it attends STRAIGHT against the
 stacked pool at a layer index, through the block tables, with a
 streaming softmax that walks one table column (one block) at a time.
-No program here materializes a dense `(max_len, ...)` per-slot view
-any more — the whole-pool `jnp.take(pool, block_tables, ...)`
-gather, the matching full-view scatter, and the engine's cross-chunk
-view cache that existed to amortize them are all gone (the static
-analyzer's KVB01 check keeps them gone). Each program's writes shrink
-to the handful of rows it actually produced, scattered by
+No program here materializes a dense `(max_len, ...)` per-slot view:
+no whole-pool `jnp.take(pool, block_tables, ...)` gather, no matching
+full-view scatter (the static analyzer's KVB01 check keeps them out).
+Each program writes only the handful of rows it produced, scattered by
 `(layer, block, offset)` before the layer's attention so in-flight rows
-see themselves and their predecessors exactly as the dense body would.
+see themselves and their predecessors exactly as `generate.generate`'s
+cache rows do.
 The pool rides every program's layer loop as a CARRY (`_layer_loop`),
 so those scatters update it in place; it is never an `xs`/`ys` of the
 layer scan, which would copy each layer's slab out and back per
@@ -57,11 +60,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from dstack_tpu.workloads.config import ModelConfig
-from dstack_tpu.workloads.generate import (
-    _nucleus_filter,
+from dstack_tpu.workloads.paged_attention import ragged_attention
+from dstack_tpu.workloads.sampling import (
+    _sampling_probs,
+    _select_next_token,
     sample_logits_row,
 )
-from dstack_tpu.workloads.paged_attention import ragged_attention
 from dstack_tpu.workloads.transformer import (
     absorb_query,
     latent_output,
@@ -78,10 +82,8 @@ Params = Dict[str, Any]
 
 
 class PagedDecodeState(NamedTuple):
-    """Block-pool decode state. Per-slot scalar fields carry the SAME
-    names as serving.DecodeState so the sampling gates
-    (`_any_active_nucleus` / `_any_active_sampling`) and engine-level
-    tests work on either."""
+    """Block-pool decode state. The sampling law (sampling.py) reads the
+    per-slot `active`, `temperature` and `top_p` rows by name."""
 
     # The pools' trailing (heads, width) is ModelConfig.kv_row_shapes():
     # (KV, hd) twice for GQA; ONE latent pool (1, row) and a zero-wide v
@@ -543,7 +545,7 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
     cache holds one entry per pow-2 chunk bucket regardless of prompt
     length, start offset, or sampling params. `first` is only meaningful
     when `finalize` is set (last chunk): it samples the last prompt
-    position's logits exactly like the dense `make_prefill`. Finalize
+    position's logits (`sampling.sample_logits_row`). Finalize
     also flips the slot live on device (lengths/last_token/active/...)
     so no separate insert program is needed.
 
@@ -645,8 +647,9 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
                            lora: bool = False,
                            attn_impl: Optional[str] = None):
     """decode_steps(params, state, rng) -> (state, tokens (B, steps),
-    active) over a PagedDecodeState — the paged twin of
-    serving.make_decode_step. With `lora=True` the program takes a
+    active) over a PagedDecodeState: `steps` tokens for every active
+    slot per call, so one host sync delivers a chunk of tokens per slot.
+    With `lora=True` the program takes a
     trailing adapter-bank arg and each slot gathers its own A/B pair by
     `state.adapter_ix` (lora_serving.project_qkv_lora); a batch with no
     live adapters skips the LoRA math behind one `lax.cond`.
@@ -654,30 +657,24 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
     Each of the `steps` per-token iterations writes the new row's K/V
     straight into the slot's current block — one O(B)-row scatter per
     layer into the carried pool (`_layer_loop`) — and attends raggedly
-    over the block tables (`paged_attention.ragged_attention`). The
-    whole-pool gather, the full-view write-back, and the carried
-    cross-chunk view cache of r08-r10 are gone, and there is no cached
-    view for boundary events (prefill chunks, CoW copies, table growth,
-    spec rounds) to invalidate. Steady-state decode touches only the
-    blocks each slot actually owns — true on the chip since PR 26 only:
-    until then the layer scan took the pool as `xs` and returned it as
+    over the block tables (`paged_attention.ragged_attention`). There
+    is no cached view for boundary events (prefill chunks, CoW copies,
+    table growth, spec rounds) to invalidate. Steady-state decode
+    touches only the blocks each slot actually owns — true on the chip
+    since PR 26 only: until then the layer scan took the pool as `xs` and returned it as
     `ys`, and the traced runs of PR 22 and PR 24 (mistral-7b.chat) show
     every layer-step slicing out and writing back the layer's whole K
     and V slab (four ops of 0.44 ms over bf16[4608,16,8,128]) and every
     step copying the whole pool twice (`copy.92/.93`, 5.2 ms each):
     31.5 ms of a 50.7 ms step to write 16 rows.
 
-    Sampling and retirement share `serving._select_next_token` — the
-    SAME traced tail as the dense `_decode_body` — so the two paths
-    cannot drift: temp-0 output is bit-exact vs the dense engine.
+    Sampling is per SLOT (`sampling._select_next_token`: temperature 0
+    = greedy argmax, else categorical at that slot's temperature and
+    top_p), so requests with different settings share one decode batch.
     Inactive slots never write: their table rows may be stale (blocks
     freed to the cache or another slot at retire), so their write lane
     is pointed at the OOB sentinel block and dropped.
     """
-    # Function-level import: serving imports this module at load time,
-    # and engines construct only after both modules exist.
-    from dstack_tpu.workloads import serving as _serving
-
     c = config
 
     def one_step(params, state: PagedDecodeState, rng, bank=None):
@@ -709,7 +706,7 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
         )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         logits = logits_linear(h[:, -1], params["lm_head"])
-        next_token = _serving._select_next_token(state, logits, rng)
+        next_token = _select_next_token(state, logits, rng)
 
         act = state.active
         remaining = state.remaining - act.astype(jnp.int32)
@@ -767,27 +764,6 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
 
 
 # -- speculative decoding (draft k cheap tokens, verify in one forward) -------
-
-
-def _sampling_probs(logits, temps, top_ps):
-    """Per-slot sampling distributions under the ENGINE's semantics —
-    temperature scale guarded like `_decode_body._sample`, nucleus
-    filter via the shared `generate._nucleus_filter` (gated so all-
-    top_p=1 traffic never pays the vocab sort). logits (B, S, V), temps
-    / top_ps (B,) -> probs (B, S, V). Rejection sampling is exact only
-    if drafter q and target p both come from THIS function."""
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None, None]
-    filtered = lax.cond(
-        jnp.any((temps > 0.0) & (top_ps < 1.0)),
-        lambda s: jax.vmap(
-            lambda rows, tp: jax.vmap(
-                lambda r: _nucleus_filter(r, tp)
-            )(rows)
-        )(s, top_ps),
-        lambda s: s,
-        scaled,
-    )
-    return jax.nn.softmax(filtered, axis=-1)
 
 
 def make_spec_draft(config: ModelConfig, k: int, shardings=None,
@@ -898,8 +874,8 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
     token from the residual norm(max(p-q, 0)), bonus token from p_k
     when everything accepts), which preserves the target distribution
     exactly. Emission caps (`remaining` budget, cache capacity) and the
-    retire conditions replicate `_decode_body`'s, so a speculative slot
-    stops on exactly the token the plain path would have stopped on.
+    retire conditions replicate `make_paged_decode_step`'s, so a
+    speculative slot stops on exactly the token the plain path would have stopped on.
 
     ROLLBACK IS LENGTH GATING OVER A PRIVATIZED WINDOW: all k+1 rows
     are written to the pool (in-flight rows must be visible to later
@@ -990,7 +966,7 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
         )[:, 0]
         bonus = jnp.where(samp, bonus_samp, bonus_greedy)
 
-        # Emission mirrors _decode_body's stop rules: at most `remaining`
+        # Emission mirrors the decode step's stop rules: at most `remaining`
         # tokens, and never past cache row ml-2 (the next round's write
         # must still fit).
         cap = jnp.maximum(ml - 1 - lens, 0)

@@ -14,8 +14,10 @@ each batch slot gathers its own A/B pair by index (the `workloads/moe.py`
 gather/dispatch pattern) and applies `y += (alpha/r)·(x@A)@B` UNMERGED on
 the LoRA target projections. The delta is added to the projection output
 before reshape/RoPE — the same place `merge_lora`'s baked-in delta lands —
-so a multiplexed engine is temp-0 token-exact with a merged single-tenant
-engine. When no live slot carries an adapter, a `lax.cond` skips the
+so a multiplexed engine computes a merged single-tenant engine's logits
+to bf16 rounding (the delta is added in f32 here and rounded into the
+bf16 weights there, so a top-2 near-tie may resolve differently;
+tests/test_lora_serving.py writes the tolerance). When no live slot carries an adapter, a `lax.cond` skips the
 gather+einsum entirely, so adapter-free batches pay one predicate, not
 two matmuls per target — and when no in-flight request holds an adapter
 ref at all (`AdapterRegistry.inflight == 0`), the engine dispatches its

@@ -3,14 +3,12 @@
 The paged-KV engine (workloads/kv_blocks.py) stores every slot's KV cache
 as scattered `(block_size, KV, hd)` blocks inside one shared
 `(L, num_blocks, block_size, KV, hd)` pool, indexed by per-slot block
-tables. Until r12 every attention consumer first *gathered* a slot's
-blocks into a dense `(max_len, KV, hd)` scratch view and ran dense
-attention over it — a whole-pool data movement per dispatch that
-BENCH_serving_r10 measured at −63.6% single-stream throughput vs the
-dense engine, despite a cross-chunk view cache built solely to amortize
-it. This module deletes that trade entirely: attention runs directly
+tables. Gathering a slot's blocks into a dense `(max_len, KV, hd)`
+scratch view to run dense attention over it is a whole-pool data
+movement per dispatch. This module avoids it: attention runs directly
 against the pool, vLLM-PagedAttention-style, one block at a time with a
-streaming softmax, and the dense view is never materialized.
+streaming softmax, and the dense view is never materialized
+(analysis/checkers/paged_gather.py keeps the gather out of kv_blocks.py).
 
 `ragged_attention` takes the STACKED pool and a layer index, never one
 layer's `(num_blocks, block_size, KV, hd)` slab: the paged programs
@@ -201,10 +199,9 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
     2 accumulates the PV
     product with the probabilities normalized at the FINAL (m, l) and
     quantized to q.dtype first. That quantization is deliberate: the
-    dense consumers this path replaced (generate._cached_attention,
-    attention.decode_attention) all run
+    dense reference (generate._cached_attention) runs
     `softmax(logits).astype(q.dtype)` before PV, and the serving tests
-    pin the engine bit-exact against them at temperature 0 — near-tied
+    pin the engine bit-exact against it at temperature 0 — near-tied
     logits (observed gaps under 1e-2) flip the argmax if the paged path
     keeps f32 probabilities the flat path rounded away. Recomputing the
     QK logits in pass 2 costs one extra (B, S, bs) einsum per column and

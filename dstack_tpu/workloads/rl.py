@@ -20,7 +20,7 @@ onto machinery this repo already ships:
 
   Anakin (colocated): `run_anakin` runs actor and learner synchronously
     in one process on one slice — the deterministic harness behind the
-    seeded learning smoke and `bench_rl.py`.
+    seeded learning smoke (tests/test_rl.py).
 
 Weight refresh semantics (epoch fencing): the learner's `publish` bumps
 a monotonically increasing weight epoch and swaps the packed snapshot
@@ -38,7 +38,7 @@ Behavior logprobs: rather than plumbing logprob outputs through every
 jitted decode program, actors re-score finished rollouts with a
 teacher-forced forward pass under the SAME weights that generated them
 (`make_sequence_scorer`). At top_p=1.0 the engine's sampler draws from
-exactly softmax(logits/T) (`serving._select_next_token`), so the
+exactly softmax(logits/T) (`sampling._select_next_token`), so the
 post-hoc score IS the behavior log-probability; actors therefore pin
 top_p=1.0. Rollout determinism rides the engine's admission gate
 (`hold_admission`): one rollout round enters prefill as one admission
@@ -206,8 +206,8 @@ def make_sequence_scorer(config: ModelConfig, mesh=None):
     under softmax(logits/temperature).
 
     This is the exact behavior distribution of the engine's sampler at
-    top_p=1.0 (`_select_next_token` draws categorical over logits/T with
-    no nucleus cut), so scoring a rollout under the weights that
+    top_p=1.0 (`sampling._select_next_token` draws categorical over
+    logits/T with no nucleus cut), so scoring a rollout under the weights that
     generated it yields the PPO denominator without touching the decode
     programs. Nucleus-filtered rollouts (top_p < 1) would need the
     filtered renormalization — the Actor pins top_p=1.0 instead."""
@@ -571,8 +571,8 @@ class WeightRefreshClient:
 
 
 class CheckpointWeightRefresh:
-    """File-based refresh baseline (the arm `bench_rl.py` compares the
-    socket channel against): publish writes the packed frame + epoch
+    """File-based refresh baseline (tests/test_rl.py holds the socket
+    channel's reward trajectory to it, seed for seed): publish writes the packed frame + epoch
     sidecar atomically (tmp + rename, same recipe as the runner's
     resize notice); poll stats the sidecar and reloads the whole file.
     Same publish/poll interface as the socket pair."""

@@ -31,17 +31,14 @@ Three kinds of jitted program run the engine:
 First tokens are delivered by a dedicated reader thread the moment the
 prefill readback lands — because prefill chunks are dispatched BEFORE
 the decode chunk each iteration, that readback completes while the
-decode chunk still runs, so TTFT no longer pays the decode-chunk
-residual (the 191 ms term in BENCH_serving_r06 at steps_per_sync=32).
+decode chunk still runs, so TTFT does not pay the decode chunk's
+residual (up to `steps_per_sync` steps).
 
-The dense primitives (DecodeState / make_prefill / make_insert /
-make_decode_step) remain the reference semantics — the paged decode
-body shares `_select_next_token` with the dense `_decode_body`, and
-tests/test_serving_paged.py pins chunked+paged token streams to the
-dense reference bit-exactly at temperature 0.
+The reference semantics are `generate.generate`'s:
+tests/test_serving_paged.py pins chunked+paged token streams to it
+bit-exactly at temperature 0 (kv_blocks.py's header).
 """
 
-import functools
 import queue
 import threading
 import time
@@ -50,7 +47,6 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from dstack_tpu.server.tracing import HistogramData
 from dstack_tpu.utils.flight_recorder import (
@@ -61,14 +57,7 @@ from dstack_tpu.utils.flight_recorder import (
 )
 from dstack_tpu.utils.stagemarkers import auto_stage
 from dstack_tpu.workloads import compile_cache
-from dstack_tpu.workloads.attention import decode_attention
 from dstack_tpu.workloads.config import ModelConfig
-from dstack_tpu.workloads.generate import (
-    KVCache,
-    _forward_cached,
-    _nucleus_filter,
-    sample_logits_row,
-)
 from dstack_tpu.workloads.kv_blocks import (
     BlockAllocator,
     init_paged_state,
@@ -89,267 +78,8 @@ from dstack_tpu.workloads.sharding import (
     make_serving_shardings,
     serving_param_shardings,
 )
-from dstack_tpu.workloads.transformer import (
-    linear,
-    logits_linear,
-    mlp_block,
-    project_qkv,
-    rms_norm,
-)
 
 Params = Dict[str, Any]
-
-# Moved to attention.py (the paged path shares it); old name kept for
-# the engine-internal call sites and external pins.
-_decode_attention = decode_attention
-
-
-class DecodeState(NamedTuple):
-    """Shared slot state: k/v (L, B, max_len, KV, hd), per-slot scalars."""
-
-    k: jnp.ndarray
-    v: jnp.ndarray
-    lengths: jnp.ndarray      # (B,) filled cache positions
-    last_token: jnp.ndarray   # (B,) next token to feed
-    active: jnp.ndarray       # (B,) bool
-    remaining: jnp.ndarray    # (B,) new tokens still budgeted
-    temperature: jnp.ndarray  # (B,) f32 per-REQUEST sampling temp; 0 = greedy
-    top_p: jnp.ndarray        # (B,) f32 nucleus cutoff; 1 = no filtering
-
-
-def init_decode_state(config: ModelConfig, batch: int, max_len: int) -> DecodeState:
-    c = config
-    if c.latent or c.n_dense_layers:
-        raise ValueError(
-            "the dense reference engine runs one stack of GQA blocks: latent"
-            " attention and leading dense layers are served by the paged"
-            " engine (ServingEngine) only"
-        )
-    shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
-    return DecodeState(
-        k=jnp.zeros(shape, c.activation_dtype),
-        v=jnp.zeros(shape, c.activation_dtype),
-        lengths=jnp.zeros((batch,), jnp.int32),
-        last_token=jnp.zeros((batch,), jnp.int32),
-        active=jnp.zeros((batch,), bool),
-        remaining=jnp.zeros((batch,), jnp.int32),
-        temperature=jnp.zeros((batch,), jnp.float32),
-        top_p=jnp.ones((batch,), jnp.float32),
-    )
-
-
-def make_prefill(config: ModelConfig):
-    """prefill(params, tokens (1, S), temp, top_p, rng) ->
-    (k (L,1,S,KV,hd), v, first_token ()).
-
-    First-token sampling is folded into the jitted program (the shared
-    `generate.sample_logits_row`), so admission never blocks the host on
-    a device readback. `temp`/`top_p`/`rng` are traced, so the compile
-    cache stays one entry per prompt bucket S. This is the DENSE
-    reference prefill; the engine itself admits through the chunked
-    paged path (kv_blocks.make_chunk_prefill), which must sample
-    identically."""
-    c = config
-
-    @jax.jit
-    def prefill(params, tokens, temp, top_p, rng):
-        cache = KVCache(
-            k=jnp.zeros(
-                (c.n_layers, 1, tokens.shape[1], c.n_kv_heads, c.head_dim),
-                c.activation_dtype,
-            ),
-            v=jnp.zeros(
-                (c.n_layers, 1, tokens.shape[1], c.n_kv_heads, c.head_dim),
-                c.activation_dtype,
-            ),
-            length=jnp.zeros((), jnp.int32),
-        )
-        logits, cache = _forward_cached(c, params, tokens, cache)
-        first = sample_logits_row(logits[0], temp, top_p, rng)
-        return cache.k, cache.v, first
-
-    return prefill
-
-
-def make_insert():
-    """insert(state, slots (N,), k_rows (L,N,S,KV,hd), v_rows, seq_lens
-    (N,), tokens (N,), budgets (N,), temps (N,), top_ps (N,)) — write N
-    prefilled requests of the SAME prompt bucket S into their slots in
-    one donated call. Part of the dense reference path (the paged
-    engine's chunk_prefill finalize replaces it)."""
-
-    @functools.partial(jax.jit, donate_argnums=0)
-    def insert(state: DecodeState, slots, k_rows, v_rows, seq_lens,
-               tokens, budgets, temps, top_ps):
-        s_len = k_rows.shape[2]
-        return DecodeState(
-            k=state.k.at[:, slots, :s_len].set(k_rows),
-            v=state.v.at[:, slots, :s_len].set(v_rows),
-            lengths=state.lengths.at[slots].set(seq_lens),
-            last_token=state.last_token.at[slots].set(tokens),
-            active=state.active.at[slots].set(True),
-            remaining=state.remaining.at[slots].set(budgets),
-            temperature=state.temperature.at[slots].set(temps),
-            top_p=state.top_p.at[slots].set(top_ps),
-        )
-
-    return insert
-
-
-def _any_active_nucleus(state) -> jnp.ndarray:
-    """True when any LIVE slot wants nucleus filtering.
-
-    Gates the per-step sort/cumsum branch in the decode body. Must look
-    only at active slots: retire keeps the old top_p in the freed row,
-    and a stale < 1 value must not tax default traffic forever (pinned
-    by tests/test_serving.py::test_nucleus_gate_ignores_retired_slots).
-    Greedy slots (temperature 0) discard their sampled value entirely,
-    so their top_p must not arm the branch either — the OpenAI-SDK
-    combo {"temperature": 0, "top_p": 0.9} is routine. Works on either
-    DecodeState or PagedDecodeState (same field names).
-    """
-    return jnp.any(
-        state.active & (state.top_p < 1.0) & (state.temperature > 0.0)
-    )
-
-
-def _any_active_sampling(state) -> jnp.ndarray:
-    """True when any LIVE slot samples (temperature > 0).
-
-    Gates the categorical branch: an all-greedy batch (the default
-    engine) compiles back to the argmax-only step instead of paying
-    gumbel RNG + a second vocab-wide argmax per decode step whose
-    result every slot discards."""
-    return jnp.any(state.active & (state.temperature > 0.0))
-
-
-def _select_next_token(state, logits, rng):
-    """Per-slot next-token selection: scale by each slot's temperature
-    (guarded so greedy slots don't divide by 0 — their sampled value is
-    unused), nucleus-filter by each slot's top_p, then select greedy vs
-    sampled per slot. top_p == 1 masks nothing (the strict `<` keeps
-    every token whose PRECEDING cumulative mass is < p, so the top token
-    always survives and p=1 keeps all).
-
-    The ONE traced sampling tail both cache layouts run: the dense
-    `_decode_body` and the paged ragged decode body
-    (kv_blocks.make_paged_decode_step) call it on their respective
-    states (DecodeState / PagedDecodeState — same scalar field names),
-    so the two paths cannot drift in sampling semantics.
-
-    Two nested runtime branches keep the DEFAULT paths free: an
-    all-greedy batch (every live temp 0) never scales, filters, or
-    draws gumbels — it compiles back to the argmax-only step; a
-    sampling batch with every live top_p=1 skips the vocab-wide
-    sort/cumsum. lax.cond executes one branch at runtime, so each
-    skipped stage costs only its predicate."""
-    temps = state.temperature
-
-    def _sample(x):
-        scaled = x / jnp.maximum(temps, 1e-6)[:, None]
-        filtered = lax.cond(
-            _any_active_nucleus(state),
-            lambda s: jax.vmap(_nucleus_filter)(s, state.top_p),
-            lambda s: s,
-            scaled,
-        )
-        return jax.random.categorical(rng, filtered, axis=-1).astype(jnp.int32)
-
-    sampled = lax.cond(
-        _any_active_sampling(state),
-        _sample,
-        lambda x: jnp.zeros((x.shape[0],), jnp.int32),  # value unused
-        logits,
-    )
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy)
-
-
-def _decode_body(config: ModelConfig):
-    """one_step(params, state, rng) -> (state, tokens (B,), active) — the
-    single-token dense decode body scanned by make_decode_step. The
-    paged engine runs its own ragged body against the block pool
-    (kv_blocks.make_paged_decode_step) but shares `_select_next_token`,
-    so the paged path cannot drift from the dense reference in
-    sampling or retirement semantics."""
-    c = config
-
-    def one_step(params, state: DecodeState, rng):
-        B = state.lengths.shape[0]
-        tokens = state.last_token[:, None]                 # (B, 1)
-        positions = state.lengths[:, None]                 # (B, 1) per-slot
-        x = jnp.take(params["embed"], tokens, axis=0)
-
-        rows = jnp.arange(B)
-
-        def body(x, layer):
-            p, ck, cv = layer
-            q, k, v = project_qkv(c, x, p, positions)
-            ck = ck.at[rows, state.lengths].set(k[:, 0].astype(ck.dtype))
-            cv = cv.at[rows, state.lengths].set(v[:, 0].astype(cv.dtype))
-            attn = _decode_attention(q, ck, cv, state.lengths + 1)
-            x = x + linear(attn, p["wo"])
-            if c.n_experts > 0:
-                from dstack_tpu.workloads.moe import moe_block
-
-                x, _ = moe_block(c, x, p)
-            else:
-                x = mlp_block(c, x, p)
-            return x, (ck, cv)
-
-        x, (new_k, new_v) = lax.scan(body, x, (params["layers"], state.k, state.v))
-        h = rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = logits_linear(h[:, -1], params["lm_head"])
-        next_token = _select_next_token(state, logits, rng)
-
-        act = state.active
-        remaining = state.remaining - act.astype(jnp.int32)
-        # A slot also retires when its cache is full (the NEXT write would
-        # land at row lengths+1, which must stay < max_len).
-        new_active = act & (remaining > 0) & (state.lengths + 2 <= state.k.shape[2])
-        new_state = DecodeState(
-            k=new_k,
-            v=new_v,
-            lengths=state.lengths + act.astype(jnp.int32),
-            last_token=jnp.where(act, next_token, state.last_token),
-            active=new_active,
-            remaining=remaining,
-            temperature=state.temperature,
-            top_p=state.top_p,
-        )
-        return new_state, jnp.where(act, next_token, -1), new_active
-
-    return one_step
-
-
-def make_decode_step(config: ModelConfig, steps: int = 1):
-    """decode_step(params, state, rng) -> (state, tokens (B, steps), active).
-
-    `steps` tokens for every active slot per call — the inner scan stays on
-    device, so one host sync delivers a chunk of tokens per slot. Larger
-    chunks amortize dispatch/readback latency at the cost of
-    up-to-`steps`-step admission latency for new requests. Sampling is per SLOT from
-    `state.temperature` (0 = greedy argmax, else categorical at that
-    temperature — requests with different temperatures share one decode
-    batch; the engine assigns its default to requests that don't
-    specify one)."""
-    one_step = _decode_body(config)
-
-    @functools.partial(jax.jit, donate_argnums=1)
-    def decode_steps(params, state: DecodeState, rng):
-        def body(carry, step_rng):
-            st, _ = carry
-            st, toks, active = one_step(params, st, step_rng)
-            return (st, active), toks
-
-        (state, active), toks = lax.scan(
-            body,
-            (state, state.active),
-            jax.random.split(rng, steps),
-        )
-        return state, toks.T, active  # (B, steps)
-
-    return decode_steps
 
 
 class EngineOverloadedError(RuntimeError):
@@ -357,9 +87,9 @@ class EngineOverloadedError(RuntimeError):
 
     `retry_after` is the engine's own estimate (seconds) of when a slot
     is likely to free up — callers surface it as an HTTP Retry-After.
-    Shedding at admission keeps TTFT bounded for accepted requests; the
-    alternative (unbounded queueing) was measured at 10.8 s TTFT p50 for
-    +7% aggregate throughput (BENCH_serving_r04, streams=32).
+    Shedding at admission keeps TTFT bounded for accepted requests;
+    unbounded queueing trades a TTFT that grows with the backlog for a
+    few percent of aggregate throughput.
     """
 
     def __init__(self, pending: int, retry_after: float):
@@ -2221,9 +1951,8 @@ class ServingEngine:
     def _deliver_loop(self) -> None:
         """Reader thread: blocks on each finalized prefill's first-token
         readback and delivers it the instant it lands — decoupled from
-        the main loop, which may still be waiting out a decode chunk
-        (the r06 `first_chunk_residual`). Also completes one-token
-        requests end-to-end."""
+        the main loop, which may still be waiting out a decode chunk.
+        Also completes one-token requests end-to-end."""
         while True:
             task = self._deliver_q.get()
             if task is None:

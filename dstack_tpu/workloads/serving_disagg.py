@@ -20,10 +20,9 @@ single-process unified engine and checks zero block residue on both
 pools after clean ends, a cancel mid-handoff, and a stale-epoch
 rejection.
 
-The same worker entrypoints back `make drill-disagg` and the
-disaggregated arms of `bench_serving.py`; the native server example
-(examples/deployment/native/server.py) exposes the same split via
-`--role` / `--kv-transfer-*` for real deployments.
+The same worker entrypoints back `make drill-disagg`; the native
+server example (examples/deployment/native/server.py) exposes the same
+split via `--role` / `--kv-transfer-*` for real deployments.
 
 Control plane: each worker listens on a control socket speaking the
 kv_transfer framing (length-prefixed JSON, no array payloads). The

@@ -17,7 +17,7 @@ from dstack_tpu.workloads.config import PRESETS, ModelConfig
 from dstack_tpu.workloads.generate import _forward_cached, generate, init_cache
 from dstack_tpu.workloads.paged_attention import ragged_attention
 from dstack_tpu.workloads.quant import quantize_params
-from dstack_tpu.workloads.serving import ServingEngine, init_decode_state
+from dstack_tpu.workloads.serving import ServingEngine
 from dstack_tpu.workloads.transformer import (
     absorb_query,
     expand_latent,
@@ -411,8 +411,3 @@ def test_engine_features_that_assume_gqa_rows_refuse_the_latent_model(feature):
         kwargs = dict(mesh=make_mesh(jax.devices()[:2], model=2))
     with pytest.raises(ValueError, match=named):
         ServingEngine(c, params, slots=2, max_len=64, kv_block_size=16, **kwargs)
-
-
-def test_the_dense_reference_engine_refuses_the_latent_model():
-    with pytest.raises(ValueError, match="paged"):
-        init_decode_state(CFG, 2, 64)

@@ -1,13 +1,15 @@
 """Multi-tenant LoRA serving: adapter registry lifecycle, mixed-adapter
-batched decode bit-exactness against merged single-tenant references,
-speculative rounds with adapters, and prefix-cache tenant isolation.
+batched decode against merged single-tenant references, speculative
+rounds with adapters, and prefix-cache tenant isolation.
 
-The exactness contract is the one that makes multiplexing an
-optimization rather than a semantics change: for every adapter in a
-mixed batch, temp-0 output must be token-identical to a dedicated
-engine serving `merge_lora(base, adapter)` — including chunked prefill
-at awkward lengths and a full speculative verify round — while
-adapter-free slots stay bit-identical to the plain engine.
+The contract is the one that makes multiplexing an optimization rather
+than a semantics change: for every adapter in a mixed batch, the engine
+computes the logits of a dedicated engine serving
+`merge_lora(base, adapter)` within LOGIT_TOL — through chunked prefill
+at awkward lengths and a full speculative verify round — and emits the
+same temp-0 tokens wherever that engine's top-2 margin is wider than
+the tolerance, while adapter-free slots stay bit-identical to the plain
+engine (the same arithmetic in the same order).
 """
 
 import jax
@@ -16,7 +18,11 @@ import pytest
 
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.generate import generate
-from dstack_tpu.workloads.kv_blocks import BlockAllocator
+from dstack_tpu.workloads.kv_blocks import (
+    BlockAllocator,
+    _layer_loop,
+    init_paged_state,
+)
 from dstack_tpu.workloads.lora import merge_lora
 from dstack_tpu.workloads.lora_serving import (
     AdapterBusyError,
@@ -27,7 +33,12 @@ from dstack_tpu.workloads.lora_serving import (
     save_adapter,
 )
 from dstack_tpu.workloads.serving import ServingEngine, prometheus_metrics
-from dstack_tpu.workloads.transformer import init_params
+from dstack_tpu.workloads.transformer import (
+    forward,
+    init_params,
+    logits_linear,
+    rms_norm,
+)
 
 CFG = PRESETS["tiny"].with_(remat=False)
 RANK = 4
@@ -66,12 +77,19 @@ def _drain(q):
 _REF_CACHE = {}
 
 
+def _merged_params(params, adapter, alpha=16.0):
+    key = (id(adapter), alpha)
+    if key not in _REF_CACHE:
+        _REF_CACHE[key] = merge_lora(params, adapter, rank=RANK, alpha=alpha)
+    return _REF_CACHE[key]
+
+
 def _merged_reference(params, adapter, prompt, n, alpha=16.0):
     key = (id(adapter), tuple(prompt), n, alpha)
     if key not in _REF_CACHE:
-        merged = merge_lora(params, adapter, rank=RANK, alpha=alpha)
         toks = generate(
-            CFG, merged, jnp.asarray([prompt], dtype=jnp.int32),
+            CFG, _merged_params(params, adapter, alpha),
+            jnp.asarray([prompt], dtype=jnp.int32),
             max_new_tokens=n, temperature=0.0,
         )
         _REF_CACHE[key] = [int(t) for t in toks[0]]
@@ -252,13 +270,57 @@ def test_lora_engine_without_adapters_matches_plain(params, engine):
         assert _drain(q) == _reference(params, p, 8), f"len={n}"
 
 
-def test_mixed_adapter_batch_bit_exact_vs_merged_engines(
+# What a logit of the batched engine (the delta added unmerged, in f32)
+# may differ by from the merged engine's (the delta rounded into the
+# bf16 weights): eight steps of a bf16 logit in [2, 4) (2 ** -6 each).
+# Measured on the prompts below: 0.058-0.070; with the adapter dropped,
+# the other tenant's, or alpha halved: 1.45-4.17.
+LOGIT_TOL = 0.125
+
+
+def _merged_logits(params, adapter, seqs):
+    """The merged engine's teacher-forced logits (B, S, V)."""
+    return forward(
+        CFG, _merged_params(params, adapter), jnp.asarray(seqs, jnp.int32)
+    )
+
+
+def _batched_logits(params, bank, seqs, adapter_ix):
+    """Teacher-forced logits (B, S, V) of the batched engine's own
+    arithmetic: its layer loop over its adapter bank, each row gathering
+    its A/B pair by `adapter_ix` as a decode batch does."""
+    b, s = len(seqs), len(seqs[0])
+    bs, mb = 8, 96 // 8
+    state = init_paged_state(CFG, b, 96, bs, b * mb)
+    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    tables = jnp.arange(b * mb, dtype=jnp.int32).reshape(b, mb)
+    aix = jnp.asarray(adapter_ix, jnp.int32)
+    x = jnp.take(params["embed"], jnp.asarray(seqs, jnp.int32), axis=0)
+    x, _, _ = _layer_loop(
+        CFG, params, x, pos, state.k, state.v,
+        jnp.take_along_axis(tables, pos // bs, axis=1), pos % bs, tables,
+        pos + 1, bank=bank, adapter_ix=aix, has_lora=jnp.any(aix >= 0),
+    )
+    h = rms_norm(x, params["final_norm"], CFG.norm_eps)
+    return logits_linear(h, params["lm_head"])
+
+
+def test_mixed_adapter_batch_matches_merged_engines(
     params, adapters, engine
 ):
     """THE acceptance criterion: one batched engine serving three tenants
-    (adapter t1, adapter t2, no adapter) concurrently produces, for each,
-    exactly the tokens a dedicated merged-LoRA engine would — prompt
-    length 27 straddles chunk (16) and block (8) boundaries."""
+    (adapter t1, adapter t2, no adapter) concurrently serves each what a
+    dedicated merged-LoRA engine would — prompt length 27 straddles chunk
+    (16) and block (8) boundaries.
+
+    The two run different arithmetic, so they are held together by
+    logits, not by tokens (ROADMAP D8): t2's second token sits on a
+    top-2 margin of 0.012 in the merged engine, inside one bf16 step of
+    a logit of 2.7, and the batched engine takes the runner-up. Tokens
+    must agree wherever the merged margin is wider than both sides'
+    tolerance; the tenant without an adapter runs the same arithmetic as
+    the plain engine and stays token-exact."""
+    _unload_all(engine)
     engine.load_adapter("t1", adapters["t1"])
     engine.load_adapter("t2", adapters["t2"])
     p1, p2, p0 = _prompt(1, 27), _prompt(2, 27), _prompt(3, 27)
@@ -266,9 +328,45 @@ def test_mixed_adapter_batch_bit_exact_vs_merged_engines(
     q2 = engine.submit(p2, max_new_tokens=8, adapter="t2")
     q0 = engine.submit(p0, max_new_tokens=8)
     out1, out2, out0 = _drain(q1), _drain(q2), _drain(q0)
-    assert out1 == _merged_reference(params, adapters["t1"], p1, 8)
-    assert out2 == _merged_reference(params, adapters["t2"], p2, 8)
     assert out0 == _reference(params, p0, 8)
+
+    # Along its own stream, every token the batched engine emitted is
+    # the merged engine's choice or within both sides' tolerance of it:
+    # where the merged margin is wider than that, the tokens are equal.
+    new = slice(26, None)
+    for name, p, out in (("t1", p1, out1), ("t2", p2, out2)):
+        ml = _merged_logits(params, adapters[name], [p + out[:-1]])[0, new]
+        behind = ml.max(-1) - ml[jnp.arange(8), jnp.asarray(out)]
+        assert float(behind.max()) <= 2 * LOGIT_TOL, (name, out, behind)
+        top2 = jax.lax.top_k(ml, 2)[0]
+        sure = (top2[:, 0] - top2[:, 1]) > 2 * LOGIT_TOL
+        assert bool(sure.any()), (name, top2)
+        assert bool(jnp.all(
+            jnp.where(sure, jnp.asarray(out) == ml.argmax(-1), True)
+        )), (name, out)
+
+    # The engine's own bank and slots through the engine's layer loop,
+    # both tenants in one batch, teacher-forced along the merged streams.
+    registry = engine._require_lora()
+    slots = [registry.slot_of("t1"), registry.slot_of("t2")]
+    seqs = [p + _merged_reference(params, adapters[n], p, 8)[:-1]
+            for n, p in (("t1", p1), ("t2", p2))]
+    want = jnp.stack([
+        _merged_logits(params, adapters[n], [seq])[0, new]
+        for n, seq in zip(("t1", "t2"), seqs)
+    ])
+
+    def gap(bank, adapter_ix):
+        got = _batched_logits(params, bank, seqs, adapter_ix)[:, new]
+        return jnp.abs(got - want).max(axis=(1, 2))
+
+    bank = registry.bank
+    assert float(gap(bank, slots).max()) <= LOGIT_TOL
+    # Tight enough to tell: no adapter, the other tenant's, half alpha.
+    half = {**bank, "scale": bank["scale"] * 0.5}
+    for wrong in (gap(bank, [-1, -1]), gap(bank, slots[::-1]),
+                  gap(half, slots)):
+        assert float(wrong.min()) > 8 * LOGIT_TOL, wrong
 
     # The adapters actually change the generation (B != 0 in
     # demo_adapter): same prompt, different tenants, different tokens.

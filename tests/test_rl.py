@@ -509,15 +509,28 @@ def test_anakin_seeded_learning_smoke():
     assert a["final_weight_epoch"] == 7
 
 
-@pytest.mark.slow
-def test_anakin_socket_and_direct_trajectories_match():
-    """The refresh channel must be invisible to the math."""
-    kwargs = dict(updates=4, batch_size=8, horizon=8, seed=0,
-                  learning_rate=2e-2)
-    direct = run_anakin(tiny_rl_config(), refresh="direct", **kwargs)
-    socketed = run_anakin(tiny_rl_config(), refresh="socket", **kwargs)
-    assert direct["rewards"] == socketed["rewards"]
-    assert direct["losses"] == socketed["losses"]
+_ANAKIN = dict(updates=3, batch_size=8, horizon=8, seed=0,
+               learning_rate=2e-2, publish_every=1)
+
+
+@pytest.fixture(scope="module")
+def anakin_direct():
+    return run_anakin(tiny_rl_config(), refresh="direct", **_ANAKIN)
+
+
+@pytest.mark.parametrize("channel", ["socket", "checkpoint"])
+def test_anakin_trajectory_is_the_same_over_every_refresh_channel(
+    channel, anakin_direct, tmp_path
+):
+    """The refresh channel must be invisible to the math: same seed,
+    same rewards and losses as the in-process swap, update for update
+    (a divergence means torn weights or a stale adoption)."""
+    extra = {"checkpoint_dir": str(tmp_path)} if channel == "checkpoint" else {}
+    out = run_anakin(tiny_rl_config(), refresh=channel, **_ANAKIN, **extra)
+    assert any(out["rewards"]), out["rewards"]  # something was learned from
+    assert out["rewards"] == anakin_direct["rewards"]
+    assert out["losses"] == anakin_direct["losses"]
+    assert out["final_weight_epoch"] == anakin_direct["final_weight_epoch"]
 
 
 @pytest.mark.slow

@@ -77,33 +77,29 @@ def test_midflight_join(params):
 
 
 def test_cache_full_retires_slot(params):
-    """The cache-full guard in decode_step is unreachable through submit()
-    (validation caps budget first) — exercise it directly with a
-    hand-built over-budget state."""
-    import jax.numpy as jnp
-
-    from dstack_tpu.workloads.serving import (
-        init_decode_state,
-        make_decode_step,
-        make_insert,
-        make_prefill,
+    """The cache-full guard in the decode step is unreachable through
+    submit() (validation caps budget first) — exercise it directly with a
+    hand-built over-budget PagedDecodeState."""
+    from dstack_tpu.workloads.kv_blocks import (
+        init_paged_state,
+        make_chunk_prefill,
+        make_paged_decode_step,
     )
 
-    max_len = 12
-    state = init_decode_state(CFG, 1, max_len)
-    prefill = make_prefill(CFG)
-    k_rows, v_rows, first = prefill(
-        params, jnp.asarray([[1, 2, 3]], jnp.int32),
-        jnp.asarray(0.0, jnp.float32), jnp.asarray(1.0, jnp.float32),
-        jax.random.PRNGKey(0),
+    max_len, block = 12, 4
+    # One slot whose table holds blocks 0..2; block 3 belongs to nobody.
+    state = init_paged_state(CFG, 1, max_len, block, 4)
+    i32, f32 = jnp.int32, jnp.float32
+    state, _ = make_chunk_prefill(CFG, 4)(
+        params, state, jnp.asarray(0, i32), jnp.arange(3, dtype=i32),
+        jnp.asarray([[1, 2, 3, 0]], i32), jnp.asarray(3, i32),
+        jnp.asarray(0, i32),
+        jnp.asarray(100, i32),  # budget far beyond the cache
+        jnp.asarray(0.0, f32), jnp.asarray(1.0, f32),
+        jax.random.PRNGKey(0), jnp.asarray(True),
     )
-    state = make_insert()(
-        state, jnp.asarray([0], jnp.int32), k_rows, v_rows,
-        jnp.asarray([3], jnp.int32), first[None],
-        jnp.asarray([100], jnp.int32),  # budget far beyond the cache
-        jnp.asarray([0.0], jnp.float32), jnp.asarray([1.0], jnp.float32),
-    )
-    step = make_decode_step(CFG)
+    assert bool(state.active[0]) and int(state.lengths[0]) == 3
+    step = make_paged_decode_step(CFG)
     rng = jax.random.PRNGKey(0)
     emitted = 0
     for _ in range(max_len + 5):
@@ -112,9 +108,10 @@ def test_cache_full_retires_slot(params):
         if not bool(active[0]):
             break
     assert not bool(active[0]), "slot must retire when the cache fills"
-    # Writes never ran past the cache: the last write landed at row
-    # lengths-1 <= max_len-1.
+    # Writes never ran past the table: the last write landed at row
+    # lengths-1 <= max_len-1, and the block outside it is untouched.
     assert int(state.lengths[0]) <= max_len
+    assert not bool(jnp.any(state.k[:, 3])) and not bool(jnp.any(state.v[:, 3]))
     assert emitted >= 1
 
 
@@ -166,25 +163,6 @@ def test_validation(params):
             engine.submit([1] * 10, max_new_tokens=10)
     finally:
         engine.close()
-
-
-def test_bench_serving_harness_smoke(params, monkeypatch):
-    """bench_serving's measurement harness (timed drain, percentile math)
-    stays runnable — the TPU numbers in BENCH_serving_r04.json are
-    produced by exactly this code path."""
-    import bench_serving as bs
-
-    monkeypatch.setattr(bs, "PROMPT_LEN", 4)
-    monkeypatch.setattr(bs, "NEW_TOKENS", 6)
-    monkeypatch.setattr(bs, "MAX_LEN", 32)
-    engine = ServingEngine(CFG, params, slots=2, max_len=32)
-    try:
-        out = bs.run_scenario(engine, 3)
-    finally:
-        engine.close()
-    assert out["streams"] == 3
-    assert out["agg_tok_s"] > 0
-    assert out["ttft_p95_ms"] >= out["ttft_p50_ms"] >= 0
 
 
 def _slow_decode(engine, delay):
@@ -367,9 +345,9 @@ def test_cancel_pending_request(params):
 def test_nucleus_gate_ignores_retired_slots(params):
     """A completed top_p request must not leave the per-step nucleus
     filter armed for default traffic: retire keeps the old top_p in the
-    DecodeState row, so the gate (serving._any_active_nucleus) may look
-    only at ACTIVE slots."""
-    from dstack_tpu.workloads.serving import _any_active_nucleus
+    PagedDecodeState row, so the gate (sampling._any_active_nucleus) may
+    look only at ACTIVE slots."""
+    from dstack_tpu.workloads.sampling import _any_active_nucleus
 
     engine = ServingEngine(CFG, params, slots=2, max_len=64)
     try:
@@ -442,7 +420,7 @@ def test_greedy_top_p_does_not_arm_nucleus_branch(params):
     """{"temperature": 0, "top_p": 0.9} (a routine OpenAI-SDK combo) must
     not arm the per-step sort/cumsum: a greedy slot discards its sampled
     value, so only sampling slots may gate the filter."""
-    from dstack_tpu.workloads.serving import (
+    from dstack_tpu.workloads.sampling import (
         _any_active_nucleus,
         _any_active_sampling,
     )

@@ -405,6 +405,17 @@ def _get_json(port, path, timeout=10):
         return json.load(r)
 
 
+def _wait_ready(port, timeout=120):
+    """Poll /readyz until it answers 200; its body."""
+    deadline = time.time() + timeout
+    while True:
+        try:
+            return _get_json(port, "/readyz")
+        except urllib.error.HTTPError:
+            assert time.time() < deadline, "never became ready"
+            time.sleep(0.5)
+
+
 def test_readyz_gated_on_warmup_and_first_request_compiles_nothing(tmp_path):
     """The cold-start readiness contract over real HTTP: /healthz green
     at socket-up, /readyz 503 while warmup builds programs, and the
@@ -431,14 +442,7 @@ def test_readyz_gated_on_warmup_and_first_request_compiles_nothing(tmp_path):
             assert e.headers["Retry-After"]
             assert json.load(e)["ready"] is False
 
-        deadline = time.time() + 120
-        while True:
-            try:
-                ready = _get_json(port, "/readyz")
-                break
-            except urllib.error.HTTPError:
-                assert time.time() < deadline, "never became ready"
-                time.sleep(0.5)
+        ready = _wait_ready(port)
         assert ready["ready"] is True
         assert ready["warmup_seconds"] > 0
         assert ready["weights_via"] == "init"
@@ -457,6 +461,34 @@ def test_readyz_gated_on_warmup_and_first_request_compiles_nothing(tmp_path):
         proc.kill()
         proc.wait(timeout=10)
         log.close()
+
+
+def test_warm_boot_retrieves_every_program_from_the_cache(tmp_path, monkeypatch):
+    """Scale-from-zero: a second boot against the first boot's
+    `--compile-cache-dir` builds the same programs and finds every one
+    of them on disk (`compile_cache_hits_total` == `compiles_total` at
+    /readyz), so a warm replica pays tracing and no XLA compile."""
+    # The flag places the cache only when no JAX_COMPILATION_CACHE_DIR is
+    # exported (compile_cache.enable); the suite's would win over it.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    flags = ("--max-new-tokens", "8", "--slots", "2",
+             "--prefill-chunk-tokens", "16",
+             "--compile-cache-dir", str(tmp_path / "cache"))
+    at_ready = []
+    for _ in range(2):
+        proc, log, port = _boot_server(tmp_path, *flags, warmup=True)
+        try:
+            _wait_ready(port, timeout=240)
+            at_ready.append(_get_json(port, "/metrics"))
+        finally:
+            proc.kill()
+            proc.wait(timeout=10)
+            log.close()
+    cold, warm = at_ready
+    assert cold["compiles_total"] > 0
+    assert cold["compile_cache_hits_total"] < cold["compiles_total"]
+    assert warm["compiles_total"] == cold["compiles_total"]
+    assert warm["compile_cache_hits_total"] == warm["compiles_total"]
 
 
 def test_warmup_failure_ends_the_process(tmp_path):

@@ -327,3 +327,20 @@ def test_ttft_histogram_tracks_deliveries(params):
     assert hist["sum"] > 0
     counts = [c for _, c in hist["buckets"]]
     assert counts == sorted(counts) and counts[-1] <= 1
+
+
+@pytest.mark.parametrize("ring,traced", [(0, 0), (16, 5)])
+def test_recorder_traces_every_request_or_none(params, ring, traced):
+    """A recorder-on engine starts and finishes one trace a request, more
+    requests than slots included; with `trace_ring=0` it keeps none (an
+    overhead reading of a recorder that silently no-oped would be no
+    reading)."""
+    engine = ServingEngine(CFG, params, slots=2, max_len=32, trace_ring=ring)
+    try:
+        qs = [engine.submit(_prompt(s, 5), max_new_tokens=4) for s in range(5)]
+        assert all(len(_drain(q)) == 4 for q in qs)
+        trace = engine.stats()["trace"]
+    finally:
+        engine.close()
+    assert trace["started_total"] == traced
+    assert trace["finished_total"] == traced

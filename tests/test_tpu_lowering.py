@@ -120,7 +120,7 @@ def _kernels():
              ((POOL_LAYERS, POOL_BLOCKS, BLOCK, 1, LATENT_W), bf16), ((), i32),
              ((b, MAX_BLOCKS), i32), ((b, s), i32)],
         ))
-    # The trainer's shape (bench.py: S=2048) and one ring step's shard.
+    # The trainer's shape (S=2048) and one ring step's shard.
     q, kv = ((2, 2048, H, HD), bf16), ((2, 2048, KV, HD), bf16)
     out.append(("flash_fwd", _flash_fwd, [q, kv, kv]))
     out.append(("flash_fwd_bwd", _flash_fwd_bwd, [q, kv, kv]))
