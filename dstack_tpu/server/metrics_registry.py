@@ -168,7 +168,8 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_kv_transfer_seconds": ("histogram", ("role",)),
     # The engine loop's own time (utils/flight_recorder.PhaseClock): host
     # wall seconds per phase (wait/admit/grow/dispatch/sync/barrier/
-    # fan_out, and admit's children as "admit/match"), whole cycles, and
+    # fan_out, and admit's children as "admit/match"; "admit/shadow" is
+    # the part of admit spent behind a decode launch), whole cycles, and
     # the cycles of a second or more.
     "dstack_tpu_serving_loop_cycles_total": ("counter", ()),
     "dstack_tpu_serving_loop_phase_seconds_total": ("counter", ("phase",)),

@@ -58,20 +58,26 @@ PHASES = (
 
 _TERMINAL = ("ok", "error", "shed", "cancelled")
 
-# Phases of one engine-loop iteration, in order (the loop's counters,
-# Prometheus labels and `engine/<phase>` span names are generated from
-# these). `wait` is the only phase outside a cycle.
+# Phases of one engine-loop iteration (the loop's counters, Prometheus
+# labels and `engine/<phase>` span names are generated from these). `wait`
+# is the only phase outside a cycle. A cycle with something live runs
+# admit -> grow -> dispatch -> admit -> sync -> barrier -> fan_out: `admit`
+# occurs twice and accumulates, and the phases still sum to the cycle.
 LOOP_PHASES = (
     "wait",      # nothing to do (or a starved pool's 1 ms sleep): device idle, rightly
-    "admit",     # readmit / preempt / prefill chunks / adopt: idle until a chunk launches
+    "admit",     # before grow (readmit / preempt / adopt / leftover chunks): device drained;
+                 # again behind dispatch (the shadow: admission, prefill chunks): hidden
     "grow",      # decode-block growth; a launched prefill chunk runs meanwhile
     "dispatch",  # rng split + decode launch (spec: draft launch -> verify launch)
     "sync",      # device_get: the host is blocked on a busy device
-    "barrier",   # first-token order barrier: device idle
-    "fan_out",   # tokens to consumers, retire slots: device idle
+    "barrier",   # first-token order barrier: device idle, unless a shadow chunk still runs
+    "fan_out",   # tokens to consumers, retire slots: likewise
 )
-# Nested work inside a phase, as "<phase>/<child>".
-LOOP_CHILDREN = ("admit/match", "admit/chunk_args", "admit/chunk_launch")
+# Nested work inside a phase, as "<phase>/<child>". `admit/shadow` is the
+# part of `admit` spent while a decode chunk was in flight (it holds the
+# other three where they ran there): host time the device does not wait for.
+LOOP_CHILDREN = ("admit/match", "admit/chunk_args", "admit/chunk_launch",
+                 "admit/shadow")
 # A cycle this long is a stall (the longest healthy cycle on the benchmark
 # ledger is ~0.27 s): counted, and the last few kept whole.
 SLOW_CYCLE_SECONDS = 1.0
@@ -341,10 +347,11 @@ class PhaseClock:
 
     `begin(phase)` opens a cycle and its first phase, `mark(phase)` closes
     the open phase and opens the next, `end()` closes both — so a cycle's
-    phases sum to the cycle exactly (integer nanoseconds). `mark()` outside
-    a cycle opens a free-standing phase (`wait`) that the next
-    `mark()`/`begin()` closes. `child(name)` times nested work inside the
-    open phase of a cycle.
+    phases sum to the cycle exactly (integer nanoseconds); a phase marked
+    again in the same cycle (`admit`, before and behind the decode launch)
+    accumulates. `mark()` outside a cycle opens a free-standing phase
+    (`wait`) that the next `mark()`/`begin()` closes. `child(name)` times
+    nested work inside the open phase of a cycle; children may nest.
 
     Each interval is two things at once: nanoseconds added to a counter
     that is always on, and — when `annotate` is given (the engine passes

@@ -676,6 +676,16 @@ class ServingEngine:
         # confirmed delivered yet — the loop waits on these after each
         # decode sync so decode tokens never overtake the first token.
         self._pending_activation: List[_PrefillTask] = []
+        # The decode chunk between its dispatch and its fan-out (None while
+        # host and device agree): the request in each slot it decodes, the
+        # slots sure to end in it (a shadow admission may take them), the
+        # tasks whose first token its tokens must not overtake, and the
+        # tasks finalized into an ending slot, which go live after fan-out.
+        self._chunk_live: Optional[List[Optional[_Request]]] = None
+        self._chunk_ending: set = set()
+        self._chunk_activations: List[_PrefillTask] = []
+        self._chunk_heirs: List[_PrefillTask] = []
+        self._shadow_spent = 0  # prompt tokens the last shadow launched
         self._deliver_q: "queue.Queue[Optional[_PrefillTask]]" = queue.Queue()
         # Output queues whose consumer is gone (client disconnect, stop
         # sequence hit): the loop retires their slots at the next chunk
@@ -1610,6 +1620,8 @@ class ServingEngine:
             self._admitting.clear()
             self._tasks.clear()
             self._pending_activation.clear()
+            self._chunk_activations.clear()
+            self._chunk_heirs.clear()
             # Swapped-out slots and the admission peek buffer hold
             # consumers too (their requests are neither pending nor live).
             for sw in self._swapped:
@@ -1750,18 +1762,25 @@ class ServingEngine:
                     task.table.append(b)
         return True
 
-    def _advance_prefills(self) -> bool:
+    def _advance_prefills(self, budget: int) -> Tuple[bool, int]:
         """One admission boundary: pull new requests into prefill tasks
         (up to `max_prefills_per_chunk` concurrent, prefix-cache matched
         on entry), then dispatch prompt chunks round-robin within a
-        TOTAL budget of `prefill_chunk_tokens` valid tokens — so one
-        long prompt and eight short ones cost a decode stream the same
-        bounded stall. Dispatch-only (no host sync): the jitted final
-        chunk samples the first token and flips the slot live on device;
-        the reader thread picks the token up the moment its readback
-        lands. Returns True if anything moved (admission, dispatch, or
-        cancel processing)."""
+        TOTAL `budget` of valid tokens (a cycle's is
+        `prefill_chunk_tokens`) — so one long prompt and eight short
+        ones cost a decode stream the same bounded stall. Dispatch-only
+        (no host sync): the jitted final chunk samples the first token
+        and flips the slot live on device; the reader thread picks the
+        token up the moment its readback lands. Safe while a decode
+        chunk is in flight: it takes free slots, and slots that chunk
+        is sure to end (`_chunk_ending`; the host side of such a slot
+        changes hands after the chunk's fan-out, `_chunk_heirs`), and
+        moves no live slot (`_try_queue_jump` refuses until the
+        boundary). Returns
+        (anything moved: admission, dispatch or cancel processing;
+        prompt tokens launched)."""
         progressed = False
+        offered = budget
         # Admit new requests into the task window (unless a gang-
         # synchronous caller is holding admission to batch a round of
         # submits into one wave; in-flight tasks keep dispatching).
@@ -1793,12 +1812,18 @@ class ServingEngine:
                 # Residency cap: a prefilling task goes live the moment
                 # it finalizes, so it counts against max_resident_slots
                 # now. Swapped-out slots deliberately do NOT count —
-                # their KV lives host-side.
-                live_n = sum(r is not None for r in self._live)
+                # their KV lives host-side. A slot sure to end in the
+                # decode chunk in flight is as good as free: what is
+                # launched for it now runs on the device after that
+                # chunk (empty slots are taken first).
+                ending = self._chunk_ending
+                live_n = sum(r is not None and s not in ending
+                             for s, r in enumerate(self._live))
                 if live_n + len(busy) >= self._max_resident:
                     return []
                 return [s for s in range(self.slots)
-                        if self._live[s] is None and s not in busy]
+                        if self._live[s] is None and s not in busy
+                        ] + sorted(ending - busy)
 
             free = _room()
             if not free:
@@ -1823,7 +1848,6 @@ class ServingEngine:
                     )
             slot = free[0]
             t_pop = time.monotonic()
-            self._slot_t0[slot] = t_pop
             self._queue_wait_s = self._ewma_seed(
                 self._queue_wait_s, t_pop - req.t_submit
             )
@@ -1833,7 +1857,6 @@ class ServingEngine:
             self._tasks.append(_PrefillTask(req, slot, matched, blocks, t_pop))
             progressed = True
         # Dispatch chunks under the shared token budget.
-        budget = self.prefill_chunk_tokens
         for task in list(self._tasks):
             if budget <= 0:
                 break
@@ -1919,14 +1942,14 @@ class ServingEngine:
                         namespace=(task.req.adapter or "").encode(),
                     )
                     if task.req.max_new_tokens > 1 and not handoff:
-                        self._live[task.slot] = task.req
-                        self._admitting.remove(task.req)
-                        self._lengths_host[task.slot] = len(task.req.tokens)
-                        self._slot_tables[task.slot] = task.table
-                        # Fresh request: restart its draft-length
-                        # adaptation from the cautious midpoint.
-                        self._slot_k[task.slot] = self._spec_init_k
-                        self._accept_ewma[task.slot] = None
+                        if task.slot in self._chunk_ending:
+                            # The slot's last request is still the chunk
+                            # in flight's to deliver: wait for its fan-out
+                            # (and offer the slot to nobody else).
+                            self._chunk_ending.remove(task.slot)
+                            self._chunk_heirs.append(task)
+                        else:
+                            self._go_live(task)
                     # One-token requests never go live: their budget is
                     # spent by the first token. The reader thread
                     # completes them (and releases their blocks); they
@@ -1946,7 +1969,34 @@ class ServingEngine:
                 else:
                     self._pending_activation.append(task)
                     self._deliver_q.put(task)
-        return progressed
+        return progressed, offered - budget
+
+    def _go_live(self, task: _PrefillTask) -> None:
+        """Host side of a finalized prefill's activation (caller holds
+        _lock): from here the slot is the request's to decode in."""
+        slot, req = task.slot, task.req
+        self._live[slot] = req
+        self._admitting.remove(req)
+        self._lengths_host[slot] = len(req.tokens)
+        self._slot_tables[slot] = task.table
+        self._slot_t0[slot] = task.t_pop
+        # Fresh request: restart its draft-length adaptation from the
+        # cautious midpoint.
+        self._slot_k[slot] = self._spec_init_k
+        self._accept_ewma[slot] = None
+
+    def _admit_in_shadow(self) -> None:
+        """Admission while the decode chunk just dispatched runs: the
+        host builds and launches the cycle's prefill chunks where it
+        would otherwise only block in `device_get`; they queue on the
+        device behind the chunk, so it is not drained while they are
+        built. Spends the cycle's budget first; the boundary after
+        fan-out gets what is left."""
+        self._clock.mark("admit")
+        with self._clock.child("shadow"):
+            _, self._shadow_spent = self._advance_prefills(
+                self.prefill_chunk_tokens
+            )
 
     def _deliver_loop(self) -> None:
         """Reader thread: blocks on each finalized prefill's first-token
@@ -2008,14 +2058,17 @@ class ServingEngine:
                     pass
             task.delivered.set()
 
-    def _wait_activations(self) -> None:
+    def _wait_activations(self, tasks: List[_PrefillTask]) -> None:
         """Order barrier: before fanning out a decode chunk's tokens,
-        make sure every first token the chunk's prefills produced has
-        been delivered (the reader thread normally finished long ago —
-        its readback completed before the decode chunk did)."""
-        for task in self._pending_activation:
+        make sure the first token of every prefill finalized before the
+        chunk's dispatch (`tasks`) has been delivered (the reader thread
+        normally finished long ago — its readback completed before the
+        decode chunk did). Finals launched in the chunk's shadow belong
+        to the next chunk's barrier: waiting for them here would hold
+        the host until the prefill chunk ends."""
+        for task in tasks:
             task.delivered.wait(timeout=60)
-        self._pending_activation.clear()
+        tasks.clear()
 
     # -- prefill/decode disaggregation ----------------------------------------
 
@@ -2616,6 +2669,11 @@ class ServingEngine:
         the longest-resident one is taken."""
         if self._host_tier is None or not self._qos_weights:
             return False
+        if self._chunk_live is not None:
+            # A decode chunk is in flight: its slots' lengths and tokens
+            # are not the host's yet. The request parks; the boundary
+            # after the fan-out asks again.
+            return False
         w = self._weight(req)
         victim: Optional[int] = None
         vw = 0.0
@@ -2884,11 +2942,34 @@ class ServingEngine:
     # -- loop ----------------------------------------------------------------
 
     def _loop(self) -> None:
+        """The engine thread, software-pipelined by one stage. A cycle
+        with something live runs, in order:
+
+        admit     what must know the last chunk's outcome (readmit,
+                  preempt, adopt: they read `_lengths_host` or move live
+                  slots) and the prefill chunks the last shadow left
+                  budget for;
+        grow      block provisioning for decode chunk N;
+        dispatch  launch N (async);
+        admit     in N's shadow: admit requests into free slots and
+                  into slots whose budget N exhausts, build and launch
+                  the cycle's prefill chunks, which queue on the device
+                  behind N;
+        sync      read N back;
+        barrier   first tokens of the slots in N, then
+        fan_out   N's tokens, over the slots that were live at dispatch;
+                  a prefill finalized into a slot N ended goes live.
+
+        The device sees decode N, chunk(s), decode N+1 with no drain
+        wherever a chunk rides, and the host's fan_out -> grow ->
+        dispatch of N+1 runs while that chunk executes. With nothing
+        live, admission runs alone."""
         clock = self._clock
         while not self._stop:
             try:
                 live = sum(r is not None for r in self._live)
-                if not live and not self._tasks:
+                if (not live and not self._tasks
+                        and not self._pending_activation):
                     with self._lock:
                         queued_handoffs = bool(self._prefilled_pending)
                         waiting = (bool(self._swapped)
@@ -2902,34 +2983,41 @@ class ServingEngine:
                 clock.begin("admit", live=live, tasks=len(self._tasks),
                             pending=self._pending.qsize())
                 if not live:
-                    # Nothing decoding: admission runs alone; the next
-                    # iteration dispatches the first decode chunk for the
-                    # freshly activated slots. Swapped-out requests get
-                    # first claim on the free capacity.
+                    # Nothing decoding: admission runs alone, with a whole
+                    # budget; the next iteration dispatches the first
+                    # decode chunk for the freshly activated slots.
+                    # Swapped-out requests get first claim on the free
+                    # capacity. (A one-token request finalized in the
+                    # last chunk's shadow is waited out here too.)
+                    self._shadow_spent = 0
                     progressed = self._readmit_swapped()
-                    progressed |= self._advance_prefills()
-                    progressed |= self._admit_prefilled()
+                    moved, _ = self._advance_prefills(self.prefill_chunk_tokens)
+                    progressed |= moved | self._admit_prefilled()
                     clock.mark("barrier")
-                    self._wait_activations()
+                    self._wait_activations(self._pending_activation)
                     clock.end()
                     self._admission_barrier_s += clock.cycle_seconds("barrier")
                     if not progressed and (self._tasks or self._swapped):
                         clock.mark("wait")
                         time.sleep(0.001)  # pool starved, nothing live
                     continue
-                # 1) Dispatch PREFILL chunks FIRST: their programs run
-                #    on device ahead of the decode chunk, so the reader
-                #    thread's first-token readbacks land while the decode
-                #    chunk still executes — TTFT never pays the
-                #    decode-chunk residual. Block growth runs AFTER
-                #    admissions: a prefill that finalizes above goes
-                #    live in THIS chunk, and its table so far only
-                #    covers the prompt — growing first would let the
-                #    chunk's writes past the last prompt block hit the
-                #    pad sentinel and silently drop.
+                # 1) The boundary: host and device agree. What the shadow
+                #    left of the cycle's prefill budget is spent here, so
+                #    a request that arrived (or a slot that freed) while
+                #    the last chunk ran waits no longer than it did when
+                #    all admission sat here; under load the shadow spent
+                #    it all and nothing launches. Block growth runs AFTER
+                #    admissions: a prefill that finalizes here goes live
+                #    in THIS chunk, and its table so far only covers the
+                #    prompt — growing first would let the chunk's writes
+                #    past the last prompt block hit the pad sentinel and
+                #    silently drop.
                 self._readmit_swapped()
                 self._process_preempt_requests()
-                self._advance_prefills()
+                left = self.prefill_chunk_tokens - self._shadow_spent
+                self._shadow_spent = 0
+                if left > 0:
+                    self._advance_prefills(left)
                 self._admit_prefilled()
                 clock.mark("grow")
                 spec_now = self._spec and self._spec_cooldown == 0
@@ -2941,8 +3029,10 @@ class ServingEngine:
                 else:
                     self._ensure_decode_blocks()
                     clock.mark("dispatch")
-                    # 2) Dispatch the decode chunk (async), sync on it.
-                    self._count_decode_launch(self._steps_per_sync)
+                    # 2) Dispatch the decode chunk (async), admit in its
+                    #    shadow, sync on it.
+                    self._begin_chunk(self._steps_per_sync,
+                                      self._steps_per_sync)
                     self._rng, sub = jax.random.split(self._rng)
                     if self._lora is not None and self._lora.inflight > 0:
                         self.state, tokens, active = self._step(
@@ -2953,6 +3043,7 @@ class ServingEngine:
                             self.params, self.state, sub
                         )
                     self._attn_dispatch[self._attn_path] += 1
+                    self._admit_in_shadow()
                     clock.mark("sync")
                     toks = jax.device_get(tokens)  # (B, steps_per_sync)
                     still = jax.device_get(active)
@@ -2968,7 +3059,7 @@ class ServingEngine:
                             self._accept_ewma = [None] * self.slots
                             self._spec_low_streak = 0
                 # 3) First-token order barrier, then fan out the chunk.
-                self._wait_activations()
+                self._wait_activations(self._chunk_activations)
                 clock.mark("fan_out")
                 self._fan_out(toks, still)
                 clock.end()
@@ -2993,6 +3084,24 @@ class ServingEngine:
                 )
                 return
         clock.close()
+
+    def _begin_chunk(self, steps: int, sure: int) -> None:
+        """A decode chunk (or speculation round) of `steps` steps is
+        about to launch: freeze who is in it, and count it. What goes
+        live or finalizes from here to its fan-out (the shadow) is the
+        next chunk's. A slot within `sure` tokens of its budget's end
+        (what the launch emits at the least for a live slot) ends in
+        this chunk whatever happens (the stop rules only end it sooner),
+        so the shadow may admit into it."""
+        self._chunk_live = list(self._live)
+        self._chunk_ending = {
+            slot for slot, req in enumerate(self._live)
+            if req is not None and req.max_new_tokens - 1 - (
+                self._lengths_host[slot] - len(req.tokens)) <= sure
+        }
+        self._chunk_activations = self._pending_activation
+        self._pending_activation = []
+        self._count_decode_launch(steps)
 
     def _count_decode_launch(self, steps: int) -> None:
         """One decode chunk (or speculation round) of `steps` steps is
@@ -3024,15 +3133,18 @@ class ServingEngine:
 
     def _observe_chunk_seconds(self) -> None:
         """Launch-to-readback wall time of the chunk whose `sync` phase
-        just closed (the cadence gauges and the TPT series read it)."""
-        self._last_chunk_s = self._clock.cycle_seconds("dispatch", "sync")
+        just closed, the admission in its shadow included (the cadence
+        gauges and the TPT series read it)."""
+        self._last_chunk_s = self._clock.cycle_seconds(
+            "dispatch", "admit/shadow", "sync")
         self._chunk_s = self._ewma(self._chunk_s, self._last_chunk_s)
 
     def _spec_round(self):
         """One speculation boundary: drafter proposes k tokens per
         slot, the target verifies all k+1 positions in one forward, and
         the host adapts per-slot draft lengths from what survived.
-        Entered in the cycle's `grow` phase, left in `barrier`. Returns
+        Entered in the cycle's `grow` phase, left in `barrier`; the
+        shadow admission runs behind the verify launch. Returns
         (toks, still) shaped exactly like a decode chunk (toks (B, k+1)
         with -1 padding) so the fan-out is shared, or (None, None) when
         no slot survived block provisioning."""
@@ -3047,7 +3159,7 @@ class ServingEngine:
         if not any(r is not None for r in self._live):
             return None, None
         t_pf = clock.mark("dispatch")
-        self._count_decode_launch(k_cur + 1)
+        self._begin_chunk(k_cur + 1, 1)  # a round emits one token at least
         self._rng_draft, dsub = jax.random.split(self._rng_draft)
         self._rng, vsub = jax.random.split(self._rng)
         dk, dv, drafts, qlogits = self._spec_draft_fn(k_cur)(
@@ -3068,6 +3180,7 @@ class ServingEngine:
             self.state, emitted, accepted, active = self._spec_verify_fn(
                 k_cur
             )(self.params, self.state, drafts, qlogits, vsub)
+        self._admit_in_shadow()
         clock.mark("sync")
         toks = jax.device_get(emitted)     # (B, k_cur + 1), -1 padded
         still = jax.device_get(active)
@@ -3082,15 +3195,15 @@ class ServingEngine:
         self._spec_rounds += 1
         live_rates = []
         n_round_tokens = 0
-        for slot in range(self.slots):
-            if self._live[slot] is None:
+        for slot, req in enumerate(self._chunk_live):
+            if req is None:
                 continue
             a = int(acc[slot])
             self._spec_proposed += k_cur
             self._spec_accepted += a
             self._spec_rejected += k_cur - a
             n_round_tokens += int((toks[slot] >= 0).sum())
-            tr = self._live[slot].trace
+            tr = req.trace
             if tr is not None:
                 tr.spec_rounds += 1
                 tr.spec_drafted += k_cur
@@ -3129,11 +3242,23 @@ class ServingEngine:
     def _fan_out(self, toks, still) -> None:
         """Deliver one chunk's tokens (decode or speculation round —
         rows are -1-padded past each slot's emissions) and retire slots
-        that finished or were cancelled."""
+        that finished or were cancelled. Walks the slots that were live
+        when the chunk was dispatched: one that went live in its shadow
+        has a padding row and `still` false here, and is the next
+        chunk's to deliver and to retire."""
         with self._lock:
             cancelled = set(self._cancelled)
+        chunk_live, self._chunk_live = self._chunk_live, None
+        self._chunk_ending = set()
+        taken = [t.slot for t in self._tasks + self._chunk_heirs
+                 if chunk_live[t.slot] is not None and still[t.slot]]
+        if taken:
+            raise RuntimeError(
+                f"slots {taken} were admitted into as ending with the"
+                " chunk in flight, and are still decoding"
+            )
         total_emitted = 0
-        for slot, req in enumerate(self._live):
+        for slot, req in enumerate(chunk_live):
             if req is None:
                 continue
             n_emitted = int((toks[slot] >= 0).sum())
@@ -3156,7 +3281,10 @@ class ServingEngine:
                         namespace=(req.adapter or "").encode(),
                     )
                     self._release_adapter(req.out)
-                self.state = self._retire(slot)
+                if still[slot]:
+                    # (One that ended anyway is retired on the device,
+                    # and its slot may already be its heir's there.)
+                    self.state = self._retire(slot)
                 self.recorder.finish(req.trace, "cancelled")
                 req.out.put(None)
                 continue
@@ -3190,6 +3318,11 @@ class ServingEngine:
             for tok in toks[slot]:
                 if tok >= 0:
                     req.out.put(int(tok))
+        if self._chunk_heirs:
+            with self._lock:
+                for task in self._chunk_heirs:
+                    self._go_live(task)
+            self._chunk_heirs.clear()
         self._decode_tokens += total_emitted
         if total_emitted:
             # One TPT sample per chunk: decode wall time amortized over

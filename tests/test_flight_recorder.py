@@ -265,6 +265,129 @@ def test_phase_clock_telescopes(factory):
     assert snap["slow_cycles"] == 0 and snap["slow"] == []
 
 
+def _pipelined_cycle(clock, now):
+    """The loop's order since admission moved behind the decode launch:
+    admit 4 (match 1) + grow 2 + dispatch 3 + admit 12 in the shadow
+    (chunk_args 5, chunk_launch 2 inside it) + sync 20 + barrier 1 +
+    fan_out 8 = 50 ns."""
+    now.t = 100
+    clock.begin("admit", live=2, tasks=1)
+    now.t = 101
+    with clock.child("match", request_id="r0", tokens=7):
+        now.t = 102
+    now.t = 104
+    clock.mark("grow")
+    now.t = 106
+    clock.mark("dispatch")
+    now.t = 109
+    clock.mark("admit")
+    with clock.child("shadow"):
+        now.t = 111
+        with clock.child("chunk_args", request_id="r1", tokens=16):
+            now.t = 116
+        with clock.child("chunk_launch", request_id="r1", tokens=16):
+            now.t = 118
+        now.t = 121
+    clock.mark("sync")
+    now.t = 141
+    clock.mark("barrier")
+    now.t = 142
+    clock.mark("fan_out")
+    now.t = 150
+    return clock.end()
+
+
+@pytest.mark.parametrize("factory", [None, "spans"])
+def test_phase_marked_twice_accumulates_and_the_cycle_still_sums(factory):
+    """`admit` before grow and again behind the decode launch is one
+    counter; the phases sum to the cycle in integer nanoseconds; the
+    shadow is the part of `admit` behind the launch and holds the
+    children that ran there."""
+    now = Clock(0)
+    clock = PhaseClock(annotate=Spans() if factory else None, clock=now)
+    assert _pipelined_cycle(clock, now) == 50
+    assert clock.cycle == {
+        "admit": 4 + 12, "admit/match": 1, "grow": 2, "dispatch": 3,
+        "admit/shadow": 12, "admit/chunk_args": 5, "admit/chunk_launch": 2,
+        "sync": 20, "barrier": 1, "fan_out": 8,
+    }
+    assert sum(v for k, v in clock.cycle.items() if "/" not in k) == 50
+    assert clock.cycle_seconds("dispatch", "admit/shadow", "sync") == 35 / 1e9
+    snap = clock.snapshot()
+    sec = snap["seconds"]
+    assert set(sec) == set(LOOP_PHASES + LOOP_CHILDREN)
+    assert 0 < sec["admit/shadow"] == 12 / 1e9 <= sec["admit"] == 16 / 1e9
+    in_cycle = sum(sec[p] for p in LOOP_PHASES if p != "wait")
+    assert in_cycle == pytest.approx(snap["cycle_seconds"], abs=1e-15)
+    # A second cycle without a shadow adds to admit alone.
+    _one_cycle(clock, now)
+    sec = clock.snapshot()["seconds"]
+    assert sec["admit/shadow"] == 12 / 1e9 and sec["admit"] == 19 / 1e9
+
+
+def test_shadow_span_nests_under_admit_and_over_its_chunks():
+    now = Clock(0)
+    spans = Spans()
+    clock = PhaseClock(annotate=spans, clock=now)
+    _pipelined_cycle(clock, now)
+    names = [(e[0], e[1]) for e in spans.log]
+    lo = names.index(("enter", "engine/dispatch"))
+    assert names[lo:lo + 11] == [
+        ("enter", "engine/dispatch"), ("exit", "engine/dispatch"),
+        ("enter", "engine/admit"), ("enter", "engine/admit/shadow"),
+        ("enter", "engine/admit/chunk_args"),
+        ("exit", "engine/admit/chunk_args"),
+        ("enter", "engine/admit/chunk_launch"),
+        ("exit", "engine/admit/chunk_launch"),
+        ("exit", "engine/admit/shadow"), ("exit", "engine/admit"),
+        ("enter", "engine/sync"),
+    ]
+    assert sum(n == ("enter", "engine/admit") for n in names) == 2
+    assert sum(n == ("enter", "engine/cycle") for n in names) == 1
+
+
+def test_admit_shadow_counter_on_a_live_engine_and_in_prometheus():
+    """loop_admit_shadow_seconds_total sits beside its siblings in a live
+    engine's stats() and under the one labelled Prometheus series, and
+    never exceeds loop_admit_seconds_total."""
+    import time
+
+    import jax
+
+    from dstack_tpu.server.metrics_registry import METRICS
+    from dstack_tpu.workloads.config import PRESETS
+    from dstack_tpu.workloads.serving import ServingEngine, prometheus_metrics
+    from dstack_tpu.workloads.transformer import init_params
+
+    cfg = PRESETS["tiny"].with_(remat=False)
+    engine = ServingEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                           slots=2, max_len=32)
+    try:
+        outs = [engine.submit([5, 7, 11 + i], max_new_tokens=6)
+                for i in range(3)]  # the third waits for a slot
+        for q in outs:
+            while q.get(timeout=60) is not None:
+                pass
+        deadline = time.monotonic() + 30
+        while engine.stats()["active"] and time.monotonic() < deadline:
+            time.sleep(0.02)
+        time.sleep(0.1)  # the last cycle publishes when it ends
+        s = engine.stats()
+    finally:
+        engine.close()
+    assert 0 < s["loop_admit_shadow_seconds_total"] \
+        <= s["loop_admit_seconds_total"]
+    in_cycles = sum(s[f"loop_{p}_seconds_total"]
+                    for p in LOOP_PHASES if p != "wait")
+    assert in_cycles == pytest.approx(s["loop_cycle_seconds_total"], abs=1e-9)
+    text = prometheus_metrics(s)
+    series = "dstack_tpu_serving_loop_phase_seconds_total"
+    assert METRICS[series] == ("counter", ("phase",))
+    assert (f'{series}{{phase="admit/shadow"}} '
+            f'{s["loop_admit_shadow_seconds_total"]}') in text
+    assert f'{series}{{phase="admit"}} {s["loop_admit_seconds_total"]}' in text
+
+
 def test_phase_clock_spans_enter_and_leave_in_order():
     now = Clock(0)
     spans = Spans()

@@ -255,6 +255,11 @@ def test_socket_refresh_roundtrip_and_epoch_fencing():
         epoch, by_name = client.poll(1)
         assert epoch == e2 == 2
         _assert_epoch(by_name, 2.0)         # never a mix of 1.0 and 2.0
+        # The server counts a pull after it has sent it: the client can be
+        # here first.
+        deadline = time.monotonic() + 10
+        while server.pulls_served < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert server.pulls_served >= 2
     finally:
         client.close()

@@ -203,10 +203,14 @@ def test_loop_counters_account_for_the_loop(params):
         assert all(v >= 0 for v in phase.values())
         assert sum(phase.values()) - phase["wait"] == pytest.approx(
             s["loop_cycle_seconds_total"], abs=1e-9)
-        children = [s[f"loop_{c.replace('/', '_')}_seconds_total"]
-                    for c in LOOP_CHILDREN]
-        assert all(c > 0 for c in children)
-        assert sum(children) <= phase["admit"]
+        children = {c: s[f"loop_{c.replace('/', '_')}_seconds_total"]
+                    for c in LOOP_CHILDREN}
+        assert all(v > 0 for v in children.values())
+        # The shadow is the part of admit behind a decode launch and holds
+        # the other children that ran there; those are disjoint.
+        assert children["admit/shadow"] <= phase["admit"]
+        assert sum(children.values()) - children["admit/shadow"] \
+            <= phase["admit"]
         # Every token but each request's first came out of a decode step.
         assert s["decode_tokens_total"] == delivered - len(prompts)
         assert s["decode_steps_total"] % 4 == 0
@@ -341,6 +345,437 @@ def test_engine_spans_land_in_a_profile(params, tmp_path):
     assert {"engine/sync", "engine/fan_out", "engine/barrier",
             "engine/admit/match", "engine/admit/chunk_args"} <= {
                 e[0] for e in spans}
+
+
+class _Gate:
+    def __init__(self, pred, skip):
+        self.pred, self.skip = pred, skip
+        self.reached, self.go = threading.Event(), threading.Event()
+
+    def release(self):
+        self.go.set()
+
+
+class _Timeline:
+    """What the loop thread did, in order: ("mark", phase) at every phase
+    mark, ("decode",) at every decode (or speculative verify) launch,
+    ("chunk", slot, tokens, pos, in_shadow) at every prefill-chunk launch,
+    ("barrier", slots whose first token is waited for) at every
+    first-token barrier, ("fan_out", slots of the chunk, live slots,
+    {slot: tokens queued}) before and ("fanned", live slots, tasks) after
+    every fan-out.
+    `gate(kind)` parks the loop at the next such event (the event is
+    recorded, what it announces has not happened yet) until released."""
+
+    def __init__(self, engine):
+        self.engine = engine
+        self.events = []
+        self._gates = []
+        clock = engine._clock
+        real_mark, real_begin = clock.mark, clock.begin
+
+        def mark(phase):
+            self._note(("mark", phase))
+            return real_mark(phase)
+
+        def begin(phase, **kw):
+            self._note(("mark", phase))
+            return real_begin(phase, **kw)
+
+        clock.mark, clock.begin = mark, begin
+        for name in ("_step_base", "_step"):
+            setattr(engine, name, self._launch(getattr(engine, name)))
+        real_verify = engine._spec_verify_fn
+        engine._spec_verify_fn = lambda k, lora=False: self._launch(
+            real_verify(k, lora=lora))
+        real_chunk = engine._chunk_fn
+
+        def chunk_fn(n_padded, lora=False):
+            fn = real_chunk(n_padded, lora=True) if lora else real_chunk(n_padded)
+
+            def wrapped(*args):
+                # (params, state, slot, table, tokens, n, pos, ...)
+                self._note(("chunk", int(args[2]), int(args[5]),
+                            int(args[6]), engine._chunk_live is not None))
+                return fn(*args)
+
+            return wrapped
+
+        engine._chunk_fn = chunk_fn
+        real_fan_out = engine._fan_out
+
+        def fan_out(toks, still):
+            self._note(("fan_out", self._slots(engine._chunk_live),
+                        self._slots(engine._live),
+                        {slot: req.out.qsize()
+                         for slot, req in enumerate(engine._live)
+                         if req is not None}))
+            real_fan_out(toks, still)
+            self._note(("fanned", self._slots(engine._live),
+                        len(engine._tasks)))
+
+        engine._fan_out = fan_out
+        real_wait = engine._wait_activations
+
+        def wait_activations(tasks):
+            self._note(("barrier", [t.slot for t in tasks]))
+            real_wait(tasks)
+
+        engine._wait_activations = wait_activations
+
+    @staticmethod
+    def _slots(reqs):
+        return [slot for slot, req in enumerate(reqs) if req is not None]
+
+    def _launch(self, fn):
+        def launch(*args):
+            self._note(("decode",))
+            return fn(*args)
+
+        return launch
+
+    def _note(self, event):
+        self.events.append(event)
+        for gate in self._gates:
+            if gate.pred(event):
+                if gate.skip:
+                    gate.skip -= 1
+                    continue
+                self._gates.remove(gate)
+                gate.reached.set()
+                assert gate.go.wait(60), "the test never released the loop"
+                return
+
+    def gate(self, pred, skip=0):
+        if isinstance(pred, str):
+            kind = pred
+
+            def pred(event):
+                return event[0] == kind
+
+        gate = _Gate(pred, skip)
+        self._gates.append(gate)
+        return gate
+
+    def kinds(self, *kinds):
+        return [e for e in self.events if e[0] in kinds]
+
+
+def _slot_of(engine, out):
+    for slot, req in enumerate(engine._live):
+        if req is not None and req.out is out:
+            return slot
+    for task in engine._tasks:
+        if task.req.out is out:
+            return task.slot
+    raise AssertionError("the request holds no slot")
+
+
+def _in_shadow_of_first_decode(engine, tl, submit_b):
+    """Park the loop at its first decode launch (so request A is live and
+    chunk N is about to go), submit B there, let go, and return the
+    events from that launch to the end of chunk N's fan-out."""
+    gate = tl.gate("decode")
+    assert gate.reached.wait(60), "no decode chunk was ever launched"
+    start = len(tl.events) - 1
+    qb = submit_b()
+    done = tl.gate("fanned")
+    gate.release()
+    assert done.reached.wait(60)
+    events = list(tl.events[start:])
+    slot_b = _slot_of(engine, qb)
+    done.release()
+    return qb, slot_b, events
+
+
+def _check_shadow_order(events, slot_b, n_b):
+    """decode N -> admit (B's chunk, in the shadow) -> sync -> barrier,
+    which does not wait for B's first token -> fan_out; returns the
+    (fan_out, fanned) events."""
+    order = [e for e in events if e[0] in ("decode", "chunk", "mark")]
+    assert order == [
+        ("decode",), ("mark", "admit"), ("chunk", slot_b, n_b, 0, True),
+        ("mark", "sync"), ("mark", "barrier"), ("mark", "fan_out"),
+    ], events
+    (barrier,) = [e for e in events if e[0] == "barrier"]
+    (fan,) = [e for e in events if e[0] == "fan_out"]
+    (fanned,) = [e for e in events if e[0] == "fanned"]
+    assert slot_b not in barrier[1]
+    return fan, fanned
+
+
+def _check_shadow_cycle(events, slot_b, n_b):
+    """... and the fan-out walks the slots N was launched with, B's (free
+    when N was launched) not among them."""
+    fan, fanned = _check_shadow_order(events, slot_b, n_b)
+    assert slot_b not in fan[1] and slot_b in fan[2]   # live, not in chunk N
+    assert slot_b in fanned[1]                         # and not retired by it
+
+
+def test_chunk_launches_in_the_decode_chunks_shadow(params):
+    """A request waiting when decode chunk N is dispatched gets its chunk
+    between N's launch and N's `device_get`; the slot that chunk flips
+    live is neither fanned out nor retired by N, and decodes from N+1 on
+    with its first token already delivered."""
+    engine = ServingEngine(CFG, params, slots=2, max_len=64,
+                           steps_per_sync=2)
+    tl = _Timeline(engine)
+    try:
+        a, b = [5, 7, 11], [13, 17, 19, 23]
+        qa = engine.submit(a, max_new_tokens=12)
+        qb, slot_b, events = _in_shadow_of_first_decode(
+            engine, tl, lambda: engine.submit(b, max_new_tokens=5))
+        _check_shadow_cycle(events, slot_b, len(b))
+        assert _drain(qa) == _reference(params, a, 12)
+        assert _drain(qb) == _reference(params, b, 5)
+        # The first fan-out B's slot is part of finds exactly its first
+        # token queued: the barrier of N+1 waited for it, N's did not.
+        first = next(e for e in tl.kinds("fan_out") if slot_b in e[1])
+        assert first[3][slot_b] == 1
+        waited = [e[1] for e in tl.kinds("barrier") if e[1]]
+        assert waited[-1] == [slot_b]  # by the barrier of N+1
+        s = _settled_stats(engine)
+        assert 0 < s["loop_admit_shadow_seconds_total"] \
+            <= s["loop_admit_seconds_total"]
+        assert s["decode_tokens_total"] == 12 + 5 - 2
+    finally:
+        engine.close()
+
+
+def test_arrival_behind_an_unspent_shadow_is_admitted_at_the_boundary(params):
+    """The cycle's budget is spent at two points. A request that arrives
+    after the shadow ran (here: during chunk N's fan-out) with the budget
+    unspent gets its chunk at the boundary, before decode N+1 is
+    dispatched, and decodes in N+1: it waits no longer than it did when
+    all admission sat there."""
+    engine = ServingEngine(CFG, params, slots=2, max_len=64,
+                           steps_per_sync=2)
+    tl = _Timeline(engine)
+    try:
+        a, c = [5, 7, 11], [2, 3, 5, 7, 13]
+        gate = tl.gate("fan_out")
+        qa = engine.submit(a, max_new_tokens=12)
+        assert gate.reached.wait(60)
+        start = len(tl.events)
+        qc = engine.submit(c, max_new_tokens=4)
+        done = tl.gate("fan_out")
+        gate.release()
+        assert done.reached.wait(60)
+        events = list(tl.events[start:])
+        slot_c = _slot_of(engine, qc)
+        done.release()
+        order = [e for e in events if e[0] in ("decode", "chunk", "mark")]
+        assert order[:5] == [
+            ("mark", "admit"), ("chunk", slot_c, len(c), 0, False),
+            ("mark", "grow"), ("mark", "dispatch"), ("decode",),
+        ], events
+        assert slot_c in events[-1][1]  # in the chunk launched right after
+        assert not [e for e in order[5:] if e[0] == "chunk"]  # nothing left
+        assert _drain(qa) == _reference(params, a, 12)
+        assert _drain(qc) == _reference(params, c, 4)
+    finally:
+        engine.close()
+
+
+def test_one_prefill_budget_a_cycle_spent_at_two_points(params):
+    """prefill_chunk_tokens bounds what rides between two decode launches,
+    shadow and boundary together: a 20-token prompt rides 8 + 8 + 4 in
+    three shadows and the boundaries between launch nothing; a request
+    arriving behind the 4 gets the 4 that are left at the boundary and
+    its last 2 in the next shadow."""
+    engine = ServingEngine(CFG, params, slots=3, max_len=64,
+                           steps_per_sync=2, prefill_chunk_tokens=8,
+                           kv_block_size=8, prefix_cache=False)
+    tl = _Timeline(engine)
+    try:
+        a = [5, 7, 11]
+        b = [(i * 29 + 3) % 50 + 1 for i in range(20)]
+        c = [(i * 31 + 7) % 50 + 1 for i in range(6)]
+        first_decode = tl.gate("decode")
+        third_fan_out = tl.gate("fan_out", skip=2)
+        qa = engine.submit(a, max_new_tokens=30)
+        assert first_decode.reached.wait(60)
+        qb = engine.submit(b, max_new_tokens=4)
+        first_decode.release()
+        assert third_fan_out.reached.wait(60)
+        slot_b = _slot_of(engine, qb)
+        qc = engine.submit(c, max_new_tokens=4)
+        third_fan_out.release()
+        assert _drain(qb) == _reference(params, b, 4)
+        assert _drain(qc) == _reference(params, c, 4)
+        assert _drain(qa) == _reference(params, a, 30)
+        chunks = tl.kinds("chunk")[1:]  # but A's own, before anything decoded
+        slot_c = chunks[-1][1]
+        assert chunks == [
+            ("chunk", slot_b, 8, 0, True), ("chunk", slot_b, 8, 8, True),
+            ("chunk", slot_b, 4, 16, True), ("chunk", slot_c, 4, 0, False),
+            ("chunk", slot_c, 2, 4, True),
+        ]
+        spent, windows = 0, []
+        for event in tl.kinds("decode", "chunk")[1:]:
+            if event[0] == "decode":
+                windows.append(spent)
+                spent = 0
+            else:
+                spent += event[2]
+        assert max(windows) <= 8 and windows[1:5] == [8, 8, 8, 2], windows
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("cancel_old", [False, True],
+                         ids=["ends", "cancelled_as_it_ends"])
+def test_shadow_admits_into_a_slot_sure_to_end_in_the_chunk(params, cancel_old):
+    """Every slot taken, one of them within a chunk of its budget's end:
+    the waiting request is admitted into THAT slot in the chunk's shadow
+    (its chunk runs on the device behind the chunk that ends the slot), the
+    old request's last tokens are delivered and its blocks released by the
+    chunk's fan-out, and only then is the slot the new request's on the
+    host. A slot with budget to spare is never taken."""
+    engine = ServingEngine(CFG, params, slots=2, max_len=64,
+                           steps_per_sync=2, prefix_cache=False)
+    tl = _Timeline(engine)
+    try:
+        a, b, c = [5, 7, 11], [13, 17, 19, 23], [2, 3, 5, 7, 13]
+        qa = engine.submit(a, max_new_tokens=5)    # first token + 2 chunks
+        qb = engine.submit(b, max_new_tokens=30)
+        # Park at the launch of the chunk that ends A: the second in which
+        # both decode (B may have gone live a chunk after A).
+        last = tl.gate(lambda e: e[0] == "decode" and any(
+            s in engine._chunk_ending and r.out is qa
+            for s, r in enumerate(engine._live) if r is not None))
+        assert last.reached.wait(60), "A never came within a chunk of its end"
+        slot_a = _slot_of(engine, qa)
+        assert engine._chunk_ending == {slot_a}
+        start = len(tl.events) - 1
+        qc = engine.submit(c, max_new_tokens=6)
+        if cancel_old:
+            engine.cancel(qa)
+        done = tl.gate("fanned")
+        last.release()
+        assert done.reached.wait(60)
+        events = list(tl.events[start:])
+        with engine._lock:
+            assert engine._live[slot_a].out is qc   # the heir took over
+            assert not engine._admitting and not engine._chunk_heirs
+        done.release()
+        # C's chunk, into A's slot, behind the chunk that ends A; the
+        # fan-out finds A there (2 tokens delivered so far + the first, or
+        # fewer read), and leaves C.
+        fan, fanned = _check_shadow_order(events, slot_a, len(c))
+        assert fan[1] == fan[2] == fanned[1] == [0, 1]
+        if cancel_old:
+            assert len(_drain(qa)) < 5               # its last chunk skipped
+        else:
+            assert _drain(qa) == _reference(params, a, 5)
+        assert _drain(qc) == _reference(params, c, 6)
+        assert _drain(qb) == _reference(params, b, 30)
+        s = _settled_stats(engine)
+        assert s["kv_blocks_in_use"] == 0            # A's blocks and C's
+        with engine._lock:
+            assert not engine._cancelled and not engine._inflight
+    finally:
+        engine.close()
+
+
+def test_mixed_batch_greedy_streams_equal_the_reference(params):
+    """Shared prefixes, a request finishing while others prefill, a cancel
+    in mid-prefill, admission in shadows and at boundaries: every greedy
+    stream is `forward`'s greedy continuation, token for token, and
+    nothing leaks."""
+    engine = ServingEngine(CFG, params, slots=4, max_len=96,
+                           steps_per_sync=2, prefill_chunk_tokens=8,
+                           kv_block_size=8)
+    tl = _Timeline(engine)
+    try:
+        head = [(i * 17 + 5) % 90 + 1 for i in range(16)]
+        r1, r2, r5 = head + [3, 1, 4], head + [1, 5, 9, 2], head + [6, 5]
+        r3 = [(i * 29 + 3) % 90 + 1 for i in range(29)]
+        rx = [(i * 13 + 11) % 90 + 1 for i in range(40)]
+        first_decode = tl.gate("decode")
+        q1 = engine.submit(r1, max_new_tokens=14)
+        assert first_decode.reached.wait(60)
+        q2 = engine.submit(r2, max_new_tokens=3)
+        q3 = engine.submit(r3, max_new_tokens=4)
+        qx = engine.submit(rx, max_new_tokens=6)
+        mid_prefill = tl.gate(lambda e: e[0] == "chunk" and e[3] > 0 and any(
+            t.slot == e[1] and t.req.out is qx for t in engine._tasks))
+        first_decode.release()
+        assert mid_prefill.reached.wait(60)
+        engine.cancel(qx)
+        mid_prefill.release()
+        assert _drain(q2) == _reference(params, r2, 3)
+        q5 = engine.submit(r5, max_new_tokens=5)
+        assert _drain(q1) == _reference(params, r1, 14)
+        assert _drain(q3) == _reference(params, r3, 4)
+        assert _drain(q5) == _reference(params, r5, 5)
+        assert _drain(qx) == []  # cancelled before its first token
+        # r2 and r5 skipped the head's two blocks; r2 retired while r3 and
+        # the cancelled prompt were still prefilling.
+        s = _settled_stats(engine)
+        assert s["prefix_tokens_reused_total"] >= 2 * 16
+        shrank = [(before, after) for before, after in zip(
+            tl.kinds("fan_out"), tl.kinds("fanned"))
+            if len(after[1]) < len(before[2]) and after[2] > 0]
+        assert shrank, "no request finished while another was prefilling"
+        assert any(e[4] for e in tl.kinds("chunk"))      # some in a shadow
+        with engine._lock:
+            assert not engine._cancelled and not engine._inflight
+            assert not engine._admitting
+        assert s["kv_blocks_in_use"] == s["kv_blocks_cached"]
+    finally:
+        engine.close()
+
+
+def _spec_engine(params):
+    return ServingEngine(CFG, params, slots=2, max_len=64,
+                         prefill_chunk_tokens=16, kv_block_size=8,
+                         spec_enable=True, spec_max_draft=3,
+                         spec_draft_params=params, spec_draft_config=CFG)
+
+
+def _lora_engine(params):
+    from dstack_tpu.workloads.lora_serving import demo_adapter
+
+    engine = ServingEngine(CFG, params, slots=2, max_len=64,
+                           steps_per_sync=2, prefill_chunk_tokens=16,
+                           kv_block_size=8, lora_max_adapters=1, lora_rank=4,
+                           lora_targets=("wq", "wv"))
+    engine.load_adapter("t1", demo_adapter(
+        CFG, params, jax.random.PRNGKey(11), rank=4, targets=("wq", "wv")))
+    return engine
+
+
+@pytest.mark.parametrize("build, a_kw", [
+    (_spec_engine, {}), (_lora_engine, {"adapter": "t1"}),
+], ids=["speculative", "lora"])
+def test_every_engine_takes_the_same_order(params, build, a_kw):
+    """One loop: behind a speculation round's verify launch, and behind a
+    decode chunk that carries a LoRA bank, admission runs in the shadow
+    like anywhere else; the slot it flips live is the next round's, and
+    the adapter-free stream is the plain engine's."""
+    engine = build(params)
+    tl = _Timeline(engine)
+    try:
+        a, b = [5, 7, 11], [13, 17, 19, 23]
+        qa = engine.submit(a, max_new_tokens=12, **a_kw)
+        qb, slot_b, events = _in_shadow_of_first_decode(
+            engine, tl, lambda: engine.submit(b, max_new_tokens=5))
+        _check_shadow_cycle(events, slot_b, len(b))
+        out_a = _drain(qa)
+        assert len(out_a) == 12
+        if not a_kw:
+            assert out_a == _reference(params, a, 12)
+        assert _drain(qb) == _reference(params, b, 5)
+        s = _settled_stats(engine)
+        # Every round proposed k drafts for each slot IT was launched
+        # with, never for one that went live behind it.
+        assert (s["spec_tokens_accepted_total"]
+                + s["spec_tokens_rejected_total"]
+                == s["spec_tokens_proposed_total"])
+        assert s["decode_tokens_total"] == 12 + 5 - 2
+    finally:
+        engine.close()
 
 
 def test_cancel_during_prefill_overlap_leaves_no_leak(params):
