@@ -137,14 +137,22 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     # launch, and the tokens those steps emitted; the blocks the live
     # slots' contexts filled at those steps against the block table's
     # columns (what paged attention had to read against what it spans).
+    # ... and layer by layer: live blocks x layers, the columns the
+    # layers' attention walks visit, and window layers' blocks behind
+    # their slot's window (held by the one pool, read by no later step).
+    "dstack_tpu_serving_decode_attended_blocks_total": ("counter", ()),
+    "dstack_tpu_serving_decode_layer_blocks_total": ("counter", ()),
     "dstack_tpu_serving_decode_live_blocks_total": ("counter", ()),
     "dstack_tpu_serving_decode_slot_steps_total": ("counter", ()),
     "dstack_tpu_serving_decode_steps_total": ("counter", ()),
     "dstack_tpu_serving_decode_table_columns_total": ("counter", ()),
     "dstack_tpu_serving_decode_tokens_total": ("counter", ()),
+    "dstack_tpu_serving_decode_window_dead_blocks_total": ("counter", ()),
     "dstack_tpu_serving_kv_blocks_cached": ("gauge", ()),
     "dstack_tpu_serving_kv_blocks_in_use": ("gauge", ()),
     "dstack_tpu_serving_kv_cow_copies_total": ("counter", ()),
+    "dstack_tpu_serving_kv_layer_blocks": ("gauge", ()),
+    "dstack_tpu_serving_kv_window_dead_blocks": ("gauge", ()),
     # Prefill/decode disaggregation (workloads/kv_transfer.py): handoff
     # outcome counters on both sides of the seam, payload bytes moved,
     # per-handoff transfer latency, and the depth of the handoff queue

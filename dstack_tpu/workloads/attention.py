@@ -43,10 +43,13 @@ def plain_attention(
     v: jnp.ndarray,
     *,
     causal: bool = True,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Reference-semantics causal attention; XLA fuses this well on one chip.
 
     q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd). Returns (B, Sq, H, hd).
+    With `window` a query sees only the `window` newest keys up to
+    itself (a sliding-attention layer): key j iff 0 <= i - j < window.
     """
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
@@ -58,7 +61,11 @@ def plain_attention(
     if causal:
         sq, sk = q.shape[1], k.shape[1]
         mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq - window)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
+    elif window:
+        raise ValueError("a window is a causal layer's")
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum(
         "bhqk,bkhd->bqhd", probs, v, preferred_element_type=jnp.float32
@@ -254,16 +261,18 @@ def make_attention_fn(
                 out_specs=spec, check_vma=False,
             )
 
-        def single_device(q, k, v):
+        def single_device(q, k, v, window=0):
             from dstack_tpu.workloads.flash_attention import use_flash
 
             # Flash needs equal q/kv lengths, a shape the kernel takes,
+            # a layer whose window (if it has one) covers the sequence
             # and — on a multi-device mesh — rows and KV heads that split
             # evenly over the axes they are sharded on.
             if (
                 q.shape[1] == k.shape[1]
                 and use_flash(
-                    q.shape[1], q.shape[3], dtype_bytes=q.dtype.itemsize
+                    q.shape[1], q.shape[3], dtype_bytes=q.dtype.itemsize,
+                    window=window,
                 )
                 and q.shape[0] % batch_shards == 0
                 and k.shape[2] % head_shards == 0
@@ -271,7 +280,7 @@ def make_attention_fn(
                 traced_paths.add("flash")
                 return flash(q, k, v)
             traced_paths.add("plain")
-            return plain_attention(q, k, v, causal=causal)
+            return plain_attention(q, k, v, causal=causal, window=window)
 
         def _quadratic(seq_len: int, head_dim: int, dtype_bytes: int = 2) -> bool:
             # The remat estimator asks whether this path saves O(S^2) score
@@ -297,7 +306,13 @@ def make_attention_fn(
         check_vma=False,
     )
 
-    def ring(q, k, v):
+    def ring(q, k, v, window=0):
+        if window and window < q.shape[1]:
+            raise ValueError(
+                "ring attention has no window: a sliding-attention layer"
+                f" of window {window} cannot run sequence-parallel over"
+                f" {q.shape[1]} positions"
+            )
         return mapped(q, k, v)
 
     n_seq_shards = mesh.shape[seq_axis]

@@ -5,13 +5,81 @@ static args. Dimensions are kept multiples of 128 so every matmul tiles
 cleanly onto the 128x128 MXU (pallas_guide: Tiling Constraints).
 """
 
+import math
 import os
 from dataclasses import dataclass, replace
-from typing import Dict, Optional, Tuple, Union
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
 
 _DTYPE = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+
+# The kinds a `layer_types` entry may name, as published configs spell them.
+FULL, SLIDING = "full_attention", "sliding_attention"
+
+
+@dataclass(frozen=True)
+class RopeParams:
+    """One kind of layer's rotary embedding, as a published
+    `rope_parameters` group gives it. `rope_type` "default" reads `theta`
+    alone; "yarn" (arXiv:2309.00071, as `transformers` computes it) blends
+    each frequency between itself and itself / `factor` by where it lies
+    between the `beta_fast` and `beta_slow` rotations over
+    `original_max_position_embeddings`, and multiplies cos and sin by
+    `attention_factor` (0.1 ln(factor) + 1 when the group gives none)."""
+
+    theta: float
+    rope_type: str = "default"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
+    truncate: bool = True
+
+    @classmethod
+    def of(cls, group: Dict[str, Any], theta: float) -> "RopeParams":
+        fields = {"theta": theta, **{
+            "theta" if k == "rope_theta" else k: v for k, v in group.items()
+        }}
+        if set(fields) - set(cls.__dataclass_fields__) or fields.get(
+            "rope_type", "default"
+        ) not in ("default", "yarn"):
+            raise ValueError(f"rope_parameters group {group!r}: not understood")
+        rope = cls(**fields)
+        if rope.rope_type == "yarn" and not (
+            rope.factor >= 1 and rope.original_max_position_embeddings > 0
+        ):
+            raise ValueError(
+                "yarn needs factor >= 1 and original_max_position_embeddings"
+            )
+        return rope
+
+    def inv_freq(self, dim: int) -> Tuple[Tuple[float, ...], float]:
+        """(the dim / 2 inverse frequencies, the factor on cos and sin)."""
+        extra = [self.theta ** (-2.0 * i / dim) for i in range(dim // 2)]
+        if self.rope_type == "default":
+            return tuple(extra), 1.0
+
+        def correction(rotations: float) -> float:
+            return (
+                dim * math.log(self.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(self.theta))
+            )
+
+        low, high = correction(self.beta_fast), correction(self.beta_slow)
+        if self.truncate:
+            low, high = math.floor(low), math.ceil(high)
+        low, high = max(low, 0), min(high, dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = [min(max((i - low) / (high - low), 0.0), 1.0)
+                for i in range(dim // 2)]
+        factor = self.attention_factor or 0.1 * math.log(self.factor) + 1.0
+        return tuple(
+            f / self.factor * r + f * (1.0 - r) for f, r in zip(extra, ramp)
+        ), factor
 
 
 @dataclass(frozen=True)
@@ -79,8 +147,54 @@ class ModelConfig:
     # and multiplied by routed_scaling.
     router_score: str = "softmax"
     routed_scaling: float = 1.0
+    # A query head's size where the model publishes one that is not
+    # d_model // n_heads (0 = that quotient): wq is d_model x n_heads *
+    # head_size, wo its transpose's shape. Read through `head_dim`.
+    head_size: int = 0
+    # Layers of more than one kind of attention, as published: one entry a
+    # layer, FULL or SLIDING (a list is taken and kept as a tuple). A
+    # SLIDING layer's query at position i sees keys j with i - j <
+    # `sliding_window`. Longer than n_layers it is cut to its first
+    # entries (a cut in depth keeps the head of the pattern); shorter is an
+    # error. Empty = every layer full.
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    # Rotary embedding by kind of layer: a mapping kind -> published group
+    # (`RopeParams.of`), kept as a tuple of (kind, RopeParams). A kind it
+    # does not name rotates by `rope_theta`.
+    rope_parameters: Any = ()
 
     def __post_init__(self):
+        set_ = lambda k, v: object.__setattr__(self, k, v)
+        set_("sliding_window", int(self.sliding_window or 0))
+        kinds = tuple(self.layer_types or ())
+        if kinds:
+            if len(kinds) < self.n_layers:
+                raise ValueError(
+                    f"layer_types names {len(kinds)} layers, n_layers is"
+                    f" {self.n_layers}"
+                )
+            kinds = kinds[: self.n_layers]
+            if set(kinds) - {FULL, SLIDING}:
+                raise ValueError(
+                    f"layer_types {sorted(set(kinds))}: expected {FULL!r} or"
+                    f" {SLIDING!r}"
+                )
+            if SLIDING in kinds and self.sliding_window < 1:
+                raise ValueError("a sliding_attention layer needs sliding_window")
+            if self.kv_lora_rank or self.n_dense_layers:
+                raise ValueError(
+                    "layer_types beside latent attention or leading dense"
+                    " layers: no program runs that pattern"
+                )
+        set_("layer_types", kinds)
+        ropes = self.rope_parameters or ()
+        if isinstance(ropes, dict):
+            ropes = tuple(
+                (kind, RopeParams.of(group, self.rope_theta))
+                for kind, group in sorted(ropes.items())
+            )
+        set_("rope_parameters", tuple(ropes))
         if self.kv_lora_rank > 0 and not (
             self.q_lora_rank > 0 and self.qk_rope_head_dim > 0
             and self.qk_nope_head_dim > 0 and self.v_head_dim > 0
@@ -112,7 +226,28 @@ class ModelConfig:
         """Values a query head scores over."""
         if self.latent:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def layer_period(self) -> Tuple[str, ...]:
+        """The kinds of one period of the layer pattern: the shortest run
+        of layers whose repetition is the whole stack ((FULL,) for a model
+        of one kind). The layer loops scan over periods, so each kind is a
+        call site of its own with its window and its rotary embedding
+        static (`transformer.scan_layers`)."""
+        kinds = self.layer_types or (FULL,)
+        for p in range(1, len(kinds) + 1):
+            if len(kinds) % p == 0 and all(
+                k == kinds[i % p] for i, k in enumerate(kinds)
+            ):
+                return kinds[:p]
+
+    def window(self, kind: str) -> int:
+        """Keys a query of a `kind` layer sees, itself included; 0 = all."""
+        return self.sliding_window if kind == SLIDING else 0
+
+    def rope(self, kind: str) -> RopeParams:
+        return dict(self.rope_parameters).get(kind) or RopeParams(self.rope_theta)
 
     @property
     def latent_row(self) -> int:
@@ -284,9 +419,16 @@ class ModelConfig:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         per_layer = 2 * self.attn_params()
         if seq_len:
-            # causal QK^T + AV: half of 2 * S * (scored + emitted) a head
+            # causal QK^T + AV: 2 * (scored + emitted) a head and key, over
+            # the S / 2 keys a query sees in the mean; a window layer's
+            # queries see min(position + 1, window), w - w^2 / 2S of them.
             v_dim = self.v_head_dim if self.latent else self.head_dim
-            per_layer += seq_len * self.n_heads * (self.head_dim + v_dim)
+            kinds = self.layer_types or (FULL,)
+            keys = sum(
+                w - w * w / (2.0 * seq_len) if 0 < w < seq_len else seq_len / 2
+                for w in map(self.window, kinds)
+            ) / len(kinds)
+            per_layer += 2 * keys * self.n_heads * (self.head_dim + v_dim)
         if self.n_experts > 0:
             active = self.experts_per_token + self.n_shared_experts
             mlp = 3 * 2 * d * f * active + 2 * d * self.n_experts
@@ -332,6 +474,22 @@ PRESETS: Dict[str, ModelConfig] = {
         d_ff=28672, max_seq_len=8192,
     ),
     # Sparse MoE for tests/dryrun (expert-parallel over the "expert" axis).
+    # Window and full attention layers mixed (three window, one full, the
+    # period twice), a head size that is not d_model // n_heads, YaRN on
+    # the full layers only, softmax-routed experts with no token dropped:
+    # every mechanism of the `mellum` block at a size the CPU runs, with
+    # contexts several windows long.
+    "tiny-window": ModelConfig(
+        vocab_size=512, d_model=96, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_size=32, d_ff=48, max_seq_len=256, remat=False, n_experts=8,
+        experts_per_token=3, capacity_factor=8 / 3, norm_eps=1e-6,
+        layer_types=(SLIDING, SLIDING, SLIDING, FULL) * 2, sliding_window=8,
+        rope_parameters={
+            FULL: {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
+                   "original_max_position_embeddings": 32},
+            SLIDING: {"rope_type": "default", "rope_theta": 10000.0},
+        },
+    ),
     "tiny-moe": ModelConfig(
         vocab_size=512, d_model=128, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=256, max_seq_len=256, remat=False, n_experts=4,

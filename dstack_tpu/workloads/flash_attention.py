@@ -85,8 +85,14 @@ def use_flash(
     num_heads: int = None,
     num_kv_heads: int = None,
     model_shards: int = 1,
+    window: int = 0,
 ) -> bool:
     """Whether the fused Pallas path handles this shape on this backend.
+
+    A sliding-attention layer (`window` > 0) whose sequence is longer than
+    its window is not handled: the flash kernels mask causally and know no
+    window (the paged kernel does, paged_attention.py). A sequence inside
+    the window is plain causal attention.
 
     With `kv_block_size` set, the caller attends over paged KV blocks
     (paged_attention.ragged_attention): the kernel streams one
@@ -109,6 +115,8 @@ def use_flash(
     import os
 
     if os.getenv("DSTACK_TPU_FLASH_ATTENTION", "1") == "0":
+        return False
+    if window and seq_len > window:
         return False
     if not interpret and jax.default_backend() != "tpu":
         return False

@@ -35,6 +35,7 @@ from dstack_tpu.workloads.transformer import (
     project_latent,
     project_qkv,
     rms_norm,
+    scan_layers,
 )
 
 Params = Dict[str, Any]
@@ -64,12 +65,13 @@ def init_cache(
     )
 
 
-def _cached_attention(q, ck, cv, valid_len, scale=None):
+def _cached_attention(q, ck, cv, valid_len, scale=None, window=0):
     """q (B, S, H, hd) against cache k/v (B, max_len, KV, hd); positions at
     or beyond valid_len (zero padding) are masked out. Causality inside the
     new tokens is handled by the caller's masking of valid_len per row.
     `cv` may be narrower than `ck` (latent attention's values are the
-    leading columns of its key rows); `scale` defaults to hd ** -0.5."""
+    leading columns of its key rows); `scale` defaults to hd ** -0.5. A
+    sliding-attention layer's rows see their `window` newest positions."""
     b, s, h, hd = q.shape
     n_rep = h // ck.shape[2]
     k = _repeat_kv(ck, n_rep)
@@ -81,6 +83,8 @@ def _cached_attention(q, ck, cv, valid_len, scale=None):
     kpos = jnp.arange(ck.shape[1], dtype=jnp.int32)
     # Row i of this chunk may attend cache positions <= valid_len[i]-1.
     mask = kpos[None, :] < valid_len[:, None]  # (S, max_len)
+    if window:
+        mask &= kpos[None, :] >= valid_len[:, None] - window
     logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum(
@@ -107,7 +111,7 @@ def _forward_cached(
 
     x = jnp.take(params["embed"], tokens, axis=0)
 
-    def body(x, layer):
+    def block(x, layer, kind):
         p, ck, cv = layer
         if c.latent:
             # The cache row is [c_kv | k_rope | pad]; decode attends in
@@ -123,14 +127,16 @@ def _forward_cached(
             )
             x = x + latent_output(c, o_lat, p)
         else:
-            q, k, v = project_qkv(c, x, p, positions)
+            q, k, v = project_qkv(c, x, p, positions, kind)
             ck = lax.dynamic_update_slice(
                 ck, k.astype(ck.dtype), (0, start, 0, 0)
             )
             cv = lax.dynamic_update_slice(
                 cv, v.astype(cv.dtype), (0, start, 0, 0)
             )
-            attn = _cached_attention(q, ck, cv, valid_len)
+            attn = _cached_attention(
+                q, ck, cv, valid_len, window=c.window(kind)
+            )
             x = x + linear(attn, p["wo"])
         if "router" in p:
             from dstack_tpu.workloads.moe import moe_block
@@ -143,8 +149,8 @@ def _forward_cached(
     new_k, new_v, first = [], [], 0
     for stack in layer_stacks(params):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
-        x, (sk, sv) = lax.scan(
-            body, x,
+        x, (sk, sv) = scan_layers(
+            c, block, x,
             (stack, cache.k[first:first + n], cache.v[first:first + n]),
         )
         new_k.append(sk)

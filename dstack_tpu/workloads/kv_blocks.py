@@ -76,6 +76,7 @@ from dstack_tpu.workloads.transformer import (
     project_latent,
     project_qkv,
     rms_norm,
+    scan_layers,
 )
 
 Params = Dict[str, Any]
@@ -468,20 +469,21 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     in layer l+1 instead of out of bounds.
     """
     if bank is None:
-        project = lambda x, p, lp: project_qkv(c, x, p, positions)
+        project = lambda x, p, lp, kind: project_qkv(c, x, p, positions, kind)
     else:
         from dstack_tpu.workloads.lora_serving import project_qkv_lora
 
         pool = bank["scale"].shape[0] - 1            # the all-zero slot
         safe = jnp.where(adapter_ix >= 0, adapter_ix, pool).astype(jnp.int32)
         scale = jnp.take(bank["scale"], safe)
-        project = lambda x, p, lp: project_qkv_lora(
+        project = lambda x, p, lp, kind: project_qkv_lora(
             c, x, p, positions, lp, safe, scale, has_lora
         )
 
-    def attend(x, p, lp, l, kp, vp):
-        """Write the rows of layer l, attend over the tables -> the
-        block's attention output (before the residual) and the pools."""
+    def attend(x, p, lp, l, kp, vp, kind):
+        """Write the rows of layer l (a `kind` layer), attend over the
+        tables -> the block's attention output (before the residual) and
+        the pools."""
         if c.latent:
             # One row a token for all heads; the absorbed query scores
             # straight against cached rows and no step up-projects them.
@@ -496,18 +498,23 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
                     latent_values=c.kv_lora_rank, scale=c.head_dim ** -0.5,
                 )
             return latent_output(c, o_lat, p), kp, vp
-        q, k, v = project(x, p, lp)
+        q, k, v = project(x, p, lp, kind)
         kp = kp.at[l, blk, off].set(k.astype(kp.dtype), mode="drop")
         vp = vp.at[l, blk, off].set(v.astype(vp.dtype), mode="drop")
-        attn = ragged_attention(
-            q, kp, vp, l, tables, valid_len, impl=attn_impl
-        )
+        # A window layer writes every row like a full one (one pool, one
+        # geometry) and reads only its window's blocks.
+        window = c.window(kind)
+        with jax.named_scope("attn/window" if window else "attn/full"):
+            attn = ragged_attention(
+                q, kp, vp, l, tables, valid_len, impl=attn_impl,
+                window=window,
+            )
         return linear(attn, p["wo"]), kp, vp
 
-    def body(carry, layer):
+    def block(carry, layer, kind):
         x, kp, vp = carry
         p, l, lp = layer
-        out, kp, vp = attend(x, p, lp, l, kp, vp)
+        out, kp, vp = attend(x, p, lp, l, kp, vp, kind)
         x = x + out
         if "router" in p:
             from dstack_tpu.workloads.moe import moe_block
@@ -519,7 +526,10 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
 
     # The pool keeps ONE layer axis over every kind of block: a model's
     # leading dense layers take its first indices, the expert layers the
-    # rest, each kind one scan with the pool still the carry.
+    # rest, each stack one scan with the pool still the carry. Where the
+    # layers are of more than one kind of ATTENTION the scan steps over
+    # periods of the pattern (transformer.scan_layers): block j of period
+    # t is layer t * p + j, its window and rotary embedding static.
     carry, first = (x, k_pool, v_pool), 0
     for stack in layer_stacks(params):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
@@ -528,7 +538,7 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             jnp.arange(first, first + n, dtype=jnp.int32),
             None if bank is None else bank["layers"],
         )
-        carry, _ = lax.scan(body, carry, xs)
+        carry, _ = scan_layers(c, block, carry, xs)
         first += n
     return carry
 

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dstack_tpu.workloads.config import ModelConfig
+from dstack_tpu.workloads.config import FULL, ModelConfig
 from dstack_tpu.workloads.lora import DEFAULT_TARGETS
 from dstack_tpu.workloads.transformer import _rope, linear, rms_norm
 
@@ -138,7 +138,8 @@ def project_qkv_lora(c, x, p, positions, lp, adapter_ix, scale, has_lora):
     q = q.reshape(b, s, c.n_heads, hd)
     k = k.reshape(b, s, c.n_kv_heads, hd)
     v = v.reshape(b, s, c.n_kv_heads, hd)
-    return _rope(q, positions, c.rope_theta), _rope(k, positions, c.rope_theta), v
+    rope = c.rope(FULL)
+    return _rope(q, positions, rope), _rope(k, positions, rope), v
 
 
 class AdapterRegistry:

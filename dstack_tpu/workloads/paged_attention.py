@@ -68,6 +68,15 @@ of the key tile already in VMEM, never a second fetch — and emit
 kernel's cross-group mask has no counterpart. The caller passes `scale`
 (the model's head size, not the row's width, sets it).
 
+Window layers (`window` > 0, a sliding-attention layer of a model that
+mixes kinds): row (b, i) attends only the `window` newest of its positions,
+`valid_len - window <= p < valid_len`. Both paths then START their walk at
+the first column any live row of the tile (the kernel) or of the call (the
+lax path) still reaches, so a window layer's call costs its window's blocks
+and not its context's: a decode row at 16k positions with a window of 1,024
+and 128-token blocks walks 9 columns of 129. The blocks behind stay in the
+pool (one pool, one geometry: ROADMAP R2's second half frees them).
+
 Semantics: query row (b, i) attends cache positions `p < valid_len[b, i]`
 in slot b's context; position p lives at block `tables[b, p // bs]`, row
 `p % bs` of layer `layer` of the pool. Garbage in masked rows (unwritten
@@ -143,6 +152,7 @@ def ragged_attention(
     interpret: bool = False,
     latent_values: int = 0,
     scale: Optional[float] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Ragged paged attention over one layer of the stacked block pool.
 
@@ -156,8 +166,12 @@ def ragged_attention(
     Returns (B, S, H*hd) in q.dtype, matching the dense consumers' shape.
     With `latent_values` the k pool is (L, NB, bs, 1, width), q is
     (B, S, H, width), v_pool is not read and the result is
-    (B, S, H*latent_values). `scale` defaults to hd ** -0.5.
+    (B, S, H*latent_values). `scale` defaults to hd ** -0.5. `window`
+    (static) makes it a sliding-attention layer's call; the latent kernel
+    has none.
     """
+    if window and latent_values:
+        raise ValueError("latent attention has no window layers")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if impl is None:
@@ -175,19 +189,33 @@ def ragged_attention(
                 latent_values=latent_values, scale=scale,
             )
         return _ragged_attention_pallas(
-            q, k_pool, v_pool, layer, tables, valid_len, interpret=interpret
+            q, k_pool, v_pool, layer, tables, valid_len, interpret=interpret,
+            window=window,
         )
     return _ragged_attention_lax(
         q, k_pool, v_pool, layer, tables, valid_len,
-        latent_values=latent_values, scale=scale,
+        latent_values=latent_values, scale=scale, window=window,
     )
+
+
+def first_column(valid_len: jnp.ndarray, window: int, block_size: int,
+                 axis=None) -> jnp.ndarray:
+    """The first table column a walk over rows of `valid_len` has to visit
+    in a layer of `window` > 0: the column of the oldest position the row
+    with the SHORTEST live context still sees. Rows with nothing to see
+    (valid_len 0) do not hold the walk back."""
+    live = jnp.where(valid_len > 0, valid_len, jnp.iinfo(jnp.int32).max)
+    oldest = jnp.maximum(jnp.min(live, axis=axis) - window, 0)
+    # No live row: int32 max - window is past every column; the walk's end
+    # (0 for such rows) bounds it.
+    return oldest // block_size
 
 
 # ------------------------------------------------------------- lax fallback
 
 
 def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
-                          latent_values=0, scale=None):
+                          latent_values=0, scale=None, window=0):
     """Gather-free fallback: two fori_loop passes over table columns.
 
     Per step the only gather is `jnp.take(pool, layer * NB + tables[:, j])`
@@ -224,6 +252,8 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
     # Columns any live row needs: garbage-masked steps past this are pure
     # no-ops, so skip them (short contexts in a MB-wide table).
     n_cols = jnp.minimum((jnp.max(valid_len) + bs - 1) // bs, mb)
+    # A window layer's walk starts at the first column a live row reaches.
+    c0 = jnp.minimum(first_column(valid_len, window, bs), n_cols) if window else 0
 
     def _block(j):
         """Masked logits for table column j plus the clamped rows of
@@ -243,6 +273,8 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
         ok = (pos[None, None, :] < valid_len[:, :, None]) & (
             col < nb
         )[:, None, None]  # (B, S, bs)
+        if window:
+            ok &= pos[None, None, :] >= valid_len[:, :, None] - window
         return jnp.where(ok[:, None], logits, NEG_INF), safe
 
     def stats(j, carry):
@@ -257,7 +289,7 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
 
     m0 = jnp.full((b, h, s, 1), NEG_INF / 2, jnp.float32)
     l0 = jnp.zeros((b, h, s, 1), jnp.float32)
-    m, l = lax.fori_loop(0, n_cols, stats, (m0, l0))
+    m, l = lax.fori_loop(c0, n_cols, stats, (m0, l0))
     l = jnp.maximum(l, 1e-30)
 
     def accum(j, o):
@@ -268,7 +300,7 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
             "bhst,bthd->bhsd", p, vb, preferred_element_type=jnp.float32
         )
 
-    o = lax.fori_loop(0, n_cols, accum, jnp.zeros((b, h, s, vd), jnp.float32))
+    o = lax.fori_loop(c0, n_cols, accum, jnp.zeros((b, h, s, vd), jnp.float32))
     return o.astype(q.dtype).transpose(0, 2, 1, 3).reshape(b, s, h * vd)
 
 
@@ -336,6 +368,7 @@ def _accumulate_block(logits, v, acc_ref, m_ref, l_ref):
 def _paged_kernel(
     t_ref,  # scalar prefetch: (B, MB) block tables in SMEM
     nc_ref,  # scalar prefetch: (B, q tiles) table columns a tile may see
+    c0_ref,  # scalar prefetch: (B, q tiles) the first of them (window layers)
     layer_ref,  # scalar prefetch: (1,) layer index into the stacked pool
     q_ref,  # (TQ, hd) query rows, ordered (s, h)
     vlen_ref,  # (TQ, 1) valid_len of each query row
@@ -355,9 +388,11 @@ def _paged_kernel(
     num_kv_heads: int,
     group: int,
     scale: float,
+    window: int,
 ):
     b = pl.program_id(0)
     n_cols = nc_ref[b, pl.program_id(1)]
+    c0 = c0_ref[b, pl.program_id(1)]
     base = layer_ref[0] * num_pool_blocks
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -378,12 +413,13 @@ def _paged_kernel(
 
     def each_live_block(step, slot, fn):
         """`fn(col, g, copies)` for the blocks of group `step` that lie
-        inside the tile's live columns, in table order; `copies` are the
-        block's two DMAs into place g of buffer slot `slot`. The last
-        group of a row is cut at `n_cols` here: nothing past it is
-        fetched, waited for or attended. A loop, not `group` copies of the
-        body: tracing the copies cost every engine start seconds."""
-        first = step * group
+        inside the tile's live columns `c0 .. n_cols`, in table order;
+        `copies` are the block's two DMAs into place g of buffer slot
+        `slot`. The last group of a row is cut at `n_cols` here: nothing
+        past it is fetched, waited for or attended. A loop, not `group`
+        copies of the body: tracing the copies cost every engine start
+        seconds."""
+        first = c0 + step * group
 
         def block(col, carry):
             g = col - first
@@ -421,9 +457,10 @@ def _paged_kernel(
                 q_ref[...], k_buf[slot, g], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale  # (TQ, bs*KV)
-            ok = same_head & (
-                col * block_size + pos_in_block < vlen_ref[...]
-            )
+            pos = col * block_size + pos_in_block
+            ok = same_head & (pos < vlen_ref[...])
+            if window:
+                ok &= pos >= vlen_ref[...] - window
             ok &= t_ref[b, col] < num_pool_blocks  # a clamped sentinel
             logits = jnp.where(ok, logits, NEG_INF)
             _accumulate_block(logits, v_buf[slot, g], acc_ref, m_ref, l_ref)
@@ -432,7 +469,7 @@ def _paged_kernel(
         return carry
 
     each_live_block(0, 0, fetch)
-    lax.fori_loop(0, pl.cdiv(n_cols, group), attend_group, 0)
+    lax.fori_loop(0, pl.cdiv(n_cols - c0, group), attend_group, 0)
 
     o_ref[...] = (
         acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
@@ -457,9 +494,9 @@ def _q_tile_positions(s: int, h: int) -> int:
     return s
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def _ragged_attention_pallas(
-    q, k_pool, v_pool, layer, tables, valid_len, *, interpret=False
+    q, k_pool, v_pool, layer, tables, valid_len, *, interpret=False, window=0
 ):
     b, s, h, hd = q.shape
     n_layers, nb, bs, kv, _ = k_pool.shape
@@ -471,12 +508,15 @@ def _ragged_attention_pallas(
     valid_len = valid_len.astype(jnp.int32)
     # Table columns each query tile may see: up to its own longest row,
     # none for a tile of dead rows (valid_len 0).
-    n_cols = jnp.clip(
-        (jnp.max(valid_len.reshape(b, s // ts, ts), axis=2) + bs - 1) // bs,
-        0, mb,
+    tiles = valid_len.reshape(b, s // ts, ts)
+    n_cols = jnp.clip((jnp.max(tiles, axis=2) + bs - 1) // bs, 0, mb)
+    # ... from the first column its rows' windows reach (0: a full layer).
+    c0 = (
+        jnp.minimum(first_column(tiles, window, bs, axis=2), n_cols)
+        if window else jnp.zeros_like(n_cols)
     )
 
-    def _q_rows(bi, qi, t, nc, lyr):
+    def _q_rows(bi, qi, t, nc, c0, lyr):
         return (bi, qi, 0)
 
     kernel = functools.partial(
@@ -487,11 +527,12 @@ def _ragged_attention_pallas(
         num_kv_heads=kv,
         group=group,
         scale=hd ** -0.5,
+        window=window,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b, s // ts),
             in_specs=[
                 pl.BlockSpec((None, tq, hd), _q_rows),
@@ -511,10 +552,11 @@ def _ragged_attention_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((b, s * h, hd), q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention",
+        name="ragged_paged_attention" + "_window" * bool(window),  # the trace tells kinds apart by it
     )(
         tables,
         n_cols,
+        c0,
         jnp.asarray(layer, jnp.int32).reshape(1),
         q.reshape(b, s * h, hd),
         jnp.repeat(valid_len, h, axis=1)[:, :, None],

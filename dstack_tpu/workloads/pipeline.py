@@ -55,10 +55,11 @@ def make_pipeline_mesh(devices=None, *, data: int = 1, pipe: int = 2) -> Mesh:
 
 def stage_params(config: ModelConfig, params: Dict, n_stages: int) -> Dict:
     """Reshape the (L, ...) layer stacks into (P, L/P, ...) stage stacks."""
-    if config.n_dense_layers:
+    if config.n_dense_layers or config.layer_types:
         raise ValueError(
-            "pipeline stages cut ONE stack of layers: a model with leading"
-            " dense layers has two"
+            "pipeline stages cut ONE stack of layers of ONE kind: a model"
+            " with leading dense layers has two stacks, one with"
+            " layer_types more than one kind"
         )
     L = config.n_layers
     if L % n_stages:

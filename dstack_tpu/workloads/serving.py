@@ -377,11 +377,12 @@ class ServingEngine:
                         f"{what} heads ({mc.n_heads} q / {mc.n_kv_heads} kv)"
                         f" must divide the mesh's model axis ({ms})"
                     )
-        # Engine features that assume GQA rows or wq/wk/wv weights, or one
-        # stack of layers: a latent-attention (or dense-leading) model
-        # names the feature and stops here; none may run and give other
-        # numbers.
-        if config.latent or config.n_dense_layers:
+        # Engine features that assume GQA rows or wq/wk/wv weights, one
+        # stack of layers, or layers of one kind, and have no test with
+        # anything else: a latent-attention, dense-leading or mixed-layer
+        # (layer_types) model names the feature and stops here; none may
+        # run and give other numbers.
+        if config.latent or config.n_dense_layers or config.layer_types:
             quantized = any(
                 isinstance(leaf, QTensor) for leaf in jax.tree_util.tree_leaves(
                     params, is_leaf=lambda x: isinstance(x, QTensor))
@@ -397,9 +398,10 @@ class ServingEngine:
             ):
                 if asked:
                     raise ValueError(
-                        f"{feature} is not supported for latent-attention or"
-                        " dense-leading models: it assumes per-head K/V rows,"
-                        " wq/wk/wv weights or one stack of layers"
+                        f"{feature} is not supported for latent-attention,"
+                        " dense-leading or mixed-layer (layer_types) models:"
+                        " it assumes per-head K/V rows, wq/wk/wv weights, one"
+                        " stack of layers or layers of one kind"
                     )
         self.state = init_paged_state(
             config, slots, self.max_len, kv_block_size, self._num_blocks
@@ -652,6 +654,12 @@ class ServingEngine:
         # the whole block table (slots x max blocks).
         self._decode_live_blocks = 0
         self._decode_table_columns = 0
+        # Layer by layer: the blocks the live slots' tables hold, those
+        # the attention walks visit, and those of window layers that lie
+        # wholly behind their slot's window (_layer_blocks).
+        self._decode_layer_blocks = 0
+        self._decode_attended_blocks = 0
+        self._decode_window_dead_blocks = 0
         # Chunked-prefill / paging counters (monotonic, for /metrics and
         # the prefix-reuse acceptance measurement: tokens_computed for a
         # cache-hit request drops by the reused prefix).
@@ -1378,6 +1386,7 @@ class ServingEngine:
         compute saved by sharing)."""
         loop = self._clock.snapshot()
         phase_s = loop["seconds"]
+        live_blocks, dead_blocks = self._layer_blocks()
         a = self._alloc
         tier = (
             self._host_tier.stats() if self._host_tier is not None else {}
@@ -1457,6 +1466,17 @@ class ServingEngine:
             # anything to read in.
             "decode_live_blocks_total": self._decode_live_blocks,
             "decode_table_columns_total": self._decode_table_columns,
+            # The same blocks layer by layer (live blocks x layers), the
+            # columns the layers' walks visit (a window layer's start at
+            # the first block its window reaches) and the window layers'
+            # blocks behind it, which no later step reads and the one pool
+            # keeps: summed over launched steps, and as they stand now.
+            "decode_layer_blocks_total": self._decode_layer_blocks,
+            "decode_attended_blocks_total": self._decode_attended_blocks,
+            "decode_window_dead_blocks_total":
+                self._decode_window_dead_blocks,
+            "kv_layer_blocks": live_blocks * self.config.n_layers,
+            "kv_window_dead_blocks": dead_blocks,
             # The older three-way split, derived from the same clock:
             # launch to readback; admission host work (with the barrier
             # of a cycle that had nothing live); waiting for work. They
@@ -1544,6 +1564,13 @@ class ServingEngine:
             "attn_path": self._attn_path + (
                 "_latent" if self.config.latent else ""
             ),
+            # One period of the layer pattern, a letter a layer: w for a
+            # sliding-attention layer of `sliding_window` keys, f for full.
+            "layer_pattern": "".join(
+                "w" if self.config.window(kind) else "f"
+                for kind in self.config.layer_period
+            ),
+            "sliding_window": self.config.sliding_window,
             # Bytes one cached token allocates per layer (padding
             # included), and the expert slots routed vs computed.
             "kv_row_bytes": self.config.kv_row_bytes(),
@@ -3106,17 +3133,36 @@ class ServingEngine:
     def _count_decode_launch(self, steps: int) -> None:
         """One decode chunk (or speculation round) of `steps` steps is
         about to launch over the slots live right now."""
-        bs = self._block_size
-        live_blocks = [
-            -(-self._lengths_host[slot] // bs)
-            for slot, r in enumerate(self._live) if r is not None
-        ]
-        live = len(live_blocks)
+        blocks, dead = self._layer_blocks()
+        layer_blocks = blocks * self.config.n_layers
+        live = sum(r is not None for r in self._live)
         self._decode_steps += steps
         self._decode_slot_steps += steps * live
-        self._decode_live_blocks += steps * sum(live_blocks)
+        self._decode_live_blocks += steps * blocks
         self._decode_table_columns += steps * self.slots * self._max_blocks
+        self._decode_layer_blocks += steps * layer_blocks
+        self._decode_attended_blocks += steps * (layer_blocks - dead)
+        self._decode_window_dead_blocks += steps * dead
         self._count_expert_slots(steps * live, steps * self.slots, 1)
+
+    def _layer_blocks(self) -> Tuple[int, int]:
+        """Over the live slots, from `_lengths_host` (no device sync): the
+        blocks their contexts fill (in every layer), and over the WINDOW
+        layers too the blocks that lie wholly behind their slot's window —
+        no later step reads them, and the one pool keeps them (ROADMAP
+        R2). A slot's walk in a window layer starts at column (length -
+        window) // block, as the kernel's does
+        (paged_attention.first_column), so blocks x layers less the second
+        is what a decode step's attention walks visit."""
+        c, bs = self.config, self._block_size
+        window_layers = sum(c.window(kind) > 0 for kind in c.layer_types)
+        blocks = behind = 0
+        for slot, r in enumerate(self._live):
+            if r is not None:
+                n = self._lengths_host[slot]
+                blocks += -(-n // bs)
+                behind += max(n - c.sliding_window, 0) // bs
+        return blocks, behind * window_layers
 
     def _count_expert_slots(self, tokens: int, rows: int, row_len: int) -> None:
         """A launch routes `tokens` valid tokens through every expert layer
@@ -3398,6 +3444,16 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
          stats.get("decode_live_blocks_total", 0)),
         ("dstack_tpu_serving_decode_table_columns_total", "counter",
          stats.get("decode_table_columns_total", 0)),
+        ("dstack_tpu_serving_decode_layer_blocks_total", "counter",
+         stats.get("decode_layer_blocks_total", 0)),
+        ("dstack_tpu_serving_decode_attended_blocks_total", "counter",
+         stats.get("decode_attended_blocks_total", 0)),
+        ("dstack_tpu_serving_decode_window_dead_blocks_total", "counter",
+         stats.get("decode_window_dead_blocks_total", 0)),
+        ("dstack_tpu_serving_kv_layer_blocks", "gauge",
+         stats.get("kv_layer_blocks", 0)),
+        ("dstack_tpu_serving_kv_window_dead_blocks", "gauge",
+         stats.get("kv_window_dead_blocks", 0)),
         ("dstack_tpu_serving_rejected_total", "counter",
          stats["rejected_total"]),
         # Speculative decoding (all zero when --spec-enable is off;

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dstack_tpu.workloads.attention import plain_attention
-from dstack_tpu.workloads.config import ModelConfig
+from dstack_tpu.workloads.config import FULL, ModelConfig, RopeParams
 
 Params = Dict[str, Any]
 AttentionFn = Callable[..., jnp.ndarray]
@@ -164,30 +164,81 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
     return (xf * rms * weight).astype(x.dtype)
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding. x: (B, S, H, hd); positions: (S,) or (B, S)."""
+def _rope(x: jnp.ndarray, positions: jnp.ndarray, rope: RopeParams) -> jnp.ndarray:
+    """Rotary embedding. x: (B, S, H, hd); positions: (S,) or (B, S);
+    `rope` a layer kind's `RopeParams` (config.rope): a scaled kind's
+    frequencies and its factor on cos and sin are constants of the trace."""
     hd = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    factor = 1.0
+    if rope.rope_type == "default":
+        inv_freq = 1.0 / (
+            rope.theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
+        )
+    else:
+        inv_freq, factor = rope.inv_freq(hd)
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * inv_freq  # (B, S, hd/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
-def project_qkv(c: ModelConfig, x: jnp.ndarray, p: Params, positions: jnp.ndarray):
-    """Pre-norm QKV projection with rope — shared by the training block and
-    the KV-cache decode path (generate.py) so they cannot drift."""
+def project_qkv(c: ModelConfig, x: jnp.ndarray, p: Params,
+                positions: jnp.ndarray, kind: str = FULL):
+    """Pre-norm QKV projection with the rope of a `kind` layer — shared by
+    the training block and the KV-cache decode path (generate.py) so they
+    cannot drift."""
     b, s, _ = x.shape
     hd = c.head_dim
+    rope = c.rope(kind)
     h = rms_norm(x, p["attn_norm"], c.norm_eps)
     q = linear(h, p["wq"]).reshape(b, s, c.n_heads, hd)
     k = linear(h, p["wk"]).reshape(b, s, c.n_kv_heads, hd)
     v = linear(h, p["wv"]).reshape(b, s, c.n_kv_heads, hd)
-    return _rope(q, positions, c.rope_theta), _rope(k, positions, c.rope_theta), v
+    return _rope(q, positions, rope), _rope(k, positions, rope), v
+
+
+def scan_layers(c: ModelConfig, block, carry, xs):
+    """`lax.scan` of `block(carry, x, kind) -> (carry, y)` over the leading
+    (layer) axis of `xs`, one PERIOD of the model's layer pattern a scan
+    step (config.layer_period): the `p` blocks of a period run in order
+    inside the body, each traced with its own kind, block j of step t
+    reading layer t * p + j of `xs`. A model of one kind is the plain scan
+    over its layers. -> (carry, ys) with `ys` on the layer axis.
+
+    A block indexes the stack itself, one layer at a time, as a plain
+    scan's body does: handed a whole period as the step's `xs`, XLA cuts
+    the period's weights out of the stack into a buffer of their own every
+    step (three copies of 1 GB a step at 4 layers of 64 experts: AOT for
+    the v5e, PERF.md section 6, PR 31)."""
+    period = c.layer_period
+    p = len(period)
+    if p == 1:
+        return lax.scan(lambda carry, x: block(carry, x, period[0]), carry, xs)
+    tree_map = jax.tree_util.tree_map
+    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+
+    def body(carry, t):
+        ys = []
+        for j, kind in enumerate(period):
+            x = tree_map(
+                lambda a: lax.dynamic_index_in_dim(a, t * p + j, keepdims=False),
+                xs,
+            )
+            carry, y = block(carry, x, kind)
+            ys.append(y)
+        return carry, tree_map(lambda *a: jnp.stack(a), *ys)
+
+    carry, ys = lax.scan(body, carry, jnp.arange(n // p, dtype=jnp.int32))
+    return carry, tree_map(
+        lambda a: a.reshape((a.shape[0] * p,) + a.shape[2:]), ys
+    )
 
 
 def project_latent(c: ModelConfig, x: jnp.ndarray, p: Params,
@@ -202,16 +253,17 @@ def project_latent(c: ModelConfig, x: jnp.ndarray, p: Params,
     (`absorb_query`, `latent_output`)."""
     b, s, _ = x.shape
     nope, kvr = c.qk_nope_head_dim, c.kv_lora_rank
+    rope = c.rope(FULL)
     with jax.named_scope("mla/project"):
         h = rms_norm(x, p["attn_norm"], c.norm_eps)
         cq = rms_norm(linear(h, p["wq_a"]), p["q_norm"], c.norm_eps)
         q = linear(cq, p["wq_b"]).reshape(b, s, c.n_heads, c.head_dim)
         q = jnp.concatenate(
-            [q[..., :nope], _rope(q[..., nope:], positions, c.rope_theta)],
+            [q[..., :nope], _rope(q[..., nope:], positions, rope)],
             axis=-1,
         )
         kv = linear(h, p["wkv_a"])
-        k_rope = _rope(kv[:, :, None, kvr:], positions, c.rope_theta)[:, :, 0]
+        k_rope = _rope(kv[:, :, None, kvr:], positions, rope)[:, :, 0]
         parts = [rms_norm(kv[..., :kvr], p["kv_norm"], c.norm_eps), k_rope]
         if width is not None and width > c.latent_row:
             parts.append(jnp.zeros((b, s, width - c.latent_row), k_rope.dtype))
@@ -338,18 +390,23 @@ def _block(
     positions: jnp.ndarray,
     attention_fn: AttentionFn,
     mesh=None,
+    kind: str = FULL,
 ):
-    """One decoder block -> (x, router_aux). aux is 0.0 for dense models.
-    A block carries experts iff its weights do (`router`): the leading
-    dense layers of an expert model run the plain MLP."""
+    """One decoder block of a `kind` layer -> (x, router_aux). aux is 0.0
+    for dense models. A block carries experts iff its weights do
+    (`router`): the leading dense layers of an expert model run the plain
+    MLP."""
     b, s, _ = x.shape
     if c.latent:
         q, row = project_latent(c, x, p, positions)
         k, v = expand_latent(c, row, p)
         attn = attention_fn(q, k, v).reshape(b, s, c.n_heads * c.v_head_dim)
     else:
-        q, k, v = project_qkv(c, x, p, positions)
-        attn = attention_fn(q, k, v).reshape(b, s, c.n_heads * c.head_dim)
+        q, k, v = project_qkv(c, x, p, positions, kind)
+        # Only a window layer names its window: an attention_fn written
+        # for full layers alone keeps working on models that have no other.
+        kw = {"window": c.window(kind)} if c.window(kind) else {}
+        attn = attention_fn(q, k, v, **kw).reshape(b, s, c.n_heads * c.head_dim)
     x = x + linear(attn, p["wo"])
     if "router" in p:
         from dstack_tpu.workloads.moe import moe_block
@@ -384,23 +441,29 @@ def forward(
 
     x = jnp.take(params["embed"], tokens, axis=0)
 
-    def body(carry, layer_p):
-        x, aux = carry
-        x, layer_aux = _block(c, x, layer_p, positions, attn, mesh)
-        return (x, aux + layer_aux), None
-
     quadratic = getattr(attn, "memory_is_quadratic", None)
     if quadratic is not None:
         attn_scores = quadratic(tokens.shape[1], c.head_dim, c.dtype_bytes)
     else:
         attn_scores = attn is plain_attention
-    body = apply_remat(
-        body, c, tokens.shape[0] * tokens.shape[1], mesh,
-        seq_len=tokens.shape[1], attn_scores=attn_scores,
-    )
+
+    def block(kind):
+        def body(carry, layer_p):
+            x, aux = carry
+            x, layer_aux = _block(c, x, layer_p, positions, attn, mesh, kind)
+            return (x, aux + layer_aux), None
+
+        return apply_remat(
+            body, c, tokens.shape[0] * tokens.shape[1], mesh,
+            seq_len=tokens.shape[1], attn_scores=attn_scores,
+        )
+
+    blocks = {kind: block(kind) for kind in set(c.layer_period)}
     carry = (x, jnp.float32(0.0))
     for stack in layer_stacks(params):
-        carry, _ = lax.scan(body, carry, stack)
+        carry, _ = scan_layers(
+            c, lambda carry, p, kind: blocks[kind](carry, p), carry, stack
+        )
     x, aux = carry
 
     x = rms_norm(x, params["final_norm"], c.norm_eps)

@@ -11,6 +11,7 @@ lowering. Neither runs a kernel: numerics on the chip are chip_smoke.py's
 phase b.
 """
 
+import hashlib
 import math
 import re
 import types
@@ -23,7 +24,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from dstack_tpu.workloads import flash_attention as fa
 from dstack_tpu.workloads import kv_blocks
 from dstack_tpu.workloads.attention import make_attention_fn
-from dstack_tpu.workloads.config import PRESETS
+from dstack_tpu.workloads.config import FULL, PRESETS, SLIDING
 from dstack_tpu.workloads.paged_attention import (
     _latent_attention_pallas,
     _q_tile_positions,
@@ -48,6 +49,19 @@ LATENT_CFG = CFG.with_(
     router_score="sigmoid", routed_scaling=1.8,
 )
 LATENT_W = LATENT_CFG.kv_row_shapes()[0][1]
+# Window and full attention layers mixed (three window, one full: one period),
+# a head size apart from d_model // n_heads, YaRN on the full layers: the
+# `mellum` block's attention at smol-1b's widths.
+WINDOW_CFG = CFG.with_(
+    d_model=1536, n_heads=12, n_kv_heads=4, head_size=128, n_layers=4,
+    layer_types=(SLIDING, SLIDING, SLIDING, FULL), sliding_window=1024,
+    rope_parameters={FULL: {"rope_type": "yarn", "rope_theta": 500000.0,
+                            "factor": 16.0,
+                            "original_max_position_embeddings": 8192}},
+)
+# The new cell's kernel geometry (Mellum2-12B: 32 query heads over 4 KV heads
+# of 128; 16 slots x 24,576 positions in 128-token blocks).
+CELL_H, CELL_KV, CELL_BLOCK, CELL_MB, CELL_WINDOW = 32, 4, 128, 24576 // 128, 1024
 # ServingEngine / native server defaults.
 SLOTS, CHUNK, BLOCK, MAX_DRAFT = 8, 128, 16, 4
 MAX_BLOCKS = CFG.max_seq_len // BLOCK
@@ -120,6 +134,17 @@ def _kernels():
              ((POOL_LAYERS, POOL_BLOCKS, BLOCK, 1, LATENT_W), bf16), ((), i32),
              ((b, MAX_BLOCKS), i32), ((b, s), i32)],
         ))
+    # A window layer's call beside a full layer's at the window cell's
+    # geometry: 16 decode rows, the 512-token chunk (32 query tiles).
+    cell_pool = ((POOL_LAYERS, 16 * CELL_MB, CELL_BLOCK, CELL_KV, HD), bf16)
+    for kind, b, s in [("decode", 16, 1), ("prefill", 1, 512)]:
+        for name, window in (("full", 0), ("window", CELL_WINDOW)):
+            out.append((
+                f"paged_{name}_{kind}_b{b}_s{s}_mb{CELL_MB}",
+                lambda *a, w=window: _ragged_attention_pallas(*a, window=w),
+                [((b, s, CELL_H, HD), bf16), cell_pool, cell_pool, ((), i32),
+                 ((b, CELL_MB), i32), ((b, s), i32)],
+            ))
     # The trainer's shape (S=2048) and one ring step's shard.
     q, kv = ((2, 2048, H, HD), bf16), ((2, 2048, KV, HD), bf16)
     out.append(("flash_fwd", _flash_fwd, [q, kv, kv]))
@@ -215,6 +240,9 @@ _MOVES = re.compile(
 )
 
 
+_PRODUCES = re.compile(r"= \w+\[([\d,]+)\]\S* ([a-z][\w\-]*)\(")
+
+
 @pytest.fixture
 def no_compile_cache():
     """A program compiled for a described chip is written to the
@@ -274,6 +302,8 @@ def _paged_program(name, cfg, attn_impl):
     ("spec_verify", "gqa"),
     # what the engine runs for a latent model (it refuses speculation)
     ("decode_steps", "latent"), ("chunk_prefill", "latent"),
+    # ... and for a model of mixed layers: the scan steps over periods
+    ("decode_steps", "window"), ("chunk_prefill", "window"),
 ])
 def test_paged_program_moves_no_pool_or_slab(name, kind, v5e, no_compile_cache):
     """The optimized HLO of each paged program holds no copy,
@@ -283,9 +313,16 @@ def test_paged_program_moves_no_pool_or_slab(name, kind, v5e, no_compile_cache):
     than one pool (K and V): updated in place, not held twice. Else for
     the backend at hand on the lax path. The latent model's programs run
     a dense layer and then scan the expert layers, the one latent pool a
-    carry of both loops."""
-    base = CFG if kind == "gqa" else LATENT_CFG
-    cfg = base.with_(n_layers=POOL_LAYERS, remat=False)
+    carry of both loops. A model of mixed layers scans over PERIODS of its
+    pattern, each block reading its own layer of the weight stack: no op
+    may cut a whole period's weights out of the stack either (handed to
+    the scan as the step's `xs` they were: three copies of 1 GB a step at
+    the window cell's sizes, PERF.md section 6, PR 31)."""
+    base = {"gqa": CFG, "latent": LATENT_CFG, "window": WINDOW_CFG}[kind]
+    period = len(WINDOW_CFG.layer_period)
+    layers = 2 * period if kind == "window" else POOL_LAYERS
+    kinds = {"layer_types": WINDOW_CFG.layer_types * 2} if kind == "window" else {}
+    cfg = base.with_(n_layers=layers, remat=False, **kinds)
     fn, args = _paged_program(
         name, cfg, "pallas" if v5e is not None else "lax_ragged"
     )
@@ -296,13 +333,27 @@ def test_paged_program_moves_no_pool_or_slab(name, kind, v5e, no_compile_cache):
         )
     compiled = fn.lower(*args).compile()
 
-    pool = (POOL_LAYERS, POOL_BLOCKS, BLOCK) + cfg.kv_row_shapes()[0]
+    pool = (layers, POOL_BLOCKS, BLOCK) + cfg.kv_row_shapes()[0]
     sizes = {math.prod(pool): "pool", math.prod(pool[1:]): "slab"}
     # Sizes are compared, not shapes (a bitcast keeps the size): no
     # weight may share one, or its re-layout would read as pool traffic.
     weights = {math.prod(a.shape) for a in jax.tree.leaves(args[0])}
     weights |= {math.prod(a.shape[1:]) for a in jax.tree.leaves(args[0])}
     assert not weights & set(sizes)
+    if kind == "window":
+        assert len(cfg.layer_period) == period
+        cuts = {
+            period * math.prod(a.shape[1:]): f"period of {name} weights"
+            for name, a in args[0]["layers"].items() if a.ndim > 2
+        }
+        assert not (weights | set(sizes)) & set(cuts)
+        # Whatever the op (the cut that was there came fused with a bitcast).
+        cut_out = [
+            f"{m.group(2)} makes a {cuts[n]} [{m.group(1)}]"
+            for m in _PRODUCES.finditer(compiled.as_text())
+            if (n := math.prod(int(d) for d in m.group(1).split(","))) in cuts
+        ]
+        assert not cut_out, cut_out
     moved = [
         f"{m.group(2)} of the {sizes[n]} [{m.group(1)}]"
         for m in _MOVES.finditer(compiled.as_text())
@@ -342,3 +393,38 @@ def test_sharded_train_loss_lowers_for_tpu(axes, monkeypatch):
     text = step.trace(params, batch).lower(lowering_platforms=("tpu",)).as_text()
     assert attention_fn.traced_paths == {"flash"}
     assert "tpu_custom_call" in text
+
+
+# ----------------------------------- programs of models of one kind of layer
+#
+# PR 31 taught the layer loop periods, windows and a rotary embedding by
+# layer kind. A model whose layers are all of one kind must trace the
+# program it traced before: the text below is `fn.lower(*shapes).as_text()`
+# on the lax path (it carries no locations), hashed, of the parent of PR 31
+# (commit aab1542). A hash that moves says the PROGRAM of every serving cell
+# moved: where that is meant, say so in CHANGES.md and replace the hash; the
+# Pallas path's text holds the kernel's body, which PR 31 did change (one
+# more scalar-prefetch operand, the walk's first column).
+
+_PARENT_OF_PR31 = {
+    ("tiny", "decode_steps"): "b6faa3ca85dbf3e0",
+    ("tiny", "chunk_prefill"): "0b5ae91847d50fc5",
+    ("tiny", "spec_draft"): "bb75eb0ff223a429",
+    ("tiny", "spec_verify"): "cb768ce55c23e2cb",
+    ("tiny-moe", "decode_steps"): "f359b0d73180d8f9",
+    ("tiny-moe", "chunk_prefill"): "f798bae2ba8ee94a",
+    ("tiny-latent", "decode_steps"): "a3b2c76260615d85",
+    ("tiny-latent", "chunk_prefill"): "ec059df1427815aa",
+}
+
+
+@pytest.mark.parametrize("preset,name", sorted(_PARENT_OF_PR31))
+def test_programs_of_one_kind_of_layer_lower_as_before_pr31(preset, name, monkeypatch):
+    monkeypatch.setitem(globals(), "SLOTS", 4)
+    monkeypatch.setitem(globals(), "CHUNK", 32)
+    cfg = PRESETS[preset]
+    monkeypatch.setitem(globals(), "MAX_BLOCKS", cfg.max_seq_len // BLOCK)
+    monkeypatch.setitem(globals(), "POOL_BLOCKS", 4 * cfg.max_seq_len // BLOCK)
+    fn, args = _paged_program(name, cfg, "lax_ragged")
+    text = fn.lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == _PARENT_OF_PR31[preset, name]
