@@ -106,10 +106,6 @@ class ModelConfig:
     experts_per_token: int = 2
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
-    # MoE dispatch formulation (workloads/moe.py): "einsum" = dense
-    # GShard dispatch/combine matmuls; "gather" = the same slot
-    # permutation via take/scatter (zero dispatch FLOPs). Same math.
-    moe_impl: str = "einsum"
     # Chunked cross-entropy: compute the lm-head + softmax-xent over
     # sequence chunks of this many tokens inside a rematerialized
     # lax.scan, so the full (B, S, V) f32 logits tensor is never

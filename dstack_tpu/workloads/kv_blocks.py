@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dstack_tpu.workloads import moe
 from dstack_tpu.workloads.config import ModelConfig
 from dstack_tpu.workloads.paged_attention import ragged_attention
 from dstack_tpu.workloads.sampling import (
@@ -444,7 +445,8 @@ def _jit_shardings(in_shardings, out_shardings):
 
 def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
                 blk, off, tables, valid_len, *, bank=None, adapter_ix=None,
-                has_lora=None, attn_impl: Optional[str] = None):
+                has_lora=None, attn_impl: Optional[str] = None,
+                partitioned: bool = False):
     """The layer loop of every paged program -> (x, k_pool, v_pool).
 
     x (B, S, d) at `positions` runs through the layers; layer l writes
@@ -456,6 +458,9 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     each request's unmerged delta (lora_serving.project_qkv_lora):
     `adapter_ix` is a scalar for the one-request prefill program, (B,)
     for decode/verify, -1 = none; `has_lora` gates the LoRA math.
+    `partitioned` says GSPMD partitions the program over a mesh (the
+    factory was given `shardings`): the expert bank is then not whole on
+    a device, which `moe.moe_mlp` cannot tell from a traced weight.
 
     The pool is a carry of the scan, never an `xs`/`ys`: a scan cannot
     alias `xs` to `ys`, so the stacked form sliced each layer's K and V
@@ -511,15 +516,15 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             )
         return linear(attn, p["wo"]), kp, vp
 
-    def block(carry, layer, kind):
+    def block(bank_stack, first, carry, layer, kind):
         x, kp, vp = carry
         p, l, lp = layer
         out, kp, vp = attend(x, p, lp, l, kp, vp, kind)
         x = x + out
-        if "router" in p:
-            from dstack_tpu.workloads.moe import moe_block
-
-            x, _ = moe_block(c, x, p)
+        if bank_stack:
+            x, _ = moe.moe_block(c, x, {**p, **bank_stack}, layer=l - first)
+        elif "router" in p:
+            x, _ = moe.moe_block(c, x, p, partitioned=partitioned)
         else:
             x = mlp_block(c, x, p)
         return (x, kp, vp), None
@@ -530,15 +535,28 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     # layers are of more than one kind of ATTENTION the scan steps over
     # periods of the pattern (transformer.scan_layers): block j of period
     # t is layer t * p + j, its window and rotary embedding static.
+    # Where the expert layers take the routed path (moe.plan: a long
+    # chunk), its kernel reads a layer's experts in place in the stacked
+    # bank, as the attention kernel reads a layer of the pool: the bank
+    # stays out of the scan's `xs`, which would cut each layer's out into
+    # a buffer of its own before a kernel may read it.
     carry, first = (x, k_pool, v_pool), 0
     for stack in layer_stacks(params):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+        bank_stack = {}
+        if "router" in stack and moe.takes_routed_path(
+            c, x.shape[0], x.shape[1], stack, partitioned=partitioned
+        ):
+            bank_stack = {w: stack[w] for w in ("we_gate", "we_up", "we_down")}
+            stack = {w: a for w, a in stack.items() if w not in bank_stack}
         xs = (
             stack,
             jnp.arange(first, first + n, dtype=jnp.int32),
             None if bank is None else bank["layers"],
         )
-        carry, _ = scan_layers(c, block, carry, xs)
+        carry, _ = scan_layers(
+            c, functools.partial(block, bank_stack, first), carry, xs
+        )
         first += n
     return carry
 
@@ -602,7 +620,7 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
             c, params, x, positions, state.k, state.v,
             blk[None], off[None], table_row[None], valid_len[None],
             bank=bank, adapter_ix=aix, has_lora=aix >= 0,
-            attn_impl=attn_impl,
+            attn_impl=attn_impl, partitioned=shardings is not None,
         )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         h_last = jnp.take(
@@ -712,7 +730,7 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
             blk[:, None], off[:, None], state.block_tables, valid_len,
             bank=bank, adapter_ix=aix,
             has_lora=jnp.any(state.active & (aix >= 0)),
-            attn_impl=attn_impl,
+            attn_impl=attn_impl, partitioned=shardings is not None,
         )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         logits = logits_linear(h[:, -1], params["lm_head"])
@@ -833,7 +851,7 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None,
                 c, params, x, pos[:, None], dk, dv,
                 blk[:, None], off[:, None], block_tables,
                 jnp.where(active, pos + 1, 0)[:, None],  # dead slots: nothing
-                attn_impl=attn_impl,
+                attn_impl=attn_impl, partitioned=shardings is not None,
             )
             h = rms_norm(x, params["final_norm"], c.norm_eps)
             logits = logits_linear(h[:, -1], params["lm_head"])  # (B, V)
@@ -934,7 +952,7 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
             blk, off, state.block_tables,
             jnp.where(act0[:, None], positions + 1, 0),  # dead slots: nothing
             bank=bank, adapter_ix=aix, has_lora=jnp.any(act0 & (aix >= 0)),
-            attn_impl=attn_impl,
+            attn_impl=attn_impl, partitioned=shardings is not None,
         )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         logits = logits_linear(h, params["lm_head"])         # (B, S, V)

@@ -72,7 +72,7 @@ from dstack_tpu.workloads.kv_transfer import KVHandoff, StaleEpochError
 from dstack_tpu.workloads.paged_attention import (
     dispatch_path as attn_dispatch_path,
 )
-from dstack_tpu.workloads.moe import expert_capacity
+from dstack_tpu.workloads import moe
 from dstack_tpu.workloads.quant import QTensor, quantize_params
 from dstack_tpu.workloads.sharding import (
     make_serving_shardings,
@@ -453,6 +453,7 @@ class ServingEngine:
         # each launch (_count_expert_slots).
         self._moe_routed_slots = 0
         self._moe_computed_slots = 0
+        self._moe_routed_launches = 0
         self._step = make_paged_decode_step(
             config, steps=steps_per_sync, shardings=self._shardings,
             lora=self._lora is not None, attn_impl=self._attn_path,
@@ -1576,6 +1577,7 @@ class ServingEngine:
             "kv_row_bytes": self.config.kv_row_bytes(),
             "moe_routed_slots_total": self._moe_routed_slots,
             "moe_computed_slots_total": self._moe_computed_slots,
+            "moe_routed_launches_total": self._moe_routed_launches,
             "attn_dispatch_pallas_total": self._attn_dispatch["pallas"],
             "attn_dispatch_lax_ragged_total":
                 self._attn_dispatch["lax_ragged"],
@@ -3164,18 +3166,29 @@ class ServingEngine:
                 behind += max(n - c.sliding_window, 0) // bs
         return blocks, behind * window_layers
 
+    @property
+    def _whole_bank(self) -> bool:
+        """What `moe.moe_mlp` sees in this engine's programs: the expert
+        bank whole on the device as plain arrays (no mesh partitions the
+        programs, no int8 bank)."""
+        bank = self.params["layers"].get("we_gate")
+        return self.mesh is None and not isinstance(bank, QTensor)
+
     def _count_expert_slots(self, tokens: int, rows: int, row_len: int) -> None:
         """A launch routes `tokens` valid tokens through every expert layer
-        and computes a slot for each of experts x rows x capacity(row_len)
-        (moe.expert_capacity), padding and dead rows included."""
+        and multiplies the slots `moe.plan` says its program does for
+        rows x row_len tokens, padding and dead rows included: the
+        capacity dispatch's experts x rows x capacity(row_len), or the
+        routed path's rows (the same function `moe.moe_mlp` dispatched on
+        when the program was traced)."""
         c = self.config
         if c.n_experts == 0:
             return
         layers = c.n_layers - c.n_dense_layers
+        routed, slots, _ = moe.plan(c, rows, row_len, self._whole_bank)
         self._moe_routed_slots += layers * tokens * c.experts_per_token
-        self._moe_computed_slots += (
-            layers * c.n_experts * rows * expert_capacity(c, row_len)
-        )
+        self._moe_computed_slots += layers * slots
+        self._moe_routed_launches += routed
 
     def _observe_chunk_seconds(self) -> None:
         """Launch-to-readback wall time of the chunk whose `sync` phase

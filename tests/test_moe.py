@@ -3,12 +3,15 @@
 Runs on the 8-device virtual CPU mesh from tests/conftest.py.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dstack_tpu.workloads.config import PRESETS
+from dstack_tpu.workloads import moe
 from dstack_tpu.workloads.moe import expert_capacity, moe_mlp, route
 from dstack_tpu.workloads.sharding import make_mesh
 from dstack_tpu.workloads.train import (
@@ -163,54 +166,64 @@ class TestMoEGenerate:
             seq = jnp.concatenate([seq, new[:, t : t + 1]], axis=1)
 
 
-class TestGatherDispatch:
-    """config.moe_impl="gather": the take/scatter formulation must equal
-    the einsum path exactly — same slot permutation, same drops, same
-    gate weighting (tests pin both clean and overflow regimes)."""
+def _rel_l2(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(a), 1e-9))
+
+
+class TestRoutedPath:
+    """The routed path (moe._moe_mlp_routed: expert-sorted rows through
+    grouped matmuls) must equal the capacity path's einsums — same slot
+    permutation, same drops, same gate weighting (tests pin both clean
+    and overflow regimes). The grouped matmul runs interpreted here."""
 
     def _pair(self, c, key, shape):
         p = _rand_params(key, c)
         h = jax.random.normal(
             jax.random.fold_in(key, 7), shape, dtype=jnp.float32
         ).astype(jnp.bfloat16)
-        out_e, aux_e = moe_mlp(c, h, p)
-        out_g, aux_g = moe_mlp(c.with_(moe_impl="gather"), h, p)
+        out_e, aux_e = moe._moe_mlp_capacity(c, h, p)
+        out_g, aux_g = moe._moe_mlp_routed(c, h, p)
         return out_e, aux_e, out_g, aux_g
 
     def test_matches_einsum_no_drops(self):
         c = CFG.with_(capacity_factor=8.0)
         out_e, aux_e, out_g, aux_g = self._pair(
-            c, jax.random.PRNGKey(11), (2, 16, c.d_model))
+            c, jax.random.PRNGKey(11), (2, 32, c.d_model))
         np.testing.assert_allclose(
             np.asarray(out_e, np.float32), np.asarray(out_g, np.float32),
-            rtol=2e-2, atol=2e-3,  # einsum path rounds the gate to bf16
+            # Each path rounds to bf16 at its own places (the einsum path
+            # the gate, the routed path the expert's gate product): a bf16
+            # ulp of the k terms a token sums (|term| < 4), both as near
+            # an f32 evaluation (0.0099 and 0.0092 from it).
+            rtol=2e-2, atol=2e-2,
         )
         assert float(aux_e) == float(aux_g)
 
     def test_matches_einsum_with_overflow_drops(self):
         c = CFG.with_(capacity_factor=0.25)
         out_e, _, out_g, _ = self._pair(
-            c, jax.random.PRNGKey(12), (1, 32, c.d_model))
+            c, jax.random.PRNGKey(12), (1, 64, c.d_model))
         np.testing.assert_allclose(
             np.asarray(out_e, np.float32), np.asarray(out_g, np.float32),
-            rtol=2e-2, atol=2e-3,
+            rtol=2e-2, atol=2e-2,
         )
 
     def test_gradients_match_einsum(self):
         c = CFG.with_(capacity_factor=1.0)
         p = _rand_params(jax.random.PRNGKey(13), c)
         h = jax.random.normal(
-            jax.random.PRNGKey(14), (2, 16, c.d_model), jnp.float32
+            jax.random.PRNGKey(14), (2, 32, c.d_model), jnp.float32
         ).astype(jnp.bfloat16)
 
-        def loss(params, cfg):
-            out, aux = moe_mlp(cfg, h, params)
+        def loss(params, mlp):
+            out, aux = mlp(c, h, params)
             return jnp.sum(out.astype(jnp.float32) ** 2) + aux
 
-        g_e = jax.grad(loss)(p, c)
-        g_g = jax.grad(loss)(p, c.with_(moe_impl="gather"))
+        g_e = jax.grad(loss)(p, moe._moe_mlp_capacity)
+        g_g = jax.grad(loss)(p, moe._moe_mlp_routed)
         # The einsum path rounds the gate to bf16 inside combine (the
-        # gather path keeps it f32), so the two formulations are slightly
+        # routed path keeps it f32), so the two formulations are slightly
         # different FUNCTIONS at bf16 — gradients agree to bf16 rounding
         # accumulated over the token sum, tightest for the expert banks
         # and loosest for the router (whose grad flows entirely through
@@ -220,20 +233,223 @@ class TestGatherDispatch:
                 np.asarray(g_e[k], np.float32), np.asarray(g_g[k], np.float32),
                 rtol=1e-1, atol=1e-1,
             )
-        re_ = np.asarray(g_e["router"], np.float32)
-        rg = np.asarray(g_g["router"], np.float32)
-        rel_l2 = np.linalg.norm(re_ - rg) / max(np.linalg.norm(re_), 1e-9)
-        assert rel_l2 < 0.05, rel_l2
+        assert _rel_l2(g_e["router"], g_g["router"]) < 0.05
 
-    def test_trains_on_mesh_with_expert_parallelism(self):
-        c = PRESETS["tiny-moe"].with_(moe_impl="gather")
-        mesh = make_mesh(data=2, fsdp=1, seq=1, model=2, expert=2)
+    @pytest.mark.parametrize("axes,routed", [
+        ({"data": 2, "fsdp": 2}, True),
+        # An expert axis makes the dispatch einsum the token all-to-all:
+        # the rule keeps the capacity path there.
+        ({"data": 2, "fsdp": 1, "model": 2, "expert": 2}, False),
+    ], ids=["fsdp", "expert"])
+    def test_trains_on_mesh_with_expert_parallelism(self, axes, routed, monkeypatch):
+        # 8 experts at the no-drop factor: a 512-token row is the least
+        # the rule hands to the routed path.
+        c = PRESETS["tiny-moe"].with_(
+            n_experts=8, capacity_factor=4.0, max_seq_len=512
+        )
+        taken = []
+        monkeypatch.setattr(
+            moe, "_moe_mlp_routed",
+            lambda *a, _f=moe._moe_mlp_routed: taken.append(1) or _f(*a),
+        )
+        mesh = make_mesh(jax.devices()[:math.prod(axes.values())], **axes)
         state = init_train_state(c, jax.random.PRNGKey(0), mesh=mesh,
                                  learning_rate=1e-2)
         step = make_train_step(c, mesh, learning_rate=1e-2)
-        batch = synthetic_batch(c, batch_size=4, seq_len=32, mesh=mesh)
+        batch = synthetic_batch(c, batch_size=4, seq_len=512, mesh=mesh)
         losses = []
         for _ in range(3):
             state, m = step(state, batch)
             losses.append(float(m["loss"]))
         assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+        assert bool(taken) == routed
+
+
+# The three routers the benchmark's cells use, at test widths.
+_ROUTERS = {
+    "softmax-top2-of-8": dict(n_experts=8, experts_per_token=2),
+    "sigmoid-top4-bias-scaling-shared": dict(
+        n_experts=16, experts_per_token=4, router_score="sigmoid",
+        routed_scaling=1.8, n_shared_experts=1,
+    ),
+    "softmax-top8-of-64": dict(n_experts=64, experts_per_token=8),
+}
+
+
+def _block_params(c, key):
+    """One expert layer's MLP weights (router, bank, shared expert, norm),
+    the router's selection bias drawn at random where there is one."""
+    layer = {
+        k: v[0] for k, v in init_params(c, key)["layers"].items()
+        if k.startswith(("router", "we_", "ws_", "mlp_norm"))
+    }
+    if "router_bias" in layer:
+        layer["router_bias"] = 0.1 * jax.random.normal(
+            jax.random.fold_in(key, 5), layer["router_bias"].shape)
+    return layer
+
+
+@pytest.mark.parametrize("drops", [False, True], ids=["no-drops", "drops"])
+@pytest.mark.parametrize("router", sorted(_ROUTERS))
+class TestRoutedAgainstCapacity:
+    """moe_block through both formulations, over the cells' routers: the
+    forward within bf16 rounding, jax.grad of a loss through both."""
+
+    def _case(self, router, drops):
+        spec = _ROUTERS[router]
+        c = CFG.with_(
+            capacity_factor=(0.5 if drops else
+                             spec["n_experts"] / spec["experts_per_token"]),
+            **spec,
+        )
+        key = jax.random.PRNGKey(len(router) + drops)
+        x = jax.random.normal(
+            jax.random.fold_in(key, 1), (2, 64, c.d_model), jnp.float32
+        ).astype(jnp.bfloat16)
+        if drops:  # some routed row must lie beyond the capacity
+            _, _, slot, _, _ = moe.route_assignments(
+                c, x, _block_params(c, key)["router"])
+            assert int(slot.max()) >= expert_capacity(c, 64)
+        return c, _block_params(c, key), x
+
+    @staticmethod
+    def _block(c, x, p, mlp, monkeypatch):
+        monkeypatch.setattr(moe, "moe_mlp", lambda c, h, p, *a: mlp(c, h, p))
+        return moe.moe_block(c, x, p)
+
+    def test_forward(self, router, drops, monkeypatch):
+        c, p, x = self._case(router, drops)
+        out_c, aux_c = self._block(c, x, p, moe._moe_mlp_capacity, monkeypatch)
+        out_r, aux_r = self._block(c, x, p, moe._moe_mlp_routed, monkeypatch)
+        np.testing.assert_allclose(
+            np.asarray(out_c, np.float32), np.asarray(out_r, np.float32),
+            rtol=2e-2, atol=2e-2,
+        )
+        assert _rel_l2(out_c - x, out_r - x) < 0.01
+        assert float(aux_c) == float(aux_r)
+
+    def test_gradients(self, router, drops, monkeypatch):
+        c, p, x = self._case(router, drops)
+
+        def loss(params, x, mlp):
+            out, aux = self._block(c, x, params, mlp, monkeypatch)
+            return jnp.sum((out - x).astype(jnp.float32) ** 2) + aux
+
+        g_c = jax.grad(loss, argnums=(0, 1))(p, x, moe._moe_mlp_capacity)
+        g_r = jax.grad(loss, argnums=(0, 1))(p, x, moe._moe_mlp_routed)
+        for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(g_c),
+            jax.tree_util.tree_leaves(g_r),
+        ):
+            name = jax.tree_util.keystr(path)
+            if "router_bias" in name:  # chooses, does not weigh: no gradient
+                assert not np.any(np.asarray(a)) and not np.any(np.asarray(b))
+                continue
+            assert _rel_l2(a, b) < (0.05 if "router" in name else 0.02), name
+
+
+def test_routed_path_with_an_expert_that_receives_no_row():
+    """A group of size 0 in the grouped matmuls: its weights get a zero
+    gradient and the other experts' rows keep their places."""
+    c = CFG.with_(n_experts=8, capacity_factor=4.0)
+    p = _rand_params(jax.random.PRNGKey(21), c)
+    p["router"] = p["router"].at[:, 3].set(0.0)
+    h = jnp.abs(jax.random.normal(
+        jax.random.PRNGKey(22), (2, 32, c.d_model), jnp.float32
+    )).astype(jnp.bfloat16)
+    # Expert 3 scores 0 on every token and some expert scores above it.
+    p["router"] = jnp.abs(p["router"]).at[:, 3].set(-1.0)
+    _, gate_idx, _, _, _ = moe.route_assignments(c, h, p["router"])
+    assert not bool(jnp.any(gate_idx == 3))
+
+    def loss(params, mlp):
+        out, aux = mlp(c, h, params)
+        return jnp.sum(out.astype(jnp.float32) ** 2) + aux
+
+    out_c, _ = moe._moe_mlp_capacity(c, h, p)
+    out_r, _ = moe._moe_mlp_routed(c, h, p)
+    assert _rel_l2(out_c, out_r) < 0.01
+    g_c = jax.grad(loss)(p, moe._moe_mlp_capacity)
+    g_r = jax.grad(loss)(p, moe._moe_mlp_routed)
+    for k in ("we_gate", "we_up", "we_down"):
+        assert not np.any(np.asarray(g_r[k][3], np.float32))
+        assert _rel_l2(g_c[k], g_r[k]) < 0.02
+
+
+# (rows on a device, tokens a row, experts, top-k, capacity factor) of the
+# launches the benchmark's cells make -> the formulation moe.plan answers
+# and the slots it multiplies (PERF.md section 3).
+_LAUNCHES = {
+    "train row, 4,096 tokens, top-2 of 8": ((1, 4096, 8, 2, 4.0), True, 8192 + 8 * 512),
+    "latent cell, 512-token chunk, top-4 of 64": ((1, 512, 64, 4, 16.0), True, 2048 + 64 * 128),
+    "mellum, 512-token chunk, top-8 of 64": ((1, 512, 64, 8, 8.0), True, 4096 + 64 * 128),
+    "rollout, 128-token chunk, top-2 of 8": ((1, 128, 8, 2, 4.0), False, 8 * 128),
+    "rollout, decode launch, 16 rows": ((16, 1, 8, 2, 4.0), False, 8 * 16),
+    "latent cell, decode launch, 16 rows": ((16, 1, 64, 4, 16.0), False, 64 * 16),
+    "mellum, decode launch, 16 rows": ((16, 1, 64, 8, 8.0), False, 64 * 16),
+}
+
+
+@pytest.mark.parametrize("launch", sorted(_LAUNCHES))
+def test_the_rule_at_the_cells_launch_shapes(launch):
+    (rows, row_len, E, k, cf), routed, slots = _LAUNCHES[launch]
+    c = CFG.with_(n_experts=E, experts_per_token=k, capacity_factor=cf)
+    assert moe.plan(c, rows, row_len)[:2] == (routed, slots)
+    # A bank that is not whole on the device keeps the capacity path.
+    capacity = E * rows * expert_capacity(c, row_len)
+    assert moe.plan(c, rows, row_len, whole_bank=False)[:2] == (False, capacity)
+
+
+def test_engine_counts_the_slots_its_programs_multiply(monkeypatch):
+    """A 512-token prefill chunk of an 8-expert model takes the routed path,
+    the short last chunk and every decode launch the capacity path: the
+    engine's counters read `moe.plan`, the function `moe_mlp` dispatched
+    on, and the tokens are those of an engine held to the capacity path."""
+    from dstack_tpu.workloads.serving import ServingEngine
+
+    c = CFG.with_(
+        n_experts=8, capacity_factor=4.0, max_seq_len=1024, dtype="float32"
+    )
+    params = init_params(c, jax.random.PRNGKey(0))
+    prompt = np.random.default_rng(3).integers(0, c.vocab_size, 600).tolist()
+
+    def serve():
+        traced = []
+        monkeypatch.setattr(
+            moe, "_routed_bank",
+            lambda *a, _f=moe._routed_bank: traced.append(a[1].shape) or _f(*a),
+        )
+        engine = ServingEngine(
+            c, params, slots=2, max_len=1024, kv_block_size=16,
+            prefill_chunk_tokens=512,
+        )
+        try:
+            out = engine.submit(prompt, max_new_tokens=5, temperature=0.0)
+            tokens = []
+            while (tok := out.get(timeout=300)) is not None:
+                assert not isinstance(tok, BaseException), tok
+                tokens.append(int(tok))
+            return tokens, engine.stats(), traced
+        finally:
+            engine.close()
+            monkeypatch.undo()
+
+    tokens, stats, traced = serve()
+    assert set(traced) == {(1, 512, c.d_model)}  # the long chunk, and only it
+    layers, k, E = c.n_layers, c.experts_per_token, c.n_experts
+    assert stats["moe_routed_launches_total"] == 1
+    decode = stats["decode_steps_total"] * 2  # rows a step: the slots
+    assert stats["moe_computed_slots_total"] == layers * (
+        (512 * k + E * 128)  # the routed chunk: its rows and a tile an expert
+        + E * 128            # 88 tokens padded to 128, at capacity 128
+        + E * decode         # capacity 1 a row
+    )
+    assert stats["moe_routed_slots_total"] == layers * k * (
+        600 + stats["decode_slot_steps_total"]
+    )
+
+    monkeypatch.setattr(moe, "plan", lambda c, rows, row_len, whole=True: (
+        False, c.n_experts * rows * expert_capacity(c, row_len), 0))
+    at_capacity, stats, traced = serve()
+    assert not traced and stats["moe_routed_launches_total"] == 0
+    assert tokens == at_capacity

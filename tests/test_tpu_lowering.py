@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from dstack_tpu.workloads import flash_attention as fa
 from dstack_tpu.workloads import kv_blocks
+from dstack_tpu.workloads import moe
 from dstack_tpu.workloads.attention import make_attention_fn
 from dstack_tpu.workloads.config import FULL, PRESETS, SLIDING
 from dstack_tpu.workloads.paged_attention import (
@@ -393,6 +394,142 @@ def test_sharded_train_loss_lowers_for_tpu(axes, monkeypatch):
     text = step.trace(params, batch).lower(lowering_platforms=("tpu",)).as_text()
     assert attention_fn.traced_paths == {"flash"}
     assert "tpu_custom_call" in text
+
+
+# ------------------------------------------------ the routed expert bank
+#
+# moe.plan hands a long prefill chunk and a train row to the routed path
+# (expert-sorted rows through megablox's grouped matmul); the programs
+# must then hold nothing of the capacity dispatch's sizes.
+
+_ARRAYS = re.compile(r"(?:\w+\[([\d,]+)\]|tensor<((?:\d+x)+)\w+>)")
+
+
+def _array_sizes(text):
+    """Element counts of every array type in HLO or StableHLO text."""
+    return {
+        math.prod(int(d) for d in re.split("[,x]", (a or b).strip("x")))
+        for a, b in _ARRAYS.findall(text)
+    }
+
+
+def _wide_expert_cfg(kind):
+    """The latent cell's and mellum's test models widened to their 64
+    experts (top-4 sigmoid with a shared expert; top-8 softmax), narrow
+    experts and few layers to build quickly."""
+    if kind == "latent":
+        return LATENT_CFG.with_(
+            n_experts=64, experts_per_token=4, capacity_factor=16.0,
+            d_ff=384, dense_d_ff=512, n_layers=3, vocab_size=2048, remat=False,
+        )
+    return WINDOW_CFG.with_(
+        n_experts=64, experts_per_token=8, capacity_factor=8.0, d_ff=384,
+        vocab_size=2048, remat=False,
+    )
+
+
+@pytest.mark.parametrize("kind", ["latent", "window"])
+def test_long_chunk_program_multiplies_only_routed_rows(kind, v5e, no_compile_cache, monkeypatch):
+    """The 512-token chunk program of a 64-expert model: no array of the
+    dispatch tensor's size (S x E x C), none of the expert slots'
+    (E x C x d_model, E x C x d_ff) — so no dot over E x C slots — and the
+    grouped matmul's kernels in their place, reading each layer's experts
+    out of the stacked bank without a copy of it. The same reading finds all
+    three in the capacity path's program. Compiled for the v5e where
+    libtpu describes one (Mosaic's verdict on the kernels' tiles)."""
+    chunk = 512
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setitem(globals(), "CHUNK", chunk)
+    cfg = _wide_expert_cfg(kind)
+    assert moe.plan(cfg, 1, chunk)[0]
+    E, C = cfg.n_experts, moe.expert_capacity(cfg, chunk)
+    capacity_sizes = {chunk * E * C, E * C * cfg.d_model, E * C * cfg.d_ff}
+    fn, args = _paged_program(
+        "chunk_prefill", cfg, "pallas" if v5e is not None else "lax_ragged"
+    )
+    assert not capacity_sizes & {
+        math.prod(a.shape) for a in jax.tree.leaves(args)
+    }
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert not capacity_sizes & _array_sizes(text)
+    kernels = text.count("tpu_custom_call")
+
+    with monkeypatch.context() as held:
+        held.setattr(moe, "plan", lambda c, rows, row_len, whole=True: (
+            False, E * rows * moe.expert_capacity(c, row_len), 0))
+        fn, _ = _paged_program(
+            "chunk_prefill", cfg, "pallas" if v5e is not None else "lax_ragged"
+        )
+        at_capacity = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert capacity_sizes <= _array_sizes(at_capacity)
+    # the grouped matmul's kernel (equal calls share one lowered function)
+    assert kernels > at_capacity.count("tpu_custom_call")
+
+    if v5e is not None:
+        fn, args = _paged_program("chunk_prefill", cfg, "pallas")
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+            args,
+        )
+        compiled = fn.lower(*args).compile().as_text()
+        assert not capacity_sizes & _array_sizes(compiled)
+        # ... and no layer's bank is cut out of the stack for the kernel:
+        # it reads the layer's experts in place (kv_blocks._layer_loop).
+        one_bank = E * cfg.d_model * cfg.d_ff
+        cut_out = [
+            f"{m.group(2)} makes a layer's bank [{m.group(1)}]"
+            for m in _PRODUCES.finditer(compiled)
+            if math.prod(int(d) for d in m.group(1).split(",")) == one_bank
+            and m.group(2) not in ("parameter", "get-tuple-element", "bitcast")
+        ]
+        assert not cut_out, cut_out
+
+
+def test_sharded_train_step_lowers_with_the_routed_bank(monkeypatch):
+    """fsdp=4, one 2,048-token row a device, 8 experts top-2 at the
+    no-drop factor: the rule takes the routed path, the bank runs under
+    shard_map (a Pallas call left to GSPMD is refused at lowering), and
+    every row gather is over ONE device's rows — no gather reads rows of
+    the whole batch, which would move tokens between devices."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, seq = 4, CFG.max_seq_len
+    cfg = CFG.with_(
+        n_experts=8, experts_per_token=2, capacity_factor=4.0, n_layers=1,
+        remat="full",
+    )
+    assert moe.plan(cfg, 1, seq)[0] and not moe.plan(cfg, rows, seq, False)[0]
+    traced = []
+    monkeypatch.setattr(
+        moe, "_routed_bank",
+        lambda *a, _f=moe._routed_bank: traced.append(a[1].shape) or _f(*a),
+    )
+    mesh = make_mesh(jax.devices()[:4])
+    attention_fn = make_attention_fn(mesh)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    batch = {
+        k: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+        for k in ("inputs", "targets")
+    }
+    step = jax.jit(
+        jax.grad(lambda p, b: loss_fn(cfg, p, b, attention_fn, mesh)[0]),
+        in_shardings=(
+            param_shardings(mesh, params),
+            {k: NamedSharding(mesh, BATCH_SPEC) for k in batch},
+        ),
+    )
+    text = step.trace(params, batch).lower(lowering_platforms=("tpu",)).as_text()
+    assert traced and set(traced) == {(1, seq, cfg.d_model)}  # a device's row
+    # the grouped matmuls forward and backward (`gmm`, its transpose and
+    # `tgmm`; equal calls share one lowered function) beside flash's three
+    assert text.count("tpu_custom_call") >= 6
+    gathers = re.findall(r'"stablehlo\.gather"\(.*?\) -> tensor<[^>]*>', text)
+    assert gathers
+    k = cfg.experts_per_token
+    across = [
+        g for g in gathers
+        if re.search(rf"tensor<({rows * seq}|{rows * seq * k})x", g)
+    ]
+    assert not across, across
 
 
 # ----------------------------------- programs of models of one kind of layer
