@@ -153,6 +153,15 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_kv_cow_copies_total": ("counter", ()),
     "dstack_tpu_serving_kv_layer_blocks": ("gauge", ()),
     "dstack_tpu_serving_kv_window_dead_blocks": ("gauge", ()),
+    # Recurrent state beside the rows (a model with state-space layers;
+    # zero otherwise): the layers that keep rows, a slot's state bytes at
+    # any context and the pool's, and the state rows (slot x state layer)
+    # the launched decode steps needed against those their programs moved.
+    "dstack_tpu_serving_decode_state_rows_computed_total": ("counter", ()),
+    "dstack_tpu_serving_decode_state_rows_total": ("counter", ()),
+    "dstack_tpu_serving_kv_pool_layers": ("gauge", ()),
+    "dstack_tpu_serving_state_pool_bytes": ("gauge", ()),
+    "dstack_tpu_serving_state_row_bytes": ("gauge", ()),
     # Prefill/decode disaggregation (workloads/kv_transfer.py): handoff
     # outcome counters on both sides of the seam, payload bytes moved,
     # per-handoff transfer latency, and the depth of the handoff queue

@@ -14,8 +14,9 @@ import jax.numpy as jnp
 
 _DTYPE = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
-# The kinds a `layer_types` entry may name, as published configs spell them.
-FULL, SLIDING = "full_attention", "sliding_attention"
+# The kinds a `layer_types` entry may name, as published configs spell them
+# (MAMBA is the `jamba` model type's word for its state-space layers).
+FULL, SLIDING, MAMBA = "full_attention", "sliding_attention", "mamba"
 
 
 @dataclass(frozen=True)
@@ -159,11 +160,59 @@ class ModelConfig:
     # (`RopeParams.of`), kept as a tuple of (kind, RopeParams). A kind it
     # does not name rotates by `rope_theta`.
     rope_parameters: Any = ()
+    # State-space (Mamba-1) layers beside attention layers, as the `jamba`
+    # model type publishes them: with `attn_layer_period` > 0 layer i is an
+    # attention layer iff i % attn_layer_period == attn_layer_offset and a
+    # MAMBA layer otherwise (`layer_types` is derived here and not given).
+    # A MAMBA layer keeps no cache rows: what a request carries through it
+    # is a state of `mamba_d_state` x d_inner values in float32 and the last
+    # `mamba_d_conv` - 1 rows of its convolution's input, whatever the
+    # context (`state_shapes`). d_inner = mamba_expand x d_model.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # False: queries and keys are not rotated (position reaches such a model
+    # through its state-space layers only).
+    use_rope: bool = True
+    # True: the head is the embedding's transpose, ONE leaf
+    # (`transformer.head_weights`; `init_params` makes no `lm_head`).
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         set_ = lambda k, v: object.__setattr__(self, k, v)
         set_("sliding_window", int(self.sliding_window or 0))
         kinds = tuple(self.layer_types or ())
+        if self.attn_layer_period:
+            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+                raise ValueError(
+                    f"attn_layer_offset {self.attn_layer_offset} must lie in"
+                    f" [0, attn_layer_period={self.attn_layer_period})"
+                )
+            derived = tuple(
+                FULL if i % self.attn_layer_period == self.attn_layer_offset
+                else MAMBA for i in range(self.n_layers)
+            )
+            # (`with_` hands the derived pattern back, of the old depth: a
+            # run of the same pattern is no conflict.)
+            if kinds[: self.n_layers] != derived[: len(kinds)]:
+                raise ValueError(
+                    "attn_layer_period derives layer_types: give one of them"
+                )
+            kinds = derived
+        if MAMBA in kinds:
+            if self.mamba_dt_rank < 1 or self.mamba_d_conv < 2:
+                raise ValueError(
+                    "a state-space layer needs mamba_dt_rank >= 1 and"
+                    " mamba_d_conv >= 2"
+                )
+            if self.n_experts:
+                raise ValueError(
+                    "state-space layers beside an expert bank: no program"
+                    " runs that pattern"
+                )
         if kinds:
             if len(kinds) < self.n_layers:
                 raise ValueError(
@@ -171,10 +220,10 @@ class ModelConfig:
                     f" {self.n_layers}"
                 )
             kinds = kinds[: self.n_layers]
-            if set(kinds) - {FULL, SLIDING}:
+            if set(kinds) - {FULL, SLIDING, MAMBA}:
                 raise ValueError(
-                    f"layer_types {sorted(set(kinds))}: expected {FULL!r} or"
-                    f" {SLIDING!r}"
+                    f"layer_types {sorted(set(kinds))}: expected {FULL!r},"
+                    f" {SLIDING!r} or {MAMBA!r}"
                 )
             if SLIDING in kinds and self.sliding_window < 1:
                 raise ValueError("a sliding_attention layer needs sliding_window")
@@ -242,6 +291,46 @@ class ModelConfig:
         """Keys a query of a `kind` layer sees, itself included; 0 = all."""
         return self.sliding_window if kind == SLIDING else 0
 
+    @property
+    def n_state_layers(self) -> int:
+        return sum(kind == MAMBA for kind in self.layer_types)
+
+    @property
+    def has_state_layers(self) -> bool:
+        """Some layer carries a recurrent state from token to token. A
+        cached block chain is then worth nothing without the state at its
+        end, a rejected draft would have to roll the state back, and a
+        preempted slot's chain drops it: the engine reads this once
+        (serving.ServingEngine) for all it refuses or turns off."""
+        return self.n_state_layers > 0
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that keep cache rows: the KV pool's layer axis."""
+        return self.n_layers - self.n_state_layers
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.d_model
+
+    def state_shapes(self) -> Tuple[Tuple[int, int], Tuple[int, int]]:
+        """One slot's recurrent state in one state-space layer: the scan's
+        `h` (d_state, d_inner), float32, and the convolution's tail
+        (d_conv - 1, d_inner) in the activation dtype — the ONE place the
+        state's geometry is derived, as `kv_row_shapes` is for a row's.
+        d_inner is the minor axis of both: d_state (16) there would be
+        padded to the TPU's 128 lanes, eight times the bytes."""
+        return (
+            (self.mamba_d_state, self.d_inner),
+            (self.mamba_d_conv - 1, self.d_inner),
+        )
+
+    def state_row_bytes(self) -> int:
+        """Bytes one slot's state takes over all state-space layers, at any
+        context."""
+        (n, di), (k, _) = self.state_shapes()
+        return self.n_state_layers * di * (n * 4 + k * self.dtype_bytes)
+
     def rope(self, kind: str) -> RopeParams:
         return dict(self.rope_parameters).get(kind) or RopeParams(self.rope_theta)
 
@@ -308,15 +397,42 @@ class ModelConfig:
             + d * self.n_experts
         )
 
+    def mamba_matmul_params(self) -> int:
+        """Weights of one state-space mixer's four projections."""
+        d, di = self.d_model, self.d_inner
+        r, n = self.mamba_dt_rank, self.mamba_d_state
+        return d * 2 * di + di * (r + 2 * n) + r * di + di * d
+
+    def mamba_params(self) -> int:
+        """Every leaf of one state-space mixer: the projections, the
+        convolution and its bias, dt's bias, A_log, D and the three inner
+        norms."""
+        di, n = self.d_inner, self.mamba_d_state
+        return (
+            self.mamba_matmul_params() + di * (self.mamba_d_conv + 1) + di
+            + di * n + di + self.mamba_dt_rank + 2 * n
+        )
+
     def param_count(self) -> int:
         """Approximate parameter count (embedding + head untied; norms
-        and the router's selection bias are not counted)."""
+        and the router's selection bias are not counted). A model with
+        state-space layers is counted leaf by leaf, norms included and the
+        head once where it is tied: its published count is checked against
+        this one (benchmarks/tests/test_cell_jamba.py)."""
         nd = self.n_dense_layers
+        heads = (1 if self.tie_embeddings else 2) * self.d_model * self.vocab_size
+        if self.has_state_layers:
+            return (
+                self.n_state_layers * self.mamba_params()
+                + self.n_attn_layers * self.attn_params()
+                + self.n_layers * (self.mlp_params() + 2 * self.d_model)
+                + heads + self.d_model
+            )
         return (
             self.n_layers * self.attn_params()
             + nd * self.mlp_params(dense=True)
             + (self.n_layers - nd) * self.mlp_params()
-            + 2 * self.d_model * self.vocab_size
+            + heads
         )
 
     def resolve_remat(
@@ -414,6 +530,17 @@ class ModelConfig:
         per token plus the router matmul, not the full expert bank."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         per_layer = 2 * self.attn_params()
+        if self.has_state_layers:
+            # Mixers by kind, averaged over the layers: a state-space one
+            # is its projections and, a token, the convolution's taps and
+            # about 9 element-wise operations a state value (exp, two
+            # products and a sum for h, a product and a sum for y).
+            di, n = self.d_inner, self.mamba_d_state
+            mamba = (2 * self.mamba_matmul_params()
+                     + 2 * self.mamba_d_conv * di + 9 * di * n)
+            per_layer = (
+                self.n_attn_layers * per_layer + self.n_state_layers * mamba
+            ) / self.n_layers
         if seq_len:
             # causal QK^T + AV: 2 * (scored + emitted) a head and key, over
             # the S / 2 keys a query sees in the mean; a window layer's
@@ -421,8 +548,10 @@ class ModelConfig:
             v_dim = self.v_head_dim if self.latent else self.head_dim
             kinds = self.layer_types or (FULL,)
             keys = sum(
-                w - w * w / (2.0 * seq_len) if 0 < w < seq_len else seq_len / 2
-                for w in map(self.window, kinds)
+                0 if kind == MAMBA
+                else w - w * w / (2.0 * seq_len) if 0 < w < seq_len
+                else seq_len / 2
+                for kind, w in zip(kinds, map(self.window, kinds))
             ) / len(kinds)
             per_layer += 2 * keys * self.n_heads * (self.head_dim + v_dim)
         if self.n_experts > 0:
@@ -490,6 +619,17 @@ PRESETS: Dict[str, ModelConfig] = {
         vocab_size=512, d_model=128, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=256, max_seq_len=256, remat=False, n_experts=4,
         experts_per_token=2,
+    ),
+    # State-space (Mamba-1) layers and attention layers in one layer loop
+    # (mmam twice), four query heads on ONE KV head, no rotary embedding, a
+    # tied head: every mechanism of the `jamba` block at a size the CPU
+    # runs.
+    "tiny-mamba": ModelConfig(
+        vocab_size=512, d_model=64, n_layers=8, n_heads=4, n_kv_heads=1,
+        d_ff=128, max_seq_len=512, remat=False, norm_eps=1e-6,
+        attn_layer_period=4, attn_layer_offset=2, mamba_d_state=16,
+        mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=8, use_rope=False,
+        tie_embeddings=True,
     ),
     # Latent attention + a leading dense layer + sigmoid-routed experts
     # beside a shared one, for tests/dryrun: every mechanism of the

@@ -24,13 +24,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from dstack_tpu.workloads.attention import NEG_INF, _repeat_kv
-from dstack_tpu.workloads.config import ModelConfig
+from dstack_tpu.workloads.config import FULL, MAMBA, ModelConfig
 from dstack_tpu.workloads.transformer import (
     absorb_query,
+    head_weights,
     latent_output,
     layer_stacks,
     linear,
     logits_linear,
+    mamba_mixer,
+    mixer_stacks,
     mlp_block,
     project_latent,
     project_qkv,
@@ -44,11 +47,16 @@ Params = Dict[str, Any]
 class KVCache(NamedTuple):
     """Static-shape per-layer cache: k/v (L, B, max_len, KV, hd); a
     latent-attention model keeps one (1, row) latent row a token in k
-    and a zero-wide v (ModelConfig.kv_row_shapes)."""
+    and a zero-wide v (ModelConfig.kv_row_shapes). A model with
+    state-space layers keeps rows for its attention layers only (L counts
+    those) and, a state-space layer, the scan's state and the
+    convolution's tail (ModelConfig.state_shapes)."""
 
     k: jnp.ndarray
     v: jnp.ndarray
     length: jnp.ndarray  # () int32 — filled positions
+    ssm: Optional[jnp.ndarray] = None   # (Ls, B, d_state, d_inner) float32
+    conv: Optional[jnp.ndarray] = None  # (Ls, B, d_conv - 1, d_inner)
 
 
 def init_cache(
@@ -56,9 +64,16 @@ def init_cache(
 ) -> KVCache:
     c = config
     k_row, v_row = c.kv_row_shapes()
-    shape = (c.n_layers, batch, max_len)
+    shape = (c.n_attn_layers, batch, max_len)
     dtype = dtype or c.activation_dtype
+    recurrent = {}
+    if c.has_state_layers:
+        h_row, tail_row = c.state_shapes()
+        rows = (c.n_state_layers, batch)
+        recurrent = {"ssm": jnp.zeros(rows + h_row, jnp.float32),
+                     "conv": jnp.zeros(rows + tail_row, dtype)}
     return KVCache(
+        **recurrent,
         k=jnp.zeros(shape + k_row, dtype),
         v=jnp.zeros(shape + v_row, dtype),
         length=jnp.zeros((), jnp.int32),
@@ -111,8 +126,20 @@ def _forward_cached(
 
     x = jnp.take(params["embed"], tokens, axis=0)
 
+    mixers = mixer_stacks(params)
+
     def block(x, layer, kind):
-        p, ck, cv = layer
+        if mixers is not None:
+            # (what every layer has, what its kind has: weights and cache)
+            p, (own, ck, cv) = layer
+            p = {**p, **own}
+        else:
+            p, ck, cv = layer
+        if kind == MAMBA:
+            out, ck, cv = mamba_mixer(
+                c, rms_norm(x, p["attn_norm"], c.norm_eps), p, ck, cv
+            )
+            return mlp_block(c, x + out, p), (ck, cv)
         if c.latent:
             # The cache row is [c_kv | k_rope | pad]; decode attends in
             # the absorbed form (transformer.absorb_query), as the paged
@@ -146,6 +173,19 @@ def _forward_cached(
             x = mlp_block(c, x, p)
         return x, (ck, cv)
 
+    if mixers is not None:
+        x, caches = scan_layers(c, block, x, params["layers"], own={
+            kind: (stack,) + ((cache.ssm, cache.conv) if kind == MAMBA
+                              else (cache.k, cache.v))
+            for kind, stack in mixers.items()
+        })
+        (ssm, conv), (new_k, new_v) = (
+            caches.get(MAMBA, (cache.ssm, cache.conv)),
+            caches.get(FULL, (cache.k, cache.v)),
+        )
+        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = logits_linear(x[:, -1], head_weights(params))
+        return logits, KVCache(new_k, new_v, start + s, ssm, conv)
     new_k, new_v, first = [], [], 0
     for stack in layer_stacks(params):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
@@ -159,7 +199,7 @@ def _forward_cached(
     new_k = new_k[0] if len(new_k) == 1 else jnp.concatenate(new_k)
     new_v = new_v[0] if len(new_v) == 1 else jnp.concatenate(new_v)
     x = rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = logits_linear(x[:, -1], params["lm_head"])
+    logits = logits_linear(x[:, -1], head_weights(params))
     return logits, KVCache(k=new_k, v=new_v, length=start + s)
 
 

@@ -60,19 +60,25 @@ import jax.numpy as jnp
 from jax import lax
 
 from dstack_tpu.workloads import moe
-from dstack_tpu.workloads.config import ModelConfig
+from dstack_tpu.workloads.config import MAMBA, ModelConfig
 from dstack_tpu.workloads.paged_attention import ragged_attention
 from dstack_tpu.workloads.sampling import (
     _sampling_probs,
     _select_next_token,
     sample_logits_row,
 )
+from dstack_tpu.workloads.selective_scan import scan_impl, selective_scan_decode
 from dstack_tpu.workloads.transformer import (
     absorb_query,
+    head_weights,
     latent_output,
     layer_stacks,
     linear,
     logits_linear,
+    mamba_inputs,
+    mamba_mixer,
+    mamba_output,
+    mixer_stacks,
     mlp_block,
     project_latent,
     project_qkv,
@@ -90,6 +96,8 @@ class PagedDecodeState(NamedTuple):
     # The pools' trailing (heads, width) is ModelConfig.kv_row_shapes():
     # (KV, hd) twice for GQA; ONE latent pool (1, row) and a zero-wide v
     # (no bytes, never read) for latent attention.
+    # Their layer axis counts the layers that keep rows (every layer, but
+    # for a model with state-space layers: its attention layers only).
     k: jnp.ndarray            # (L, num_blocks, block_size, KV, hd)
     v: jnp.ndarray
     block_tables: jnp.ndarray  # (B, max_blocks) int32; pad = num_blocks
@@ -100,6 +108,16 @@ class PagedDecodeState(NamedTuple):
     temperature: jnp.ndarray  # (B,) f32; 0 = greedy
     top_p: jnp.ndarray        # (B,) f32; 1 = no filtering
     adapter_ix: jnp.ndarray   # (B,) int32 LoRA pool slot; -1 = no adapter
+    # The recurrent-state pool of a model with state-space layers, a slot a
+    # row whatever its context (ModelConfig.state_shapes): the scan's state
+    # and the convolution's tail. None (no leaf, no operand of any program)
+    # for every other model.
+    # A slot's tail is ONE row of the pool, its d_conv - 1 = 3 inputs side
+    # by side: as a second-minor axis the 3 would be padded to a tile of
+    # 16, and XLA re-laid the whole pool out at each end of a chunk's
+    # layer loop (AOT for the v5e, PR 33).
+    ssm: Optional[jnp.ndarray] = None   # (Ls, B, d_state, d_inner) float32
+    conv: Optional[jnp.ndarray] = None  # (Ls, B, (d_conv - 1) * d_inner)
 
 
 def init_paged_state(
@@ -108,7 +126,10 @@ def init_paged_state(
     max_len: int,
     block_size: int,
     num_blocks: int,
+    state_dtype=jnp.float32,
 ) -> PagedDecodeState:
+    """`state_dtype` is the scan state's: float32, but for the test that
+    shows what bfloat16 costs (tests/test_state_space_model.py)."""
     c = config
     if max_len % block_size != 0:
         raise ValueError(
@@ -116,8 +137,17 @@ def init_paged_state(
         )
     max_blocks = max_len // block_size
     k_row, v_row = c.kv_row_shapes()
-    shape = (c.n_layers, num_blocks, block_size)
+    shape = (c.n_attn_layers, num_blocks, block_size)
+    recurrent = {}
+    if c.has_state_layers:
+        h_row, (taps, width) = c.state_shapes()
+        recurrent = {
+            "ssm": jnp.zeros((c.n_state_layers, batch) + h_row, state_dtype),
+            "conv": jnp.zeros(
+                (c.n_state_layers, batch, taps * width), c.activation_dtype),
+        }
     return PagedDecodeState(
+        **recurrent,
         k=jnp.zeros(shape + k_row, c.activation_dtype),
         v=jnp.zeros(shape + v_row, c.activation_dtype),
         block_tables=jnp.full((batch, max_blocks), num_blocks, jnp.int32),
@@ -446,8 +476,9 @@ def _jit_shardings(in_shardings, out_shardings):
 def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
                 blk, off, tables, valid_len, *, bank=None, adapter_ix=None,
                 has_lora=None, attn_impl: Optional[str] = None,
-                partitioned: bool = False):
-    """The layer loop of every paged program -> (x, k_pool, v_pool).
+                partitioned: bool = False, recurrent=None):
+    """The layer loop of every paged program -> (x, k_pool, v_pool), and
+    with `recurrent` -> (x, k_pool, v_pool, ssm, conv).
 
     x (B, S, d) at `positions` runs through the layers; layer l writes
     its new K/V rows into the STACKED pool (L, num_blocks, block_size,
@@ -472,7 +503,60 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     (tests/test_tpu_lowering.py reads the compiled HLO). Do not flatten
     (L, num_blocks) for the WRITE: sentinel + l * num_blocks would land
     in layer l+1 instead of out of bounds.
+
+    A model with state-space layers hands in `recurrent` = (ssm, conv,
+    slot, n_valid (B,), fresh (B,)): the state pool, carried and updated in
+    place like the KV pool; which slot x's ONE row is (a prefill chunk), or
+    None where x's rows are the pool's slots in order (a decode step); how
+    many of a row's tokens are the sequence's (a padded tail and a row that
+    is not live move no state); and which rows start a sequence, from zero
+    state whoever held the slot before. Its attention layers index the KV
+    pool by their rank among the attention layers.
     """
+    mixers = mixer_stacks(params)
+    if recurrent is not None:
+        _, _, slot, n_valid, fresh = recurrent
+
+    def rows_of(pool, m):
+        """Layer m of a state pool at x's rows: every slot's, or the one
+        slot's of a prefill chunk."""
+        if slot is None:
+            return lax.dynamic_index_in_dim(pool, m, keepdims=False)
+        at = (m, slot) + (0,) * (pool.ndim - 2)
+        return lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])[0]
+
+    def put_rows(pool, m, rows):
+        if slot is None:
+            return lax.dynamic_update_index_in_dim(pool, rows, m, 0)
+        return lax.dynamic_update_slice(
+            pool, rows[None], (m, slot) + (0,) * (pool.ndim - 2))
+
+    def mix(x, p, m, ssm, conv):
+        """State-space layer of rank m: read the rows' state, mix, write
+        the state back -> (the mixer's output, the pools)."""
+        impl = scan_impl(*ssm.shape[2:])
+        start = fresh[:, None, None]
+        x = rms_norm(x, p["attn_norm"], c.norm_eps)
+        tail0 = rows_of(conv, m).reshape((x.shape[0],) + c.state_shapes()[1])
+        tail0 = jnp.where(start, 0, tail0)
+        if slot is None and impl == "pallas" and ssm.dtype == jnp.float32:
+            # A decode step on the TPU: the kernel updates the live rows'
+            # state in the pool, in place, and touches no other row.
+            u, z, delta, b_in, c_out, tail = mamba_inputs(c, x, p, tail0, n_valid)
+            with jax.named_scope("mamba/scan"):
+                y, ssm = selective_scan_decode(
+                    ssm, m, n_valid > 0, delta[:, 0], u[:, 0].astype(jnp.float32),
+                    b_in[:, 0], c_out[:, 0], -jnp.exp(p["A_log"]),
+                )
+            out = mamba_output(x.dtype, y[:, None], u, z, p)
+        else:
+            out, h, tail = mamba_mixer(
+                c, x, p, jnp.where(start, 0, rows_of(ssm, m)), tail0, n_valid,
+                scan_impl=impl,
+            )
+            ssm = put_rows(ssm, m, h)
+        return out, ssm, put_rows(conv, m, tail.reshape(x.shape[0], -1))
+
     if bank is None:
         project = lambda x, p, lp, kind: project_qkv(c, x, p, positions, kind)
     else:
@@ -517,6 +601,15 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
         return linear(attn, p["wo"]), kp, vp
 
     def block(bank_stack, first, carry, layer, kind):
+        if mixers is not None:
+            (p, l, lp), (own, rank) = layer
+            p = {**p, **own}
+            x, kp, vp, ssm, conv = carry
+            if kind == MAMBA:
+                out, ssm, conv = mix(x, p, rank, ssm, conv)
+            else:
+                out, kp, vp = attend(x, p, lp, rank, kp, vp, kind)
+            return (mlp_block(c, x + out, p), kp, vp, ssm, conv), None
         x, kp, vp = carry
         p, l, lp = layer
         out, kp, vp = attend(x, p, lp, l, kp, vp, kind)
@@ -540,7 +633,18 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     # bank, as the attention kernel reads a layer of the pool: the bank
     # stays out of the scan's `xs`, which would cut each layer's out into
     # a buffer of its own before a kernel may read it.
-    carry, first = (x, k_pool, v_pool), 0
+    # Where the layers differ in their LEAVES (state-space mixers beside
+    # attention mixers), each kind's mixers are a stack of their own, read
+    # at a layer's rank among its kind; that rank is also the layer's index
+    # in the pool of its kind (KV rows, or state).
+    carry, first, own = (x, k_pool, v_pool), 0, None
+    if mixers is not None:
+        carry += recurrent[:2]
+        own = {
+            kind: (stack, jnp.arange(
+                jax.tree_util.tree_leaves(stack)[0].shape[0], dtype=jnp.int32))
+            for kind, stack in mixers.items()
+        }
     for stack in layer_stacks(params):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
         bank_stack = {}
@@ -555,7 +659,7 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             None if bank is None else bank["layers"],
         )
         carry, _ = scan_layers(
-            c, functools.partial(block, bank_stack, first), carry, xs
+            c, functools.partial(block, bank_stack, first), carry, xs, own=own
         )
         first += n
     return carry
@@ -616,17 +720,24 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
         # positions <= start + i, including the rows just written.
         # Padded lanes hit the sentinel block and drop; valid_len masks
         # whatever garbage their attention rows read.
-        x, new_k, new_v = _layer_loop(
+        # A slot's first chunk starts from zero state inside this program,
+        # whoever held the slot before; the recurrence stops at n_valid.
+        recurrent = None if state.ssm is None else (
+            state.ssm, state.conv, slot, n_valid[None],
+            ((start == 0) & (n_valid > 0))[None],
+        )
+        x, new_k, new_v, *new_recurrent = _layer_loop(
             c, params, x, positions, state.k, state.v,
             blk[None], off[None], table_row[None], valid_len[None],
             bank=bank, adapter_ix=aix, has_lora=aix >= 0,
             attn_impl=attn_impl, partitioned=shardings is not None,
+            recurrent=recurrent,
         )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
         h_last = jnp.take(
             h[0], jnp.clip(n_valid - 1, 0, C - 1), axis=0, mode="clip"
         )
-        logits = logits_linear(h_last[None], params["lm_head"])[0]
+        logits = logits_linear(h_last[None], head_weights(params))[0]
         first = sample_logits_row(logits, temp, top_p, rng)
 
         B = state.lengths.shape[0]
@@ -645,6 +756,7 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
             # Finalize claims the slot for this request's adapter; a slot
             # reused by an adapter-free request resets to -1 here.
             adapter_ix=jnp.where(sel, aix, state.adapter_ix),
+            **dict(zip(("ssm", "conv"), new_recurrent)),
         )
         return new_state, first
 
@@ -725,15 +837,22 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
         )[:, None]                                   # (B, 1)
 
         aix = state.adapter_ix
-        x, new_k, new_v = _layer_loop(
+        # Live slots only: a row that is not active (empty, or between two
+        # of its prefill chunks) keeps its state bit for bit.
+        recurrent = None if state.ssm is None else (
+            state.ssm, state.conv, None, state.active.astype(jnp.int32),
+            jnp.zeros((B,), bool),
+        )
+        x, new_k, new_v, *new_recurrent = _layer_loop(
             c, params, x, positions, state.k, state.v,
             blk[:, None], off[:, None], state.block_tables, valid_len,
             bank=bank, adapter_ix=aix,
             has_lora=jnp.any(state.active & (aix >= 0)),
             attn_impl=attn_impl, partitioned=shardings is not None,
+            recurrent=recurrent,
         )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = logits_linear(h[:, -1], params["lm_head"])
+        logits = logits_linear(h[:, -1], head_weights(params))
         next_token = _select_next_token(state, logits, rng)
 
         act = state.active
@@ -750,6 +869,7 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
             temperature=state.temperature,
             top_p=state.top_p,
             adapter_ix=state.adapter_ix,
+            **dict(zip(("ssm", "conv"), new_recurrent)),
         )
         return new_state, jnp.where(act, next_token, -1), new_active
 
@@ -854,7 +974,7 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None,
                 attn_impl=attn_impl, partitioned=shardings is not None,
             )
             h = rms_norm(x, params["final_norm"], c.norm_eps)
-            logits = logits_linear(h[:, -1], params["lm_head"])  # (B, V)
+            logits = logits_linear(h[:, -1], head_weights(params))  # (B, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             probs = _sampling_probs(logits[:, None], temps, top_ps)[:, 0]
             sampled = jax.random.categorical(
@@ -955,7 +1075,7 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
             attn_impl=attn_impl, partitioned=shardings is not None,
         )
         h = rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = logits_linear(h, params["lm_head"])         # (B, S, V)
+        logits = logits_linear(h, head_weights(params))      # (B, S, V)
 
         temps = state.temperature
         samp = temps > 0

@@ -57,7 +57,7 @@ from dstack_tpu.utils.flight_recorder import (
 )
 from dstack_tpu.utils.stagemarkers import auto_stage
 from dstack_tpu.workloads import compile_cache
-from dstack_tpu.workloads.config import ModelConfig
+from dstack_tpu.workloads.config import MAMBA, ModelConfig
 from dstack_tpu.workloads.kv_blocks import (
     BlockAllocator,
     init_paged_state,
@@ -74,6 +74,7 @@ from dstack_tpu.workloads.paged_attention import (
 )
 from dstack_tpu.workloads import moe
 from dstack_tpu.workloads.quant import QTensor, quantize_params
+from dstack_tpu.workloads.selective_scan import scan_impl
 from dstack_tpu.workloads.sharding import (
     make_serving_shardings,
     serving_param_shardings,
@@ -343,8 +344,19 @@ class ServingEngine:
         self._preemptions = 0       # slots swapped out, monotonic
         self._slot_swap_ins = 0     # slots swapped back in, monotonic
         self._swap_in_hist = HistogramData()
+        # A model with state-space layers runs with prefix reuse OFF: a
+        # matched block chain is worth nothing without the recurrent state
+        # at its end, which nothing keeps (ROADMAP R5: a snapshot entry).
+        # Read here, once; `match` then hands no request a chain, and
+        # stats()["prefix_cache"] names the reason.
+        self._prefix_cache = (
+            "off: state-space layers (a cached chain lacks the recurrent"
+            " state at its end)" if config.has_state_layers
+            else "on" if prefix_cache else "off: prefix_cache=False"
+        )
         self._alloc = BlockAllocator(
-            self._num_blocks, kv_block_size, cache=prefix_cache,
+            self._num_blocks, kv_block_size,
+            cache=self._prefix_cache == "on",
             spill=(self._spill_block if self._host_tier is not None
                    else None),
             swap_in=(self._swap_in_block if self._host_tier is not None
@@ -381,7 +393,10 @@ class ServingEngine:
         # stack of layers, or layers of one kind, and have no test with
         # anything else: a latent-attention, dense-leading or mixed-layer
         # (layer_types) model names the feature and stops here; none may
-        # run and give other numbers.
+        # run and give other numbers. A model with state-space layers is a
+        # mixed-layer model, and has reasons of its own: a rejected draft
+        # would have to roll the state back, a preempted or handed-over
+        # block chain drops it, and its mixers are stacks of their own.
         if config.latent or config.n_dense_layers or config.layer_types:
             quantized = any(
                 isinstance(leaf, QTensor) for leaf in jax.tree_util.tree_leaves(
@@ -399,9 +414,11 @@ class ServingEngine:
                 if asked:
                     raise ValueError(
                         f"{feature} is not supported for latent-attention,"
-                        " dense-leading or mixed-layer (layer_types) models:"
+                        " dense-leading or mixed-layer (layer_types, or"
+                        " state-space layers by attn_layer_period) models:"
                         " it assumes per-head K/V rows, wq/wk/wv weights, one"
-                        " stack of layers or layers of one kind"
+                        " stack of layers or layers of one kind, and no"
+                        " recurrent state beside the rows"
                     )
         self.state = init_paged_state(
             config, slots, self.max_len, kv_block_size, self._num_blocks
@@ -480,7 +497,7 @@ class ServingEngine:
         self._spec_min_accept = spec_min_accept
 
         def _pool_bytes(cfg: ModelConfig) -> int:
-            return (cfg.n_layers * self._num_blocks * kv_block_size
+            return (cfg.n_attn_layers * self._num_blocks * kv_block_size
                     * cfg.kv_row_bytes())
 
         self._draft_config = spec_draft_config or config
@@ -661,6 +678,17 @@ class ServingEngine:
         self._decode_layer_blocks = 0
         self._decode_attended_blocks = 0
         self._decode_window_dead_blocks = 0
+        # State rows (slot x state-space layer) the update of each launched
+        # step needs, the live slots', against those the program moves:
+        # the same on the TPU, whose kernel walks live rows only
+        # (selective_scan.selective_scan_decode); every slot row's, live or
+        # not, in the plain form.
+        self._scan_path = (
+            scan_impl(*config.state_shapes()[0])
+            if config.has_state_layers else "none"
+        )
+        self._decode_state_rows = 0
+        self._decode_state_rows_computed = 0
         # Chunked-prefill / paging counters (monotonic, for /metrics and
         # the prefix-reuse acceptance measurement: tokens_computed for a
         # cache-hit request drops by the reused prefix).
@@ -1476,7 +1504,7 @@ class ServingEngine:
             "decode_attended_blocks_total": self._decode_attended_blocks,
             "decode_window_dead_blocks_total":
                 self._decode_window_dead_blocks,
-            "kv_layer_blocks": live_blocks * self.config.n_layers,
+            "kv_layer_blocks": live_blocks * self.config.n_attn_layers,
             "kv_window_dead_blocks": dead_blocks,
             # The older three-way split, derived from the same clock:
             # launch to readback; admission host work (with the barrier
@@ -1566,15 +1594,34 @@ class ServingEngine:
                 "_latent" if self.config.latent else ""
             ),
             # One period of the layer pattern, a letter a layer: w for a
-            # sliding-attention layer of `sliding_window` keys, f for full.
+            # sliding-attention layer of `sliding_window` keys, f for full;
+            # a model with state-space layers writes m for those and a for
+            # its attention layers.
             "layer_pattern": "".join(
-                "w" if self.config.window(kind) else "f"
+                "m" if kind == MAMBA
+                else "a" if self.config.has_state_layers
+                else "w" if self.config.window(kind) else "f"
                 for kind in self.config.layer_period
             ),
             "sliding_window": self.config.sliding_window,
             # Bytes one cached token allocates per layer (padding
             # included), and the expert slots routed vs computed.
             "kv_row_bytes": self.config.kv_row_bytes(),
+            # Layers that keep rows (the KV pool's layer axis), and beside
+            # the rows the recurrent state of a model with state-space
+            # layers: bytes a slot at any context, the pool's bytes, and
+            # the state rows (slot x state layer) the launched decode
+            # steps needed against those their programs moved.
+            "kv_pool_layers": self.config.n_attn_layers,
+            "state_row_bytes": self.config.state_row_bytes(),
+            "state_pool_bytes": self.slots * self.config.state_row_bytes(),
+            "decode_state_rows_total": self._decode_state_rows,
+            "decode_state_rows_computed_total":
+                self._decode_state_rows_computed,
+            # "on", or why prefix reuse is off; the recurrence's form
+            # ("pallas", "lax"; "none": no state-space layer).
+            "prefix_cache": self._prefix_cache,
+            "scan_path": self._scan_path,
             "moe_routed_slots_total": self._moe_routed_slots,
             "moe_computed_slots_total": self._moe_computed_slots,
             "moe_routed_launches_total": self._moe_routed_launches,
@@ -3136,8 +3183,12 @@ class ServingEngine:
         """One decode chunk (or speculation round) of `steps` steps is
         about to launch over the slots live right now."""
         blocks, dead = self._layer_blocks()
-        layer_blocks = blocks * self.config.n_layers
+        layer_blocks = blocks * self.config.n_attn_layers
         live = sum(r is not None for r in self._live)
+        state_layers = self.config.n_state_layers
+        moved = live if self._scan_path == "pallas" else self.slots
+        self._decode_state_rows += steps * live * state_layers
+        self._decode_state_rows_computed += steps * moved * state_layers
         self._decode_steps += steps
         self._decode_slot_steps += steps * live
         self._decode_live_blocks += steps * blocks
@@ -3467,6 +3518,18 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
          stats.get("kv_layer_blocks", 0)),
         ("dstack_tpu_serving_kv_window_dead_blocks", "gauge",
          stats.get("kv_window_dead_blocks", 0)),
+        # Recurrent state beside the rows (zero for a model without
+        # state-space layers).
+        ("dstack_tpu_serving_kv_pool_layers", "gauge",
+         stats.get("kv_pool_layers", 0)),
+        ("dstack_tpu_serving_state_row_bytes", "gauge",
+         stats.get("state_row_bytes", 0)),
+        ("dstack_tpu_serving_state_pool_bytes", "gauge",
+         stats.get("state_pool_bytes", 0)),
+        ("dstack_tpu_serving_decode_state_rows_total", "counter",
+         stats.get("decode_state_rows_total", 0)),
+        ("dstack_tpu_serving_decode_state_rows_computed_total", "counter",
+         stats.get("decode_state_rows_computed_total", 0)),
         ("dstack_tpu_serving_rejected_total", "counter",
          stats["rejected_total"]),
         # Speculative decoding (all zero when --spec-enable is off;

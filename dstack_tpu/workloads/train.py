@@ -26,7 +26,12 @@ from dstack_tpu.workloads.sharding import (
     BATCH_SPEC,
     param_shardings,
 )
-from dstack_tpu.workloads.transformer import forward, init_params, logits_linear
+from dstack_tpu.workloads.transformer import (
+    forward,
+    head_weights,
+    init_params,
+    logits_linear,
+)
 
 
 class TrainState(NamedTuple):
@@ -208,7 +213,7 @@ def loss_fn(
             return_aux=True, return_hidden=True,
         )
         total, denom = _chunked_ce(
-            hidden, params["lm_head"], targets, mask, config.ce_chunk
+            hidden, head_weights(params), targets, mask, config.ce_chunk
         )
         ce = total / jnp.maximum(denom, 1.0)
         return ce + config.router_aux_coef * aux, aux

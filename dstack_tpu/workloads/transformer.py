@@ -17,6 +17,7 @@ examples/accelerators/tpu/README.md); this module is the TPU-native
 equivalent workload the orchestrator launches.
 """
 
+import math
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -24,7 +25,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from dstack_tpu.workloads.attention import plain_attention
-from dstack_tpu.workloads.config import FULL, ModelConfig, RopeParams
+from dstack_tpu.workloads.config import FULL, MAMBA, ModelConfig, RopeParams
+from dstack_tpu.workloads.selective_scan import (
+    selective_scan,
+    selective_scan_chunk,
+)
 
 Params = Dict[str, Any]
 AttentionFn = Callable[..., jnp.ndarray]
@@ -36,7 +41,11 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     Blocks of one kind share a stack. A model whose first
     `n_dense_layers` blocks are plain SwiGLU and the rest carry experts
     has two: `dense_layers` (run first) and `layers`; every other model
-    has `layers` alone, as before."""
+    has `layers` alone, as before. A model with state-space layers keeps
+    in `layers` what every layer has (the two norms, the MLP) and under
+    `mixers` one stack a KIND of mixer, each as long as the model has
+    layers of that kind (`mixer_stacks`, `scan_layers`). A tied head is no
+    leaf: `head_weights` reads the embedding."""
     c = config
     dt = c.activation_dtype
     keys = jax.random.split(key, 8)
@@ -48,6 +57,15 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
         return (jax.random.normal(key, shape, dtype=jnp.float32) * fan_in**-0.5).astype(dt)
 
     D, V = c.d_model, c.vocab_size
+
+    def attention(keys, L):
+        hd = c.head_dim
+        return {
+            "wq": dense(keys[1], (L, D, c.n_heads * hd), D),
+            "wk": dense(keys[2], (L, D, c.n_kv_heads * hd), D),
+            "wv": dense(keys[3], (L, D, c.n_kv_heads * hd), D),
+            "wo": dense(keys[4], (L, c.n_heads * hd, D), c.n_heads * hd),
+        }
 
     def stack(keys, L, d_ff, experts):
         """L blocks of one kind: attention + dense MLP or expert bank."""
@@ -69,12 +87,8 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
             layers["wo"] = dense(
                 keys[4], (L, H * c.v_head_dim, D), H * c.v_head_dim
             )
-        else:
-            hd = c.head_dim
-            layers["wq"] = dense(keys[1], (L, D, c.n_heads * hd), D)
-            layers["wk"] = dense(keys[2], (L, D, c.n_kv_heads * hd), D)
-            layers["wv"] = dense(keys[3], (L, D, c.n_kv_heads * hd), D)
-            layers["wo"] = dense(keys[4], (L, c.n_heads * hd, D), c.n_heads * hd)
+        elif not c.has_state_layers:   # (theirs are stacks of their own: `mixers`)
+            layers.update(attention(keys, L))
         F = d_ff
         if experts:
             E = c.n_experts
@@ -104,13 +118,46 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
             layers["w_down"] = dense(keys[7], (L, F, D), F)
         return layers
 
+    def mamba(key, L):
+        """L state-space mixers. The recurrence starts in the regime the
+        published initialisation gives: A_log = log(1..d_state) a channel,
+        D = 1, dt's bias the inverse softplus of a step log-uniform in
+        [1e-3, 1e-1] (a random A_log makes the state vanish or explode).
+        A_log and the convolution keep d_inner as their minor axis, as the
+        state does (config.state_shapes)."""
+        Di, N, R, K = c.d_inner, c.mamba_d_state, c.mamba_dt_rank, c.mamba_d_conv
+        ks = jax.random.split(key, 7)
+        step = jnp.exp(jax.random.uniform(
+            ks[5], (L, Di), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return {
+            "in_proj": dense(ks[0], (L, D, 2 * Di), D),
+            "conv_w": dense(ks[1], (L, K, Di), K),
+            "conv_b": dense(ks[6], (L, Di), K),
+            "x_proj": dense(ks[2], (L, Di, R + 2 * N), Di),
+            "dt_norm": norm_init((L, R)),
+            "b_norm": norm_init((L, N)),
+            "c_norm": norm_init((L, N)),
+            "dt_proj": dense(ks[3], (L, R, Di), R),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.broadcast_to(jnp.log(
+                jnp.arange(1, N + 1, dtype=jnp.float32))[:, None], (L, N, Di)),
+            "D": jnp.ones((L, Di), jnp.float32),
+            "out_proj": dense(ks[4], (L, Di, D), Di),
+        }
+
     nd = c.n_dense_layers
     params = {
         "embed": dense(keys[0], (V, D), D),
         "layers": stack(keys, c.n_layers - nd, c.d_ff, c.n_experts > 0),
         "final_norm": norm_init((D,)),
-        "lm_head": dense(jax.random.fold_in(key, 99), (D, V), D),
     }
+    if not c.tie_embeddings:
+        params["lm_head"] = dense(jax.random.fold_in(key, 99), (D, V), D)
+    if c.has_state_layers:
+        params["mixers"] = {
+            MAMBA: mamba(jax.random.fold_in(key, 97), c.n_state_layers),
+            FULL: attention(keys, c.n_attn_layers),
+        }
     if nd:
         params["dense_layers"] = stack(
             jax.random.split(jax.random.fold_in(key, 98), 8), nd,
@@ -125,6 +172,21 @@ def layer_stacks(params: Params):
     if "dense_layers" in params:
         return (params["dense_layers"], params["layers"])
     return (params["layers"],)
+
+
+def mixer_stacks(params: Params):
+    """The stacks that only the layers of one kind have, by kind (None for
+    a model whose every layer has the same leaves): `scan_layers`' `own`."""
+    return params.get("mixers")
+
+
+def head_weights(params: Params):
+    """The head's (d_model, vocab) weights: `lm_head`, or the embedding's
+    transpose where the two are tied (one leaf; the transpose is the
+    product's dimension numbers, no copy)."""
+    if "lm_head" in params:
+        return params["lm_head"]
+    return params["embed"].T
 
 
 def linear(x: jnp.ndarray, w) -> jnp.ndarray:
@@ -201,16 +263,25 @@ def project_qkv(c: ModelConfig, x: jnp.ndarray, p: Params,
     q = linear(h, p["wq"]).reshape(b, s, c.n_heads, hd)
     k = linear(h, p["wk"]).reshape(b, s, c.n_kv_heads, hd)
     v = linear(h, p["wv"]).reshape(b, s, c.n_kv_heads, hd)
+    if not c.use_rope:
+        return q, k, v
     return _rope(q, positions, rope), _rope(k, positions, rope), v
 
 
-def scan_layers(c: ModelConfig, block, carry, xs):
+def scan_layers(c: ModelConfig, block, carry, xs, own=None):
     """`lax.scan` of `block(carry, x, kind) -> (carry, y)` over the leading
     (layer) axis of `xs`, one PERIOD of the model's layer pattern a scan
     step (config.layer_period): the `p` blocks of a period run in order
     inside the body, each traced with its own kind, block j of step t
     reading layer t * p + j of `xs`. A model of one kind is the plain scan
     over its layers. -> (carry, ys) with `ys` on the layer axis.
+
+    `own` maps a kind to what only the layers of that kind have (a stack
+    of mixer weights, a cache, an index), its leading axis as long as the
+    model has layers of that kind. The block of layer i then gets
+    `(x, own_x)`, `own_x` the entry of `own[kind]` at i's rank among the
+    layers of its kind, and `ys` comes back as a mapping kind -> the `y`s
+    of that kind's layers (a kind without layers has no entry).
 
     A block indexes the stack itself, one layer at a time, as a plain
     scan's body does: handed a whole period as the step's `xs`, XLA cuts
@@ -220,24 +291,41 @@ def scan_layers(c: ModelConfig, block, carry, xs):
     period = c.layer_period
     p = len(period)
     if p == 1:
+        if own is not None:
+            carry, ys = lax.scan(
+                lambda carry, x: block(carry, x, period[0]), carry,
+                (xs, own[period[0]]),
+            )
+            return carry, {period[0]: ys}
         return lax.scan(lambda carry, x: block(carry, x, period[0]), carry, xs)
     tree_map = jax.tree_util.tree_map
     n = jax.tree_util.tree_leaves(xs)[0].shape[0]
 
+    def at(tree, index):
+        # (the index is computed where it is read, leaf by leaf, as the
+        # programs of PR 31 were traced: tests/test_tpu_lowering.py)
+        return tree_map(
+            lambda a: lax.dynamic_index_in_dim(a, index(), keepdims=False), tree
+        )
+
     def body(carry, t):
-        ys = []
+        ys, by_kind = [], {kind: [] for kind in period}
         for j, kind in enumerate(period):
-            x = tree_map(
-                lambda a: lax.dynamic_index_in_dim(a, t * p + j, keepdims=False),
-                xs,
-            )
+            x = at(xs, lambda: t * p + j)
+            if own is not None:
+                rank = len(by_kind[kind])
+                x = (x, at(own[kind], lambda: t * period.count(kind) + rank))
             carry, y = block(carry, x, kind)
             ys.append(y)
-        return carry, tree_map(lambda *a: jnp.stack(a), *ys)
+            by_kind[kind].append(y)
+        stacked = lambda of: tree_map(lambda *a: jnp.stack(a), *of)
+        if own is None:
+            return carry, stacked(ys)
+        return carry, {kind: stacked(of) for kind, of in by_kind.items()}
 
     carry, ys = lax.scan(body, carry, jnp.arange(n // p, dtype=jnp.int32))
     return carry, tree_map(
-        lambda a: a.reshape((a.shape[0] * p,) + a.shape[2:]), ys
+        lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]), ys
     )
 
 
@@ -360,6 +448,92 @@ def mlp_block(c: ModelConfig, x: jnp.ndarray, p: Params) -> jnp.ndarray:
     return x + linear(gate * up, p["w_down"])
 
 
+def mamba_inputs(c: ModelConfig, x: jnp.ndarray, p: Params, tail0, n_valid):
+    """What a state-space mixer computes before its recurrence, from the
+    normed input `x` (B, S, d), the convolution's tail the rows come with
+    (B, K - 1, Di) and the rows' counts of valid tokens (B,) -> (u, z (B, S,
+    Di) in the activation dtype; delta (B, S, Di), B, C (B, S, N) float32;
+    the tail after the rows' last VALID token). delta is 0 past a row's
+    valid tokens: they move no state."""
+    s = x.shape[1]
+    di, n, r, k = c.d_inner, c.mamba_d_state, c.mamba_dt_rank, c.mamba_d_conv
+    f32 = jnp.float32
+    with jax.named_scope("mamba/proj"):
+        xz = linear(x, p["in_proj"])
+        xs, z = xz[..., :di], xz[..., di:]
+    with jax.named_scope("mamba/conv"):
+        padded = jnp.concatenate([tail0.astype(x.dtype), xs], axis=1)
+        conv = sum(
+            padded[:, j:j + s].astype(f32) * p["conv_w"][j].astype(f32)
+            for j in range(k)
+        ) + p["conv_b"].astype(f32)
+        u = jax.nn.silu(conv).astype(x.dtype)
+        # Input i sits at row i + K - 1 of `padded`: the last K - 1 valid
+        # ones are its rows n_valid .. n_valid + K - 2, reaching into the
+        # tail the row came with where it has fewer.
+        tail = jax.vmap(
+            lambda rows, at: lax.dynamic_slice_in_dim(rows, at, k - 1, axis=0)
+        )(padded, n_valid)
+    with jax.named_scope("mamba/proj"):
+        dbc = linear(u, p["x_proj"])
+        eps = c.norm_eps
+        dt = rms_norm(dbc[..., :r], p["dt_norm"], eps)
+        b_in = rms_norm(dbc[..., r:r + n], p["b_norm"], eps).astype(f32)
+        c_out = rms_norm(dbc[..., r + n:], p["c_norm"], eps).astype(f32)
+        delta = jax.nn.softplus(linear(dt, p["dt_proj"]).astype(f32) + p["dt_bias"])
+        valid = jnp.arange(s, dtype=jnp.int32)[None, :] < n_valid[:, None]
+        delta = jnp.where(valid[..., None], delta, 0.0)
+    return u, z, delta, b_in, c_out, tail
+
+
+def mamba_output(x_dtype, y, u, z, p: Params):
+    """The recurrence's y (B, S, Di) float32 -> the mixer's output: the
+    skip D u, the gate silu(z), the out projection."""
+    with jax.named_scope("mamba/proj"):
+        y = (y + p["D"] * u.astype(jnp.float32)) * jax.nn.silu(z.astype(jnp.float32))
+        return linear(y.astype(x_dtype), p["out_proj"])
+
+
+def mamba_mixer(c: ModelConfig, x: jnp.ndarray, p: Params, h0=None, tail0=None,
+                n_valid=None, scan_impl: str = "lax"):
+    """A state-space (Mamba-1, with Jamba's three inner norms) mixer on the
+    normed input `x` (B, S, d) -> (its output (B, S, d) before the
+    residual, h (B, N, Di) float32, tail (B, K - 1, Di)).
+
+    `h0` and `tail0` are the state the rows come with (zero: a sequence's
+    start). Only the first `n_valid` (B,) tokens of a row are the
+    sequence's (None: all): the rest move nothing — their delta is 0, and
+    the tail that comes back is the last K - 1 VALID inputs of the
+    convolution. A row with no valid token keeps its state bit for bit.
+
+    A = -exp(A_log), softplus and the recurrence are float32, as the
+    published implementation computes them; `h` stays float32 from token
+    to token (tests/test_state_space_model.py shows what bfloat16 costs).
+    `scan_impl` "pallas" runs a chunk of one row through the chunk kernel
+    (workloads/selective_scan.py; the decode step's kernel works on the
+    state POOL and is called from kv_blocks._layer_loop)."""
+    b, s, _ = x.shape
+    f32 = jnp.float32
+    if h0 is None:
+        h0 = jnp.zeros((b,) + c.state_shapes()[0], f32)
+    if tail0 is None:
+        tail0 = jnp.zeros((b,) + c.state_shapes()[1], x.dtype)
+    if n_valid is None:
+        n_valid = jnp.full((b,), s, jnp.int32)
+    u, z, delta, b_in, c_out, tail = mamba_inputs(c, x, p, tail0, n_valid)
+    with jax.named_scope("mamba/scan"):
+        a = -jnp.exp(p["A_log"].astype(f32))
+        if scan_impl == "pallas" and b == 1 and s % 8 == 0:
+            y, h = selective_scan_chunk(
+                delta[0], u[0].astype(f32), b_in[0], c_out[0], a, h0[0].astype(f32)
+            )
+            y, h = y[None], h[None]
+        else:
+            y, h = selective_scan(delta, u.astype(f32), b_in, c_out, a, h0.astype(f32))
+        h = jnp.where((n_valid > 0)[:, None, None], h, h0.astype(f32))
+    return mamba_output(x.dtype, y, u, z, p), h.astype(h0.dtype), tail
+
+
 def apply_remat(
     body, c: ModelConfig, n_tokens: int, mesh=None,
     seq_len: Optional[int] = None, attn_scores: bool = False,
@@ -397,6 +571,9 @@ def _block(
     (`router`): the leading dense layers of an expert model run the plain
     MLP."""
     b, s, _ = x.shape
+    if kind == MAMBA:
+        out, _, _ = mamba_mixer(c, rms_norm(x, p["attn_norm"], c.norm_eps), p)
+        return mlp_block(c, x + out, p), jnp.float32(0.0)
     if c.latent:
         q, row = project_latent(c, x, p, positions)
         k, v = expand_latent(c, row, p)
@@ -447,9 +624,13 @@ def forward(
     else:
         attn_scores = attn is plain_attention
 
+    own = mixer_stacks(params)
+
     def block(kind):
         def body(carry, layer_p):
             x, aux = carry
+            if own is not None:
+                layer_p = {**layer_p[0], **layer_p[1]}
             x, layer_aux = _block(c, x, layer_p, positions, attn, mesh, kind)
             return (x, aux + layer_aux), None
 
@@ -462,14 +643,15 @@ def forward(
     carry = (x, jnp.float32(0.0))
     for stack in layer_stacks(params):
         carry, _ = scan_layers(
-            c, lambda carry, p, kind: blocks[kind](carry, p), carry, stack
+            c, lambda carry, p, kind: blocks[kind](carry, p), carry, stack,
+            own=own,
         )
     x, aux = carry
 
     x = rms_norm(x, params["final_norm"], c.norm_eps)
     if return_hidden:
         return (x, aux) if return_aux else x
-    logits = logits_linear(x, params["lm_head"])
+    logits = logits_linear(x, head_weights(params))
     if return_aux:
         return logits, aux
     return logits

@@ -526,7 +526,19 @@ class Engine:
 
 def main() -> None:
     parser = argparse.ArgumentParser()
-    parser.add_argument("--preset", default="smol-1b", choices=sorted(PRESETS))
+    parser.add_argument(
+        "--preset", default="smol-1b", choices=sorted(PRESETS),
+        help="a ModelConfig of workloads/config.py. A preset with state-space"
+             " layers (`tiny-mamba`; ModelConfig's attn_layer_period,"
+             " attn_layer_offset, mamba_d_state, mamba_d_conv, mamba_expand,"
+             " mamba_dt_rank, use_rope, tie_embeddings: the `jamba` block) keeps"
+             " a recurrent state a slot beside the KV blocks: its engine runs"
+             " with prefix reuse off (/metrics `prefix_cache` says why) and"
+             " refuses, with a ValueError at start-up, --spec-enable,"
+             " --quantize int8, --lora-max-adapters, --mesh-model > 1, --role"
+             " prefill / decode and --kv-host-budget-mb (so"
+             " --max-resident-slots too): each would drop or have to roll"
+             " back the state")
     parser.add_argument("--layers", type=int, default=0,
                         help="serve the preset cut to this many layers"
                              " (0 = the preset's depth); must match the"

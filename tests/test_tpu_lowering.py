@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 from dstack_tpu.workloads import flash_attention as fa
 from dstack_tpu.workloads import kv_blocks
 from dstack_tpu.workloads import moe
+from dstack_tpu.workloads import selective_scan as scans
 from dstack_tpu.workloads.attention import make_attention_fn
 from dstack_tpu.workloads.config import FULL, PRESETS, SLIDING
 from dstack_tpu.workloads.paged_attention import (
@@ -146,6 +147,28 @@ def _kernels():
                 [((b, s, CELL_H, HD), bf16), cell_pool, cell_pool, ((), i32),
                  ((b, CELL_MB), i32), ((b, s), i32)],
             ))
+    # Twenty query heads on ONE KV head (the `jamba` block's two attention
+    # layers) at its cell's geometry, 128 slots x 18 blocks of 256: a decode
+    # row is a 20-row query tile, the 512-token chunk 32 tiles of 320 rows,
+    # the smallest chunk bucket one of 160.
+    one_kv = ((2, 128 * 18, 256, 1, HD), bf16)
+    for kind, b, s in [("decode", 128, 1), ("prefill", 1, 512), ("prefill", 1, 8)]:
+        out.append((
+            f"paged_20on1_{kind}_b{b}_s{s}", _ragged_attention_pallas,
+            [((b, s, 20, HD), bf16), one_kv, one_kv, ((), i32),
+             ((b, 18), i32), ((b, s), i32)],
+        ))
+    # The state-space recurrence at the same cell's sizes (26 layers of 128
+    # slots x 16 x 5,120 float32): the decode step's in-place update, and a
+    # chunk of one sequence at the longest and the shortest bucket.
+    f32, n, di = jnp.float32, 16, 5120
+    out.append(("selective_scan_decode", scans.selective_scan_decode, [
+        ((26, 128, n, di), f32), ((), i32), ((128,), jnp.bool_), ((128, di), f32),
+        ((128, di), f32), ((128, n), f32), ((128, n), f32), ((n, di), f32)]))
+    for s in (512, 8):
+        out.append((f"selective_scan_chunk_s{s}", scans.selective_scan_chunk, [
+            ((s, di), f32), ((s, di), f32), ((s, n), f32), ((s, n), f32),
+            ((n, di), f32), ((n, di), f32)]))
     # The trainer's shape (S=2048) and one ring step's shard.
     q, kv = ((2, 2048, H, HD), bf16), ((2, 2048, KV, HD), bf16)
     out.append(("flash_fwd", _flash_fwd, [q, kv, kv]))
@@ -369,6 +392,64 @@ def test_paged_program_moves_no_pool_or_slab(name, kind, v5e, no_compile_cache):
         assert compiled.memory_analysis().temp_size_in_bytes < one_pool
 
 
+# State-space layers beside attention layers (one period mmam, twice), 10
+# query heads on one KV head of 128, at widths that compile in seconds.
+MAMBA_CFG = CFG.with_(
+    d_model=1280, n_heads=10, n_kv_heads=1, d_ff=2048, n_layers=8, vocab_size=8192,
+    attn_layer_period=4, attn_layer_offset=2, mamba_dt_rank=64, use_rope=False,
+    tie_embeddings=True, remat=False,
+)
+
+
+@pytest.mark.parametrize("name,chunk", [
+    ("decode_steps", 0), ("chunk_prefill", 64),
+    # the shortest bucket: with no loop left around its recurrence XLA copied
+    # the whole pool a layer to write one slot's state back (PR 33)
+    ("chunk_prefill", 8),
+])
+def test_state_pool_is_updated_in_place(name, chunk, v5e, no_compile_cache, monkeypatch):
+    """The recurrent-state pool rides the layer loop as a carry, like the KV
+    pool: the optimized HLO of the decode and chunk programs copies no array
+    of the state pool's size or of one layer's share of it, and the program's
+    scratch is smaller than the pool (it is not held twice). The KV pool's
+    layer axis counts the attention layers only."""
+    if chunk:
+        monkeypatch.setitem(globals(), "CHUNK", chunk)
+    if v5e is not None:   # what a TPU backend would choose
+        monkeypatch.setattr(kv_blocks, "scan_impl", lambda n, di: "pallas")
+    fn, args = _paged_program(
+        name, MAMBA_CFG, "pallas" if v5e is not None else "lax_ragged"
+    )
+    st = args[1]
+    assert st.k.shape[0] == 2 and st.ssm.shape[:2] == (6, SLOTS)
+    assert st.ssm.dtype == jnp.float32 and st.conv.shape == (6, SLOTS, 3 * 2560)
+    if v5e is not None:
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), args
+        )
+    compiled = fn.lower(*args).compile()
+    sizes = {
+        math.prod(st.ssm.shape): "state pool",
+        math.prod(st.ssm.shape[1:]): "one layer of the state pool",
+    }
+    # (float32 arrays only: the state is the one float32 array of its size)
+    floats = [a for a in jax.tree.leaves(args[0]) if a.dtype == jnp.float32]
+    weights = {math.prod(a.shape) for a in floats}
+    weights |= {math.prod(a.shape[1:]) for a in floats}
+    assert not weights & set(sizes)
+    copied = [
+        f"copy of the {sizes[n]} [{m.group(1)}]"
+        for m in re.finditer(r"= f32\[([\d,]+)\]\S* copy\(", compiled.as_text())
+        if (n := math.prod(int(d) for d in m.group(1).split(","))) in sizes
+    ]
+    assert not copied, copied
+    if v5e is not None:
+        kernel = "selective_scan_" + ("chunk" if chunk else "decode")
+        assert "tpu_custom_call" in compiled.as_text() and kernel in compiled.as_text()
+        pool_bytes = 4 * math.prod(st.ssm.shape)
+        assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
 @pytest.mark.parametrize("axes", [{}, {"model": 2}], ids=["fsdp4", "model2"])
 def test_sharded_train_loss_lowers_for_tpu(axes, monkeypatch):
     """The train step's forward+backward on a multi-device mesh, with the
@@ -552,11 +633,19 @@ _PARENT_OF_PR31 = {
     ("tiny-moe", "chunk_prefill"): "f798bae2ba8ee94a",
     ("tiny-latent", "decode_steps"): "a3b2c76260615d85",
     ("tiny-latent", "chunk_prefill"): "ec059df1427815aa",
+    # PR 33 gave the decode state a recurrent-state pool, the layer loop a
+    # stack a kind of mixer and the head a tied form. A model without
+    # state-space layers has none of them (no leaf, no operand): the hashes
+    # above still hold, and the model of mixed attention layers traces what it
+    # traced at the parent of PR 33 (commit 1e1737b), hashed the same way.
+    ("tiny-window", "decode_steps"): "c112128a7170a2c1",
+    ("tiny-window", "chunk_prefill"): "664264e23acfa788",
 }
 
 
 @pytest.mark.parametrize("preset,name", sorted(_PARENT_OF_PR31))
 def test_programs_of_one_kind_of_layer_lower_as_before_pr31(preset, name, monkeypatch):
+    """(... and, since PR 33, programs of models without state-space layers.)"""
     monkeypatch.setitem(globals(), "SLOTS", 4)
     monkeypatch.setitem(globals(), "CHUNK", 32)
     cfg = PRESETS[preset]
