@@ -185,9 +185,9 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_kv_transfer_seconds": ("histogram", ("role",)),
     # The engine loop's own time (utils/flight_recorder.PhaseClock): host
     # wall seconds per phase (wait/admit/grow/dispatch/sync/barrier/
-    # fan_out, and admit's children as "admit/match"; "admit/shadow" is
-    # the part of admit spent behind a decode launch), whole cycles, and
-    # the cycles of a second or more.
+    # fan_out, and children as "admit/match"; "admit/shadow" and
+    # "fan_out/shadow" are the parts of admit and of fan_out spent behind
+    # a decode launch), whole cycles, and the cycles of a second or more.
     "dstack_tpu_serving_loop_cycles_total": ("counter", ()),
     "dstack_tpu_serving_loop_phase_seconds_total": ("counter", ("phase",)),
     "dstack_tpu_serving_loop_slow_cycles_total": ("counter", ()),

@@ -60,24 +60,31 @@ _TERMINAL = ("ok", "error", "shed", "cancelled")
 
 # Phases of one engine-loop iteration (the loop's counters, Prometheus
 # labels and `engine/<phase>` span names are generated from these). `wait`
-# is the only phase outside a cycle. A cycle with something live runs
-# admit -> grow -> dispatch -> admit -> sync -> barrier -> fan_out: `admit`
-# occurs twice and accumulates, and the phases still sum to the cycle.
+# is the only phase outside a cycle. A cycle with something live launches
+# and settles chunk N and hands out N-1's tokens:
+# admit -> grow -> dispatch N -> barrier -> fan_out (N-1's tokens) -> admit
+# -> grow -> sync N -> fan_out (N settled): `admit`, `grow` and `fan_out`
+# occur twice and accumulate, and the phases still sum to the cycle.
 LOOP_PHASES = (
     "wait",      # nothing to do (or a starved pool's 1 ms sleep): device idle, rightly
     "admit",     # before grow (readmit / preempt / adopt / leftover chunks): device drained;
                  # again behind dispatch (the shadow: admission, prefill chunks): hidden
-    "grow",      # decode-block growth; a launched prefill chunk runs meanwhile
-    "dispatch",  # rng split + decode launch (spec: draft launch -> verify launch)
+    "grow",      # decode-block growth: what is left of it, and a starved slot's fate, before
+                 # dispatch; again behind it, ahead for the next chunk (tables, its key): hidden
+    "dispatch",  # decode launch (spec: draft launch -> verify launch)
     "sync",      # device_get: the host is blocked on a busy device
-    "barrier",   # first-token order barrier: device idle, unless a shadow chunk still runs
-    "fan_out",   # tokens to consumers, retire slots: likewise
+    "barrier",   # first-token order barrier, ahead of a delivery: behind the launch, hidden
+    "fan_out",   # after the sync, the settlement (lengths, ended slots freed): device drained;
+                 # behind the next launch, the last chunk's tokens to consumers: hidden
 )
 # Nested work inside a phase, as "<phase>/<child>". `admit/shadow` is the
 # part of `admit` spent while a decode chunk was in flight (it holds the
 # other three where they ran there): host time the device does not wait for.
+# `fan_out/shadow` is the delivery of a chunk's tokens behind the next
+# chunk's launch; what is left of `fan_out` is the settlement, and the
+# delivery of a chunk after which nothing was live to launch.
 LOOP_CHILDREN = ("admit/match", "admit/chunk_args", "admit/chunk_launch",
-                 "admit/shadow")
+                 "admit/shadow", "fan_out/shadow")
 # A cycle this long is a stall (the longest healthy cycle on the benchmark
 # ledger is ~0.27 s): counted, and the last few kept whole.
 SLOW_CYCLE_SECONDS = 1.0

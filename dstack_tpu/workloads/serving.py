@@ -713,15 +713,15 @@ class ServingEngine:
         # confirmed delivered yet — the loop waits on these after each
         # decode sync so decode tokens never overtake the first token.
         self._pending_activation: List[_PrefillTask] = []
-        # The decode chunk between its dispatch and its fan-out (None while
-        # host and device agree): the request in each slot it decodes, the
-        # slots sure to end in it (a shadow admission may take them), the
-        # tasks whose first token its tokens must not overtake, and the
-        # tasks finalized into an ending slot, which go live after fan-out.
-        self._chunk_live: Optional[List[Optional[_Request]]] = None
-        self._chunk_ending: set = set()
-        self._chunk_activations: List[_PrefillTask] = []
-        self._chunk_heirs: List[_PrefillTask] = []
+        # The decode chunk in flight, one record from its launch to its
+        # settlement (`_Chunk`, below the engine; None while host and device
+        # agree), and the last settled chunk until its tokens are handed
+        # out, which happens behind the NEXT launch: two records at once.
+        self._chunk: Optional["_Chunk"] = None
+        self._settled: Optional["_Chunk"] = None
+        # Keys split ahead, in a launch's shadow, for the next launch: the
+        # chains `_rng` / `_rng_draft` are consumed in the order they were.
+        self._key_ahead, self._draft_key_ahead = None, None
         self._shadow_spent = 0  # prompt tokens the last shadow launched
         self._deliver_q: "queue.Queue[Optional[_PrefillTask]]" = queue.Queue()
         # Output queues whose consumer is gone (client disconnect, stop
@@ -770,10 +770,10 @@ class ServingEngine:
         self._kv_transfer_bytes = 0
         self._kv_transfer_hist = HistogramData()
         # Decode time per emitted token, sampled once per chunk/spec
-        # round (chunk wall time / tokens it emitted) — the TPT series
-        # behind the disaggregation bench's decode-isolation check.
+        # round (the chunk's wall time from launch to read-back over the
+        # tokens it emitted) — the TPT series behind the disaggregation
+        # bench's decode-isolation check.
         self._tpt_hist = HistogramData()
-        self._last_chunk_s = 0.0
         self._gather_fns: Dict[int, Any] = {}
         self._inject_fns: Dict[Tuple[int, bool], Any] = {}
         self._place_slot_fn: Optional[Any] = None
@@ -1674,6 +1674,11 @@ class ServingEngine:
         failure is delivered as the exception itself, NOT the clean-end
         None — partial output must not read as success."""
         sentinel: object = error if error is not None else None
+        # A settled chunk's tokens are whole and its ended slots are
+        # nobody's any more: hand them out before anything is cut short.
+        chunk, self._settled, self._chunk = self._settled, None, None
+        if chunk is not None:
+            self._hand_out(chunk)
         with self._lock:
             self._cancelled.clear()
             self._inflight.clear()
@@ -1696,8 +1701,6 @@ class ServingEngine:
             self._admitting.clear()
             self._tasks.clear()
             self._pending_activation.clear()
-            self._chunk_activations.clear()
-            self._chunk_heirs.clear()
             # Swapped-out slots and the admission peek buffer hold
             # consumers too (their requests are neither pending nor live).
             for sw in self._swapped:
@@ -1849,14 +1852,16 @@ class ServingEngine:
         and flips the slot live on device; the reader thread picks the
         token up the moment its readback lands. Safe while a decode
         chunk is in flight: it takes free slots, and slots that chunk
-        is sure to end (`_chunk_ending`; the host side of such a slot
-        changes hands after the chunk's fan-out, `_chunk_heirs`), and
+        is sure to end (`_Chunk.ending`; the host side of such a slot
+        changes hands when the chunk is settled, `_Chunk.heirs`), and
         moves no live slot (`_try_queue_jump` refuses until the
         boundary). Returns
         (anything moved: admission, dispatch or cancel processing;
         prompt tokens launched)."""
         progressed = False
         offered = budget
+        in_flight = self._chunk
+        ending = in_flight.ending if in_flight is not None else set()
         # Admit new requests into the task window (unless a gang-
         # synchronous caller is holding admission to batch a round of
         # submits into one wave; in-flight tasks keep dispatching).
@@ -1892,7 +1897,6 @@ class ServingEngine:
                 # decode chunk in flight is as good as free: what is
                 # launched for it now runs on the device after that
                 # chunk (empty slots are taken first).
-                ending = self._chunk_ending
                 live_n = sum(r is not None and s not in ending
                              for s, r in enumerate(self._live))
                 if live_n + len(busy) >= self._max_resident:
@@ -1951,7 +1955,7 @@ class ServingEngine:
             span = {"request_id": _span_id(task.req), "tokens": n}
             lora = self._lora is not None and task.req.adapter_ix >= 0
             with self._clock.child("chunk_args", **span):
-                self._rng, sub = jax.random.split(self._rng)
+                sub = self._next_key()
                 chunk_args = (
                     jnp.asarray(task.slot, jnp.int32),
                     jnp.asarray(self._pad_table(task.table), jnp.int32),
@@ -1966,7 +1970,7 @@ class ServingEngine:
                 if lora:
                     adapter_arg = jnp.asarray(task.req.adapter_ix, jnp.int32)
                 if self._spec:
-                    self._rng_draft, dsub = jax.random.split(self._rng_draft)
+                    dsub = self._next_draft_key()
             with self._clock.child("chunk_launch", **span):
                 if lora:
                     # Target-only: the drafter below never applies LoRA.
@@ -2018,12 +2022,12 @@ class ServingEngine:
                         namespace=(task.req.adapter or "").encode(),
                     )
                     if task.req.max_new_tokens > 1 and not handoff:
-                        if task.slot in self._chunk_ending:
-                            # The slot's last request is still the chunk
-                            # in flight's to deliver: wait for its fan-out
+                        if task.slot in ending:
+                            # The slot is its last request's until the
+                            # chunk in flight is settled: wait for that
                             # (and offer the slot to nobody else).
-                            self._chunk_ending.remove(task.slot)
-                            self._chunk_heirs.append(task)
+                            ending.remove(task.slot)
+                            in_flight.heirs.append(task)
                         else:
                             self._go_live(task)
                     # One-token requests never go live: their budget is
@@ -2067,12 +2071,51 @@ class ServingEngine:
         would otherwise only block in `device_get`; they queue on the
         device behind the chunk, so it is not drained while they are
         built. Spends the cycle's budget first; the boundary after
-        fan-out gets what is left."""
+        the settlement gets what is left."""
         self._clock.mark("admit")
         with self._clock.child("shadow"):
             _, self._shadow_spent = self._advance_prefills(
                 self.prefill_chunk_tokens
             )
+
+    def _in_shadow(self, chunk: "_Chunk") -> None:
+        """The host's work behind the launch of `chunk`, none of which
+        the device waits for: the last chunk's tokens to their consumers
+        (whose threads wake here, not between a sync and a launch), the
+        cycle's admission and prefill chunks, then what the NEXT launch
+        would otherwise do with the device drained: its key, and table
+        rows for what it can write at most."""
+        self._deliver()
+        self._admit_in_shadow()
+        self._clock.mark("grow")
+        self._grow_ahead(chunk)
+        self._split_ahead()
+
+    def _split_ahead(self) -> None:
+        """The next launch's key(s), now: whatever that launch is, it
+        takes the next split of the chain, as it did when it split at
+        the launch, so sampled streams are what they were."""
+        if self._key_ahead is None:
+            self._rng, self._key_ahead = jax.random.split(self._rng)
+        if self._spec and self._draft_key_ahead is None:
+            self._rng_draft, self._draft_key_ahead = jax.random.split(
+                self._rng_draft)
+
+    def _next_key(self):
+        """The key of the launch about to be made: one split of the
+        chain a launch, whatever its kind, split ahead in the last
+        launch's shadow where there was one."""
+        sub, self._key_ahead = self._key_ahead, None
+        if sub is None:
+            self._rng, sub = jax.random.split(self._rng)
+        return sub
+
+    def _next_draft_key(self):
+        """`_next_key` of the drafter's chain."""
+        sub, self._draft_key_ahead = self._draft_key_ahead, None
+        if sub is None:
+            self._rng_draft, sub = jax.random.split(self._rng_draft)
+        return sub
 
     def _deliver_loop(self) -> None:
         """Reader thread: blocks on each finalized prefill's first-token
@@ -2745,10 +2788,10 @@ class ServingEngine:
         the longest-resident one is taken."""
         if self._host_tier is None or not self._qos_weights:
             return False
-        if self._chunk_live is not None:
+        if self._chunk is not None:
             # A decode chunk is in flight: its slots' lengths and tokens
             # are not the host's yet. The request parks; the boundary
-            # after the fan-out asks again.
+            # after its settlement asks again.
             return False
         w = self._weight(req)
         victim: Optional[int] = None
@@ -2901,15 +2944,51 @@ class ServingEngine:
                 continue
             if grew:
                 updates[slot] = self._pad_table(table)
-        if updates:
-            bt = self.state.block_tables
-            for s in sorted(updates):
-                bt = self._set_table_row(
-                    bt,
-                    jnp.asarray(s, jnp.int32),
-                    jnp.asarray(updates[s], jnp.int32),
-                )
-            self.state = self.state._replace(block_tables=bt)
+        self._push_table_rows(updates)
+
+    def _grow_ahead(self, chunk: "_Chunk") -> None:
+        """In `chunk`'s shadow, before its emissions are known: table
+        rows for what the NEXT chunk can write at most (a slot in `chunk`
+        may be a whole chunk further on by then, and no request writes
+        past its budget), from blocks the pool gives. The boundary's
+        `_ensure_decode_blocks` then usually finds nothing to do; it
+        alone decides what happens to a slot the pool cannot feed."""
+        bs = self._block_size
+        updates: Dict[int, List[int]] = {}
+        dry = False
+        for slot, req in enumerate(self._live):
+            table = self._slot_tables[slot]
+            if req is None or table is None or dry:
+                continue
+            rows = self._lengths_host[slot] + chunk.steps * (
+                2 if chunk.live[slot] is not None else 1)
+            rows = min(rows, len(req.tokens) + req.max_new_tokens - 1)
+            need = min((rows - 1) // bs + 1, self._max_blocks)
+            had = len(table)
+            while len(table) < need and not dry:
+                with self._lock:
+                    b = self._alloc.alloc()
+                if b is None:
+                    dry = True  # the boundary's to settle
+                else:
+                    table.append(b)
+            if len(table) > had:
+                updates[slot] = self._pad_table(table)
+        self._push_table_rows(updates)
+
+    def _push_table_rows(self, updates: Dict[int, List[int]]) -> None:
+        """Write grown slots' (padded) table rows into the device state:
+        one launch of the one compiled row update a slot."""
+        if not updates:
+            return
+        bt = self.state.block_tables
+        for s in sorted(updates):
+            bt = self._set_table_row(
+                bt,
+                jnp.asarray(s, jnp.int32),
+                jnp.asarray(updates[s], jnp.int32),
+            )
+        self.state = self.state._replace(block_tables=bt)
 
     def _ensure_spec_writable(self, k: int) -> None:
         """Copy-on-write pass over each live slot's speculation write
@@ -2956,17 +3035,10 @@ class ServingEngine:
                     )
                     table[idx] = b
                     updates[slot] = self._pad_table(table)
-        if updates:
-            bt = self.state.block_tables
-            for s in sorted(updates):
-                bt = self._set_table_row(
-                    bt,
-                    jnp.asarray(s, jnp.int32),
-                    jnp.asarray(updates[s], jnp.int32),
-                )
-            self.state = self.state._replace(block_tables=bt)
+        self._push_table_rows(updates)
 
     def _force_retire(self, slot: int, error: BaseException) -> None:
+        self._deliver()  # the error comes after the tokens it follows
         req = self._live[slot]
         with self._lock:
             self._live[slot] = None
@@ -3018,28 +3090,42 @@ class ServingEngine:
     # -- loop ----------------------------------------------------------------
 
     def _loop(self) -> None:
-        """The engine thread, software-pipelined by one stage. A cycle
-        with something live runs, in order:
+        """The engine thread, software-pipelined by one stage at each end
+        of a decode chunk: of a cycle's host work only what needs the
+        last chunk's outcome stands between its sync and the next
+        launch. A cycle with something live launches and settles chunk
+        N, and hands out N-1's tokens:
 
-        admit     what must know the last chunk's outcome (readmit,
-                  preempt, adopt: they read `_lengths_host` or move live
-                  slots) and the prefill chunks the last shadow left
-                  budget for;
-        grow      block provisioning for decode chunk N;
-        dispatch  launch N (async);
-        admit     in N's shadow: admit requests into free slots and
-                  into slots whose budget N exhausts, build and launch
-                  the cycle's prefill chunks, which queue on the device
-                  behind N;
+        admit     what must know N-1's outcome, settled at the end of
+                  the last cycle (readmit, preempt, adopt: they read
+                  `_lengths_host` or move live slots) and the prefill
+                  chunks the last shadow left budget for;
+        grow      block provisioning for N: what the last shadow could
+                  not grow ahead, and what happens to a starved slot;
+        dispatch  launch N (async), with the key split ahead;
+        barrier   in N's shadow from here to the sync: first tokens of
+                  the slots in N-1, then
+        fan_out   N-1's tokens and clean ends to their consumers (child
+                  `shadow`: their threads wake behind a launch);
+        admit     admit requests into free slots and into slots whose
+                  budget N exhausts, build and launch the cycle's
+                  prefill chunks, which queue on the device behind N;
+        grow      ahead, for N+1: table rows for what it can write at
+                  most, and its key;
         sync      read N back;
-        barrier   first tokens of the slots in N, then
-        fan_out   N's tokens, over the slots that were live at dispatch;
-                  a prefill finalized into a slot N ended goes live.
+        fan_out   settle N: lengths, cancelled and ended slots freed
+                  (before their last tokens are handed out), a prefill
+                  finalized into a slot N ended goes live. If nothing
+                  is left live no launch will follow, and N's tokens go
+                  out here (barrier, fan_out with no child).
 
         The device sees decode N, chunk(s), decode N+1 with no drain
-        wherever a chunk rides, and the host's fan_out -> grow ->
-        dispatch of N+1 runs while that chunk executes. With nothing
-        live, admission runs alone."""
+        wherever a chunk rides, and where none does it waits for the
+        sync's wake-up, the settlement and one launch. With nothing
+        live, admission runs alone. A speculation round is a chunk
+        (`_spec_round`); LoRA banks, the host tier's readmit / preempt,
+        the decode role's adoption and state-space slots ride the same
+        order: they act at the boundary, on settled slots."""
         clock = self._clock
         while not self._stop:
             try:
@@ -3096,48 +3182,20 @@ class ServingEngine:
                     self._advance_prefills(left)
                 self._admit_prefilled()
                 clock.mark("grow")
-                spec_now = self._spec and self._spec_cooldown == 0
-                if spec_now:
-                    toks, still = self._spec_round()
-                    if toks is None:
+                # 2) Launch the chunk (async), do everything that does not
+                #    need its outcome in its shadow, sync on it.
+                if self._spec and self._spec_cooldown == 0:
+                    chunk = self._spec_round()
+                    if chunk is None:
+                        self._deliver()
                         clock.end()
                         continue  # every slot force-retired mid-round
                 else:
-                    self._ensure_decode_blocks()
-                    clock.mark("dispatch")
-                    # 2) Dispatch the decode chunk (async), admit in its
-                    #    shadow, sync on it.
-                    self._begin_chunk(self._steps_per_sync,
-                                      self._steps_per_sync)
-                    self._rng, sub = jax.random.split(self._rng)
-                    if self._lora is not None and self._lora.inflight > 0:
-                        self.state, tokens, active = self._step(
-                            self.params, self.state, sub, self._lora.bank
-                        )
-                    else:
-                        self.state, tokens, active = self._step_base(
-                            self.params, self.state, sub
-                        )
-                    self._attn_dispatch[self._attn_path] += 1
-                    self._admit_in_shadow()
-                    clock.mark("sync")
-                    toks = jax.device_get(tokens)  # (B, steps_per_sync)
-                    still = jax.device_get(active)
-                    clock.mark("barrier")
-                    self._observe_chunk_seconds()
-                    if self._spec and self._spec_cooldown > 0:
-                        self._spec_fallback_rounds += 1
-                        self._spec_cooldown -= 1
-                        if self._spec_cooldown == 0:
-                            # Re-probe cautiously: shortest drafts,
-                            # fresh acceptance estimates.
-                            self._slot_k = [1] * self.slots
-                            self._accept_ewma = [None] * self.slots
-                            self._spec_low_streak = 0
-                # 3) First-token order barrier, then fan out the chunk.
-                self._wait_activations(self._chunk_activations)
-                clock.mark("fan_out")
-                self._fan_out(toks, still)
+                    chunk = self._decode_chunk()
+                # 3) Settle it. Its tokens go out behind the next launch.
+                self._settle(chunk)
+                if not any(r is not None for r in self._live):
+                    self._deliver()
                 clock.end()
             except Exception as e:  # device/compile error: fail loudly, not
                 # by wedging every consumer on a dead queue.
@@ -3161,23 +3219,58 @@ class ServingEngine:
                 return
         clock.close()
 
-    def _begin_chunk(self, steps: int, sure: int) -> None:
+    def _decode_chunk(self) -> "_Chunk":
+        """One decode chunk of `steps_per_sync` steps, from the cycle's
+        `grow` phase to its read-back (phase `fan_out` open)."""
+        clock = self._clock
+        self._ensure_decode_blocks()
+        chunk = self._begin_chunk(self._steps_per_sync, self._steps_per_sync,
+                                  clock.mark("dispatch"))
+        sub = self._next_key()
+        if self._lora is not None and self._lora.inflight > 0:
+            self.state, tokens, active = self._step(
+                self.params, self.state, sub, self._lora.bank
+            )
+        else:
+            self.state, tokens, active = self._step_base(
+                self.params, self.state, sub
+            )
+        self._attn_dispatch[self._attn_path] += 1
+        self._in_shadow(chunk)
+        clock.mark("sync")
+        chunk.toks = jax.device_get(tokens)  # (B, steps_per_sync)
+        chunk.still = jax.device_get(active)
+        self._observe_chunk_seconds(chunk, clock.mark("fan_out"))
+        if self._spec and self._spec_cooldown > 0:
+            self._spec_fallback_rounds += 1
+            self._spec_cooldown -= 1
+            if self._spec_cooldown == 0:
+                # Re-probe cautiously: shortest drafts,
+                # fresh acceptance estimates.
+                self._slot_k = [1] * self.slots
+                self._accept_ewma = [None] * self.slots
+                self._spec_low_streak = 0
+        return chunk
+
+    def _begin_chunk(self, steps: int, sure: int, t_launch: int) -> "_Chunk":
         """A decode chunk (or speculation round) of `steps` steps is
         about to launch: freeze who is in it, and count it. What goes
-        live or finalizes from here to its fan-out (the shadow) is the
-        next chunk's. A slot within `sure` tokens of its budget's end
+        live or finalizes from here to its settlement (the shadow) is
+        the next chunk's. A slot within `sure` tokens of its budget's end
         (what the launch emits at the least for a live slot) ends in
         this chunk whatever happens (the stop rules only end it sooner),
         so the shadow may admit into it."""
-        self._chunk_live = list(self._live)
-        self._chunk_ending = {
+        ending = {
             slot for slot, req in enumerate(self._live)
             if req is not None and req.max_new_tokens - 1 - (
                 self._lengths_host[slot] - len(req.tokens)) <= sure
         }
-        self._chunk_activations = self._pending_activation
+        chunk = self._chunk = _Chunk(
+            steps, list(self._live), ending, self._pending_activation,
+            t_launch)
         self._pending_activation = []
         self._count_decode_launch(steps)
+        return chunk
 
     def _count_decode_launch(self, steps: int) -> None:
         """One decode chunk (or speculation round) of `steps` steps is
@@ -3241,23 +3334,22 @@ class ServingEngine:
         self._moe_computed_slots += layers * slots
         self._moe_routed_launches += routed
 
-    def _observe_chunk_seconds(self) -> None:
+    def _observe_chunk_seconds(self, chunk: "_Chunk", t_read: int) -> None:
         """Launch-to-readback wall time of the chunk whose `sync` phase
-        just closed, the admission in its shadow included (the cadence
-        gauges and the TPT series read it)."""
-        self._last_chunk_s = self._clock.cycle_seconds(
-            "dispatch", "admit/shadow", "sync")
-        self._chunk_s = self._ewma(self._chunk_s, self._last_chunk_s)
+        closed at `t_read`, the host's work in its shadow included (the
+        cadence gauges and the TPT series read it)."""
+        chunk.seconds = (t_read - chunk.t_launch) / 1e9
+        self._chunk_s = self._ewma(self._chunk_s, chunk.seconds)
 
-    def _spec_round(self):
+    def _spec_round(self) -> Optional["_Chunk"]:
         """One speculation boundary: drafter proposes k tokens per
         slot, the target verifies all k+1 positions in one forward, and
         the host adapts per-slot draft lengths from what survived.
-        Entered in the cycle's `grow` phase, left in `barrier`; the
-        shadow admission runs behind the verify launch. Returns
-        (toks, still) shaped exactly like a decode chunk (toks (B, k+1)
-        with -1 padding) so the fan-out is shared, or (None, None) when
-        no slot survived block provisioning."""
+        Entered in the cycle's `grow` phase, left in `fan_out` like a
+        decode chunk; the shadow runs behind the verify launch. Returns
+        the round as a chunk (toks (B, k+1) with -1 padding) so the
+        settlement and the delivery are shared, or None when no slot
+        survived block provisioning."""
         clock = self._clock
         k_cur = max(
             (self._slot_k[s] for s in range(self.slots)
@@ -3267,11 +3359,12 @@ class ServingEngine:
         self._ensure_decode_blocks(k_cur + 1)
         self._ensure_spec_writable(k_cur)
         if not any(r is not None for r in self._live):
-            return None, None
+            return None
         t_pf = clock.mark("dispatch")
-        self._begin_chunk(k_cur + 1, 1)  # a round emits one token at least
-        self._rng_draft, dsub = jax.random.split(self._rng_draft)
-        self._rng, vsub = jax.random.split(self._rng)
+        # A round emits one token at least.
+        chunk = self._begin_chunk(k_cur + 1, 1, t_pf)
+        dsub = self._next_draft_key()
+        vsub = self._next_key()
         dk, dv, drafts, qlogits = self._spec_draft_fn(k_cur)(
             self._draft_params, self._draft_state.k, self._draft_state.v,
             self.state.block_tables, self.state.lengths,
@@ -3290,22 +3383,22 @@ class ServingEngine:
             self.state, emitted, accepted, active = self._spec_verify_fn(
                 k_cur
             )(self.params, self.state, drafts, qlogits, vsub)
-        self._admit_in_shadow()
+        self._in_shadow(chunk)
         clock.mark("sync")
-        toks = jax.device_get(emitted)     # (B, k_cur + 1), -1 padded
-        still = jax.device_get(active)
+        toks = chunk.toks = jax.device_get(emitted)  # (B, k_cur + 1), -1 padded
+        chunk.still = jax.device_get(active)
         acc = jax.device_get(accepted)
-        t_sync = clock.mark("barrier")
+        t_sync = clock.mark("fan_out")
         self._attn_dispatch[self._draft_attn_path] += 1
         self._attn_dispatch[self._attn_path] += 1
-        self._observe_chunk_seconds()
+        self._observe_chunk_seconds(chunk, t_sync)
         self._t_spec_draft += (t_draft - t_pf) / 1e9
         self._t_spec_verify += (t_sync - t_draft) / 1e9
         # Acceptance bookkeeping + per-slot draft-length adaptation.
         self._spec_rounds += 1
         live_rates = []
         n_round_tokens = 0
-        for slot, req in enumerate(self._chunk_live):
+        for slot, req in enumerate(chunk.live):
             if req is None:
                 continue
             a = int(acc[slot])
@@ -3347,98 +3440,156 @@ class ServingEngine:
                     self._spec_cooldown = 50
             else:
                 self._spec_low_streak = 0
-        return toks, still
+        return chunk
 
-    def _fan_out(self, toks, still) -> None:
-        """Deliver one chunk's tokens (decode or speculation round —
-        rows are -1-padded past each slot's emissions) and retire slots
-        that finished or were cancelled. Walks the slots that were live
-        when the chunk was dispatched: one that went live in its shadow
-        has a padding row and `still` false here, and is the next
-        chunk's to deliver and to retire."""
+    def _settle(self, chunk: "_Chunk") -> None:
+        """Make the host agree with the device after `chunk` (a decode
+        chunk or speculation round, read back: rows of `toks` are
+        -1-padded past each slot's emissions): lengths, the slots that
+        ended or were cancelled freed, the chunk's heirs live — all that
+        the boundary's admission, `grow` and the next launch read, and
+        nothing else. Walks the slots that were live when the chunk was
+        dispatched: one that went live in its shadow has a padding row
+        and `still` false here, and is the next chunk's. The tokens go
+        out later (`_deliver`), behind the next launch: a slot is free
+        before its last tokens and clean end are delivered, so a client
+        that sees its stream finish and resubmits at once finds the
+        capacity it released (max_pending=0 semantics)."""
         with self._lock:
             cancelled = set(self._cancelled)
-        chunk_live, self._chunk_live = self._chunk_live, None
-        self._chunk_ending = set()
-        taken = [t.slot for t in self._tasks + self._chunk_heirs
-                 if chunk_live[t.slot] is not None and still[t.slot]]
+        self._chunk = None
+        rows = chunk.rows = chunk.toks.tolist()
+        still = chunk.still.tolist()
+        taken = [t.slot for t in self._tasks + chunk.heirs
+                 if chunk.live[t.slot] is not None and still[t.slot]]
         if taken:
             raise RuntimeError(
                 f"slots {taken} were admitted into as ending with the"
                 " chunk in flight, and are still decoding"
             )
-        total_emitted = 0
-        for slot, req in enumerate(chunk_live):
+        lengths = self._lengths_host
+        for slot, req in enumerate(chunk.live):
             if req is None:
                 continue
-            n_emitted = int((toks[slot] >= 0).sum())
-            self._lengths_host[slot] += n_emitted
-            total_emitted += n_emitted
+            n_emitted = len(rows[slot]) - rows[slot].count(-1)
+            lengths[slot] += n_emitted
+            chunk.emitted += n_emitted
+            gone = req.out in cancelled
+            if still[slot] and not gone:
+                continue
+            # Free the slot under the submit lock. cancel() racing
+            # normal completion must not leave a stale entry behind.
+            with self._lock:
+                self._live[slot] = None
+                self._cancelled.discard(req.out)
+                self._inflight.discard(req.out)
+                self._release_slot_blocks(
+                    slot, cache_tail=True, prompt=req.tokens,
+                    namespace=(req.adapter or "").encode(),
+                )
+                self._release_adapter(req.out)
+            if not gone:
+                chunk.ended[slot] = self._slot_t0[slot]
+                continue
+            # The consumer is gone: nobody reads the chunk's tokens.
+            chunk.live[slot] = None
+            if still[slot]:
+                # (One that ended anyway is retired on the device,
+                # and its slot may already be its heir's there.)
+                self.state = self._retire(slot)
+            if req.trace is not None:
+                req.trace.decode_steps += 1
+                req.trace.decode_tokens += n_emitted
+            self.recorder.finish(req.trace, "cancelled")
+            req.out.put(None)
+        if chunk.heirs:
+            with self._lock:
+                for task in chunk.heirs:
+                    self._go_live(task)
+            chunk.heirs.clear()
+        self._decode_tokens += chunk.emitted
+        self._settled = chunk
+
+    def _deliver(self) -> None:
+        """Hand the settled chunk's tokens to their consumers, after the
+        first tokens they follow. With a decode launch in flight (the
+        usual case: `_in_shadow`) the time is the child `fan_out/shadow`;
+        with none (nothing left live, a round nothing survived, a
+        force-retire at the boundary) the device waits for it."""
+        chunk, self._settled = self._settled, None
+        if chunk is None:
+            return
+        clock = self._clock
+        clock.mark("barrier")
+        self._wait_activations(chunk.activations)
+        clock.mark("fan_out")
+        if self._chunk is not None:
+            with clock.child("shadow"):
+                self._hand_out(chunk)
+        else:
+            self._hand_out(chunk)
+
+    def _hand_out(self, chunk: "_Chunk") -> None:
+        """A settled chunk's tokens into the queues of the requests it
+        decoded, and the clean end behind the last tokens of those that
+        ended in it."""
+        for slot, req in enumerate(chunk.live):
+            if req is None:
+                continue
+            n_emitted = 0
+            for tok in chunk.rows[slot]:
+                if tok >= 0:
+                    req.out.put(tok)
+                    n_emitted += 1
             if req.trace is not None:
                 # Hot-path bookkeeping is attribute increments on the
                 # preallocated trace slot — no allocation per chunk.
                 req.trace.decode_steps += 1
                 req.trace.decode_tokens += n_emitted
-            if req.out in cancelled:
-                # consumer is gone: free the slot now, skip the
-                # chunk's tokens (nobody reads them)
-                with self._lock:
-                    self._cancelled.discard(req.out)
-                    self._inflight.discard(req.out)
-                    self._live[slot] = None
-                    self._release_slot_blocks(
-                        slot, cache_tail=True, prompt=req.tokens,
-                        namespace=(req.adapter or "").encode(),
-                    )
-                    self._release_adapter(req.out)
-                if still[slot]:
-                    # (One that ended anyway is retired on the device,
-                    # and its slot may already be its heir's there.)
-                    self.state = self._retire(slot)
-                self.recorder.finish(req.trace, "cancelled")
-                req.out.put(None)
-                continue
-            if not still[slot]:
-                # Free the slot (under the submit lock) BEFORE
-                # delivering the final tokens + clean end: a
-                # client that sees its stream finish and
-                # immediately resubmits must find the capacity
-                # it just released (max_pending=0 semantics).
-                with self._lock:
-                    self._live[slot] = None
-                    # cancel() racing normal completion must not
-                    # leave a stale entry behind
-                    self._cancelled.discard(req.out)
-                    self._inflight.discard(req.out)
-                    self._release_slot_blocks(
-                        slot, cache_tail=True, prompt=req.tokens,
-                        namespace=(req.adapter or "").encode(),
-                    )
-                    self._release_adapter(req.out)
-                for tok in toks[slot]:
-                    if tok >= 0:
-                        req.out.put(int(tok))
+            if slot in chunk.ended:
                 t_done = time.monotonic()
                 self.recorder.finish(req.trace, "ok", t_done)
                 req.out.put(None)
                 self._turn_s = self._ewma(
-                    self._turn_s, t_done - self._slot_t0[slot],
+                    self._turn_s, t_done - chunk.ended[slot],
                 )
-                continue
-            for tok in toks[slot]:
-                if tok >= 0:
-                    req.out.put(int(tok))
-        if self._chunk_heirs:
-            with self._lock:
-                for task in self._chunk_heirs:
-                    self._go_live(task)
-            self._chunk_heirs.clear()
-        self._decode_tokens += total_emitted
-        if total_emitted:
+        if chunk.emitted:
             # One TPT sample per chunk: decode wall time amortized over
             # the tokens it emitted (the decode-isolation measurement
             # the disaggregation bench reads, labeled by engine role).
-            self._tpt_hist.observe(self._last_chunk_s / total_emitted)
+            self._tpt_hist.observe(chunk.seconds / chunk.emitted)
+
+
+class _Chunk:
+    """One decode chunk (or speculation round) from its launch to the
+    delivery of its tokens (loop thread only). `_begin_chunk` freezes who
+    is in it, the read-back adds what it emitted, `_settle` frees what
+    ended and `_deliver` hands the tokens out behind the NEXT chunk's
+    launch, when that chunk's record is already open: hence an object."""
+
+    __slots__ = ("steps", "live", "ending", "activations", "heirs", "t_launch",
+                 "toks", "still", "rows", "seconds", "emitted", "ended")
+
+    def __init__(self, steps: int, live: List[Optional[_Request]],
+                 ending: set, activations: List[_PrefillTask], t_launch: int):
+        self.steps = steps
+        # The request in each slot at the launch (None: not in the chunk;
+        # the settlement also clears a cancelled one: nothing to deliver).
+        self.live = live
+        # Slots sure to end in it: the shadow may admit into them, and a
+        # task finalized into one (taken out of `ending`) waits in `heirs`
+        # for the settlement to go live.
+        self.ending = ending
+        self.heirs: List[_PrefillTask] = []
+        # Tasks whose first token its tokens must not overtake.
+        self.activations = activations
+        self.t_launch = t_launch            # ns, the `dispatch` mark
+        self.toks: Any = None               # (slots, steps), -1 padded
+        self.still: Any = None              # (slots,) active after it
+        self.rows: List[List[int]] = []     # `toks` as lists (settlement)
+        self.seconds = 0.0                  # launch to read-back
+        self.emitted = 0                    # tokens, over its slots
+        self.ended: Dict[int, float] = {}   # ended slot -> when it went live
 
 
 def prometheus_metrics(stats: Dict[str, Any]) -> str:

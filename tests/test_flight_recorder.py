@@ -265,11 +265,14 @@ def test_phase_clock_telescopes(factory):
     assert snap["slow_cycles"] == 0 and snap["slow"] == []
 
 
-def _pipelined_cycle(clock, now):
-    """The loop's order since admission moved behind the decode launch:
-    admit 4 (match 1) + grow 2 + dispatch 3 + admit 12 in the shadow
-    (chunk_args 5, chunk_launch 2 inside it) + sync 20 + barrier 1 +
-    fan_out 8 = 50 ns."""
+def _pipelined_cycle(clock, now, delivers=True):
+    """The loop's order since delivery moved behind the decode launch
+    (admission moved there before it): admit 4 (match 1) + grow 2 +
+    dispatch 3 + barrier 1 + fan_out 6 delivering the last chunk (all of
+    it the child `shadow`) + admit 12 in the shadow (chunk_args 5,
+    chunk_launch 2 inside it) + grow 2 ahead + sync 18 + fan_out 2 settling
+    this chunk = 50 ns. Without `delivers` (the engine's first chunk: none
+    before it to hand out) the sync takes the 7."""
     now.t = 100
     clock.begin("admit", live=2, tasks=1)
     now.t = 101
@@ -280,18 +283,24 @@ def _pipelined_cycle(clock, now):
     now.t = 106
     clock.mark("dispatch")
     now.t = 109
+    if delivers:
+        clock.mark("barrier")
+        now.t = 110
+        clock.mark("fan_out")
+        with clock.child("shadow"):
+            now.t = 116
     clock.mark("admit")
     with clock.child("shadow"):
-        now.t = 111
+        now.t += 2
         with clock.child("chunk_args", request_id="r1", tokens=16):
-            now.t = 116
+            now.t += 5
         with clock.child("chunk_launch", request_id="r1", tokens=16):
-            now.t = 118
-        now.t = 121
+            now.t += 2
+        now.t += 3
+    clock.mark("grow")
+    now.t += 2
     clock.mark("sync")
-    now.t = 141
-    clock.mark("barrier")
-    now.t = 142
+    now.t = 148
     clock.mark("fan_out")
     now.t = 150
     return clock.end()
@@ -299,57 +308,81 @@ def _pipelined_cycle(clock, now):
 
 @pytest.mark.parametrize("factory", [None, "spans"])
 def test_phase_marked_twice_accumulates_and_the_cycle_still_sums(factory):
-    """`admit` before grow and again behind the decode launch is one
-    counter; the phases sum to the cycle in integer nanoseconds; the
-    shadow is the part of `admit` behind the launch and holds the
-    children that ran there."""
+    """`admit` and `grow` before the decode launch and again behind it,
+    `fan_out` behind it (the last chunk's tokens out) and after the sync
+    (this chunk settled): one counter each; the phases sum to the cycle
+    in integer nanoseconds; a shadow is the part of its phase behind the
+    launch, and `admit`'s holds the children that ran there."""
     now = Clock(0)
     clock = PhaseClock(annotate=Spans() if factory else None, clock=now)
     assert _pipelined_cycle(clock, now) == 50
     assert clock.cycle == {
-        "admit": 4 + 12, "admit/match": 1, "grow": 2, "dispatch": 3,
+        "admit": 4 + 12, "admit/match": 1, "grow": 2 + 2, "dispatch": 3,
+        "barrier": 1, "fan_out": 6 + 2, "fan_out/shadow": 6,
         "admit/shadow": 12, "admit/chunk_args": 5, "admit/chunk_launch": 2,
-        "sync": 20, "barrier": 1, "fan_out": 8,
+        "sync": 18,
     }
     assert sum(v for k, v in clock.cycle.items() if "/" not in k) == 50
-    assert clock.cycle_seconds("dispatch", "admit/shadow", "sync") == 35 / 1e9
+    assert clock.cycle_seconds("dispatch", "admit/shadow", "sync") == 33 / 1e9
     snap = clock.snapshot()
     sec = snap["seconds"]
     assert set(sec) == set(LOOP_PHASES + LOOP_CHILDREN)
     assert 0 < sec["admit/shadow"] == 12 / 1e9 <= sec["admit"] == 16 / 1e9
+    assert 0 < sec["fan_out/shadow"] == 6 / 1e9 < sec["fan_out"] == 8 / 1e9
     in_cycle = sum(sec[p] for p in LOOP_PHASES if p != "wait")
     assert in_cycle == pytest.approx(snap["cycle_seconds"], abs=1e-15)
-    # A second cycle without a shadow adds to admit alone.
+    # A second cycle without a shadow adds to admit and fan_out alone.
     _one_cycle(clock, now)
     sec = clock.snapshot()["seconds"]
     assert sec["admit/shadow"] == 12 / 1e9 and sec["admit"] == 19 / 1e9
+    assert sec["fan_out/shadow"] == 6 / 1e9 and sec["fan_out"] == 13 / 1e9
 
 
-def test_shadow_span_nests_under_admit_and_over_its_chunks():
+def test_a_cycle_with_nothing_to_deliver_has_no_delivery_shadow():
+    """The first chunk of an engine has no chunk before it to hand out:
+    no barrier, one `fan_out` (its settlement), no `fan_out/shadow`."""
+    now = Clock(0)
+    clock = PhaseClock(clock=now)
+    assert _pipelined_cycle(clock, now, delivers=False) == 50
+    assert clock.cycle["fan_out"] == 2 and clock.cycle["sync"] == 18 + 7
+    assert "fan_out/shadow" not in clock.cycle and "barrier" not in clock.cycle
+    sec = clock.snapshot()["seconds"]
+    assert sec["fan_out/shadow"] == 0.0 and sec["fan_out"] == 2 / 1e9
+
+
+def test_shadow_spans_nest_under_their_phase_behind_the_launch():
     now = Clock(0)
     spans = Spans()
     clock = PhaseClock(annotate=spans, clock=now)
     _pipelined_cycle(clock, now)
     names = [(e[0], e[1]) for e in spans.log]
     lo = names.index(("enter", "engine/dispatch"))
-    assert names[lo:lo + 11] == [
+    assert names[lo:lo + 21] == [
         ("enter", "engine/dispatch"), ("exit", "engine/dispatch"),
+        ("enter", "engine/barrier"), ("exit", "engine/barrier"),
+        ("enter", "engine/fan_out"), ("enter", "engine/fan_out/shadow"),
+        ("exit", "engine/fan_out/shadow"), ("exit", "engine/fan_out"),
         ("enter", "engine/admit"), ("enter", "engine/admit/shadow"),
         ("enter", "engine/admit/chunk_args"),
         ("exit", "engine/admit/chunk_args"),
         ("enter", "engine/admit/chunk_launch"),
         ("exit", "engine/admit/chunk_launch"),
         ("exit", "engine/admit/shadow"), ("exit", "engine/admit"),
-        ("enter", "engine/sync"),
+        ("enter", "engine/grow"), ("exit", "engine/grow"),
+        ("enter", "engine/sync"), ("exit", "engine/sync"),
+        ("enter", "engine/fan_out"),
     ]
-    assert sum(n == ("enter", "engine/admit") for n in names) == 2
+    for phase in ("admit", "grow", "fan_out"):
+        assert sum(n == ("enter", f"engine/{phase}") for n in names) == 2
+    assert sum(n == ("enter", "engine/fan_out/shadow") for n in names) == 1
     assert sum(n == ("enter", "engine/cycle") for n in names) == 1
 
 
-def test_admit_shadow_counter_on_a_live_engine_and_in_prometheus():
-    """loop_admit_shadow_seconds_total sits beside its siblings in a live
-    engine's stats() and under the one labelled Prometheus series, and
-    never exceeds loop_admit_seconds_total."""
+@pytest.mark.parametrize("child", ["admit/shadow", "fan_out/shadow"])
+def test_shadow_counters_on_a_live_engine_and_in_prometheus(child):
+    """loop_admit_shadow_seconds_total and loop_fan_out_shadow_seconds_total
+    sit beside their siblings in a live engine's stats() and under the one
+    labelled Prometheus series, and never exceed their phase's counter."""
     import time
 
     import jax
@@ -375,17 +408,43 @@ def test_admit_shadow_counter_on_a_live_engine_and_in_prometheus():
         s = engine.stats()
     finally:
         engine.close()
-    assert 0 < s["loop_admit_shadow_seconds_total"] \
-        <= s["loop_admit_seconds_total"]
+    phase = child.split("/")[0]
+    own = s[f"loop_{child.replace('/', '_')}_seconds_total"]
+    assert 0 < own <= s[f"loop_{phase}_seconds_total"]
     in_cycles = sum(s[f"loop_{p}_seconds_total"]
                     for p in LOOP_PHASES if p != "wait")
     assert in_cycles == pytest.approx(s["loop_cycle_seconds_total"], abs=1e-9)
     text = prometheus_metrics(s)
     series = "dstack_tpu_serving_loop_phase_seconds_total"
     assert METRICS[series] == ("counter", ("phase",))
-    assert (f'{series}{{phase="admit/shadow"}} '
-            f'{s["loop_admit_shadow_seconds_total"]}') in text
-    assert f'{series}{{phase="admit"}} {s["loop_admit_seconds_total"]}' in text
+    assert f'{series}{{phase="{child}"}} {own}' in text
+    assert (f'{series}{{phase="{phase}"}} '
+            f'{s[f"loop_{phase}_seconds_total"]}') in text
+
+
+def test_a_rehearsal_reads_the_delivery_shadow_share():
+    """The benchmark's flow at a tiny size on the CPU: the counter reaches
+    `/metrics`, the reader finds it and the line carries
+    `scheduler.deliver_shadow_share` as a number (not UNREAD, not absent)."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parents[1]
+    proc = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", "mistral-7b.docqa",
+         "--seed", "3000000411", "--seconds", "4", "--trace", "1",
+         "--rehearsal"],
+        cwd=repo, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "UNREAD scheduler.deliver_shadow_share" not in proc.stdout
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    share = line["metrics"]["scheduler.deliver_shadow_share"]
+    assert share["unit"] == "%" and 0.0 < share["value"] <= 100.0
+    assert line["correct"] is True and line["failed"] == 0
 
 
 def test_phase_clock_spans_enter_and_leave_in_order():

@@ -209,8 +209,11 @@ def test_loop_counters_account_for_the_loop(params):
         # The shadow is the part of admit behind a decode launch and holds
         # the other children that ran there; those are disjoint.
         assert children["admit/shadow"] <= phase["admit"]
-        assert sum(children.values()) - children["admit/shadow"] \
-            <= phase["admit"]
+        assert sum(v for c, v in children.items() if c.startswith("admit/")) \
+            - children["admit/shadow"] <= phase["admit"]
+        # Delivery behind a launch is the part of fan_out the device does
+        # not wait for; the settlements are the rest.
+        assert children["fan_out/shadow"] < phase["fan_out"]
         # Every token but each request's first came out of a decode step.
         assert s["decode_tokens_total"] == delivered - len(prompts)
         assert s["decode_steps_total"] % 4 == 0
@@ -343,8 +346,14 @@ def test_engine_spans_land_in_a_profile(params, tmp_path):
     for _, lo, hi, _ in launches:
         assert any(a[1] <= lo and hi <= a[2] for a in admits)
     assert {"engine/sync", "engine/fan_out", "engine/barrier",
-            "engine/admit/match", "engine/admit/chunk_args"} <= {
-                e[0] for e in spans}
+            "engine/admit/match", "engine/admit/chunk_args",
+            "engine/fan_out/shadow"} <= {e[0] for e in spans}
+    # A delivery behind a launch lies between that launch and its sync.
+    syncs = sorted(e[1] for e in spans if e[0] == "engine/sync")
+    launches = sorted(e[2] for e in spans if e[0] == "engine/dispatch")
+    for _, lo, hi, _ in (e for e in spans if e[0] == "engine/fan_out/shadow"):
+        launched = max(t for t in launches if t <= lo)
+        assert min(t for t in syncs if t >= launched) >= hi
 
 
 class _Gate:
@@ -361,9 +370,10 @@ class _Timeline:
     mark, ("decode",) at every decode (or speculative verify) launch,
     ("chunk", slot, tokens, pos, in_shadow) at every prefill-chunk launch,
     ("barrier", slots whose first token is waited for) at every
-    first-token barrier, ("fan_out", slots of the chunk, live slots,
-    {slot: tokens queued}) before and ("fanned", live slots, tasks) after
-    every fan-out.
+    first-token barrier, ("settle", slots of the chunk, live slots) before
+    and ("settled", live slots, tasks) after every settlement,
+    ("deliver", slots whose tokens go out, {slot: tokens queued so far},
+    behind a launch) before and ("delivered",) after every delivery.
     `gate(kind)` parks the loop at the next such event (the event is
     recorded, what it announces has not happened yet) until released."""
 
@@ -396,25 +406,34 @@ class _Timeline:
             def wrapped(*args):
                 # (params, state, slot, table, tokens, n, pos, ...)
                 self._note(("chunk", int(args[2]), int(args[5]),
-                            int(args[6]), engine._chunk_live is not None))
+                            int(args[6]), engine._chunk is not None))
                 return fn(*args)
 
             return wrapped
 
         engine._chunk_fn = chunk_fn
-        real_fan_out = engine._fan_out
+        real_settle = engine._settle
 
-        def fan_out(toks, still):
-            self._note(("fan_out", self._slots(engine._chunk_live),
-                        self._slots(engine._live),
-                        {slot: req.out.qsize()
-                         for slot, req in enumerate(engine._live)
-                         if req is not None}))
-            real_fan_out(toks, still)
-            self._note(("fanned", self._slots(engine._live),
+        def settle(chunk):
+            self._note(("settle", self._slots(chunk.live),
+                        self._slots(engine._live)))
+            real_settle(chunk)
+            self._note(("settled", self._slots(engine._live),
                         len(engine._tasks)))
 
-        engine._fan_out = fan_out
+        engine._settle = settle
+        real_hand_out = engine._hand_out
+
+        def hand_out(chunk):
+            self._note(("deliver", self._slots(chunk.live),
+                        {slot: req.out.qsize()
+                         for slot, req in enumerate(chunk.live)
+                         if req is not None},
+                        engine._chunk is not None))
+            real_hand_out(chunk)
+            self._note(("delivered",))
+
+        engine._hand_out = hand_out
         real_wait = engine._wait_activations
 
         def wait_activations(tasks):
@@ -474,49 +493,71 @@ def _slot_of(engine, out):
 def _in_shadow_of_first_decode(engine, tl, submit_b):
     """Park the loop at its first decode launch (so request A is live and
     chunk N is about to go), submit B there, let go, and return the
-    events from that launch to the end of chunk N's fan-out."""
+    events from that launch to the settlement of chunk N+1, behind whose
+    launch N's tokens go out."""
     gate = tl.gate("decode")
     assert gate.reached.wait(60), "no decode chunk was ever launched"
     start = len(tl.events) - 1
     qb = submit_b()
-    done = tl.gate("fanned")
+    settled = tl.gate("settled")
     gate.release()
+    assert settled.reached.wait(60)
+    slot_b = _slot_of(engine, qb)
+    done = tl.gate("settled")
+    settled.release()
     assert done.reached.wait(60)
     events = list(tl.events[start:])
-    slot_b = _slot_of(engine, qb)
     done.release()
     return qb, slot_b, events
 
 
-def _check_shadow_order(events, slot_b, n_b):
-    """decode N -> admit (B's chunk, in the shadow) -> sync -> barrier,
-    which does not wait for B's first token -> fan_out; returns the
-    (fan_out, fanned) events."""
-    order = [e for e in events if e[0] in ("decode", "chunk", "mark")]
+def _check_pipelined_order(events, slot, n, first=True):
+    """From the launch of N (`first`: the engine's first chunk, with no
+    chunk before it to deliver): a chunk of `n` tokens for `slot` in N's
+    shadow -> tables and key ahead -> sync N -> settle N -> the boundary's
+    admit -> grow -> dispatch N+1 -> N's first-token barrier and N's tokens
+    out, behind that launch -> shadow admit -> sync N+1 -> settle N+1.
+    Returns the events (settle N, settled N, barrier N, deliver N,
+    settle N+1)."""
+    deliver = [("mark", "barrier"), ("barrier",), ("mark", "fan_out"),
+               ("deliver",), ("delivered",)]
+    order = [e if e[0] in ("mark", "chunk") else e[:1] for e in events]
     assert order == [
-        ("decode",), ("mark", "admit"), ("chunk", slot_b, n_b, 0, True),
-        ("mark", "sync"), ("mark", "barrier"), ("mark", "fan_out"),
+        ("decode",), *([] if first else deliver),
+        ("mark", "admit"), ("chunk", slot, n, 0, True),
+        ("mark", "grow"), ("mark", "sync"), ("mark", "fan_out"),
+        ("settle",), ("settled",),
+        ("mark", "admit"), ("mark", "grow"), ("mark", "dispatch"),
+        ("decode",), *deliver,
+        ("mark", "admit"), ("mark", "grow"), ("mark", "sync"),
+        ("mark", "fan_out"), ("settle",), ("settled",),
     ], events
-    (barrier,) = [e for e in events if e[0] == "barrier"]
-    (fan,) = [e for e in events if e[0] == "fan_out"]
-    (fanned,) = [e for e in events if e[0] == "fanned"]
-    assert slot_b not in barrier[1]
-    return fan, fanned
+    settle_n, settle_n1 = [e for e in events if e[0] == "settle"]
+    settled_n, _ = [e for e in events if e[0] == "settled"]
+    barrier = [e for e in events if e[0] == "barrier"][-1]
+    delivers = [e for e in events if e[0] == "deliver"]
+    assert all(e[3] for e in delivers), "tokens went out, nothing launched"
+    return settle_n, settled_n, barrier, delivers[-1], settle_n1
 
 
 def _check_shadow_cycle(events, slot_b, n_b):
-    """... and the fan-out walks the slots N was launched with, B's (free
-    when N was launched) not among them."""
-    fan, fanned = _check_shadow_order(events, slot_b, n_b)
-    assert slot_b not in fan[1] and slot_b in fan[2]   # live, not in chunk N
-    assert slot_b in fanned[1]                         # and not retired by it
+    """... and B's slot (free when N was launched, live from N's shadow
+    on) is neither settled nor delivered with N, nor is its first token
+    waited for by N's barrier: it is N+1's."""
+    settle_n, settled_n, barrier, deliver, settle_n1 = \
+        _check_pipelined_order(events, slot_b, n_b)
+    assert slot_b not in settle_n[1] and slot_b in settle_n[2]
+    assert slot_b in settled_n[1]                      # and not retired by it
+    assert slot_b not in barrier[1] and slot_b not in deliver[1]
+    assert slot_b in settle_n1[1]
 
 
 def test_chunk_launches_in_the_decode_chunks_shadow(params):
     """A request waiting when decode chunk N is dispatched gets its chunk
     between N's launch and N's `device_get`; the slot that chunk flips
-    live is neither fanned out nor retired by N, and decodes from N+1 on
-    with its first token already delivered."""
+    live is neither settled nor delivered with N, and decodes from N+1 on
+    with its first token already delivered. N's tokens go out behind the
+    launch of N+1."""
     engine = ServingEngine(CFG, params, slots=2, max_len=64,
                            steps_per_sync=2)
     tl = _Timeline(engine)
@@ -528,15 +569,21 @@ def test_chunk_launches_in_the_decode_chunks_shadow(params):
         _check_shadow_cycle(events, slot_b, len(b))
         assert _drain(qa) == _reference(params, a, 12)
         assert _drain(qb) == _reference(params, b, 5)
-        # The first fan-out B's slot is part of finds exactly its first
+        # The first delivery B's slot is part of finds exactly its first
         # token queued: the barrier of N+1 waited for it, N's did not.
-        first = next(e for e in tl.kinds("fan_out") if slot_b in e[1])
-        assert first[3][slot_b] == 1
+        first = next(e for e in tl.kinds("deliver") if slot_b in e[1])
+        assert first[2][slot_b] == 1
         waited = [e[1] for e in tl.kinds("barrier") if e[1]]
         assert waited[-1] == [slot_b]  # by the barrier of N+1
+        # Every chunk but the last was delivered behind a launch; the last
+        # left nothing live, and went out at its settlement.
+        behind = [e[3] for e in tl.kinds("deliver")]
+        assert behind == [True] * (len(behind) - 1) + [False]
         s = _settled_stats(engine)
         assert 0 < s["loop_admit_shadow_seconds_total"] \
             <= s["loop_admit_seconds_total"]
+        assert 0 < s["loop_fan_out_shadow_seconds_total"] \
+            < s["loop_fan_out_seconds_total"]
         assert s["decode_tokens_total"] == 12 + 5 - 2
     finally:
         engine.close()
@@ -544,7 +591,7 @@ def test_chunk_launches_in_the_decode_chunks_shadow(params):
 
 def test_arrival_behind_an_unspent_shadow_is_admitted_at_the_boundary(params):
     """The cycle's budget is spent at two points. A request that arrives
-    after the shadow ran (here: during chunk N's fan-out) with the budget
+    after the shadow ran (here: as chunk N is settled) with the budget
     unspent gets its chunk at the boundary, before decode N+1 is
     dispatched, and decodes in N+1: it waits no longer than it did when
     all admission sat there."""
@@ -553,12 +600,12 @@ def test_arrival_behind_an_unspent_shadow_is_admitted_at_the_boundary(params):
     tl = _Timeline(engine)
     try:
         a, c = [5, 7, 11], [2, 3, 5, 7, 13]
-        gate = tl.gate("fan_out")
+        gate = tl.gate("settle")
         qa = engine.submit(a, max_new_tokens=12)
         assert gate.reached.wait(60)
         start = len(tl.events)
         qc = engine.submit(c, max_new_tokens=4)
-        done = tl.gate("fan_out")
+        done = tl.gate("settle")
         gate.release()
         assert done.reached.wait(60)
         events = list(tl.events[start:])
@@ -592,15 +639,15 @@ def test_one_prefill_budget_a_cycle_spent_at_two_points(params):
         b = [(i * 29 + 3) % 50 + 1 for i in range(20)]
         c = [(i * 31 + 7) % 50 + 1 for i in range(6)]
         first_decode = tl.gate("decode")
-        third_fan_out = tl.gate("fan_out", skip=2)
+        third_settle = tl.gate("settle", skip=2)
         qa = engine.submit(a, max_new_tokens=30)
         assert first_decode.reached.wait(60)
         qb = engine.submit(b, max_new_tokens=4)
         first_decode.release()
-        assert third_fan_out.reached.wait(60)
+        assert third_settle.reached.wait(60)
         slot_b = _slot_of(engine, qb)
         qc = engine.submit(c, max_new_tokens=4)
-        third_fan_out.release()
+        third_settle.release()
         assert _drain(qb) == _reference(params, b, 4)
         assert _drain(qc) == _reference(params, c, 4)
         assert _drain(qa) == _reference(params, a, 30)
@@ -628,10 +675,11 @@ def test_one_prefill_budget_a_cycle_spent_at_two_points(params):
 def test_shadow_admits_into_a_slot_sure_to_end_in_the_chunk(params, cancel_old):
     """Every slot taken, one of them within a chunk of its budget's end:
     the waiting request is admitted into THAT slot in the chunk's shadow
-    (its chunk runs on the device behind the chunk that ends the slot), the
-    old request's last tokens are delivered and its blocks released by the
-    chunk's fan-out, and only then is the slot the new request's on the
-    host. A slot with budget to spare is never taken."""
+    (its chunk runs on the device behind the chunk that ends the slot).
+    The chunk's settlement releases the old request's blocks and makes the
+    slot the new request's on the host, BEFORE the old request's last
+    tokens and clean end go out behind the next launch, in which the heir
+    already decodes. A slot with budget to spare is never taken."""
     engine = ServingEngine(CFG, params, slots=2, max_len=64,
                            steps_per_sync=2, prefix_cache=False)
     tl = _Timeline(engine)
@@ -642,31 +690,41 @@ def test_shadow_admits_into_a_slot_sure_to_end_in_the_chunk(params, cancel_old):
         # Park at the launch of the chunk that ends A: the second in which
         # both decode (B may have gone live a chunk after A).
         last = tl.gate(lambda e: e[0] == "decode" and any(
-            s in engine._chunk_ending and r.out is qa
+            s in engine._chunk.ending and r.out is qa
             for s, r in enumerate(engine._live) if r is not None))
         assert last.reached.wait(60), "A never came within a chunk of its end"
         slot_a = _slot_of(engine, qa)
-        assert engine._chunk_ending == {slot_a}
+        assert engine._chunk.ending == {slot_a}
         start = len(tl.events) - 1
         qc = engine.submit(c, max_new_tokens=6)
         if cancel_old:
             engine.cancel(qa)
-        done = tl.gate("fanned")
+        settled = tl.gate("settled")
         last.release()
-        assert done.reached.wait(60)
-        events = list(tl.events[start:])
+        assert settled.reached.wait(60)
         with engine._lock:
             assert engine._live[slot_a].out is qc   # the heir took over
-            assert not engine._admitting and not engine._chunk_heirs
+            assert not engine._admitting and not engine._settled.heirs
+        # A's blocks went back with the settlement (the table is C's), and
+        # its stream holds what earlier chunks delivered: the first token
+        # and one chunk (and the clean end at once if nobody reads).
+        assert qa.qsize() == 3 + cancel_old
+        done = tl.gate("settled")
+        settled.release()
+        assert done.reached.wait(60)
+        events = list(tl.events[start:])
         done.release()
-        # C's chunk, into A's slot, behind the chunk that ends A; the
-        # fan-out finds A there (2 tokens delivered so far + the first, or
-        # fewer read), and leaves C.
-        fan, fanned = _check_shadow_order(events, slot_a, len(c))
-        assert fan[1] == fan[2] == fanned[1] == [0, 1]
+        # C's chunk, into A's slot, behind the chunk that ends A; A's last
+        # tokens go out behind the launch of the chunk C first decodes in.
+        settle_n, settled_n, _, deliver, settle_n1 = _check_pipelined_order(
+            events, slot_a, len(c), first=False)
+        assert settle_n[1] == settle_n[2] == settled_n[1] == [0, 1]
+        assert settle_n1[1] == [0, 1]
         if cancel_old:
+            assert deliver[1] == [1 - slot_a]        # nothing of A's
             assert len(_drain(qa)) < 5               # its last chunk skipped
         else:
+            assert deliver[1] == [0, 1] and deliver[2][slot_a] == 3
             assert _drain(qa) == _reference(params, a, 5)
         assert _drain(qc) == _reference(params, c, 6)
         assert _drain(qb) == _reference(params, b, 30)
@@ -674,6 +732,45 @@ def test_shadow_admits_into_a_slot_sure_to_end_in_the_chunk(params, cancel_old):
         assert s["kv_blocks_in_use"] == 0            # A's blocks and C's
         with engine._lock:
             assert not engine._cancelled and not engine._inflight
+    finally:
+        engine.close()
+
+
+def test_cancelled_while_a_chunk_runs_is_settled_without_delivery(params):
+    """A request cancelled while chunk N runs, with budget to spare: N's
+    settlement frees its slot and blocks and ends its stream; the delivery
+    behind the next launch has nothing of it."""
+    engine = ServingEngine(CFG, params, slots=2, max_len=64,
+                           steps_per_sync=2, prefix_cache=False)
+    tl = _Timeline(engine)
+    try:
+        a, b = [5, 7, 11], [13, 17, 19, 23]
+        both = tl.gate(lambda e: e[0] == "decode" and all(
+            r is not None for r in engine._live))
+        qa = engine.submit(a, max_new_tokens=30)
+        qb = engine.submit(b, max_new_tokens=30)
+        assert both.reached.wait(60)
+        slot_a = _slot_of(engine, qa)
+        engine.cancel(qa)
+        settled = tl.gate("settled")
+        both.release()
+        assert settled.reached.wait(60)
+        with engine._lock:
+            assert engine._live[slot_a] is None
+            assert engine._slot_tables[slot_a] is None
+            assert qa not in engine._inflight | engine._cancelled
+        queued = list(qa.queue)
+        assert queued[-1] is None and None not in queued[:-1]
+        delivered = tl.gate("delivered")
+        settled.release()
+        assert delivered.reached.wait(60)
+        deliver = tl.kinds("deliver")[-1]
+        assert deliver[1] == [1 - slot_a] and deliver[3]
+        assert list(qa.queue) == queued              # nothing more of A's
+        delivered.release()
+        assert len(_drain(qa)) < 30
+        assert _drain(qb) == _reference(params, b, 30)
+        assert _settled_stats(engine)["kv_blocks_in_use"] == 0
     finally:
         engine.close()
 
@@ -715,7 +812,7 @@ def test_mixed_batch_greedy_streams_equal_the_reference(params):
         s = _settled_stats(engine)
         assert s["prefix_tokens_reused_total"] >= 2 * 16
         shrank = [(before, after) for before, after in zip(
-            tl.kinds("fan_out"), tl.kinds("fanned"))
+            tl.kinds("settle"), tl.kinds("settled"))
             if len(after[1]) < len(before[2]) and after[2] > 0]
         assert shrank, "no request finished while another was prefilling"
         assert any(e[4] for e in tl.kinds("chunk"))      # some in a shadow
@@ -744,6 +841,52 @@ def _lora_engine(params):
     engine.load_adapter("t1", demo_adapter(
         CFG, params, jax.random.PRNGKey(11), rank=4, targets=("wq", "wv")))
     return engine
+
+
+def _plain_engine(params):
+    return ServingEngine(CFG, params, slots=2, max_len=64, steps_per_sync=2,
+                         prefill_chunk_tokens=16, kv_block_size=8)
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("build, a_kw", [
+    (_plain_engine, {}), (_spec_engine, {}), (_lora_engine, {"adapter": "t1"}),
+], ids=["decode_chunks", "speculative", "lora"])
+def test_streams_are_what_they_were_before_keys_were_split_ahead(
+        params, build, a_kw, temperature):
+    """One key of the chain a launch, in launch order, whatever the kind of
+    launch: splitting the next launch's key in the last one's shadow draws
+    the streams that splitting at the launch drew (the order before this
+    loop delivered behind the launch; the same scenario on that tree gave
+    these streams token for token). A is live and B arrives at the first
+    decode launch, so B's chunk rides in a shadow, between two decode keys."""
+    streams = []
+    for ahead in (True, False):
+        engine = build(params)
+        if not ahead:
+            engine._split_ahead = lambda: None   # every key at its launch
+        tl = _Timeline(engine)
+        try:
+            first = tl.gate("decode")
+            qa = engine.submit([5, 7, 11], max_new_tokens=12,
+                               temperature=temperature, **a_kw)
+            assert first.reached.wait(60)
+            qb = engine.submit([13, 17, 19, 23], max_new_tokens=7,
+                               temperature=temperature)
+            first.release()
+            streams.append((_drain(qa), _drain(qb)))
+            assert (engine._key_ahead is not None) == ahead
+        finally:
+            engine.close()
+    assert streams[0] == streams[1]
+    out_a, out_b = streams[0]
+    assert len(out_a) == 12 and len(out_b) == 7
+    if temperature == 0.0:
+        assert out_b == _reference(params, [13, 17, 19, 23], 7)
+        if not a_kw:
+            assert out_a == _reference(params, [5, 7, 11], 12)
+    else:
+        assert out_b != _reference(params, [13, 17, 19, 23], 7)
 
 
 @pytest.mark.parametrize("build, a_kw", [
