@@ -27,10 +27,12 @@ from dstack_tpu.workloads.attention import NEG_INF, _repeat_kv
 from dstack_tpu.workloads.config import FULL, MAMBA, ModelConfig
 from dstack_tpu.workloads.transformer import (
     absorb_query,
+    attn_output,
+    embed_tokens,
+    final_norm,
     head_weights,
     latent_output,
     layer_stacks,
-    linear,
     logits_linear,
     mamba_mixer,
     mixer_stacks,
@@ -124,7 +126,7 @@ def _forward_cached(
     # Row i sees cache slots [0, start+i] — causal over old + new tokens.
     valid_len = start + 1 + jnp.arange(s, dtype=jnp.int32)
 
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = embed_tokens(params, tokens)
 
     mixers = mixer_stacks(params)
 
@@ -164,7 +166,7 @@ def _forward_cached(
             attn = _cached_attention(
                 q, ck, cv, valid_len, window=c.window(kind)
             )
-            x = x + linear(attn, p["wo"])
+            x = x + attn_output(attn, p)
         if "router" in p:
             from dstack_tpu.workloads.moe import moe_block
 
@@ -183,7 +185,7 @@ def _forward_cached(
             caches.get(MAMBA, (cache.ssm, cache.conv)),
             caches.get(FULL, (cache.k, cache.v)),
         )
-        x = rms_norm(x, params["final_norm"], c.norm_eps)
+        x = final_norm(c, params, x)
         logits = logits_linear(x[:, -1], head_weights(params))
         return logits, KVCache(new_k, new_v, start + s, ssm, conv)
     new_k, new_v, first = [], [], 0
@@ -198,7 +200,7 @@ def _forward_cached(
         first += n
     new_k = new_k[0] if len(new_k) == 1 else jnp.concatenate(new_k)
     new_v = new_v[0] if len(new_v) == 1 else jnp.concatenate(new_v)
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
+    x = final_norm(c, params, x)
     logits = logits_linear(x[:, -1], head_weights(params))
     return logits, KVCache(k=new_k, v=new_v, length=start + s)
 

@@ -70,10 +70,12 @@ from dstack_tpu.workloads.sampling import (
 from dstack_tpu.workloads.selective_scan import scan_impl, selective_scan_decode
 from dstack_tpu.workloads.transformer import (
     absorb_query,
+    attn_output,
+    embed_tokens,
+    final_norm,
     head_weights,
     latent_output,
     layer_stacks,
-    linear,
     logits_linear,
     mamba_inputs,
     mamba_mixer,
@@ -451,20 +453,18 @@ class BlockAllocator:
 
 # -- jitted programs ----------------------------------------------------------
 #
-# Every factory takes an optional `shardings` (a
-# `sharding.ServingShardings`): when set, the program is jitted with
-# explicit in/out shardings — params column-parallel over "model", KV
-# pools sharded on the KV-head dim, control state replicated — and GSPMD
-# partitions the SAME traced logic; there are no sharded/unsharded code
-# forks. When None (the default), jit behaves exactly as before.
+# Every factory takes an optional `shardings` (a `sharding.ServingShardings`):
+# when set, the program is jitted with explicit in/out shardings — params
+# column-parallel over "model", KV pools sharded on the KV-head dim, control
+# state replicated — and GSPMD partitions the SAME traced logic; there are no
+# sharded/unsharded code forks. When None (the default), jit behaves as before.
 #
-# `attn_impl` names the ragged-attention implementation the program
-# traces ("pallas" / "lax_ragged"). The engine decides it ONCE from its
-# geometry, backend and shard count (paged_attention.dispatch_path) and
-# hands the same string to every factory and to its dispatch counter, so
-# the path an engine reports is the path its programs traced. None lets
-# `ragged_attention` decide from the operand shapes alone (unsharded
-# library callers and tests).
+# `attn_impl` names the ragged-attention implementation the program traces
+# ("pallas" / "lax_ragged"). The engine decides it ONCE from its geometry,
+# backend and shard count (paged_attention.dispatch_path) and hands the same
+# string to every factory and to its dispatch counter, so the path an engine
+# reports is the path its programs traced. None lets `ragged_attention` decide
+# from the operand shapes alone (unsharded library callers and tests).
 
 
 def _jit_shardings(in_shardings, out_shardings):
@@ -480,65 +480,65 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     """The layer loop of every paged program -> (x, k_pool, v_pool), and
     with `recurrent` -> (x, k_pool, v_pool, ssm, conv).
 
-    x (B, S, d) at `positions` runs through the layers; layer l writes
-    its new K/V rows into the STACKED pool (L, num_blocks, block_size,
-    KV, hd) at `[l, blk, off]` (blk/off (B, S); lanes pointed at the
-    sentinel block `num_blocks` drop) and then attends raggedly over
-    `tables` with per-row `valid_len`, so in-flight rows see themselves
-    and their predecessors. With a LoRA `bank` the q/k/v projection adds
-    each request's unmerged delta (lora_serving.project_qkv_lora):
-    `adapter_ix` is a scalar for the one-request prefill program, (B,)
-    for decode/verify, -1 = none; `has_lora` gates the LoRA math.
-    `partitioned` says GSPMD partitions the program over a mesh (the
-    factory was given `shardings`): the expert bank is then not whole on
-    a device, which `moe.moe_mlp` cannot tell from a traced weight.
+    x (B, S, d) at `positions` runs through the layers; layer l writes its new
+    K/V rows into the STACKED pool (L, num_blocks, block_size, KV, hd) at `[l,
+    blk, off]` (blk/off (B, S); lanes pointed at the sentinel block
+    `num_blocks` drop) and then attends raggedly over `tables` with per-row
+    `valid_len`, so in-flight rows see themselves and their predecessors. With
+    a LoRA `bank` the q/k/v projection adds each request's unmerged delta
+    (lora_serving.project_qkv_lora): `adapter_ix` is a scalar for the
+    one-request prefill program, (B,) for decode/verify, -1 = none; `has_lora`
+    gates the LoRA math. `partitioned` says GSPMD partitions the program over a
+    mesh (the factory was given `shardings`): the expert bank is then not whole
+    on a device, which `moe.moe_mlp` cannot tell from a traced weight.
 
-    The pool is a carry of the scan, never an `xs`/`ys`: a scan cannot
-    alias `xs` to `ys`, so the stacked form sliced each layer's K and V
-    slab out into a fresh buffer and wrote it back into a second stack
-    every layer-step, and the step loop around it copied the whole pool
-    once per decode step — 64% of the chat cell's device time on the v5e
-    (PERF.md §6, PR 26). As a carry the two scatters update the donated
-    pool in place and nothing of pool or slab shape is moved
-    (tests/test_tpu_lowering.py reads the compiled HLO). Do not flatten
-    (L, num_blocks) for the WRITE: sentinel + l * num_blocks would land
+    The pool is a carry of the scan, never an `xs`/`ys`: a scan cannot alias
+    `xs` to `ys`, so the stacked form sliced each layer's K and V slab out into
+    a fresh buffer and wrote it back into a second stack every layer-step, and
+    the step loop around it copied the whole pool once per decode step — 64% of
+    the chat cell's device time on the v5e (PERF.md §6, PR 26). As a carry the
+    two scatters update the donated pool in place and nothing of pool or slab
+    shape is moved (tests/test_tpu_lowering.py reads the compiled HLO). Do not
+    flatten (L, num_blocks) for the WRITE: sentinel + l * num_blocks would land
     in layer l+1 instead of out of bounds.
 
-    A model with state-space layers hands in `recurrent` = (ssm, conv,
-    slot, n_valid (B,), fresh (B,)): the state pool, carried and updated in
-    place like the KV pool; which slot x's ONE row is (a prefill chunk), or
-    None where x's rows are the pool's slots in order (a decode step); how
-    many of a row's tokens are the sequence's (a padded tail and a row that
-    is not live move no state); and which rows start a sequence, from zero
-    state whoever held the slot before. Its attention layers index the KV
-    pool by their rank among the attention layers.
+    A model with state-space layers hands in `recurrent` = (ssm, conv, slot,
+    n_valid (B,), fresh (B,)): the state pool, a carry updated in place like
+    the KV pool; which slot x's ONE row is (a prefill chunk), or None where x's
+    rows are the pool's slots in order (a decode step); how many of a row's
+    tokens are the sequence's (a padded tail and a row that is not live move no
+    state); and which rows start a sequence (from zero state, whoever held the
+    slot). Its attention layers index the KV pool by their rank among them.
     """
     mixers = mixer_stacks(params)
     if recurrent is not None:
         _, _, slot, n_valid, fresh = recurrent
 
-    def rows_of(pool, m):
-        """Layer m of a state pool at x's rows: every slot's, or the one
-        slot's of a prefill chunk."""
-        if slot is None:
-            return lax.dynamic_index_in_dim(pool, m, keepdims=False)
-        at = (m, slot) + (0,) * (pool.ndim - 2)
-        return lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])[0]
+    def rows_of(pool, m, start, shape=None):
+        """Layer m of a state pool at x's rows (each of `shape`), 0 at `start`."""
+        with jax.named_scope("mamba/state"):
+            if slot is None:
+                rows = lax.dynamic_index_in_dim(pool, m, keepdims=False)
+            else:
+                at = (m, slot) + (0,) * (pool.ndim - 2)
+                rows = lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])[0]
+            rows = rows.reshape(rows.shape[:1] + (shape or rows.shape[1:]))
+            return jnp.where(start, 0, rows)
 
     def put_rows(pool, m, rows):
-        if slot is None:
-            return lax.dynamic_update_index_in_dim(pool, rows, m, 0)
-        return lax.dynamic_update_slice(
-            pool, rows[None], (m, slot) + (0,) * (pool.ndim - 2))
+        with jax.named_scope("mamba/state"):
+            if slot is None:
+                return lax.dynamic_update_index_in_dim(pool, rows, m, 0)
+            return lax.dynamic_update_slice(
+                pool, rows[None], (m, slot) + (0,) * (pool.ndim - 2))
 
     def mix(x, p, m, ssm, conv):
-        """State-space layer of rank m: read the rows' state, mix, write
-        the state back -> (the mixer's output, the pools)."""
+        """State-space layer of rank m -> (the mixer's output, the pools)."""
         impl = scan_impl(*ssm.shape[2:])
         start = fresh[:, None, None]
-        x = rms_norm(x, p["attn_norm"], c.norm_eps)
-        tail0 = rows_of(conv, m).reshape((x.shape[0],) + c.state_shapes()[1])
-        tail0 = jnp.where(start, 0, tail0)
+        with jax.named_scope("mamba/proj"):
+            x = rms_norm(x, p["attn_norm"], c.norm_eps)
+        tail0 = rows_of(conv, m, start, c.state_shapes()[1])
         if slot is None and impl == "pallas" and ssm.dtype == jnp.float32:
             # A decode step on the TPU: the kernel updates the live rows'
             # state in the pool, in place, and touches no other row.
@@ -551,7 +551,7 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             out = mamba_output(x.dtype, y[:, None], u, z, p)
         else:
             out, h, tail = mamba_mixer(
-                c, x, p, jnp.where(start, 0, rows_of(ssm, m)), tail0, n_valid,
+                c, x, p, rows_of(ssm, m, start), tail0, n_valid,
                 scan_impl=impl,
             )
             ssm = put_rows(ssm, m, h)
@@ -570,16 +570,16 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
         )
 
     def attend(x, p, lp, l, kp, vp, kind):
-        """Write the rows of layer l (a `kind` layer), attend over the
-        tables -> the block's attention output (before the residual) and
-        the pools."""
+        """Write layer l's rows (it is a `kind` layer), attend over the tables
+        -> the block's attention output (before the residual) and the pools."""
         if c.latent:
             # One row a token for all heads; the absorbed query scores
             # straight against cached rows and no step up-projects them.
             q, row = project_latent(c, x, p, positions, kp.shape[-1])
-            kp = kp.at[l, blk, off].set(
-                row[:, :, None].astype(kp.dtype), mode="drop"
-            )
+            with jax.named_scope("attn/write"):
+                kp = kp.at[l, blk, off].set(
+                    row[:, :, None].astype(kp.dtype), mode="drop"
+                )
             q = absorb_query(c, q, p, kp.shape[-1])
             with jax.named_scope("mla/attend"):
                 o_lat = ragged_attention(
@@ -588,17 +588,17 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
                 )
             return latent_output(c, o_lat, p), kp, vp
         q, k, v = project(x, p, lp, kind)
-        kp = kp.at[l, blk, off].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[l, blk, off].set(v.astype(vp.dtype), mode="drop")
-        # A window layer writes every row like a full one (one pool, one
-        # geometry) and reads only its window's blocks.
+        with jax.named_scope("attn/write"):
+            kp = kp.at[l, blk, off].set(k.astype(kp.dtype), mode="drop")
+            vp = vp.at[l, blk, off].set(v.astype(vp.dtype), mode="drop")
+        # A window layer writes every row as a full one does: it READS a window.
         window = c.window(kind)
         with jax.named_scope("attn/window" if window else "attn/full"):
             attn = ragged_attention(
                 q, kp, vp, l, tables, valid_len, impl=attn_impl,
                 window=window,
             )
-        return linear(attn, p["wo"]), kp, vp
+        return attn_output(attn, p), kp, vp
 
     def block(bank_stack, first, carry, layer, kind):
         if mixers is not None:
@@ -714,7 +714,7 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
         # Row i of the chunk attends cache positions <= start + i.
         valid_len = start + 1 + offs
 
-        x = jnp.take(params["embed"], tokens, axis=0)  # (1, C, d)
+        x = embed_tokens(params, tokens)             # (1, C, d)
         # Each layer writes the chunk's rows into the pool FIRST, then
         # attends raggedly over the slot's blocks: row i sees cache
         # positions <= start + i, including the rows just written.
@@ -733,7 +733,7 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
             attn_impl=attn_impl, partitioned=shardings is not None,
             recurrent=recurrent,
         )
-        h = rms_norm(x, params["final_norm"], c.norm_eps)
+        h = final_norm(c, params, x)
         h_last = jnp.take(
             h[0], jnp.clip(n_valid - 1, 0, C - 1), axis=0, mode="clip"
         )
@@ -822,7 +822,7 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
         B, mb = state.block_tables.shape
         ml = mb * bs
         positions = state.lengths[:, None]           # (B, 1)
-        x = jnp.take(params["embed"], state.last_token[:, None], axis=0)
+        x = embed_tokens(params, state.last_token[:, None])
         write_ok = state.active & (state.lengths < ml)
         blk = jnp.take_along_axis(
             state.block_tables,
@@ -851,7 +851,7 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
             attn_impl=attn_impl, partitioned=shardings is not None,
             recurrent=recurrent,
         )
-        h = rms_norm(x, params["final_norm"], c.norm_eps)
+        h = final_norm(c, params, x)
         logits = logits_linear(h[:, -1], head_weights(params))
         next_token = _select_next_token(state, logits, rng)
 
@@ -959,7 +959,7 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None,
 
         def one(carry, step_rng):
             dk, dv, pos, token = carry          # dk/dv: the POOL
-            x = jnp.take(params["embed"], token[:, None], axis=0)
+            x = embed_tokens(params, token[:, None])
             write_ok = active & (pos < ml)
             blk = jnp.take_along_axis(
                 block_tables, jnp.clip(pos[:, None] // bs, 0, mb - 1), axis=1
@@ -973,7 +973,7 @@ def make_spec_draft(config: ModelConfig, k: int, shardings=None,
                 jnp.where(active, pos + 1, 0)[:, None],  # dead slots: nothing
                 attn_impl=attn_impl, partitioned=shardings is not None,
             )
-            h = rms_norm(x, params["final_norm"], c.norm_eps)
+            h = final_norm(c, params, x)
             logits = logits_linear(h[:, -1], head_weights(params))  # (B, V)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             probs = _sampling_probs(logits[:, None], temps, top_ps)[:, 0]
@@ -1064,7 +1064,7 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
         blk = jnp.where(ok_w, blk, nb)
         off = positions % bs
 
-        x = jnp.take(params["embed"], tokens, axis=0)        # (B, S, d)
+        x = embed_tokens(params, tokens)                     # (B, S, d)
 
         aix = state.adapter_ix
         x, new_k, new_v = _layer_loop(
@@ -1074,7 +1074,7 @@ def make_spec_verify(config: ModelConfig, k: int, shardings=None,
             bank=bank, adapter_ix=aix, has_lora=jnp.any(act0 & (aix >= 0)),
             attn_impl=attn_impl, partitioned=shardings is not None,
         )
-        h = rms_norm(x, params["final_norm"], c.norm_eps)
+        h = final_norm(c, params, x)
         logits = logits_linear(h, head_weights(params))      # (B, S, V)
 
         temps = state.temperature

@@ -104,42 +104,43 @@ def project_qkv_lora(c, x, p, positions, lp, adapter_ix, scale, has_lora):
     branch is byte-for-byte the plain q/k/v projection (no f32 casts, no
     zero adds), so adapter-free steps pay one predicate, not the feature.
     """
-    b, s, _ = x.shape
-    hd = c.head_dim
-    h = rms_norm(x, p["attn_norm"], c.norm_eps)
+    with jax.named_scope("attn/qkv"):
+        b, s, _ = x.shape
+        hd = c.head_dim
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
 
-    def _plain(_):
-        return (linear(h, p["wq"]), linear(h, p["wk"]), linear(h, p["wv"]))
+        def _plain(_):
+            return (linear(h, p["wq"]), linear(h, p["wk"]), linear(h, p["wv"]))
 
-    def _with_lora(_):
-        hf = h.astype(jnp.float32)
+        def _with_lora(_):
+            hf = h.astype(jnp.float32)
 
-        def _delta(name: str):
-            a_pool, b_pool = lp[f"{name}_a"], lp[f"{name}_b"]
-            if adapter_ix.ndim == 0:  # chunked prefill: one request
-                a = a_pool[adapter_ix].astype(jnp.float32)
-                bm = b_pool[adapter_ix].astype(jnp.float32)
-                t = jnp.einsum("bsd,dr->bsr", hf, a)
-                return jnp.einsum("bsr,ro->bso", t, bm) * scale
-            a = jnp.take(a_pool, adapter_ix, axis=0).astype(jnp.float32)
-            bm = jnp.take(b_pool, adapter_ix, axis=0).astype(jnp.float32)
-            t = jnp.einsum("bsd,bdr->bsr", hf, a)
-            return jnp.einsum("bsr,bro->bso", t, bm) * scale[:, None, None]
+            def _delta(name: str):
+                a_pool, b_pool = lp[f"{name}_a"], lp[f"{name}_b"]
+                if adapter_ix.ndim == 0:  # chunked prefill: one request
+                    a = a_pool[adapter_ix].astype(jnp.float32)
+                    bm = b_pool[adapter_ix].astype(jnp.float32)
+                    t = jnp.einsum("bsd,dr->bsr", hf, a)
+                    return jnp.einsum("bsr,ro->bso", t, bm) * scale
+                a = jnp.take(a_pool, adapter_ix, axis=0).astype(jnp.float32)
+                bm = jnp.take(b_pool, adapter_ix, axis=0).astype(jnp.float32)
+                t = jnp.einsum("bsd,bdr->bsr", hf, a)
+                return jnp.einsum("bsr,bro->bso", t, bm) * scale[:, None, None]
 
-        def proj(name: str):
-            y = linear(h, p[name])
-            if f"{name}_a" in lp:
-                y = (y.astype(jnp.float32) + _delta(name)).astype(y.dtype)
-            return y
+            def proj(name: str):
+                y = linear(h, p[name])
+                if f"{name}_a" in lp:
+                    y = (y.astype(jnp.float32) + _delta(name)).astype(y.dtype)
+                return y
 
-        return (proj("wq"), proj("wk"), proj("wv"))
+            return (proj("wq"), proj("wk"), proj("wv"))
 
-    q, k, v = lax.cond(has_lora, _with_lora, _plain, 0)
-    q = q.reshape(b, s, c.n_heads, hd)
-    k = k.reshape(b, s, c.n_kv_heads, hd)
-    v = v.reshape(b, s, c.n_kv_heads, hd)
-    rope = c.rope(FULL)
-    return _rope(q, positions, rope), _rope(k, positions, rope), v
+        q, k, v = lax.cond(has_lora, _with_lora, _plain, 0)
+        q = q.reshape(b, s, c.n_heads, hd)
+        k = k.reshape(b, s, c.n_kv_heads, hd)
+        v = v.reshape(b, s, c.n_kv_heads, hd)
+        rope = c.rope(FULL)
+        return _rope(q, positions, rope), _rope(k, positions, rope), v
 
 
 class AdapterRegistry:

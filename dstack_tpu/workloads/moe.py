@@ -308,23 +308,23 @@ def _moe_mlp_capacity(
             return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
         return x
 
-    # Token all-to-all: tokens (batch-sharded) -> expert slots
-    # (expert-sharded). XLA materializes the collective from the two
-    # constraints on either side of this einsum.
-    expert_in = jnp.einsum(
-        "bsec,bsd->ebcd", dispatch.astype(h.dtype), h
-    )
-    expert_in = constrain(expert_in, P("expert", ("data", "fsdp"), None, None))
     with jax.named_scope("moe/experts"):
+        # Token all-to-all: tokens (batch-sharded) -> expert slots
+        # (expert-sharded). XLA materializes the collective from the two
+        # constraints on either side of this einsum.
+        expert_in = jnp.einsum(
+            "bsec,bsd->ebcd", dispatch.astype(h.dtype), h
+        )
+        expert_in = constrain(expert_in, P("expert", ("data", "fsdp"), None, None))
         expert_out = _expert_ffn(h.dtype, expert_in, p)
-    expert_out = constrain(
-        expert_out, P("expert", ("data", "fsdp"), None, None)
-    )
+        expert_out = constrain(
+            expert_out, P("expert", ("data", "fsdp"), None, None)
+        )
 
-    out = jnp.einsum(
-        "bsec,ebcd->bsd", combine.astype(h.dtype), expert_out
-    )
-    return out, aux
+        out = jnp.einsum(
+            "bsec,ebcd->bsd", combine.astype(h.dtype), expert_out
+        )
+        return out, aux
 
 
 def _weight_tile(tile: int, k: int, n: int) -> Tuple[int, int]:
@@ -436,39 +436,39 @@ def _routed_bank(
     B, S, D = h.shape
     E, k = we_gate.shape[-3], gate_idx.shape[-1]
     T = B * S
-    # Expert-major place of every routed row: the experts before its own,
-    # then the earlier batch rows' share of its expert, then its slot
-    # (route_assignments' cumsum already counted its place in its row).
-    sel = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)  # (B,S,k,E)
-    counts = jnp.sum(sel, axis=(1, 2))  # (B,E)
-    group_sizes = jnp.sum(counts, axis=0)  # (E,)
-    first = jnp.cumsum(group_sizes) - group_sizes  # (E,)
-    before = first[None, :] + jnp.cumsum(counts, axis=0) - counts  # (B,E)
-    dest = (slot + jnp.einsum("bske,be->bsk", sel, before)).reshape(T * k)
-    src = jnp.argsort(dest)  # sorted row -> t * k + j
-    if layer is not None:
-        # The stack as L * E groups, every one empty but this layer's: an
-        # empty group costs the kernel no tile and no read.
-        L = we_gate.shape[0]
-        group_sizes = lax.dynamic_update_slice(
-            jnp.zeros((L * E,), group_sizes.dtype), group_sizes, (layer * E,)
-        )
-        we_gate, we_up, we_down = (
-            w.reshape((L * E,) + w.shape[2:]) for w in (we_gate, we_up, we_down)
-        )
-
-    x = _take_rows(h.reshape(T, D), src // k, dest.reshape(T, k))  # (T*k, D)
     with jax.named_scope("moe/experts"):
+        # Expert-major place of every routed row: the experts before its own,
+        # then the earlier batch rows' share of its expert, then its slot
+        # (route_assignments' cumsum already counted its place in its row).
+        sel = jax.nn.one_hot(gate_idx, E, dtype=jnp.int32)  # (B,S,k,E)
+        counts = jnp.sum(sel, axis=(1, 2))  # (B,E)
+        group_sizes = jnp.sum(counts, axis=0)  # (E,)
+        first = jnp.cumsum(group_sizes) - group_sizes  # (E,)
+        before = first[None, :] + jnp.cumsum(counts, axis=0) - counts  # (B,E)
+        dest = (slot + jnp.einsum("bske,be->bsk", sel, before)).reshape(T * k)
+        src = jnp.argsort(dest)  # sorted row -> t * k + j
+        if layer is not None:
+            # The stack as L * E groups, every one empty but this layer's: an
+            # empty group costs the kernel no tile and no read.
+            L = we_gate.shape[0]
+            group_sizes = lax.dynamic_update_slice(
+                jnp.zeros((L * E,), group_sizes.dtype), group_sizes, (layer * E,)
+            )
+            we_gate, we_up, we_down = (
+                w.reshape((L * E,) + w.shape[2:]) for w in (we_gate, we_up, we_down)
+            )
+
+        x = _take_rows(h.reshape(T, D), src // k, dest.reshape(T, k))  # (T*k, D)
         gate = _grouped_matmul(x, we_gate, group_sizes, tile)
         up = _grouped_matmul(x, we_up, group_sizes, tile)
         act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
         y = _grouped_matmul(act.astype(h.dtype), we_down, group_sizes, tile)
-    y = _take_rows(y, dest, src[:, None])  # (T*k, D)
-    out = jnp.sum(
-        gates.reshape(T, k, 1) * y.reshape(T, k, D).astype(jnp.float32),
-        axis=1,
-    )
-    return out.astype(h.dtype).reshape(B, S, D)
+        y = _take_rows(y, dest, src[:, None])  # (T*k, D)
+        out = jnp.sum(
+            gates.reshape(T, k, 1) * y.reshape(T, k, D).astype(jnp.float32),
+            axis=1,
+        )
+        return out.astype(h.dtype).reshape(B, S, D)
 
 
 def _moe_mlp_routed(
@@ -523,11 +523,12 @@ def moe_block(
     With shared experts (`ws_*` weights) every token also passes through
     their SwiGLU, added beside the routed sum."""
     from dstack_tpu.workloads.transformer import _silu, linear, rms_norm
-
-    h = rms_norm(x, p["mlp_norm"], c.norm_eps)
+    with jax.named_scope("moe/route"):
+        h = rms_norm(x, p["mlp_norm"], c.norm_eps)
     out, aux = moe_mlp(c, h, p, mesh, partitioned, layer)
     if "ws_gate" in p:
         with jax.named_scope("moe/shared"):
             gate = _silu(linear(h, p["ws_gate"]))
             out = out + linear(gate * linear(h, p["ws_up"]), p["ws_down"])
-    return x + out, aux
+    with jax.named_scope("moe/experts"):
+        return x + out, aux

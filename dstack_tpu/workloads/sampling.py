@@ -39,23 +39,23 @@ def sample_logits_row(logits, temp, top_p, rng):
     extra compile entries per sampling config. The chunked paged prefill
     (kv_blocks.make_chunk_prefill) samples a prompt's first token with
     it."""
+    with jax.named_scope("sample"):
+        def _sample(x):
+            scaled = x / jnp.maximum(temp, 1e-6)
+            filtered = lax.cond(
+                top_p < 1.0,
+                lambda s: _nucleus_filter(s, top_p),
+                lambda s: s,
+                scaled,
+            )
+            return jax.random.categorical(rng, filtered).astype(jnp.int32)
 
-    def _sample(x):
-        scaled = x / jnp.maximum(temp, 1e-6)
-        filtered = lax.cond(
-            top_p < 1.0,
-            lambda s: _nucleus_filter(s, top_p),
-            lambda s: s,
-            scaled,
+        return lax.cond(
+            temp > 0.0,
+            _sample,
+            lambda x: jnp.argmax(x).astype(jnp.int32),
+            logits,
         )
-        return jax.random.categorical(rng, filtered).astype(jnp.int32)
-
-    return lax.cond(
-        temp > 0.0,
-        _sample,
-        lambda x: jnp.argmax(x).astype(jnp.int32),
-        logits,
-    )
 
 
 def _any_active_nucleus(state) -> jnp.ndarray:
@@ -104,26 +104,27 @@ def _select_next_token(state, logits, rng):
     sampling batch with every live top_p=1 skips the vocab-wide
     sort/cumsum. lax.cond executes one branch at runtime, so each
     skipped stage costs only its predicate."""
-    temps = state.temperature
+    with jax.named_scope("sample"):
+        temps = state.temperature
 
-    def _sample(x):
-        scaled = x / jnp.maximum(temps, 1e-6)[:, None]
-        filtered = lax.cond(
-            _any_active_nucleus(state),
-            lambda s: jax.vmap(_nucleus_filter)(s, state.top_p),
-            lambda s: s,
-            scaled,
+        def _sample(x):
+            scaled = x / jnp.maximum(temps, 1e-6)[:, None]
+            filtered = lax.cond(
+                _any_active_nucleus(state),
+                lambda s: jax.vmap(_nucleus_filter)(s, state.top_p),
+                lambda s: s,
+                scaled,
+            )
+            return jax.random.categorical(rng, filtered, axis=-1).astype(jnp.int32)
+
+        sampled = lax.cond(
+            _any_active_sampling(state),
+            _sample,
+            lambda x: jnp.zeros((x.shape[0],), jnp.int32),  # value unused
+            logits,
         )
-        return jax.random.categorical(rng, filtered, axis=-1).astype(jnp.int32)
-
-    sampled = lax.cond(
-        _any_active_sampling(state),
-        _sample,
-        lambda x: jnp.zeros((x.shape[0],), jnp.int32),  # value unused
-        logits,
-    )
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy)
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jnp.where(temps > 0, sampled, greedy)
 
 
 def _sampling_probs(logits, temps, top_ps):
@@ -133,15 +134,16 @@ def _sampling_probs(logits, temps, top_ps):
     traffic never pays the vocab sort). logits (B, S, V), temps /
     top_ps (B,) -> probs (B, S, V). Rejection sampling is exact only
     if drafter q and target p both come from THIS function."""
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None, None]
-    filtered = lax.cond(
-        jnp.any((temps > 0.0) & (top_ps < 1.0)),
-        lambda s: jax.vmap(
-            lambda rows, tp: jax.vmap(
-                lambda r: _nucleus_filter(r, tp)
-            )(rows)
-        )(s, top_ps),
-        lambda s: s,
-        scaled,
-    )
-    return jax.nn.softmax(filtered, axis=-1)
+    with jax.named_scope("sample"):
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None, None]
+        filtered = lax.cond(
+            jnp.any((temps > 0.0) & (top_ps < 1.0)),
+            lambda s: jax.vmap(
+                lambda rows, tp: jax.vmap(
+                    lambda r: _nucleus_filter(r, tp)
+                )(rows)
+            )(s, top_ps),
+            lambda s: s,
+            scaled,
+        )
+        return jax.nn.softmax(filtered, axis=-1)

@@ -210,14 +210,14 @@ def logits_linear(x: jnp.ndarray, w) -> jnp.ndarray:
     """The lm-head matmul: f32 logits from bf16/quantized weights."""
     from dstack_tpu.workloads.quant import QTensor
 
-    if isinstance(w, QTensor):
-        y = jnp.matmul(
-            x, w.q.astype(x.dtype), preferred_element_type=jnp.float32
-        )
-        return y * w.scale
-    return jnp.matmul(
-        x, w, preferred_element_type=jnp.float32
-    ).astype(jnp.float32)
+    with jax.named_scope("head"):
+        if isinstance(w, QTensor):
+            y = jnp.matmul(
+                x, w.q.astype(x.dtype), preferred_element_type=jnp.float32
+            )
+            return y * w.scale
+        y = jnp.matmul(x, w, preferred_element_type=jnp.float32)
+        return y.astype(jnp.float32)
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -253,19 +253,19 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, rope: RopeParams) -> jnp.ndarr
 
 def project_qkv(c: ModelConfig, x: jnp.ndarray, p: Params,
                 positions: jnp.ndarray, kind: str = FULL):
-    """Pre-norm QKV projection with the rope of a `kind` layer — shared by
-    the training block and the KV-cache decode path (generate.py) so they
-    cannot drift."""
+    """Pre-norm QKV projection with the rope of a `kind` layer: one function
+    for the training block and the KV-cache decode path (generate.py)."""
     b, s, _ = x.shape
     hd = c.head_dim
     rope = c.rope(kind)
-    h = rms_norm(x, p["attn_norm"], c.norm_eps)
-    q = linear(h, p["wq"]).reshape(b, s, c.n_heads, hd)
-    k = linear(h, p["wk"]).reshape(b, s, c.n_kv_heads, hd)
-    v = linear(h, p["wv"]).reshape(b, s, c.n_kv_heads, hd)
-    if not c.use_rope:
-        return q, k, v
-    return _rope(q, positions, rope), _rope(k, positions, rope), v
+    with jax.named_scope("attn/qkv"):
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
+        q = linear(h, p["wq"]).reshape(b, s, c.n_heads, hd)
+        k = linear(h, p["wk"]).reshape(b, s, c.n_kv_heads, hd)
+        v = linear(h, p["wv"]).reshape(b, s, c.n_kv_heads, hd)
+        if not c.use_rope:
+            return q, k, v
+        return _rope(q, positions, rope), _rope(k, positions, rope), v
 
 
 def scan_layers(c: ModelConfig, block, carry, xs, own=None):
@@ -442,10 +442,10 @@ _silu.defvjp(_silu_fwd, _silu_bwd)
 
 def mlp_block(c: ModelConfig, x: jnp.ndarray, p: Params) -> jnp.ndarray:
     """Pre-norm SwiGLU MLP with residual — shared with generate.py."""
-    h = rms_norm(x, p["mlp_norm"], c.norm_eps)
-    gate = _silu(linear(h, p["w_gate"]))
-    up = linear(h, p["w_up"])
-    return x + linear(gate * up, p["w_down"])
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["mlp_norm"], c.norm_eps)
+        gate, up = _silu(linear(h, p["w_gate"])), linear(h, p["w_up"])
+        return x + linear(gate * up, p["w_down"])
 
 
 def mamba_inputs(c: ModelConfig, x: jnp.ndarray, p: Params, tail0, n_valid):
@@ -584,7 +584,7 @@ def _block(
         # for full layers alone keeps working on models that have no other.
         kw = {"window": c.window(kind)} if c.window(kind) else {}
         attn = attention_fn(q, k, v, **kw).reshape(b, s, c.n_heads * c.head_dim)
-    x = x + linear(attn, p["wo"])
+    x = x + attn_output(attn, p)
     if "router" in p:
         from dstack_tpu.workloads.moe import moe_block
 
@@ -616,7 +616,7 @@ def forward(
     if positions is None:
         positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
 
-    x = jnp.take(params["embed"], tokens, axis=0)
+    x = embed_tokens(params, tokens)
 
     quadratic = getattr(attn, "memory_is_quadratic", None)
     if quadratic is not None:
@@ -648,10 +648,32 @@ def forward(
         )
     x, aux = carry
 
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
+    x = final_norm(c, params, x)
     if return_hidden:
         return (x, aux) if return_aux else x
     logits = logits_linear(x, head_weights(params))
     if return_aux:
         return logits, aux
     return logits
+
+
+# The two ends of every program and the attention block's way out, each
+# under its scope (a device profile is read by them: benchmarks/scope_reduce.py).
+
+
+def embed_tokens(params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
+    """tokens (...) int32 -> their embedding rows (..., d)."""
+    with jax.named_scope("embed"):
+        return jnp.take(params["embed"], tokens, axis=0)
+
+
+def final_norm(c: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """The norm before the head (`logits_linear` carries the same scope)."""
+    with jax.named_scope("head"):
+        return rms_norm(x, params["final_norm"], c.norm_eps)
+
+
+def attn_output(attn: jnp.ndarray, p: Params) -> jnp.ndarray:
+    """The attention block's out projection (before the residual)."""
+    with jax.named_scope("attn/out"):
+        return linear(attn, p["wo"])
