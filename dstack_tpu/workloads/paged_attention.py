@@ -31,15 +31,23 @@ Two implementations behind one dispatch seam (`ragged_attention`):
   for a group of columns a step with `make_async_copy`, into one half of
   a two-slot VMEM buffer while the other half is attended: the gather is
   those DMAs, and a dead slot or the table's unused tail costs nothing.
-  Softmax state (running max m, denominator l, unnormalized output o)
-  accumulates in VMEM scratch block by block, the standard flash
+  The unit of work is a (KV head, fetched group): a KV head's query rows
+  (positions x its H / KV heads) are multiplied against THAT head's rows
+  of the whole group, lifted out of the buffer in VMEM (`_head_rows`), so
+  a query meets no key of another KV head and a group of eight 16-token
+  blocks is one 128-column logits tile a KV head. Softmax state (running
+  max m, denominator l, unnormalized output o), one set a KV head,
+  accumulates in VMEM scratch group by group, the standard flash
   accumulation (same math as `attention._block_attend`). Pad-sentinel
   table entries (== num_blocks) clamp to a real block before the layer
   offset and are masked out of the logits, as are rows at or beyond each
-  query's `valid_len`. The slab shapes are chosen for the TPU (8, 128)
-  tiling rule (see the note above the kernel); tests/test_tpu_lowering.py
-  compiles it for a v5e at the engine's shapes and holds its grid to
-  slots x query tiles, chip_smoke.py checks it on the chip.
+  query's `valid_len` and the unfetched places of a row's cut last group
+  (whose value rows are cleared: a masked column still meets them as
+  0 x value). The slab shapes are chosen for the TPU (8, 128) tiling rule
+  (see the note above the kernel); tests/test_tpu_lowering.py compiles it
+  for a v5e at the engine's shapes, holds its grid to slots x query tiles
+  and its products to one KV head's rows; chip_smoke.py checks it on the
+  chip.
 
 - `_ragged_attention_lax`: pure-lax path for CPU, sharded engines and
   geometries the kernel does not take. Two `lax.fori_loop` passes walk
@@ -56,8 +64,9 @@ Two implementations behind one dispatch seam (`ragged_attention`):
 
 Both paths mask, scale, and accumulate identically, so the
 interpret-mode parity test (tests/test_paged_attention.py) pins them
-together to f32 rounding (the kernel folds its softmax into one pass;
-on the test's f32 inputs the quantization casts are no-ops).
+together to f32 rounding (the kernel folds its softmax into one pass, a
+group of blocks a step where the lax path takes a block; on the test's
+f32 inputs the quantization casts are no-ops).
 
 Latent pools (`latent_values` > 0, kv_blocks' pool of a latent-attention
 model): a token keeps ONE row for all heads, `(bs, 1, width)` a block, and
@@ -65,7 +74,7 @@ its value is the first `latent_values` columns of that same row. Both
 paths then read the k pool alone — the value tile is a lane-aligned slice
 of the key tile already in VMEM, never a second fetch — and emit
 `(B, S, H * latent_values)`; every head shares the row, so the GQA
-kernel's cross-group mask has no counterpart. The caller passes `scale`
+kernel's sorting of rows by KV head has no counterpart. The caller passes `scale`
 (the model's head size, not the row's width, sets it).
 
 Window layers (`window` > 0, a sliding-attention layer of a model that
@@ -308,34 +317,47 @@ def _ragged_attention_lax(q, k_pool, v_pool, layer, tables, valid_len, *,
 #
 # TPU block shapes must tile the LAST TWO array dims by (8, 128) — (16, 128)
 # for bf16 — or span them whole. The pool's last two dims are (KV, hd), so a
-# one-head K tile `(bs, 1, hd)` is not a legal block. The kernel instead
-# takes every operand as a 2-D slab whose trailing dims are whole:
+# one-head K tile `(bs, 1, hd)` is not a legal block, and on the device two
+# bf16 rows share a 32-bit sublane word: heads 2w and 2w+1 of one position
+# lie 16 bits apart. The kernel takes its operands as
 #
 #   pool  (L, NB, bs, KV, hd) -> (L*NB, bs*KV, hd)   rows ordered (t, g)
-#   q     (B, S, H, hd)       -> (B, S*H, hd)        rows ordered (s, h)
+#   q     (B, S, H, hd)       -> (B, KV, S*G, hd)    rows ordered (s, h % G)
 #
-# (both reshapes keep the row-major order; with KV a multiple of the
-# sublane tile they are layout bitcasts, not copies). One block's step
-# multiplies a tile of query rows against ALL of the block's (t, g) rows in
-# a single matmul and masks the pairs whose query head does not belong to
-# the column's KV head — KV times the needed MXU work, spent to keep every
-# load a plain aligned tile: no strided sublane reads, no in-kernel
-# transposes. That all-pairs product is what is left of ROADMAP S2.
+# (the pool's is a layout bitcast of the stack, as it was; `(bs, KV*hd)` is
+# the same bytes only in row-major terms, on the chip it is another tiling
+# and XLA would copy the pool to make it. The query's and the output's are
+# small transposes under the caller's `attn` scope, free at S = 1.) A block
+# is fetched whole, one contiguous copy, and the unit of work is a (KV head,
+# fetched group): `_head_rows` lifts head j's rows of the WHOLE group out of
+# the buffer, `group * bs` positions x hd, with sublane-strided 32-bit loads
+# and, for 16-bit pools, three integer operations a vector register that
+# unzip the two heads of a word; a query row is multiplied against the keys
+# of its own KV head alone, so every column of a logits tile is a wanted
+# pair up to the length, window and sentinel masks, and the softmax's
+# exponentials, its two reductions, the accumulator's rescale and `p.V` run
+# once a (KV head, group), not once a block over KV times the pairs.
 #
 # The grid is (slot, query tile) and nothing else: a grid step costs time
 # whether or not it has work, and a grid over the table's columns made a
 # decode call cost its 16 x 288 steps whatever was live (PERF.md section 6,
-# PR 28). The pool stays in HBM (`pl.ANY`) and the body walks the tile's
-# live table columns itself, a group of blocks a loop step: while one
-# group is attended, the DMAs of the next are in flight into the other
-# half of a two-slot VMEM buffer. The trip count is the tile's own
-# (`n_cols[b, tile]`, scalar-prefetched), so a dead slot makes no trip and
-# a short context pays for its own blocks only.
+# PR 28). A query tile is positions x the G heads of a KV head, every KV
+# head's tile in one grid step with an accumulator each, so a chunk's tile
+# fetches the row's keys and values once for all heads. The pool stays in
+# HBM (`pl.ANY`) and the body walks the tile's live table columns itself, a
+# group of blocks a loop step: while one group is attended, the DMAs of the
+# next are in flight into the other half of a two-slot VMEM buffer. The trip
+# count is the tile's own (`n_cols[b, tile]`, scalar-prefetched), so a dead
+# slot makes no trip and a short context pays for its own blocks only.
 
 # Bytes of K (and as many of V) in flight per buffer slot. The group of
-# blocks one loop step fetches is this over a block's bytes: eight 32 KiB
-# blocks at 16-token blocks of 8 KV heads, one block from 256 tokens up.
+# blocks one loop step fetches and attends is this over a block's bytes:
+# eight 32 KiB blocks (128 positions) at 16-token blocks of 8 KV heads, one
+# block from 256 tokens of 4 KV heads up.
 _GROUP_BYTES = 256 * 1024
+
+# A position no context reaches: what a pad sentinel's columns are given.
+_NOWHERE = 1 << 30
 
 
 def _group_blocks(block_bytes: int, table_cols: int) -> int:
@@ -344,9 +366,10 @@ def _group_blocks(block_bytes: int, table_cols: int) -> int:
 
 
 def _accumulate_block(logits, v, acc_ref, m_ref, l_ref):
-    """One block's step of the streaming softmax, shared by both kernels:
-    fold the masked (TQ, cols) logits and the block's (cols, vd) values
-    into the running max, denominator and unnormalized output."""
+    """One step of the streaming softmax, shared by both kernels: fold the
+    masked (TQ, cols) logits and the step's (cols, vd) values into the
+    running max, denominator and unnormalized output. With a leading axis
+    on all five it is one such step a KV head, in one batched product."""
     blk_m = jnp.maximum(
         jnp.max(logits, axis=-1, keepdims=True), NEG_INF / 2
     )
@@ -358,11 +381,46 @@ def _accumulate_block(logits, v, acc_ref, m_ref, l_ref):
     beta = jnp.exp(blk_m - m_new)
     m_ref[...] = m_new
     l_ref[...] = l_ref[...] * alpha + blk_l * beta
+    heads = tuple(range(logits.ndim - 2))
     pv = lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        p.astype(v.dtype), v,
+        (((logits.ndim - 1,), (logits.ndim - 2,)), (heads, heads)),
         preferred_element_type=jnp.float32,
     )  # (TQ, vd)
     acc_ref[...] = acc_ref[...] * alpha + beta * pv
+
+
+def _head_rows(buf, slot, num_kv_heads, positions):
+    """Every KV head's rows of the group in buffer slot `slot`, whose rows
+    are ordered (position, head): (KV, positions, hd). A sublane-strided
+    load takes whole 32-bit words, and two heads of a 16-bit pool share
+    one, so they come out together: a head pair's even and odd positions
+    are loaded apart and their halves exchanged, exact bit moves that leave
+    the packed rows a one-head buffer would hold (Mosaic loads no 16-bit
+    type with a stride)."""
+    if num_kv_heads == 1:
+        return buf[slot][None]
+    if buf.dtype.itemsize == 4:
+        return jnp.stack([
+            buf[slot, pl.ds(j, positions, stride=num_kv_heads), :]
+            for j in range(num_kv_heads)
+        ])
+    words = buf.bitcast(jnp.uint32)  # row (t, w): heads 2w, 2w+1 of position t
+    per_pos = num_kv_heads // 2
+    even, odd = (
+        jnp.stack([
+            words[slot, pl.ds(w + per_pos * i, positions // 2, stride=2 * per_pos), :]
+            for w in range(per_pos)
+        ])
+        for i in (0, 1)
+    )  # (KV / 2, positions / 2, hd): a word = heads 2w | 2w+1 of one position
+    pairs = jnp.stack([
+        (even & 0xFFFF) | (odd << 16),  # head 2w at positions 2u | 2u+1
+        (even >> 16) | (odd & jnp.uint32(0xFFFF0000)),  # head 2w+1
+    ], axis=1)
+    return pltpu.bitcast(
+        pairs.reshape(num_kv_heads, positions // 2, pairs.shape[-1]), buf.dtype
+    )
 
 
 def _paged_kernel(
@@ -370,22 +428,20 @@ def _paged_kernel(
     nc_ref,  # scalar prefetch: (B, q tiles) table columns a tile may see
     c0_ref,  # scalar prefetch: (B, q tiles) the first of them (window layers)
     layer_ref,  # scalar prefetch: (1,) layer index into the stacked pool
-    q_ref,  # (TQ, hd) query rows, ordered (s, h)
-    vlen_ref,  # (TQ, 1) valid_len of each query row
+    q_ref,  # (KV, TQ, hd) query rows a KV head, ordered (s, h % G)
+    vlen_ref,  # (TQ, 1) valid_len of each query row, the same for every head
     k_hbm,  # (L*NB, bs*KV, hd) the whole K pool, left in HBM
     v_hbm,  # (L*NB, bs*KV, hd)
-    o_ref,  # (TQ, hd)
-    k_buf,  # VMEM scratch (2, group, bs*KV, hd): two slots of one group
-    v_buf,  # VMEM scratch (2, group, bs*KV, hd)
+    o_ref,  # (KV, TQ, hd)
+    k_buf,  # VMEM scratch (2, group*bs*KV, hd): two slots of one group
+    v_buf,  # VMEM scratch (2, group*bs*KV, hd)
     sems,  # DMA semaphores (2, group): one per buffered block
-    acc_ref,  # VMEM scratch (TQ, hd) f32
-    m_ref,  # VMEM scratch (TQ, 1) f32
-    l_ref,  # VMEM scratch (TQ, 1) f32
+    acc_ref,  # VMEM scratch (KV, TQ, hd) f32
+    m_ref,  # VMEM scratch (KV, TQ, 1) f32
+    l_ref,  # VMEM scratch (KV, TQ, 1) f32
     *,
     block_size: int,
     num_pool_blocks: int,
-    num_heads: int,
-    num_kv_heads: int,
     group: int,
     scale: float,
     window: int,
@@ -394,29 +450,29 @@ def _paged_kernel(
     n_cols = nc_ref[b, pl.program_id(1)]
     c0 = c0_ref[b, pl.program_id(1)]
     base = layer_ref[0] * num_pool_blocks
+    num_kv_heads = q_ref.shape[0]
+    block_rows = block_size * num_kv_heads
+    positions = group * block_size
 
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, NEG_INF / 2)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    # What of a block's mask no block changes, made once a grid step: which
-    # (query row, block row) pairs share a KV head, and each block row's
-    # position inside its block. 2D iotas (TPU requires >= 2D); TQ is a
-    # multiple of H, so a tile-local row index resolves the query head.
-    pairs = (q_ref.shape[0], block_size * num_kv_heads)
-    row = lax.broadcasted_iota(jnp.int32, pairs, 0)
-    lane = lax.broadcasted_iota(jnp.int32, pairs, 1)
-    same_head = lax.div(
-        lax.rem(row, num_heads), num_heads // num_kv_heads
-    ) == lax.rem(lane, num_kv_heads)
-    pos_in_block = lax.div(lane, num_kv_heads)
+    # A group's columns are its positions, block after block (2D iota: TPU
+    # requires >= 2D).
+    lane = lax.broadcasted_iota(jnp.int32, (1, positions), 1)
+    lane_block = lax.div(lane, block_size)
 
-    def each_live_block(step, slot, fn):
-        """`fn(col, g, copies)` for the blocks of group `step` that lie
-        inside the tile's live columns `c0 .. n_cols`, in table order;
-        `copies` are the block's two DMAs into place g of buffer slot
-        `slot`. The last group of a row is cut at `n_cols` here: nothing
-        past it is fetched, waited for or attended. A loop, not `group`
+    def place(g):
+        """The buffer rows of the group's g-th block."""
+        return pl.ds(pl.multiple_of(g * block_rows, block_rows), block_rows)
+
+    def each_live_block(step, slot, fn, carry):
+        """Fold `fn(col, g, copies, carry)` over the blocks of group `step`
+        that lie inside the tile's live columns `c0 .. n_cols`, in table
+        order; `copies` are the block's two DMAs into place g of buffer
+        slot `slot`. The last group of a row is cut at `n_cols` here:
+        nothing past it is fetched or waited for. A loop, not `group`
         copies of the body: tracing the copies cost every engine start
         seconds."""
         first = c0 + step * group
@@ -424,51 +480,79 @@ def _paged_kernel(
         def block(col, carry):
             g = col - first
             # The pad sentinel clamps inside the layer's own NB blocks
-            # BEFORE the layer offset; `attend` masks what it fetched.
+            # BEFORE the layer offset; `arrive` masks what it fetched.
             src = base + jnp.minimum(t_ref[b, col], num_pool_blocks - 1)
+            rows = place(g)
             # One semaphore a buffered block, signalled by its K and its
             # V copy: a semaphore counts bytes, so one shared by a group's
             # copies in flight could pass a wait on parts of several.
-            fn(col, g, [
+            return fn(col, g, [
                 pltpu.make_async_copy(
-                    pool.at[src], buf.at[slot, g], sems.at[slot, g]
+                    pool.at[src], buf.at[slot, rows], sems.at[slot, g]
                 )
                 for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))
-            ])
-            return carry
+            ], carry)
 
-        lax.fori_loop(first, jnp.minimum(first + group, n_cols), block, 0)
+        return lax.fori_loop(
+            first, jnp.minimum(first + group, n_cols), block, carry
+        )
 
-    def fetch(col, g, copies):
+    def fetch(col, g, copies, carry):
         for copy in copies:
             copy.start()
+        return carry
+
+    def arrive(col, g, copies, pos):
+        """Wait for a block; a pad sentinel's block was clamped to a real
+        one, so its columns are sent where no row's length reaches."""
+        for copy in copies:
+            copy.wait()
+        away = jnp.where(t_ref[b, col] < num_pool_blocks, 0, _NOWHERE)
+        return pos + jnp.where(lane_block == g, away, 0)
 
     def attend_group(step, carry):
         slot = lax.rem(step, 2)
-        each_live_block(step + 1, 1 - slot, fetch)
+        each_live_block(step + 1, 1 - slot, fetch, 0)
+        first = c0 + step * group
+        pos = each_live_block(step, slot, arrive, first * block_size + lane)
 
-        def attend(col, g, copies):
-            for copy in copies:
-                copy.wait()
-            # Storage-dtype operands with f32 accumulation, scale applied
-            # to the f32 logits — the same placement as
-            # attention._block_attend.
-            logits = lax.dot_general(
-                q_ref[...], k_buf[slot, g], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (TQ, bs*KV)
-            pos = col * block_size + pos_in_block
-            ok = same_head & (pos < vlen_ref[...])
-            if window:
-                ok &= pos >= vlen_ref[...] - window
-            ok &= t_ref[b, col] < num_pool_blocks  # a clamped sentinel
-            logits = jnp.where(ok, logits, NEG_INF)
-            _accumulate_block(logits, v_buf[slot, g], acc_ref, m_ref, l_ref)
+        # A cut last group leaves buffer places unfetched. Their columns
+        # lie at or past every row's length and are masked below, but a
+        # masked column still meets its value row in `p.V` as 0 x value:
+        # whatever the place holds (another row's block, or bits no copy
+        # ever wrote, NaN among them) is cleared first. Keys need nothing:
+        # their logits are replaced, not scaled.
+        def clear(g, carry):
+            v_buf[slot, place(g), :] = jnp.zeros(
+                (block_rows, v_buf.shape[-1]), v_buf.dtype
+            )
+            return carry
 
-        each_live_block(step, slot, attend)
+        lax.fori_loop(jnp.minimum(n_cols - first, group), group, clear, 0)
+
+        # The masks are the same for every KV head: made once a group.
+        ok = pos < vlen_ref[...]  # (TQ, positions)
+        if window:
+            ok &= pos >= vlen_ref[...] - window
+        # One batched step over the KV heads, not a loop over them: written
+        # head after head the heads' chains (product, softmax, product, fold)
+        # ran one behind the other, 0.17 us a live block of a decode call
+        # against 0.12, and eight copies of the step cost every engine start
+        # 3 s of tracing (PR 36, on the chip). Storage-dtype operands with
+        # f32 accumulation, scale applied to the f32 logits: the same
+        # placement as attention._block_attend.
+        logits = lax.dot_general(
+            q_ref[...], _head_rows(k_buf, slot, num_kv_heads, positions),
+            (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32,
+        ) * scale  # (KV, TQ, positions)
+        _accumulate_block(
+            jnp.where(ok[None], logits, NEG_INF),
+            _head_rows(v_buf, slot, num_kv_heads, positions),
+            acc_ref, m_ref, l_ref,
+        )
         return carry
 
-    each_live_block(0, 0, fetch)
+    each_live_block(0, 0, fetch, 0)
     lax.fori_loop(0, pl.cdiv(n_cols - c0, group), attend_group, 0)
 
     o_ref[...] = (
@@ -476,22 +560,31 @@ def _paged_kernel(
     ).astype(o_ref.dtype)
 
 
-# Query rows per grid step: bounds the kernel's VMEM (q/o tiles, the f32
-# accumulators and one (TQ, bs*KV) logits tile) independently of the
-# prefill chunk length.
+# Query rows per tile: bounds a kernel's VMEM (q/o tiles, the f32
+# accumulators and one logits tile) independently of the prefill chunk
+# length. The GQA kernel holds a tile a KV head in one grid step, all of
+# them within _MAX_STEP_ROWS.
 _MAX_Q_ROWS = 512
+_MAX_STEP_ROWS = 4096
 
 
-def _q_tile_positions(s: int, h: int) -> int:
-    """Query positions per tile: the largest divisor of S whose rows
-    (positions x heads) fit _MAX_Q_ROWS and tile the sublanes; the whole
-    of S when no divisor does (a full-extent block is always legal)."""
-    if s * h <= _MAX_Q_ROWS:
+def _q_tile_positions(s: int, rows: int, max_rows: int = _MAX_Q_ROWS) -> int:
+    """Query positions per tile, at `rows` query rows a position (the heads
+    of a KV head; every head of a latent pool): the largest divisor of S
+    whose rows fit `max_rows` and tile the sublanes; the whole of S when no
+    divisor does (a full-extent block is always legal)."""
+    if s * rows <= max_rows:
         return s
-    for ts in range(_MAX_Q_ROWS // h, 0, -1):
-        if s % ts == 0 and (ts * h) % 16 == 0:
+    for ts in range(max_rows // rows, 0, -1):
+        if s % ts == 0 and (ts * rows) % 16 == 0:
             return ts
     return s
+
+
+def _head_tile_positions(s: int, h: int, kv: int) -> int:
+    """Query positions per tile of the GQA kernel: a KV head's tile holds
+    its h // kv heads a position, and a grid step a tile of every KV head."""
+    return _q_tile_positions(s, h // kv, min(_MAX_Q_ROWS, _MAX_STEP_ROWS // kv))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
@@ -501,20 +594,29 @@ def _ragged_attention_pallas(
     b, s, h, hd = q.shape
     n_layers, nb, bs, kv, _ = k_pool.shape
     mb = tables.shape[1]
-    ts = _q_tile_positions(s, h)
-    tq = ts * h
+    n_rep = h // kv
+    if kv > 1 and (kv * k_pool.dtype.itemsize) % 4:
+        raise NotImplementedError(
+            f"{kv} KV heads of {k_pool.dtype} do not fill 32-bit words"
+        )
+    ts = _head_tile_positions(s, h, kv)
+    tq = ts * n_rep
     group = _group_blocks(bs * kv * hd * k_pool.dtype.itemsize, mb)
 
-    valid_len = valid_len.astype(jnp.int32)
+    # No row sees past its table (the lax path's walk ends there too).
+    valid_len = jnp.minimum(valid_len.astype(jnp.int32), mb * bs)
     # Table columns each query tile may see: up to its own longest row,
     # none for a tile of dead rows (valid_len 0).
     tiles = valid_len.reshape(b, s // ts, ts)
-    n_cols = jnp.clip((jnp.max(tiles, axis=2) + bs - 1) // bs, 0, mb)
+    n_cols = (jnp.max(tiles, axis=2) + bs - 1) // bs
     # ... from the first column its rows' windows reach (0: a full layer).
     c0 = (
         jnp.minimum(first_column(tiles, window, bs, axis=2), n_cols)
         if window else jnp.zeros_like(n_cols)
     )
+
+    def _head_tiles(bi, qi, t, nc, c0, lyr):
+        return (bi, 0, qi, 0)
 
     def _q_rows(bi, qi, t, nc, c0, lyr):
         return (bi, qi, 0)
@@ -523,8 +625,6 @@ def _ragged_attention_pallas(
         _paged_kernel,
         block_size=bs,
         num_pool_blocks=nb,
-        num_heads=h,
-        num_kv_heads=kv,
         group=group,
         scale=hd ** -0.5,
         window=window,
@@ -535,22 +635,22 @@ def _ragged_attention_pallas(
             num_scalar_prefetch=4,
             grid=(b, s // ts),
             in_specs=[
-                pl.BlockSpec((None, tq, hd), _q_rows),
+                pl.BlockSpec((None, kv, tq, hd), _head_tiles),
                 pl.BlockSpec((None, tq, 1), _q_rows),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((None, tq, hd), _q_rows),
+            out_specs=pl.BlockSpec((None, kv, tq, hd), _head_tiles),
             scratch_shapes=[
-                pltpu.VMEM((2, group, bs * kv, hd), k_pool.dtype),
-                pltpu.VMEM((2, group, bs * kv, hd), v_pool.dtype),
+                pltpu.VMEM((2, group * bs * kv, hd), k_pool.dtype),
+                pltpu.VMEM((2, group * bs * kv, hd), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, group)),
-                pltpu.VMEM((tq, hd), jnp.float32),
-                pltpu.VMEM((tq, 1), jnp.float32),
-                pltpu.VMEM((tq, 1), jnp.float32),
+                pltpu.VMEM((kv, tq, hd), jnp.float32),
+                pltpu.VMEM((kv, tq, 1), jnp.float32),
+                pltpu.VMEM((kv, tq, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, s * h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, s * n_rep, hd), q.dtype),
         interpret=interpret,
         name="ragged_paged_attention" + "_window" * bool(window),  # the trace tells kinds apart by it
     )(
@@ -558,11 +658,13 @@ def _ragged_attention_pallas(
         n_cols,
         c0,
         jnp.asarray(layer, jnp.int32).reshape(1),
-        q.reshape(b, s * h, hd),
-        jnp.repeat(valid_len, h, axis=1)[:, :, None],
+        # A KV head's query rows together: (b, j, s * G + h % G).
+        q.reshape(b, s, kv, n_rep, hd).swapaxes(1, 2).reshape(b, kv, s * n_rep, hd),
+        jnp.repeat(valid_len, n_rep, axis=1)[:, :, None],
         k_pool.reshape(n_layers * nb, bs * kv, hd),
         v_pool.reshape(n_layers * nb, bs * kv, hd),
     )
+    out = out.reshape(b, kv, s, n_rep, hd).swapaxes(1, 2)
     return out.reshape(b, s, h * hd)
 
 

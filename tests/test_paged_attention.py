@@ -89,13 +89,20 @@ SHAPES = (
     (3, 1, 4, 2, 32, 16, 8, 6),
     (2, 5, 4, 4, 32, 12, 8, 5),
     (1, 16, 8, 2, 128, 20, 16, 4),
+    # The cells' head geometries (the kernel's unit of work is a KV head):
+    # 8 KV heads of 4 query heads, 4 of 8, twenty query heads on one.
+    (2, 3, 32, 8, 128, 14, 16, 6),
+    (2, 2, 32, 4, 128, 12, 16, 5),
+    (2, 2, 20, 1, 128, 12, 16, 5),
 )
 
 
 # The kernel walks a query tile's live table columns a GROUP of blocks at
-# a time (paged_attention._group_blocks). These cases sit where that walk
-# can go wrong; at 64 KiB blocks (f32, bs 16 x KV 8 x hd 128) a group is
-# 4 blocks, so every table below is wider than one group.
+# a time (paged_attention._group_blocks) and multiplies a KV head's query
+# rows against that head's rows of the whole group at once. These cases sit
+# where that can go wrong; at 64 KiB blocks (f32, bs 16 x KV 8 x hd 128) a
+# group is 4 blocks (8 at 4 KV heads, and at one KV head of 64-token
+# blocks), so every table below is wider than one group.
 #   name: ((B, S, H, KV, hd, NB, bs, MB), context per slot (0 = dead),
 #          table entries (slot, column) punched out to the pad sentinel)
 WALK_CASES = {
@@ -116,6 +123,29 @@ WALK_CASES = {
     # ends at column 10, the second at 12.
     "chunk_tiles_end_at_different_columns": (
         (1, 64, 16, 8, 128, 40, 16, 14), (200,), ()),
+    # The cells' head geometries, each with a cut last group, a dead row
+    # and a sentinel inside the live range: 8 KV heads of 4 query heads
+    # (9, 6 live blocks in groups of 4), 4 of 8 (13, 10 in groups of 8),
+    # twenty query heads on one KV head (11, 9 in groups of 8).
+    "kv8_g4_verify_rows": (
+        (3, 3, 32, 8, 128, 40, 16, 10), (140, 0, 90), ((0, 2),)),
+    "kv4_g8_decode_rows": (
+        (3, 1, 32, 4, 128, 40, 16, 14), (200, 0, 150), ((2, 8),)),
+    "kv1_h20_chunk_rows": (
+        (2, 4, 20, 1, 128, 24, 64, 12), (700, 520), ((0, 3),)),
+    # A 128-position chunk of 4 query heads a KV head: one 512-row tile a
+    # KV head, every KV head's in one grid step.
+    "kv8_g4_chunk_is_one_tile_a_kv_head": (
+        (1, 128, 32, 8, 128, 20, 16, 12), (170,), ()),
+    # The last group is cut and what its unfetched buffer places hold must
+    # not reach the result. A row's only group, 3 blocks of 4: the places
+    # no copy ever wrote (the interpreter starts scratch memory as NaN).
+    "only_group_is_partial_over_unwritten_buffer": (
+        (1, 1, 32, 8, 128, 12, 16, 6), (40,), ()),
+    # ... and a short row after a long one: the places hold the long row's
+    # keys and values (both slots of the buffer: 13 = 4 + 4 + 4 + 1).
+    "partial_group_over_another_rows_blocks": (
+        (2, 1, 32, 8, 128, 40, 16, 14), (200, 37), ()),
 }
 
 
@@ -154,8 +184,9 @@ def test_pallas_walk_matches_lax_fallback(case):
     B, S, H, KV, hd, NB, bs, MB = shape
     group = _group_blocks(bs * KV * hd * 4, MB)
     live = [-(-n // bs) for n in ctx]
-    assert 1 < group < max(live)  # more than one loop step, several blocks each
-    assert any(n % group for n in live if n) or MB % group
+    assert group > 1 and (any(n % group for n in live if n) or MB % group)
+    if "only_group" not in case:
+        assert group < max(live)  # more than one loop step, several blocks each
     q, kp, vp, tables, vlen, _ = _walk_inputs(17, shape, ctx, holes)
     got_lax = _ragged_attention_lax(q, kp[None], vp[None], 0, tables, vlen)
     got_pal = _ragged_attention_pallas(
@@ -190,6 +221,49 @@ def test_pallas_interpret_matches_lax_fallback(shape):
     np.testing.assert_allclose(
         np.asarray(got_pal), np.asarray(got_lax), rtol=1e-6, atol=1e-6
     )
+
+
+# (B, S, H, KV, hd, NB, bs, MB) of a bfloat16 pool: two KV heads share a
+# 32-bit word there, and the kernel lifts a head's rows out of a fetched
+# group by 32-bit strided loads and an exchange of halves.
+PACKED_SHAPES = (
+    (2, 3, 32, 8, 128, 20, 16, 9),   # 8 KV heads: four words a position
+    (2, 1, 32, 4, 128, 20, 16, 9),   # 4 KV heads
+    (1, 8, 4, 2, 128, 12, 16, 5),    # 2 KV heads: one word a position
+    (2, 2, 20, 1, 128, 12, 16, 5),   # one KV head: nothing to lift
+)
+
+
+@pytest.mark.parametrize("shape", PACKED_SHAPES)
+def test_pallas_lifts_a_kv_heads_rows_out_of_a_16_bit_pool(shape):
+    """On a bfloat16 pool the kernel and the lax path agree to bfloat16's
+    rounding (both hand the MXU storage-dtype operands and round the
+    probabilities to it), and a head's rows are exactly its own: NaN in
+    every OTHER head of the value pool's owned blocks reaches nothing when
+    only one KV head's queries are kept."""
+    B, S, H, KV, hd, NB, bs, MB = shape
+    q, kp, vp, tables, vlen = (
+        a.astype(jnp.bfloat16) if a.dtype == jnp.float32 else a
+        for a in _ragged_inputs(19, *shape)
+    )
+    got_lax = _ragged_attention_lax(q, kp[None], vp[None], 0, tables, vlen)
+    got_pal = _ragged_attention_pallas(
+        q, kp[None], vp[None], 0, tables, vlen, interpret=True
+    )
+    np.testing.assert_allclose(
+        np.asarray(got_pal, np.float32), np.asarray(got_lax, np.float32),
+        rtol=2e-2, atol=2e-2,
+    )
+    for j in range(KV):
+        others = jnp.arange(KV) != j
+        poisoned = jnp.where(others[None, None, :, None], jnp.nan, vp)
+        out = _ragged_attention_pallas(
+            q, kp[None], poisoned[None], 0, tables, vlen, interpret=True
+        ).reshape(B, S, KV, H // KV * hd)
+        np.testing.assert_array_equal(
+            np.asarray(out[:, :, j], np.float32),
+            np.asarray(got_pal.reshape(out.shape)[:, :, j], np.float32),
+        )
 
 
 @pytest.mark.parametrize("shape", SHAPES)

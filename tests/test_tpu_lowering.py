@@ -28,8 +28,9 @@ from dstack_tpu.workloads import selective_scan as scans
 from dstack_tpu.workloads.attention import make_attention_fn
 from dstack_tpu.workloads.config import FULL, PRESETS, SLIDING
 from dstack_tpu.workloads.paged_attention import (
+    _group_blocks,
+    _head_tile_positions,
     _latent_attention_pallas,
-    _q_tile_positions,
     _ragged_attention_pallas,
 )
 from dstack_tpu.workloads.serving import ServingEngine
@@ -137,20 +138,25 @@ def _kernels():
              ((b, MAX_BLOCKS), i32), ((b, s), i32)],
         ))
     # A window layer's call beside a full layer's at the window cell's
-    # geometry: 16 decode rows, the 512-token chunk (32 query tiles).
-    cell_pool = ((POOL_LAYERS, 16 * CELL_MB, CELL_BLOCK, CELL_KV, HD), bf16)
-    for kind, b, s in [("decode", 16, 1), ("prefill", 1, 512)]:
-        for name, window in (("full", 0), ("window", CELL_WINDOW)):
-            out.append((
-                f"paged_{name}_{kind}_b{b}_s{s}_mb{CELL_MB}",
-                lambda *a, w=window: _ragged_attention_pallas(*a, window=w),
-                [((b, s, CELL_H, HD), bf16), cell_pool, cell_pool, ((), i32),
-                 ((b, CELL_MB), i32), ((b, s), i32)],
-            ))
+    # geometry: 16 decode rows, the 512-token chunk (8 query tiles of 64
+    # positions x 8 heads a KV head), at 128-token blocks and at the cell's
+    # own 256.
+    for block in (CELL_BLOCK, 256):
+        mb = 24576 // block
+        cell_pool = ((POOL_LAYERS, 16 * mb, block, CELL_KV, HD), bf16)
+        for kind, b, s in [("decode", 16, 1), ("prefill", 1, 512)]:
+            for name, window in (("full", 0), ("window", CELL_WINDOW)):
+                out.append((
+                    f"paged_{name}_{kind}_b{b}_s{s}_mb{mb}",
+                    lambda *a, w=window: _ragged_attention_pallas(*a, window=w),
+                    [((b, s, CELL_H, HD), bf16), cell_pool, cell_pool, ((), i32),
+                     ((b, mb), i32), ((b, s), i32)],
+                ))
     # Twenty query heads on ONE KV head (the `jamba` block's two attention
     # layers) at its cell's geometry, 128 slots x 18 blocks of 256: a decode
     # row is a 20-row query tile, the 512-token chunk 32 tiles of 320 rows,
-    # the smallest chunk bucket one of 160.
+    # the smallest chunk bucket one of 160; a group is four blocks, 1,024
+    # positions a product.
     one_kv = ((2, 128 * 18, 256, 1, HD), bf16)
     for kind, b, s in [("decode", 128, 1), ("prefill", 1, 512), ("prefill", 1, 8)]:
         out.append((
@@ -243,8 +249,59 @@ def test_paged_kernel_grid_has_no_table_column_axis(b, s, h, slots, max_blocks):
         for shape, dt in _paged_args(b, s, h, slots, max_blocks)
     ]
     grids = _pallas_grids(jax.make_jaxpr(_ragged_attention_pallas)(*specs).jaxpr)
-    assert grids == [(b, s // _q_tile_positions(s, h))]
+    assert grids == [(b, s // _head_tile_positions(s, h, KV))]
     assert math.prod(grids[0]) < max_blocks  # nowhere near rows x columns
+
+
+def _dot_generals(jaxpr):
+    """Every dot_general of a jaxpr, loop and branch bodies included."""
+    dots = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            dots.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            dots += _dot_generals(sub)
+    return dots
+
+
+@pytest.mark.parametrize(
+    "b,s,h,kv,block,max_blocks",
+    [(b, s, WIDE_H, KV, BLOCK, WIDE_BLOCKS) for _, b, s in WIDE_SHAPES]
+    + [(16, 1, CELL_H, CELL_KV, 256, 96), (1, 512, CELL_H, CELL_KV, 256, 96),
+       (128, 1, 20, 1, 256, 18)],
+)
+def test_paged_kernel_multiplies_a_query_against_its_own_kv_head(
+    b, s, h, kv, block, max_blocks
+):
+    """The mechanism of PR 36, read from the kernel's body: the score
+    product is batched over the KV heads, one KV head's query rows (tile
+    positions x its h / kv heads) against THAT head's rows of a whole
+    fetched group (group x block positions). So the pairs multiplied a
+    fetched block are tile rows x block, where the all-pairs product (every
+    head's rows against all of a block's block x kv rows, kv - 1 of kv pairs
+    masked) made them tile rows x block x kv; and the probabilities meet as
+    many value rows."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = ((POOL_LAYERS, b * max_blocks, block, kv, HD), bf16)
+    specs = [
+        jax.ShapeDtypeStruct(shape, dt)
+        for shape, dt in [((b, s, h, HD), bf16), pool, pool, ((), i32),
+                          ((b, max_blocks), i32), ((b, s), i32)]
+    ]
+    jaxpr = jax.make_jaxpr(_ragged_attention_pallas)(*specs).jaxpr
+    ts = _head_tile_positions(s, h, kv)
+    group = _group_blocks(block * kv * HD * 2, max_blocks)
+    head_rows, positions = ts * (h // kv), group * block
+    scores, values = _dot_generals(jaxpr)
+    heads = ((0,), (0,))  # the batch axis of both operands: the KV head
+    assert scores.params["dimension_numbers"] == (((2,), (2,)), heads)
+    assert [v.aval.shape for v in scores.invars] == [
+        (kv, head_rows, HD), (kv, positions, HD)]
+    assert values.params["dimension_numbers"] == (((2,), (1,)), heads)
+    assert [v.aval.shape for v in values.invars] == [
+        (kv, head_rows, positions), (kv, positions, HD)]
+    pairs_a_block = math.prod(scores.outvars[0].aval.shape) // group
+    assert pairs_a_block == ts * h * block  # not x kv
 
 
 # ------------------------------------------- the pool rides the layer loop
