@@ -162,6 +162,13 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_kv_pool_layers": ("gauge", ()),
     "dstack_tpu_serving_state_pool_bytes": ("gauge", ()),
     "dstack_tpu_serving_state_row_bytes": ("gauge", ()),
+    # The expert bank an engine holds (all the experts its router scores,
+    # or a device's share of them) and the (token, expert) pairs routing
+    # chose against those that fell on an expert held.
+    "dstack_tpu_serving_moe_experts_held": ("gauge", ()),
+    "dstack_tpu_serving_moe_experts_published": ("gauge", ()),
+    "dstack_tpu_serving_moe_local_pairs_total": ("counter", ()),
+    "dstack_tpu_serving_moe_pairs_total": ("counter", ()),
     # Prefill/decode disaggregation (workloads/kv_transfer.py): handoff
     # outcome counters on both sides of the seam, payload bytes moved,
     # per-handoff transfer latency, and the depth of the handoff queue

@@ -19,6 +19,16 @@ _DTYPE = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 FULL, SLIDING, MAMBA = "full_attention", "sliding_attention", "mamba"
 
 
+def period_of(kinds: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The shortest run of kinds whose repetition, cut where `kinds` ends,
+    is `kinds`: (a, b, b, b, a, b, b) has the period (a, b, b, b) once whole
+    and then its first three."""
+    for p in range(1, len(kinds) + 1):
+        if all(k == kinds[i % p] for i, k in enumerate(kinds)):
+            return kinds[:p]
+    return kinds
+
+
 @dataclass(frozen=True)
 class RopeParams:
     """One kind of layer's rotary embedding, as a published
@@ -27,7 +37,10 @@ class RopeParams:
     each frequency between itself and itself / `factor` by where it lies
     between the `beta_fast` and `beta_slow` rotations over
     `original_max_position_embeddings`, and multiplies cos and sin by
-    `attention_factor` (0.1 ln(factor) + 1 when the group gives none)."""
+    `attention_factor` (0.1 ln(factor) + 1 when the group gives none).
+    `partial_rotary_factor` < 1 rotates the first `rotary_dim(head)` values
+    of a head and passes the rest as they are; the frequencies, YaRN's
+    blend among them, are then those of the rotated width."""
 
     theta: float
     rope_type: str = "default"
@@ -37,6 +50,7 @@ class RopeParams:
     beta_slow: float = 1.0
     attention_factor: float = 0.0
     truncate: bool = True
+    partial_rotary_factor: float = 1.0
 
     @classmethod
     def of(cls, group: Dict[str, Any], theta: float) -> "RopeParams":
@@ -48,6 +62,8 @@ class RopeParams:
         ) not in ("default", "yarn"):
             raise ValueError(f"rope_parameters group {group!r}: not understood")
         rope = cls(**fields)
+        if not 0 < rope.partial_rotary_factor <= 1:
+            raise ValueError("partial_rotary_factor must lie in (0, 1]")
         if rope.rope_type == "yarn" and not (
             rope.factor >= 1 and rope.original_max_position_embeddings > 0
         ):
@@ -56,8 +72,13 @@ class RopeParams:
             )
         return rope
 
+    def rotary_dim(self, head_dim: int) -> int:
+        """The leading values of a head that are rotated (an even count)."""
+        return int(head_dim * self.partial_rotary_factor) // 2 * 2
+
     def inv_freq(self, dim: int) -> Tuple[Tuple[float, ...], float]:
-        """(the dim / 2 inverse frequencies, the factor on cos and sin)."""
+        """(the dim / 2 inverse frequencies, the factor on cos and sin) for
+        a rotated width of `dim`."""
         extra = [self.theta ** (-2.0 * i / dim) for i in range(dim // 2)]
         if self.rope_type == "default":
             return tuple(extra), 1.0
@@ -180,6 +201,25 @@ class ModelConfig:
     # True: the head is the embedding's transpose, ONE leaf
     # (`transformer.head_weights`; `init_params` makes no `lm_head`).
     tie_embeddings: bool = False
+    # Query heads by layer, as published: one entry a layer, cut and checked
+    # like `layer_types`. The count must be a function of the layer's KIND
+    # (`heads(kind)`); where the kinds differ in it, `wq`, `wo` and the gate
+    # are a stack a kind (`heads_by_kind`, transformer.init_params). Empty =
+    # n_heads in every layer. The KV heads are the same in every layer.
+    heads_per_layer: Tuple[int, ...] = ()
+    # A gate on each query head's output: g = act(h Wg), one value a token
+    # and head from the block's normed input (Wg is d_model x heads, the
+    # `wg` leaf), float32; the head's attention output is multiplied by it
+    # before the out projection. "" = no gate, else the activation:
+    # "softplus" or "sigmoid".
+    attn_gate: str = ""
+    # The device's share of the expert bank: of the `n_experts` the router
+    # scores (its published width), the bank here holds `experts_held`,
+    # starting at `experts_first` (0 held = all). Routing, top-k, the
+    # renormalisation and the scaling are over all `n_experts`; a token's
+    # pairs that fall on experts held elsewhere add nothing here (moe.py).
+    experts_held: int = 0
+    experts_first: int = 0
 
     def __post_init__(self):
         set_ = lambda k, v: object.__setattr__(self, k, v)
@@ -227,12 +267,55 @@ class ModelConfig:
                 )
             if SLIDING in kinds and self.sliding_window < 1:
                 raise ValueError("a sliding_attention layer needs sliding_window")
-            if self.kv_lora_rank or self.n_dense_layers:
+            if self.kv_lora_rank:
                 raise ValueError(
-                    "layer_types beside latent attention or leading dense"
-                    " layers: no program runs that pattern"
+                    "layer_types beside latent attention: no program runs"
+                    " that pattern"
                 )
         set_("layer_types", kinds)
+        heads = tuple(self.heads_per_layer or ())
+        if heads:
+            if len(heads) < self.n_layers:
+                raise ValueError(
+                    f"heads_per_layer names {len(heads)} layers, n_layers is"
+                    f" {self.n_layers}"
+                )
+            heads = heads[: self.n_layers]
+            by_kind = set(zip(kinds or (FULL,) * self.n_layers, heads))
+            if len(by_kind) != len({kind for kind, _ in by_kind}):
+                raise ValueError(
+                    "heads_per_layer must give every layer of one kind the"
+                    f" same count, not {sorted(by_kind)}"
+                )
+            if self.kv_lora_rank or MAMBA in kinds or any(
+                h < 1 or h % self.n_kv_heads for h in heads
+            ):
+                raise ValueError(
+                    "heads_per_layer needs attention layers of per-head K/V"
+                    " rows and counts that n_kv_heads divides"
+                )
+        set_("heads_per_layer", heads)
+        if self.attn_gate not in ("", "softplus", "sigmoid"):
+            raise ValueError(
+                f"attn_gate={self.attn_gate!r}: expected '', 'softplus' or"
+                " 'sigmoid'"
+            )
+        if self.attn_gate and (self.kv_lora_rank or MAMBA in kinds):
+            raise ValueError(
+                "attn_gate beside latent attention or state-space layers: no"
+                " program runs that pattern"
+            )
+        if self.experts_held or self.experts_first:
+            if not (
+                0 <= self.experts_first
+                and 0 < self.experts_held
+                and self.experts_first + self.experts_held <= self.n_experts
+            ):
+                raise ValueError(
+                    f"experts {self.experts_first} .."
+                    f" {self.experts_first + self.experts_held - 1} held of"
+                    f" n_experts={self.n_experts}: not a share of the bank"
+                )
         ropes = self.rope_parameters or ()
         if isinstance(ropes, dict):
             ropes = tuple(
@@ -286,6 +369,44 @@ class ModelConfig:
                 k == kinds[i % p] for i, k in enumerate(kinds)
             ):
                 return kinds[:p]
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The kind of every layer, in order (FULL where no pattern is given)."""
+        return self.layer_types or (FULL,) * self.n_layers
+
+    @property
+    def stack_kinds(self) -> Tuple[Tuple[str, ...], ...]:
+        """The kinds of each stack of blocks, in the order the stacks run
+        (`transformer.layer_stacks`): the leading dense layers take the
+        head of the pattern, the expert layers what follows. Each stack is
+        scanned over its OWN period (`period_of`), which need not divide it:
+        a published depth may end inside a period."""
+        kinds, nd = self.layer_kinds, self.n_dense_layers
+        return (kinds[:nd], kinds[nd:]) if nd else (kinds,)
+
+    def heads(self, kind: str = FULL) -> int:
+        """Query heads of a `kind` layer."""
+        return dict(zip(self.layer_kinds, self.heads_per_layer)).get(
+            kind, self.n_heads
+        )
+
+    @property
+    def heads_by_kind(self) -> bool:
+        """The kinds of attention layer differ in their query heads: `wq`,
+        `wo` and the gate are then no one stack (transformer.init_params)."""
+        return len(set(self.heads_per_layer)) > 1
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first expert held here, how many): the whole bank unless
+        `experts_held` says otherwise."""
+        return self.experts_first, self.experts_held or self.n_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """The bank here is a share of the experts the router scores."""
+        return 0 < self.experts_held < self.n_experts
 
     def window(self, kind: str) -> int:
         """Keys a query of a `kind` layer sees, itself included; 0 = all."""
@@ -369,9 +490,10 @@ class ModelConfig:
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
 
-    def attn_params(self) -> int:
-        """Weights of one block's attention projections (no norms)."""
-        d, h = self.d_model, self.n_heads
+    def attn_params(self, kind: str = FULL) -> int:
+        """Weights of a `kind` block's attention projections, its gate among
+        them (no norms)."""
+        d, h = self.d_model, self.heads(kind)
         if self.latent:
             return (
                 d * self.q_lora_rank + self.q_lora_rank * h * self.head_dim
@@ -381,19 +503,20 @@ class ModelConfig:
                 + h * self.v_head_dim * d
             )
         hd = self.head_dim
-        return d * (h + 2 * self.n_kv_heads) * hd + h * hd * d
+        gate = d * h if self.attn_gate else 0
+        return d * (h + 2 * self.n_kv_heads) * hd + h * hd * d + gate
 
     def mlp_params(self, dense: bool = False) -> int:
         """Weights of one block's MLP: the plain SwiGLU of a leading
         dense layer (`dense`) or of a model without experts, else the
-        whole expert bank, the shared experts and the router."""
+        experts held here, the shared experts and the router (whole)."""
         d = self.d_model
         if self.n_experts == 0:
             return 3 * d * self.d_ff
         if dense:
             return 3 * d * self.dense_d_ff
         return (
-            3 * d * self.d_ff * (self.n_experts + self.n_shared_experts)
+            3 * d * self.d_ff * (self.held[1] + self.n_shared_experts)
             + d * self.n_experts
         )
 
@@ -418,9 +541,18 @@ class ModelConfig:
         and the router's selection bias are not counted). A model with
         state-space layers is counted leaf by leaf, norms included and the
         head once where it is tied: its published count is checked against
-        this one (benchmarks/tests/test_cell_jamba.py)."""
+        this one (benchmarks/tests/test_cell_jamba.py). So is a model whose
+        query heads go by layer, or whose heads are gated: every leaf but
+        the router's selection bias, a buffer the gradient does not move."""
         nd = self.n_dense_layers
         heads = (1 if self.tie_embeddings else 2) * self.d_model * self.vocab_size
+        if self.heads_per_layer or self.attn_gate:
+            return (
+                sum(map(self.attn_params, self.layer_kinds))
+                + nd * self.mlp_params(dense=True)
+                + (self.n_layers - nd) * self.mlp_params()
+                + self.n_layers * 2 * self.d_model + heads + self.d_model
+            )
         if self.has_state_layers:
             return (
                 self.n_state_layers * self.mamba_params()
@@ -527,9 +659,12 @@ class ModelConfig:
         causal mask) — the standard model-FLOPs accounting MFU uses
         (PaLM appendix B). Without it, only parameter matmuls count
         (a conservative lower bound). MoE counts the k active experts
-        per token plus the router matmul, not the full expert bank."""
+        per token plus the router matmul, not the full expert bank; of a
+        share of the bank, the part of the k that falls here in the mean."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        per_layer = 2 * self.attn_params()
+        kinds = self.layer_types or (FULL,)
+        # (the projections of each kind's own head count, the gate's too)
+        per_layer = 2 * sum(map(self.attn_params, kinds)) / len(kinds)
         if self.has_state_layers:
             # Mixers by kind, averaged over the layers: a state-space one
             # is its projections and, a token, the convolution's taps and
@@ -539,23 +674,27 @@ class ModelConfig:
             mamba = (2 * self.mamba_matmul_params()
                      + 2 * self.mamba_d_conv * di + 9 * di * n)
             per_layer = (
-                self.n_attn_layers * per_layer + self.n_state_layers * mamba
+                self.n_attn_layers * 2 * self.attn_params()
+                + self.n_state_layers * mamba
             ) / self.n_layers
         if seq_len:
             # causal QK^T + AV: 2 * (scored + emitted) a head and key, over
             # the S / 2 keys a query sees in the mean; a window layer's
             # queries see min(position + 1, window), w - w^2 / 2S of them.
             v_dim = self.v_head_dim if self.latent else self.head_dim
-            kinds = self.layer_types or (FULL,)
-            keys = sum(
+            head_keys = sum(
                 0 if kind == MAMBA
-                else w - w * w / (2.0 * seq_len) if 0 < w < seq_len
-                else seq_len / 2
+                else self.heads(kind) * (w - w * w / (2.0 * seq_len))
+                if 0 < w < seq_len
+                else self.heads(kind) * seq_len / 2
                 for kind, w in zip(kinds, map(self.window, kinds))
             ) / len(kinds)
-            per_layer += 2 * keys * self.n_heads * (self.head_dim + v_dim)
+            per_layer += 2 * head_keys * (self.head_dim + v_dim)
         if self.n_experts > 0:
-            active = self.experts_per_token + self.n_shared_experts
+            active = (
+                self.experts_per_token * self.held[1] / self.n_experts
+                + self.n_shared_experts
+            )
             mlp = 3 * 2 * d * f * active + 2 * d * self.n_experts
         else:
             mlp = 3 * 2 * d * f
@@ -642,6 +781,28 @@ PRESETS: Dict[str, ModelConfig] = {
         kv_lora_rank=32, qk_nope_head_dim=24, qk_rope_head_dim=16,
         v_head_dim=32, n_dense_layers=1, dense_d_ff=256,
         n_shared_experts=1, router_score="sigmoid", routed_scaling=1.8,
+    ),
+    # Query heads and a per-head output gate by layer kind (4 on a full
+    # layer, 6 on a window layer, 2 KV heads), a leading dense layer BESIDE
+    # the layer pattern, half of a head rotated with YaRN on the full kind,
+    # sigmoid-routed experts with scaling beside a shared one: every
+    # mechanism of the `laguna` block at a size the CPU runs. The bank is
+    # whole here; `with_(experts_held=4)` is one of two devices' share.
+    "tiny-laguna": ModelConfig(
+        vocab_size=512, d_model=96, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_size=32, d_ff=48, max_seq_len=256, remat=False, n_experts=8,
+        experts_per_token=3, capacity_factor=8 / 3, norm_eps=1e-6,
+        layer_types=(FULL, SLIDING, SLIDING, SLIDING, FULL),
+        heads_per_layer=(4, 6, 6, 6, 4), sliding_window=8,
+        attn_gate="softplus", n_dense_layers=1, dense_d_ff=192,
+        n_shared_experts=1, router_score="sigmoid", routed_scaling=2.5,
+        rope_parameters={
+            FULL: {"rope_type": "yarn", "rope_theta": 10000.0, "factor": 4.0,
+                   "original_max_position_embeddings": 32,
+                   "partial_rotary_factor": 0.5},
+            SLIDING: {"rope_type": "default", "rope_theta": 100.0,
+                      "partial_rotary_factor": 1},
+        },
     ),
     # Mixtral-shaped 8x top-2 at the 1B-active scale.
     "smol-moe": ModelConfig(

@@ -30,6 +30,7 @@ from dstack_tpu.workloads.transformer import (
     attn_output,
     embed_tokens,
     final_norm,
+    head_gate,
     head_weights,
     latent_output,
     layer_stacks,
@@ -131,9 +132,13 @@ def _forward_cached(
     mixers = mixer_stacks(params)
 
     def block(x, layer, kind):
-        if mixers is not None:
+        if c.has_state_layers:
             # (what every layer has, what its kind has: weights and cache)
             p, (own, ck, cv) = layer
+            p = {**p, **own}
+        elif isinstance(layer[0], tuple):
+            # (every layer's weights and cache, its kind's own weights)
+            (p, ck, cv), own = layer
             p = {**p, **own}
         else:
             p, ck, cv = layer
@@ -166,7 +171,7 @@ def _forward_cached(
             attn = _cached_attention(
                 q, ck, cv, valid_len, window=c.window(kind)
             )
-            x = x + attn_output(attn, p)
+            x = x + attn_output(attn, p, head_gate(c, x, p))
         if "router" in p:
             from dstack_tpu.workloads.moe import moe_block
 
@@ -175,11 +180,11 @@ def _forward_cached(
             x = mlp_block(c, x, p)
         return x, (ck, cv)
 
-    if mixers is not None:
-        x, caches = scan_layers(c, block, x, params["layers"], own={
+    if c.has_state_layers:
+        x, caches = scan_layers(block, x, params["layers"], c.layer_kinds, own={
             kind: (stack,) + ((cache.ssm, cache.conv) if kind == MAMBA
                               else (cache.k, cache.v))
-            for kind, stack in mixers.items()
+            for kind, stack in mixers[0].items()
         })
         (ssm, conv), (new_k, new_v) = (
             caches.get(MAMBA, (cache.ssm, cache.conv)),
@@ -189,12 +194,13 @@ def _forward_cached(
         logits = logits_linear(x[:, -1], head_weights(params))
         return logits, KVCache(new_k, new_v, start + s, ssm, conv)
     new_k, new_v, first = [], [], 0
-    for stack in layer_stacks(params):
+    for stack, own, kinds in zip(layer_stacks(params), mixers, c.stack_kinds):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
-        x, (sk, sv) = scan_layers(
-            c, block, x,
+        x, (sk, sv) = in_layer_order(kinds, *scan_layers(
+            block, x,
             (stack, cache.k[first:first + n], cache.v[first:first + n]),
-        )
+            kinds, own=own,
+        ))
         new_k.append(sk)
         new_v.append(sv)
         first += n
@@ -203,6 +209,19 @@ def _forward_cached(
     x = final_norm(c, params, x)
     logits = logits_linear(x[:, -1], head_weights(params))
     return logits, KVCache(k=new_k, v=new_v, length=start + s)
+
+
+def in_layer_order(kinds, x, ys):
+    """`scan_layers`' answer with the `ys` on the layer axis: they come
+    back by kind where the layers' kinds have stacks of their own."""
+    if not isinstance(ys, dict):
+        return x, ys
+    rank = {kind: 0 for kind in ys}
+    rows = []
+    for kind in kinds:
+        rows.append(jax.tree_util.tree_map(lambda a: a[rank[kind]], ys[kind]))
+        rank[kind] += 1
+    return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *rows)
 
 
 def generate(

@@ -73,6 +73,7 @@ from dstack_tpu.workloads.transformer import (
     attn_output,
     embed_tokens,
     final_norm,
+    head_gate,
     head_weights,
     latent_output,
     layer_stacks,
@@ -120,6 +121,13 @@ class PagedDecodeState(NamedTuple):
     # layer loop (AOT for the v5e, PR 33).
     ssm: Optional[jnp.ndarray] = None   # (Ls, B, d_state, d_inner) float32
     conv: Optional[jnp.ndarray] = None  # (Ls, B, (d_conv - 1) * d_inner)
+    # Where the expert bank here is a share of the experts routed over
+    # (ModelConfig.expert_share): the pairs real tokens were routed to, and
+    # those of them that fell on the experts held (moe.local_pairs), summed
+    # over layers and programs since a decode launch last handed them out
+    # (it returns them beside its tokens and starts again from zero). None
+    # for every other model, as above.
+    moe_pairs: Optional[jnp.ndarray] = None  # (2,) int32
 
 
 def init_paged_state(
@@ -148,6 +156,8 @@ def init_paged_state(
             "conv": jnp.zeros(
                 (c.n_state_layers, batch, taps * width), c.activation_dtype),
         }
+    if c.expert_share:
+        recurrent["moe_pairs"] = jnp.zeros((2,), jnp.int32)
     return PagedDecodeState(
         **recurrent,
         k=jnp.zeros(shape + k_row, c.activation_dtype),
@@ -467,6 +477,14 @@ class BlockAllocator:
 # from the operand shapes alone (unsharded library callers and tests).
 
 
+def _extra_leaves(state: PagedDecodeState):
+    """The names of the state's leaves beyond the KV pool that
+    `_layer_loop` hands back, in its order."""
+    if state.ssm is not None:
+        return ("ssm", "conv")
+    return ("moe_pairs",) if state.moe_pairs is not None else ()
+
+
 def _jit_shardings(in_shardings, out_shardings):
     if in_shardings is None:
         return {}
@@ -476,9 +494,12 @@ def _jit_shardings(in_shardings, out_shardings):
 def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
                 blk, off, tables, valid_len, *, bank=None, adapter_ix=None,
                 has_lora=None, attn_impl: Optional[str] = None,
-                partitioned: bool = False, recurrent=None):
-    """The layer loop of every paged program -> (x, k_pool, v_pool), and
-    with `recurrent` -> (x, k_pool, v_pool, ssm, conv).
+                partitioned: bool = False, recurrent=None, tally=None):
+    """The layer loop of every paged program -> (x, k_pool, v_pool), with
+    `recurrent` -> (x, k_pool, v_pool, ssm, conv), and with `tally` =
+    (counted (B, S) bool: the rows' real tokens, the count so far (2,) int32)
+    -> (x, k_pool, v_pool, the count with the expert layers'
+    `moe.local_pairs` of those tokens added).
 
     x (B, S, d) at `positions` runs through the layers; layer l writes its new
     K/V rows into the STACKED pool (L, num_blocks, block_size, KV, hd) at `[l,
@@ -510,7 +531,6 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     state); and which rows start a sequence (from zero state, whoever held the
     slot). Its attention layers index the KV pool by their rank among them.
     """
-    mixers = mixer_stacks(params)
     if recurrent is not None:
         _, _, slot, n_valid, fresh = recurrent
 
@@ -598,10 +618,12 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
                 q, kp, vp, l, tables, valid_len, impl=attn_impl,
                 window=window,
             )
-        return attn_output(attn, p), kp, vp
+        return attn_output(attn, p, head_gate(c, x, p)), kp, vp
+
+    tallied = {} if tally is None else {"counted": tally[0]}
 
     def block(bank_stack, first, carry, layer, kind):
-        if mixers is not None:
+        if recurrent is not None:
             (p, l, lp), (own, rank) = layer
             p = {**p, **own}
             x, kp, vp, ssm, conv = carry
@@ -610,17 +632,24 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             else:
                 out, kp, vp = attend(x, p, lp, rank, kp, vp, kind)
             return (mlp_block(c, x + out, p), kp, vp, ssm, conv), None
-        x, kp, vp = carry
+        x, kp, vp, *count = carry
+        if isinstance(layer[0], tuple):   # (every layer's, its kind's own)
+            layer, (own, _) = layer
+            layer = ({**layer[0], **own},) + layer[1:]
         p, l, lp = layer
         out, kp, vp = attend(x, p, lp, l, kp, vp, kind)
         x = x + out
         if bank_stack:
-            x, _ = moe.moe_block(c, x, {**p, **bank_stack}, layer=l - first)
+            x, _, *pairs = moe.moe_block(
+                c, x, {**p, **bank_stack}, layer=l - first, **tallied)
         elif "router" in p:
-            x, _ = moe.moe_block(c, x, p, partitioned=partitioned)
+            x, _, *pairs = moe.moe_block(
+                c, x, p, partitioned=partitioned, **tallied)
         else:
-            x = mlp_block(c, x, p)
-        return (x, kp, vp), None
+            x, pairs = mlp_block(c, x, p), ()
+        if pairs:
+            count = [count[0] + pairs[0]]
+        return (x, kp, vp, *count), None
 
     # The pool keeps ONE layer axis over every kind of block: a model's
     # leading dense layers take its first indices, the expert layers the
@@ -636,16 +665,23 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     # Where the layers differ in their LEAVES (state-space mixers beside
     # attention mixers), each kind's mixers are a stack of their own, read
     # at a layer's rank among its kind; that rank is also the layer's index
-    # in the pool of its kind (KV rows, or state).
-    carry, first, own = (x, k_pool, v_pool), 0, None
-    if mixers is not None:
+    # in the pool of its kind (KV rows, or state). Where only the query
+    # heads differ by kind (`heads_by_kind`: wq, wo and the gate a stack a
+    # kind and stack of layers), every layer keeps rows and the pool's
+    # index is the layer's own.
+    carry, first = (x, k_pool, v_pool), 0
+    if recurrent is not None:
         carry += recurrent[:2]
-        own = {
-            kind: (stack, jnp.arange(
-                jax.tree_util.tree_leaves(stack)[0].shape[0], dtype=jnp.int32))
-            for kind, stack in mixers.items()
+    if tally is not None:
+        carry += (tally[1],)
+    for stack, mixers, kinds in zip(
+        layer_stacks(params), mixer_stacks(params), c.stack_kinds
+    ):
+        own = mixers and {
+            kind: (of, jnp.arange(
+                jax.tree_util.tree_leaves(of)[0].shape[0], dtype=jnp.int32))
+            for kind, of in mixers.items()
         }
-    for stack in layer_stacks(params):
         n = jax.tree_util.tree_leaves(stack)[0].shape[0]
         bank_stack = {}
         if "router" in stack and moe.takes_routed_path(
@@ -659,7 +695,8 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             None if bank is None else bank["layers"],
         )
         carry, _ = scan_layers(
-            c, functools.partial(block, bank_stack, first), carry, xs, own=own
+            functools.partial(block, bank_stack, first), carry, xs, kinds,
+            own=own,
         )
         first += n
     return carry
@@ -732,6 +769,8 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
             bank=bank, adapter_ix=aix, has_lora=aix >= 0,
             attn_impl=attn_impl, partitioned=shardings is not None,
             recurrent=recurrent,
+            tally=None if state.moe_pairs is None
+            else (valid[None], state.moe_pairs),
         )
         h = final_norm(c, params, x)
         h_last = jnp.take(
@@ -756,7 +795,7 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
             # Finalize claims the slot for this request's adapter; a slot
             # reused by an adapter-free request resets to -1 here.
             adapter_ix=jnp.where(sel, aix, state.adapter_ix),
-            **dict(zip(("ssm", "conv"), new_recurrent)),
+            **dict(zip(_extra_leaves(state), new_recurrent)),
         )
         return new_state, first
 
@@ -850,6 +889,8 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
             has_lora=jnp.any(state.active & (aix >= 0)),
             attn_impl=attn_impl, partitioned=shardings is not None,
             recurrent=recurrent,
+            tally=None if state.moe_pairs is None
+            else (state.active[:, None], state.moe_pairs),
         )
         h = final_norm(c, params, x)
         logits = logits_linear(h[:, -1], head_weights(params))
@@ -869,7 +910,7 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
             temperature=state.temperature,
             top_p=state.top_p,
             adapter_ix=state.adapter_ix,
-            **dict(zip(("ssm", "conv"), new_recurrent)),
+            **dict(zip(_extra_leaves(state), new_recurrent)),
         )
         return new_state, jnp.where(act, next_token, -1), new_active
 
@@ -906,7 +947,15 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
         (state, active), toks = lax.scan(
             body, (state, state.active), jax.random.split(rng, steps)
         )
-        return state, toks.T, active
+        if state.moe_pairs is None:
+            return state, toks.T, active
+        # The counts go out with the tokens, read at the sync that reads
+        # those, and the state starts again from zero: the programs count
+        # in int32, the engine sums in Python's integers.
+        return (
+            state._replace(moe_pairs=jnp.zeros_like(state.moe_pairs)),
+            toks.T, active, state.moe_pairs,
+        )
 
     return decode_steps
 

@@ -21,6 +21,16 @@ between from a call's shapes, at trace time:
   chunk take: with a no-drop capacity factor the capacity path multiplies
   experts / experts-per-token times the routed rows (docs/design/perf.md).
 
+A device may hold a SHARE of the bank (`ModelConfig.held`: experts
+`first .. first + held - 1` of the `n_experts` the router scores, as one of
+the devices that divide a layer under expert parallelism holds). Routing, the
+top k, the renormalisation and the scaling are over all `n_experts`; the
+weights, the dispatch and the grouped matmul are over the experts held, and a
+token's pairs that fall on experts held elsewhere add nothing here. What comes
+out is this device's part of the layer's sum; nothing stands in for the other
+devices or for the exchange with them. With the whole bank held both paths
+are what they were, operation for operation.
+
 Parity note: the reference orchestrator ships no model math (SURVEY §2.7
 "absent by design" — users bring torch MoE in containers); this is part of
 the framework-native workload library the orchestrator launches.
@@ -115,13 +125,35 @@ def route(
     c: ModelConfig, h: jnp.ndarray, router: jnp.ndarray,
     bias: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Top-k routing -> (dispatch (B,S,E,C), combine (B,S,E,C), aux scalar)."""
-    C = expert_capacity(c, h.shape[1])
-    gate_vals, _, slot, sel, aux = route_assignments(c, h, router, bias)
+    """Top-k routing -> (dispatch (B,S,E,C), combine (B,S,E,C), aux scalar),
+    E the experts held here."""
+    gate_vals, gate_idx, slot, sel, aux = route_assignments(c, h, router, bias)
+    return _dispatch_combine(c, h.shape[1], gate_vals, slot, sel) + (aux,)
+
+
+def _dispatch_combine(c: ModelConfig, seq_len: int, gate_vals, slot, sel):
+    """The capacity path's dispatch and combine tensors (B,S,E,C) from
+    `route_assignments`' answer, over the columns of the experts held."""
+    C = expert_capacity(c, seq_len)
+    if c.expert_share:
+        first, held = c.held
+        sel = sel[..., first:first + held]
     slot_oh = jax.nn.one_hot(slot, C, dtype=jnp.float32)  # 0-row when >= C
     dispatch = jnp.einsum("bske,bskc->bsec", sel, slot_oh)
     combine = jnp.einsum("bsk,bske,bskc->bsec", gate_vals, sel, slot_oh)
-    return dispatch, combine, aux
+    return dispatch, combine
+
+
+def local_pairs(c: ModelConfig, gate_idx: jnp.ndarray, counted: jnp.ndarray):
+    """-> (2,) int32: the (token, expert) pairs routing chose for the tokens
+    `counted` (B,S) bool marks (padding and dead rows route too, and count
+    for nothing), and those of them that fell on an expert held here."""
+    first, held = c.held
+    here = (gate_idx >= first) & (gate_idx < first + held)
+    return jnp.stack([
+        jnp.sum(counted) * gate_idx.shape[-1],
+        jnp.sum(here & counted[..., None]),
+    ]).astype(jnp.int32)
 
 
 def _expert_ffn(h_dtype, expert_in: jnp.ndarray, p: Params) -> jnp.ndarray:
@@ -144,19 +176,24 @@ def _expert_ffn(h_dtype, expert_in: jnp.ndarray, p: Params) -> jnp.ndarray:
 
 
 def bank_slots(
-    rows: int, row_len: int, k: int, n_experts: int, capacity: int, tile: int
+    rows: int, row_len: int, k: int, n_experts: int, capacity: int, tile: int,
+    held: Optional[int] = None,
 ) -> Tuple[int, int]:
     """Expert slots one layer multiplies for `rows` batch rows of
-    `row_len` tokens under each formulation -> (capacity, routed).
+    `row_len` tokens under each formulation -> (capacity, routed), in a
+    bank that holds `held` of the `n_experts` routed over (None: all).
 
     The capacity dispatch fills `capacity` slots an expert and row whether
-    or not a token was routed there; the routed path multiplies the
-    rows * row_len * k routed rows, and its grouped matmul at most one
+    or not a token was routed there; the routed path multiplies the routed
+    rows that fall on the bank (all rows * row_len * k of them on a whole
+    bank, held / n_experts of them in the mean on a share: what the counts
+    of a run read is `local_pairs`), and its grouped matmul at most one
     row tile more an expert (a group that ends inside a tile pays for the
     whole tile)."""
+    held = n_experts if held is None else held
     return (
-        n_experts * rows * capacity,
-        rows * row_len * k + n_experts * tile,
+        held * rows * capacity,
+        rows * row_len * k * held // n_experts + held * tile,
     )
 
 
@@ -208,7 +245,7 @@ def plan(
     k, E = c.experts_per_token, c.n_experts
     tile = row_tile(rows * row_len * k, E)
     at_capacity, routed = bank_slots(
-        rows, row_len, k, E, expert_capacity(c, row_len), tile
+        rows, row_len, k, E, expert_capacity(c, row_len), tile, c.held[1]
     )
     if whole_bank and tile and 3 * routed <= 2 * at_capacity:
         return True, routed, tile
@@ -259,8 +296,11 @@ def moe_mlp(
     mesh: Optional[Mesh] = None,
     partitioned: bool = False,
     layer: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The routed SwiGLU experts on a normed input h -> (out, aux_loss).
+    counted: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, ...]:
+    """The routed SwiGLU experts on a normed input h -> (out, aux_loss), and
+    with `counted` (B,S) bool -> (out, aux_loss, `local_pairs` of the tokens
+    it marks).
 
     p carries: router (D,E) f32, we_gate/we_up (E,D,F), we_down (E,F,D).
     `plan` chooses the formulation from what this call can see: the rows
@@ -278,8 +318,8 @@ def moe_mlp(
     if layer is not None or takes_routed_path(
         c, h.shape[0], h.shape[1], p, mesh, partitioned
     ):
-        return _moe_mlp_routed(c, h, p, mesh, layer)
-    return _moe_mlp_capacity(c, h, p, mesh)
+        return _moe_mlp_routed(c, h, p, mesh, layer, counted)
+    return _moe_mlp_capacity(c, h, p, mesh, counted)
 
 
 def _batch_shards(mesh: Optional[Mesh]) -> int:
@@ -294,14 +334,17 @@ def _moe_mlp_capacity(
     h: jnp.ndarray,
     p: Params,
     mesh: Optional[Mesh] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    counted: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, ...]:
     """The capacity path: dense GShard dispatch/combine tensors, every op
     a static matmul over E x C slots a row (2*E*C*D FLOPs a token each
-    way for the dispatch and the combine alone)."""
+    way for the dispatch and the combine alone), E the experts held."""
     with jax.named_scope("moe/route"):
-        dispatch, combine, aux = route(
+        gate_vals, gate_idx, slot, sel, aux = route_assignments(
             c, h, p["router"], p.get("router_bias")
         )
+        dispatch, combine = _dispatch_combine(c, h.shape[1], gate_vals, slot, sel)
+        tally = () if counted is None else (local_pairs(c, gate_idx, counted),)
 
     def constrain(x, spec):
         if mesh is not None and "expert" in mesh.axis_names:
@@ -324,7 +367,7 @@ def _moe_mlp_capacity(
         out = jnp.einsum(
             "bsec,ebcd->bsd", combine.astype(h.dtype), expert_out
         )
-        return out, aux
+        return (out, aux) + tally
 
 
 def _weight_tile(tile: int, k: int, n: int) -> Tuple[int, int]:
@@ -429,14 +472,26 @@ def _routed_bank(
     we_up: jnp.ndarray,
     we_down: jnp.ndarray,
     layer: Optional[jnp.ndarray] = None,
+    share: Optional[int] = None,
 ) -> jnp.ndarray:
     """The bank over ONE device's rows: h (B,S,D); gates (f32, 0 for a
     dropped row), gate_idx, slot (B,S,k); the bank whole (E,D,F), or with
-    `layer` the stack (L,E,D,F) it is layer `layer` of -> (B,S,D)."""
+    `layer` the stack (L,E,D,F) it is layer `layer` of -> (B,S,D).
+
+    `share` is None for a whole bank, else the first expert held: experts
+    `share .. share + E - 1` of those `gate_idx` names. The static T * k
+    rows stay: the pairs that fall on the experts held are sorted to the
+    front by expert, the others behind them as one more group, the last,
+    for which the bank has no weights: the grouped matmul visits no tile
+    of a group beyond its weights and gives zero rows for it, forward and
+    backward (megablox's own form of a bank sharded by experts)."""
     B, S, D = h.shape
     E, k = we_gate.shape[-3], gate_idx.shape[-1]
     T = B * S
     with jax.named_scope("moe/experts"):
+        if share is not None:
+            here = (gate_idx >= share) & (gate_idx < share + E)
+            gate_idx = jnp.where(here, gate_idx - share, E)  # E: no column
         # Expert-major place of every routed row: the experts before its own,
         # then the earlier batch rows' share of its expert, then its slot
         # (route_assignments' cumsum already counted its place in its row).
@@ -446,6 +501,10 @@ def _routed_bank(
         first = jnp.cumsum(group_sizes) - group_sizes  # (E,)
         before = first[None, :] + jnp.cumsum(counts, axis=0) - counts  # (B,E)
         dest = (slot + jnp.einsum("bske,be->bsk", sel, before)).reshape(T * k)
+        if share is not None:
+            here = here.reshape(T * k)
+            n_here = jnp.sum(group_sizes)
+            dest = jnp.where(here, dest, n_here + jnp.cumsum(~here) - 1)
         src = jnp.argsort(dest)  # sorted row -> t * k + j
         if layer is not None:
             # The stack as L * E groups, every one empty but this layer's: an
@@ -457,6 +516,8 @@ def _routed_bank(
             we_gate, we_up, we_down = (
                 w.reshape((L * E,) + w.shape[2:]) for w in (we_gate, we_up, we_down)
             )
+        if share is not None:
+            group_sizes = jnp.append(group_sizes, T * k - n_here)
 
         x = _take_rows(h.reshape(T, D), src // k, dest.reshape(T, k))  # (T*k, D)
         gate = _grouped_matmul(x, we_gate, group_sizes, tile)
@@ -477,7 +538,8 @@ def _moe_mlp_routed(
     p: Params,
     mesh: Optional[Mesh] = None,
     layer: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    counted: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, ...]:
     """The routed path: only the rows routing chose are multiplied.
 
     Same scores, same top-k, same slots as the capacity path
@@ -494,10 +556,13 @@ def _moe_mlp_routed(
             c, h, p["router"], p.get("router_bias")
         )
         gates = jnp.where(slot < expert_capacity(c, S), gate_vals, 0.0)
+        tally = () if counted is None else (local_pairs(c, gate_idx, counted),)
     tile = row_tile(
         B // _batch_shards(mesh) * S * c.experts_per_token, c.n_experts
     )
-    bank = functools.partial(_routed_bank, tile)
+    bank = functools.partial(
+        _routed_bank, tile, **({"share": c.held[0]} if c.expert_share else {})
+    )
     weights = [p["we_gate"], p["we_up"], p["we_down"]]
     if layer is not None:
         weights.append(layer)
@@ -508,7 +573,7 @@ def _moe_mlp_routed(
             in_specs=(P(batch),) * 4 + (P(),) * len(weights),
             out_specs=P(batch), check_vma=False,
         )
-    return bank(h, gates, gate_idx, slot, *weights), aux
+    return (bank(h, gates, gate_idx, slot, *weights), aux) + tally
 
 
 def moe_block(
@@ -518,17 +583,19 @@ def moe_block(
     mesh: Optional[Mesh] = None,
     partitioned: bool = False,
     layer: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Pre-norm MoE block with residual: x -> (x + moe(norm(x)), aux).
+    counted: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, ...]:
+    """Pre-norm MoE block with residual: x -> (x + moe(norm(x)), aux), and
+    with `counted` -> (.., .., `local_pairs`) as `moe_mlp` gives them.
     With shared experts (`ws_*` weights) every token also passes through
     their SwiGLU, added beside the routed sum."""
     from dstack_tpu.workloads.transformer import _silu, linear, rms_norm
     with jax.named_scope("moe/route"):
         h = rms_norm(x, p["mlp_norm"], c.norm_eps)
-    out, aux = moe_mlp(c, h, p, mesh, partitioned, layer)
+    out, aux, *tally = moe_mlp(c, h, p, mesh, partitioned, layer, counted)
     if "ws_gate" in p:
         with jax.named_scope("moe/shared"):
             gate = _silu(linear(h, p["ws_gate"]))
             out = out + linear(gate * linear(h, p["ws_up"]), p["ws_down"])
     with jax.named_scope("moe/experts"):
-        return x + out, aux
+        return (x + out, aux, *tally)

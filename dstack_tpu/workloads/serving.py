@@ -468,9 +468,15 @@ class ServingEngine:
         # Expert slots, window-diffable: what routing asked for against
         # what the capacity dispatch computes, counted from the shapes at
         # each launch (_count_expert_slots).
-        self._moe_routed_slots = 0
         self._moe_computed_slots = 0
         self._moe_routed_launches = 0
+        # The (token, expert) pairs routing chose, and those that fell on
+        # an expert held here: one number, counted at the launch, where the
+        # bank is whole; where it is a share of the experts routed over,
+        # what the programs counted on the device (kv_blocks: `moe_pairs`),
+        # read with each decode chunk's tokens.
+        self._moe_pairs = 0
+        self._moe_local_pairs = 0
         self._step = make_paged_decode_step(
             config, steps=steps_per_sync, shardings=self._shardings,
             lora=self._lora is not None, attn_impl=self._attn_path,
@@ -972,7 +978,7 @@ class ServingEngine:
                 )
                 programs += 1
                 self._rng, sub = jax.random.split(self._rng)
-            self.state, toks, _ = self._step_base(
+            self.state, toks, *_ = self._step_base(
                 self.params, self.state, sub
             )
             programs += 1
@@ -1622,7 +1628,18 @@ class ServingEngine:
             # ("pallas", "lax"; "none": no state-space layer).
             "prefix_cache": self._prefix_cache,
             "scan_path": self._scan_path,
-            "moe_routed_slots_total": self._moe_routed_slots,
+            # The bank held here (all the experts routed over, or a
+            # device's share of them) and what was asked of it: the (token,
+            # expert) pairs routing chose, those that fell on an expert
+            # held (all of them on a whole bank: counted at the launch
+            # from shapes; on a share the programs count them on the
+            # device and a decode chunk's sync reads them), and the slots
+            # the launched programs multiplied for them.
+            "moe_experts_published": self.config.n_experts,
+            "moe_experts_held": self.config.held[1] if self.config.n_experts else 0,
+            "moe_pairs_total": self._moe_pairs,
+            "moe_local_pairs_total": self._moe_local_pairs,
+            "moe_routed_slots_total": self._moe_local_pairs,
             "moe_computed_slots_total": self._moe_computed_slots,
             "moe_routed_launches_total": self._moe_routed_launches,
             "attn_dispatch_pallas_total": self._attn_dispatch["pallas"],
@@ -3228,11 +3245,11 @@ class ServingEngine:
                                   clock.mark("dispatch"))
         sub = self._next_key()
         if self._lora is not None and self._lora.inflight > 0:
-            self.state, tokens, active = self._step(
+            self.state, tokens, active, *pairs = self._step(
                 self.params, self.state, sub, self._lora.bank
             )
         else:
-            self.state, tokens, active = self._step_base(
+            self.state, tokens, active, *pairs = self._step_base(
                 self.params, self.state, sub
             )
         self._attn_dispatch[self._attn_path] += 1
@@ -3240,6 +3257,10 @@ class ServingEngine:
         clock.mark("sync")
         chunk.toks = jax.device_get(tokens)  # (B, steps_per_sync)
         chunk.still = jax.device_get(active)
+        if pairs:   # (a share of the expert bank: what the programs counted)
+            routed, here = jax.device_get(pairs[0])
+            self._moe_pairs += int(routed)
+            self._moe_local_pairs += int(here)
         self._observe_chunk_seconds(chunk, clock.mark("fan_out"))
         if self._spec and self._spec_cooldown > 0:
             self._spec_fallback_rounds += 1
@@ -3330,7 +3351,9 @@ class ServingEngine:
             return
         layers = c.n_layers - c.n_dense_layers
         routed, slots, _ = moe.plan(c, rows, row_len, self._whole_bank)
-        self._moe_routed_slots += layers * tokens * c.experts_per_token
+        if not c.expert_share:   # (a share's pairs: counted on the device)
+            self._moe_pairs += layers * tokens * c.experts_per_token
+            self._moe_local_pairs += layers * tokens * c.experts_per_token
         self._moe_computed_slots += layers * slots
         self._moe_routed_launches += routed
 
@@ -3681,6 +3704,15 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
          stats.get("decode_state_rows_total", 0)),
         ("dstack_tpu_serving_decode_state_rows_computed_total", "counter",
          stats.get("decode_state_rows_computed_total", 0)),
+        # The expert bank held here and what routing asked of it.
+        ("dstack_tpu_serving_moe_experts_published", "gauge",
+         stats.get("moe_experts_published", 0)),
+        ("dstack_tpu_serving_moe_experts_held", "gauge",
+         stats.get("moe_experts_held", 0)),
+        ("dstack_tpu_serving_moe_pairs_total", "counter",
+         stats.get("moe_pairs_total", 0)),
+        ("dstack_tpu_serving_moe_local_pairs_total", "counter",
+         stats.get("moe_local_pairs_total", 0)),
         ("dstack_tpu_serving_rejected_total", "counter",
          stats["rejected_total"]),
         # Speculative decoding (all zero when --spec-enable is off;

@@ -18,6 +18,7 @@ equivalent workload the orchestrator launches.
 """
 
 import math
+from dataclasses import replace
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -25,7 +26,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from dstack_tpu.workloads.attention import plain_attention
-from dstack_tpu.workloads.config import FULL, MAMBA, ModelConfig, RopeParams
+from dstack_tpu.workloads.config import (
+    FULL,
+    MAMBA,
+    ModelConfig,
+    RopeParams,
+    period_of,
+)
 from dstack_tpu.workloads.selective_scan import (
     selective_scan,
     selective_scan_chunk,
@@ -44,8 +51,14 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     has `layers` alone, as before. A model with state-space layers keeps
     in `layers` what every layer has (the two norms, the MLP) and under
     `mixers` one stack a KIND of mixer, each as long as the model has
-    layers of that kind (`mixer_stacks`, `scan_layers`). A tied head is no
-    leaf: `head_weights` reads the embedding."""
+    layers of that kind (`mixer_stacks`, `scan_layers`). So does a model
+    whose kinds of attention layer differ in their query heads
+    (`heads_by_kind`): `wq`, `wo` and the gate `wg` are a stack a kind, for
+    each stack of layers (`dense_mixers` beside `dense_layers`); `wk` and
+    `wv`, alike in every layer, stay with the layers. An expert layer's
+    bank holds the experts this device holds (`ModelConfig.held`); its
+    router scores all of them. A tied head is no leaf: `head_weights` reads
+    the embedding."""
     c = config
     dt = c.activation_dtype
     keys = jax.random.split(key, 8)
@@ -58,13 +71,28 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
 
     D, V = c.d_model, c.vocab_size
 
-    def attention(keys, L):
-        hd = c.head_dim
-        return {
-            "wq": dense(keys[1], (L, D, c.n_heads * hd), D),
+    def attention(keys, L, kind=FULL):
+        hd, H = c.head_dim, c.heads(kind)
+        weights = {
+            "wq": dense(keys[1], (L, D, H * hd), D),
             "wk": dense(keys[2], (L, D, c.n_kv_heads * hd), D),
             "wv": dense(keys[3], (L, D, c.n_kv_heads * hd), D),
-            "wo": dense(keys[4], (L, c.n_heads * hd, D), c.n_heads * hd),
+            "wo": dense(keys[4], (L, H * hd, D), H * hd),
+        }
+        if c.attn_gate:
+            weights["wg"] = dense(jax.random.fold_in(keys[1], 1), (L, D, H), D)
+        return weights
+
+    def own_attention(key, kinds):
+        """What only the layers of one kind have, a stack a kind of `kinds`."""
+        return {
+            kind: {
+                w: a for w, a in attention(
+                    jax.random.split(jax.random.fold_in(key, i), 8),
+                    kinds.count(kind), kind,
+                ).items() if w in HEADS_LEAVES
+            }
+            for i, kind in enumerate(sorted(set(kinds)))
         }
 
     def stack(keys, L, d_ff, experts):
@@ -88,19 +116,22 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                 keys[4], (L, H * c.v_head_dim, D), H * c.v_head_dim
             )
         elif not c.has_state_layers:   # (theirs are stacks of their own: `mixers`)
-            layers.update(attention(keys, L))
+            layers.update({
+                w: a for w, a in attention(keys, L).items()
+                if not (c.heads_by_kind and w in HEADS_LEAVES)
+            })
         F = d_ff
         if experts:
-            E = c.n_experts
+            E, held = c.n_experts, c.held[1]
             # Router stays f32: tiny, and routing decisions are precision-
             # sensitive (a bf16 tie flips top-k membership).
             layers["router"] = (
                 jax.random.normal(keys[5], (L, D, E), dtype=jnp.float32) * D**-0.5
             )
             ek = jax.random.split(keys[6], 3)
-            layers["we_gate"] = dense(ek[0], (L, E, D, F), D)
-            layers["we_up"] = dense(ek[1], (L, E, D, F), D)
-            layers["we_down"] = dense(ek[2], (L, E, F, D), F)
+            layers["we_gate"] = dense(ek[0], (L, held, D, F), D)
+            layers["we_up"] = dense(ek[1], (L, held, D, F), D)
+            layers["we_down"] = dense(ek[2], (L, held, F, D), F)
             if c.router_score == "sigmoid":
                 # The selection bias of an aux-loss-free router: a buffer
                 # the published models start at zero and move by a rule
@@ -163,7 +194,17 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
             jax.random.split(jax.random.fold_in(key, 98), 8), nd,
             c.dense_d_ff, False,
         )
+    if c.heads_by_kind:
+        for name, kinds, fold in zip(
+            ("dense_mixers", "mixers") if nd else ("mixers",), c.stack_kinds,
+            (96, 95) if nd else (95,),
+        ):
+            params[name] = own_attention(jax.random.fold_in(key, fold), kinds)
     return params
+
+
+# The attention leaves whose shape goes by a layer's query heads.
+HEADS_LEAVES = ("wq", "wo", "wg")
 
 
 def layer_stacks(params: Params):
@@ -175,9 +216,12 @@ def layer_stacks(params: Params):
 
 
 def mixer_stacks(params: Params):
-    """The stacks that only the layers of one kind have, by kind (None for
-    a model whose every layer has the same leaves): `scan_layers`' `own`."""
-    return params.get("mixers")
+    """For each of `layer_stacks`, the stacks that only its layers of one
+    kind have, by kind (None for a model whose every layer has the same
+    leaves): `scan_layers`' `own`."""
+    if "dense_layers" in params:
+        return (params.get("dense_mixers"), params.get("mixers"))
+    return (params.get("mixers"),)
 
 
 def head_weights(params: Params):
@@ -229,7 +273,15 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, rope: RopeParams) -> jnp.ndarray:
     """Rotary embedding. x: (B, S, H, hd); positions: (S,) or (B, S);
     `rope` a layer kind's `RopeParams` (config.rope): a scaled kind's
-    frequencies and its factor on cos and sin are constants of the trace."""
+    frequencies and its factor on cos and sin are constants of the trace.
+    A partial rotation turns the first `rotary_dim` values of a head, as a
+    head of that width, and passes the rest unrotated and unscaled."""
+    rot = rope.rotary_dim(x.shape[-1])
+    if rot < x.shape[-1]:
+        whole = replace(rope, partial_rotary_factor=1.0)
+        return jnp.concatenate(
+            [_rope(x[..., :rot], positions, whole), x[..., rot:]], axis=-1
+        )
     hd = x.shape[-1]
     factor = 1.0
     if rope.rope_type == "default":
@@ -260,7 +312,7 @@ def project_qkv(c: ModelConfig, x: jnp.ndarray, p: Params,
     rope = c.rope(kind)
     with jax.named_scope("attn/qkv"):
         h = rms_norm(x, p["attn_norm"], c.norm_eps)
-        q = linear(h, p["wq"]).reshape(b, s, c.n_heads, hd)
+        q = linear(h, p["wq"]).reshape(b, s, c.heads(kind), hd)
         k = linear(h, p["wk"]).reshape(b, s, c.n_kv_heads, hd)
         v = linear(h, p["wv"]).reshape(b, s, c.n_kv_heads, hd)
         if not c.use_rope:
@@ -268,17 +320,22 @@ def project_qkv(c: ModelConfig, x: jnp.ndarray, p: Params,
         return _rope(q, positions, rope), _rope(k, positions, rope), v
 
 
-def scan_layers(c: ModelConfig, block, carry, xs, own=None):
+def scan_layers(block, carry, xs, kinds, own=None):
     """`lax.scan` of `block(carry, x, kind) -> (carry, y)` over the leading
-    (layer) axis of `xs`, one PERIOD of the model's layer pattern a scan
-    step (config.layer_period): the `p` blocks of a period run in order
-    inside the body, each traced with its own kind, block j of step t
-    reading layer t * p + j of `xs`. A model of one kind is the plain scan
-    over its layers. -> (carry, ys) with `ys` on the layer axis.
+    (layer) axis of `xs`, one PERIOD of the layers' pattern a scan step: the
+    `p` blocks of a period run in order inside the body, each traced with
+    its own kind, block j of step t reading layer t * p + j of `xs`. A stack
+    of one kind is the plain scan over its layers. -> (carry, ys) with `ys`
+    on the layer axis.
+
+    `kinds` names the kind of each layer of `xs` (one of
+    `ModelConfig.stack_kinds`); the period is `config.period_of` it. Where
+    the period does not divide the stack, the layers past the last whole
+    period run after the scan, one block each, in order.
 
     `own` maps a kind to what only the layers of that kind have (a stack
-    of mixer weights, a cache, an index), its leading axis as long as the
-    model has layers of that kind. The block of layer i then gets
+    of mixer weights, a cache, an index), its leading axis as long as
+    `xs` has layers of that kind. The block of layer i then gets
     `(x, own_x)`, `own_x` the entry of `own[kind]` at i's rank among the
     layers of its kind, and `ys` comes back as a mapping kind -> the `y`s
     of that kind's layers (a kind without layers has no entry).
@@ -288,7 +345,9 @@ def scan_layers(c: ModelConfig, block, carry, xs, own=None):
     the period's weights out of the stack into a buffer of their own every
     step (three copies of 1 GB a step at 4 layers of 64 experts: AOT for
     the v5e, PERF.md section 6, PR 31)."""
-    period = c.layer_period
+    tree_map = jax.tree_util.tree_map
+    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    period = period_of(tuple(kinds))
     p = len(period)
     if p == 1:
         if own is not None:
@@ -298,8 +357,6 @@ def scan_layers(c: ModelConfig, block, carry, xs, own=None):
             )
             return carry, {period[0]: ys}
         return lax.scan(lambda carry, x: block(carry, x, period[0]), carry, xs)
-    tree_map = jax.tree_util.tree_map
-    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
 
     def at(tree, index):
         # (the index is computed where it is read, leaf by leaf, as the
@@ -308,9 +365,10 @@ def scan_layers(c: ModelConfig, block, carry, xs, own=None):
             lambda a: lax.dynamic_index_in_dim(a, index(), keepdims=False), tree
         )
 
-    def body(carry, t):
-        ys, by_kind = [], {kind: [] for kind in period}
-        for j, kind in enumerate(period):
+    def run(carry, t, kinds_run):
+        """The blocks of `kinds_run` from period t's first layer on."""
+        ys, by_kind = [], {kind: [] for kind in kinds_run}
+        for j, kind in enumerate(kinds_run):
             x = at(xs, lambda: t * p + j)
             if own is not None:
                 rank = len(by_kind[kind])
@@ -323,10 +381,25 @@ def scan_layers(c: ModelConfig, block, carry, xs, own=None):
             return carry, stacked(ys)
         return carry, {kind: stacked(of) for kind, of in by_kind.items()}
 
-    carry, ys = lax.scan(body, carry, jnp.arange(n // p, dtype=jnp.int32))
-    return carry, tree_map(
+    carry, ys = lax.scan(
+        lambda carry, t: run(carry, t, period), carry,
+        jnp.arange(n // p, dtype=jnp.int32),
+    )
+    ys = tree_map(
         lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]), ys
     )
+    if n % p:
+        carry, tail = run(carry, n // p, period[: n % p])
+        if own is None:
+            ys = tree_map(lambda a, b: jnp.concatenate([a, b]), ys, tail)
+        else:
+            ys = {
+                kind: tree_map(
+                    lambda a, b: jnp.concatenate([a, b]), y, tail[kind]
+                ) if kind in tail else y
+                for kind, y in ys.items()
+            }
+    return carry, ys
 
 
 def project_latent(c: ModelConfig, x: jnp.ndarray, p: Params,
@@ -583,8 +656,8 @@ def _block(
         # Only a window layer names its window: an attention_fn written
         # for full layers alone keeps working on models that have no other.
         kw = {"window": c.window(kind)} if c.window(kind) else {}
-        attn = attention_fn(q, k, v, **kw).reshape(b, s, c.n_heads * c.head_dim)
-    x = x + attn_output(attn, p)
+        attn = attention_fn(q, k, v, **kw).reshape(b, s, c.heads(kind) * c.head_dim)
+    x = x + attn_output(attn, p, head_gate(c, x, p))
     if "router" in p:
         from dstack_tpu.workloads.moe import moe_block
 
@@ -624,12 +697,10 @@ def forward(
     else:
         attn_scores = attn is plain_attention
 
-    own = mixer_stacks(params)
-
     def block(kind):
         def body(carry, layer_p):
             x, aux = carry
-            if own is not None:
+            if isinstance(layer_p, tuple):   # (every layer's, its kind's own)
                 layer_p = {**layer_p[0], **layer_p[1]}
             x, layer_aux = _block(c, x, layer_p, positions, attn, mesh, kind)
             return (x, aux + layer_aux), None
@@ -639,12 +710,14 @@ def forward(
             seq_len=tokens.shape[1], attn_scores=attn_scores,
         )
 
-    blocks = {kind: block(kind) for kind in set(c.layer_period)}
+    blocks = {kind: block(kind) for kind in set(c.layer_types or (FULL,))}
     carry = (x, jnp.float32(0.0))
-    for stack in layer_stacks(params):
+    for stack, own, kinds in zip(
+        layer_stacks(params), mixer_stacks(params), c.stack_kinds
+    ):
         carry, _ = scan_layers(
-            c, lambda carry, p, kind: blocks[kind](carry, p), carry, stack,
-            own=own,
+            lambda carry, p, kind: blocks[kind](carry, p), carry, stack,
+            kinds, own=own,
         )
     x, aux = carry
 
@@ -673,7 +746,28 @@ def final_norm(c: ModelConfig, params: Params, x: jnp.ndarray) -> jnp.ndarray:
         return rms_norm(x, params["final_norm"], c.norm_eps)
 
 
-def attn_output(attn: jnp.ndarray, p: Params) -> jnp.ndarray:
-    """The attention block's out projection (before the residual)."""
+def attn_output(attn: jnp.ndarray, p: Params, gate=None) -> jnp.ndarray:
+    """The attention block's out projection (before the residual), of the
+    heads' outputs (B, S, H * hd), each multiplied by its `gate` (B, S, H)
+    where the model gates its heads (`head_gate`)."""
     with jax.named_scope("attn/out"):
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                b, s, h = gate.shape
+                attn = (
+                    attn.reshape(b, s, h, -1).astype(jnp.float32) * gate[..., None]
+                ).astype(attn.dtype).reshape(attn.shape)
         return linear(attn, p["wo"])
+
+
+def head_gate(c: ModelConfig, x: jnp.ndarray, p: Params):
+    """The per-head output gate of the block whose input is `x`: act(h Wg)
+    (B, S, H) float32, from the same normed input as the queries; None for
+    a model without (`ModelConfig.attn_gate`). Under `attn/qkv` it counts
+    with the attention's projections, and by its own name apart."""
+    if not c.attn_gate:
+        return None
+    act = {"softplus": jax.nn.softplus, "sigmoid": jax.nn.sigmoid}[c.attn_gate]
+    with jax.named_scope("attn/qkv/attn_gate"):
+        h = rms_norm(x, p["attn_norm"], c.norm_eps)
+        return act(linear(h, p["wg"]).astype(jnp.float32))

@@ -538,7 +538,11 @@ def main() -> None:
              " --quantize int8, --lora-max-adapters, --mesh-model > 1, --role"
              " prefill / decode and --kv-host-budget-mb (so"
              " --max-resident-slots too): each would drop or have to roll"
-             " back the state")
+             " back the state. `tiny-laguna` (query heads and a per-head"
+             " output gate by layer kind, a dense layer beside a window/full"
+             " pattern, sigmoid-routed experts of which `experts_held` may"
+             " be a device's share: /metrics `moe_experts_held`,"
+             " `moe_local_pairs_total`) refuses the same options")
     parser.add_argument("--layers", type=int, default=0,
                         help="serve the preset cut to this many layers"
                              " (0 = the preset's depth); must match the"

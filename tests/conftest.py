@@ -4,6 +4,17 @@ import os
 # exercise real multi-device code paths without an accelerator. Both
 # variables must be in the environment before the first `import jax`.
 os.environ["JAX_PLATFORMS"] = "cpu"
+# XLA:CPU compiles a large module in several parts at once. On this jaxlib
+# that killed a worker in five whole runs out of five (PR 37: a segmentation
+# fault or an abort inside the compile, or inside the persistent cache's
+# `executable.serialize()` / its read-back, always at a `tiny-laguna` program,
+# the suite's largest: a dense block and four expert blocks in one body; a
+# different test each time, never alone) and in none with one part.
+if "xla_cpu_parallel_codegen_split_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_cpu_parallel_codegen_split_count=1"
+    ).strip()
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")

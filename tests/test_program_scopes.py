@@ -34,7 +34,8 @@ from dstack_tpu.workloads import kv_blocks
 from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.transformer import init_params
 
-PRESET_NAMES = ("tiny", "tiny-moe", "tiny-latent", "tiny-window", "tiny-mamba")
+PRESET_NAMES = ("tiny", "tiny-moe", "tiny-latent", "tiny-window", "tiny-mamba",
+                "tiny-laguna")
 PROGRAM_NAMES = ("decode_steps", "chunk_prefill")
 SLOTS, CHUNK, BLOCK, STEPS = 4, 32, 16, 2
 
@@ -131,6 +132,10 @@ def test_a_recorded_trace_holds_the_optimised_hlo_by_program(tmp_path):
     ("jit(f)/moe/experts/moe/route/top_k", "moe/route"),           # the LAST one
     ("jit(step)/transpose(jvp(mlp))/dot_general", "mlp"),
     ("jit(step)/transpose(jvp(attn/qkv))/mul", "attn/qkv"),
+    # a scope inside one of the vocabulary's counts with it (readers/scope_named.py
+    # reads it apart)
+    ("jit(decode_steps)/while/body/closed_call/attn/qkv/attn_gate/dot_general", "attn/qkv"),
+    ("jit(decode_steps)/while/body/closed_call/attn/out/attn_gate/mul", "attn/out"),
     ("jit(decode_steps)/while/body/dynamic_slice", None),
     ("jit(headline)/attn/add", None),        # a part of a word is no scope
     ("", None),
@@ -222,6 +227,27 @@ def test_every_matrix_product_lies_under_a_component(preset, name, compiled_prog
     if cfg.has_state_layers:
         want |= {"mamba/proj", "mamba/conv", "mamba/scan", "mamba/state"}
     assert want <= found, sorted(want - found)
+
+
+def test_the_head_gate_counts_with_the_projections_and_reads_apart(
+        compiled_programs, monkeypatch):
+    """The per-head output gate's product lies under `attn/qkv/attn_gate`:
+    `attn_proj` to the component shares, and a component of its own name to
+    the reduction `readers/scope_named.py` runs with the scope added."""
+    proto = hlo_proto_of(compiled_programs("tiny-laguna")["decode_steps"])
+    program = scope_reduce.Program(proto)
+    gates = [n for n, row in program.by_name.items()
+             if row.opcode in scope_reduce.PRODUCTS and "/attn_gate/" in row.op_name]
+    assert gates and {program.component_of(n) for n in gates} == {("attn_proj", "attn/qkv")}
+    monkeypatch.setattr(scope_reduce, "SCOPES", {**scope_reduce.SCOPES, "attn_gate": "attn_gate"})
+    apart = scope_reduce.Program(proto)
+    assert {apart.component_of(n) for n in gates} == {("attn_gate", "attn_gate")}
+    others = [n for n, row in apart.by_name.items()
+              if row.opcode in scope_reduce.PRODUCTS and "/attn/qkv/dot" in row.op_name]
+    assert others and {apart.component_of(n)[0] for n in others} == {"attn_proj"}
+    # a model without the gate has no such operation
+    plain = scope_reduce.Program(hlo_proto_of(compiled_programs("tiny-window")["decode_steps"]))
+    assert not [n for n, row in plain.by_name.items() if "attn_gate" in row.op_name]
 
 
 def strip_metadata(text: str) -> str:

@@ -152,6 +152,19 @@ def _kernels():
                     [((b, s, CELL_H, HD), bf16), cell_pool, cell_pool, ((), i32),
                      ((b, mb), i32), ((b, s), i32)],
                 ))
+    # Query heads by layer kind on 8 KV heads of 128 (the `laguna` block: 48
+    # on a full layer, 6 query rows a KV head; 72 on a sliding layer, 9) at
+    # its cell's geometry, 16 slots x 12,288 positions in blocks of 256,
+    # window 512: decode, the 512-token chunk, the smallest chunk bucket.
+    by_kind = ((2, 16 * 48, 256, 8, HD), bf16)
+    for heads, window in ((48, 0), (72, 512)):
+        for kind, b, s in [("decode", 16, 1), ("prefill", 1, 512), ("prefill", 1, 8)]:
+            out.append((
+                f"paged_{heads // 8}on1of8_{kind}_b{b}_s{s}",
+                lambda *a, w=window: _ragged_attention_pallas(*a, window=w),
+                [((b, s, heads, HD), bf16), by_kind, by_kind, ((), i32),
+                 ((b, 48), i32), ((b, s), i32)],
+            ))
     # Twenty query heads on ONE KV head (the `jamba` block's two attention
     # layers) at its cell's geometry, 128 slots x 18 blocks of 256: a decode
     # row is a 20-row query tile, the 512-token chunk 32 tiles of 320 rows,
@@ -447,6 +460,53 @@ def test_paged_program_moves_no_pool_or_slab(name, kind, v5e, no_compile_cache):
         itemsize = jnp.dtype(cfg.activation_dtype).itemsize
         one_pool = 2 * math.prod(pool) * itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < one_pool
+
+
+# Query heads and a head gate by layer kind (6 and 9 query rows a KV head), a
+# dense layer beside the pattern, and a device's HALF of a sigmoid-routed
+# bank: the `laguna` block at widths that compile in seconds.
+SHARE_CFG = CFG.with_(
+    d_model=1536, n_heads=24, n_kv_heads=4, head_size=128, n_layers=5,
+    layer_types=(FULL, SLIDING, SLIDING, SLIDING, FULL),
+    heads_per_layer=(24, 36, 36, 36, 24), sliding_window=512, attn_gate="softplus",
+    d_ff=2048, n_experts=32, experts_held=16, experts_per_token=4, capacity_factor=8.0,
+    n_dense_layers=1, dense_d_ff=4096, n_shared_experts=1, router_score="sigmoid",
+    routed_scaling=2.5, vocab_size=8192, remat=False,
+    rope_parameters={FULL: {"rope_type": "yarn", "rope_theta": 500000.0, "factor": 16.0,
+                            "original_max_position_embeddings": 8192,
+                            "partial_rotary_factor": 0.5}},
+)
+
+
+@pytest.mark.parametrize("name", ["decode_steps", "chunk_prefill"])
+def test_a_share_of_the_bank_moves_no_pool_slab_or_bank(name, v5e, no_compile_cache):
+    """The paged programs of a model whose stacks of `wq`, `wo` and the gate
+    go by layer kind, scanned one period a step (here ONE step, which XLA
+    unrolls), with a share of the expert bank and the pair counters in the
+    carry: nothing of the pool's, a slab's or one layer's bank's size is
+    copied or cut out, and the counters come back beside the tokens."""
+    cfg = SHARE_CFG
+    fn, args = _paged_program(name, cfg, "pallas" if v5e is not None else "lax_ragged")
+    if v5e is not None:
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), args)
+    compiled = fn.lower(*args).compile()
+    if name == "decode_steps":
+        assert compiled.out_info[3].shape == (2,)          # [pairs, pairs held here]
+    pool = (cfg.n_layers, POOL_BLOCKS, BLOCK) + cfg.kv_row_shapes()[0]
+    bank = args[0]["layers"]["we_gate"].shape
+    sizes = {math.prod(pool): "pool", math.prod(pool[1:]): "slab",
+             math.prod(bank): "bank", math.prod(bank[1:]): "a layer's bank"}
+    assert len(sizes) == 4 and bank[1] == 16
+    moved = [
+        f"{m.group(2)} of the {sizes[n]} [{m.group(1)}]"
+        for m in _MOVES.finditer(compiled.as_text())
+        if (n := math.prod(int(d) for d in m.group(1).split(","))) in sizes
+    ]
+    assert not moved, moved
+    if v5e is not None:
+        one_bank = math.prod(bank[1:]) * jnp.dtype(cfg.activation_dtype).itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < one_bank
 
 
 # State-space layers beside attention layers (one period mmam, twice), 10
