@@ -169,6 +169,10 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_moe_experts_published": ("gauge", ()),
     "dstack_tpu_serving_moe_local_pairs_total": ("counter", ()),
     "dstack_tpu_serving_moe_pairs_total": ("counter", ()),
+    # Launches whose programs pass an expert layer, and those of them whose
+    # expert layers took the routed path (workloads/moe.py: `plan`).
+    "dstack_tpu_serving_moe_expert_launches_total": ("counter", ()),
+    "dstack_tpu_serving_moe_routed_launches_total": ("counter", ()),
     # Prefill/decode disaggregation (workloads/kv_transfer.py): handoff
     # outcome counters on both sides of the seam, payload bytes moved,
     # per-handoff transfer latency, and the depth of the handoff queue

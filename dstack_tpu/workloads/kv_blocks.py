@@ -657,11 +657,11 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     # layers are of more than one kind of ATTENTION the scan steps over
     # periods of the pattern (transformer.scan_layers): block j of period
     # t is layer t * p + j, its window and rotary embedding static.
-    # Where the expert layers take the routed path (moe.plan: a long
-    # chunk), its kernel reads a layer's experts in place in the stacked
-    # bank, as the attention kernel reads a layer of the pool: the bank
-    # stays out of the scan's `xs`, which would cut each layer's out into
-    # a buffer of its own before a kernel may read it.
+    # Where the expert layers take the routed path (moe.plan: a long chunk,
+    # a decode launch whose rows touch few experts), its kernel reads a
+    # layer's experts in place in the stacked bank, as the attention kernel
+    # reads the pool: the bank stays out of the scan's `xs`, which would cut
+    # each layer's out into a buffer of its own before a kernel may read it.
     # Where the layers differ in their LEAVES (state-space mixers beside
     # attention mixers), each kind's mixers are a stack of their own, read
     # at a layer's rank among its kind; that rank is also the layer's index

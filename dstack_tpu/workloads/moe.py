@@ -11,15 +11,15 @@ between from a call's shapes, at trace time:
   Capacity overflow drops tokens by construction: `one_hot` of an
   out-of-range slot index is the zero row, so overflowing tokens simply
   fall out of dispatch and keep their residual value. It multiplies every
-  slot, filled or not: right where there are few tokens (a decode launch,
-  a short chunk) or where the bank is sharded.
+  slot, filled or not, and reads every expert's weights: right where the
+  rows touch most of the bank, or where the bank is sharded.
 - routed: the rows routing chose, sorted by expert, go through three
   grouped matmuls whose group sizes are the rows each expert received
   (`_moe_mlp_routed`): no (B,S,E,C) tensor, no dispatch FLOPs, no empty
-  slot. A dropped row weighs zero, so the result is the capacity path's
-  at any capacity factor. It is what a train step and a long prefill
-  chunk take: with a no-drop capacity factor the capacity path multiplies
-  experts / experts-per-token times the routed rows (docs/design/perf.md).
+  slot, no read of an expert that received no row. A dropped row weighs
+  zero, so the result is the capacity path's at any capacity factor. A
+  train step, a long prefill chunk (docs/design/perf.md) and a decode
+  launch whose few rows touch few of the experts take it.
 
 A device may hold a SHARE of the bank (`ModelConfig.held`: experts
 `first .. first + held - 1` of the `n_experts` the router scores, as one of
@@ -177,7 +177,7 @@ def _expert_ffn(h_dtype, expert_in: jnp.ndarray, p: Params) -> jnp.ndarray:
 
 def bank_slots(
     rows: int, row_len: int, k: int, n_experts: int, capacity: int, tile: int,
-    held: Optional[int] = None,
+    held: Optional[int] = None, touched: float = 1.0,
 ) -> Tuple[int, int]:
     """Expert slots one layer multiplies for `rows` batch rows of
     `row_len` tokens under each formulation -> (capacity, routed), in a
@@ -188,12 +188,12 @@ def bank_slots(
     rows that fall on the bank (all rows * row_len * k of them on a whole
     bank, held / n_experts of them in the mean on a share: what the counts
     of a run read is `local_pairs`), and its grouped matmul at most one
-    row tile more an expert (a group that ends inside a tile pays for the
-    whole tile)."""
+    row tile more for each of the `touched` share of the experts held
+    (a group that ends inside a tile pays for the whole tile)."""
     held = n_experts if held is None else held
     return (
         held * rows * capacity,
-        rows * row_len * k * held // n_experts + held * tile,
+        rows * row_len * k * held // n_experts + round(held * touched) * tile,
     )
 
 
@@ -215,18 +215,38 @@ def row_tile(routed_rows: int, n_experts: int) -> int:
     return tile
 
 
+def touched_share(tokens: int, k: int, n_experts: int) -> float:
+    """The share of the experts that `tokens` tokens are expected to route
+    to, each choosing k of the `n_experts` at random: 1 - (1 - k / E) **
+    tokens. Routing is over all the experts, so it is also the share of
+    the experts of any part of the bank that a device holds."""
+    return 1.0 - (1.0 - k / n_experts) ** tokens
+
+
+# A call of one token a row (a decode launch) is bound by the weights' BYTES:
+# the capacity path reads every expert held, the routed path only those some
+# row chose, in row tiles of `_FEW_ROWS_TILE` (the routed rows are padded to a
+# whole tile). `plan` routes it where its rows are expected to touch at most
+# `_TOUCHED_MAX` of the experts (the measurements that set both: `plan`).
+_FEW_ROWS_TILE = 32
+_TOUCHED_MAX = 0.80
+
+
 def plan(
     c: ModelConfig, rows: int, row_len: int, whole_bank: bool = True
 ) -> Tuple[bool, int, int]:
     """Which formulation a call of `rows` x `row_len` tokens on a device
     takes -> (routed, slots it multiplies, the routed path's row tile).
 
-    A pure function of the call's shapes: the routed path where it
-    multiplies at most two thirds of the capacity path's slots, the
-    capacity path otherwise, and wherever the bank is not whole on the
-    device (`whole_bank` false: `moe_mlp` tells from the mesh and the
-    weights' types). `moe_mlp` dispatches on it and the serving engine's
-    slot counters read it, so the two cannot disagree.
+    A pure function of the call's shapes: capacity wherever the bank is
+    not whole on the device (`whole_bank` false: `moe_mlp` tells from the
+    mesh and the weights' types). Else a call of one token a row is routed
+    where its rows are expected to touch at most `_TOUCHED_MAX` of the
+    experts (`touched_share`): the grouped matmul then reads only theirs;
+    a longer call where the routed path multiplies at most two thirds of
+    the capacity path's slots. `moe_mlp` dispatches on it, `_moe_mlp_routed`
+    takes its tile and the engine's slot counters read it, so the three
+    cannot disagree.
 
     What set the two thirds: one expert layer forward on the v5e, both
     ways, ms (my chip run, PR 32; capacity slots : routed slots with
@@ -239,15 +259,29 @@ def plan(
       256-token chunk, top-8 of 64            16,384 : 10,240 -> 2.23, 2.07
       128-token chunk, top-8 of 64             8,192 :  9,216 -> 1.84, 1.97
       128-token chunk, top-2 of 8 (rollout)    1,024 :  1,280 -> 4.59, 5.02
-    A decode launch (16 rows of one token: 128 or 1,024 slots) has no
-    tile to fill: capacity, as every call whose routed rows 128 does not
-    divide."""
-    k, E = c.experts_per_token, c.n_experts
-    tile = row_tile(rows * row_len * k, E)
-    at_capacity, routed = bank_slots(
-        rows, row_len, k, E, expert_capacity(c, row_len), tile, c.held[1]
-    )
-    if whole_bank and tile and 3 * routed <= 2 * at_capacity:
+    What set `_TOUCHED_MAX` and `_FEW_ROWS_TILE`: one expert layer of 16
+    rows of one token, routed at random, both ways on a TPU v5e, ms (the
+    experts the rows touch -> capacity, routed at row tiles of 16 and 32
+    beside 2 MiB weight tiles, and of 32 beside 4 MiB ones):
+      top-10 of 256, 128 held, 3,072 x 1,024    47.1% -> 3.25, 1.54 1.53 1.53
+      top-4 of 64, 2,048 x 1,536                64.4% -> 1.65, 1.16 1.14 1.14
+      top-8 of 64, 2,304 x 896                  88.2% -> 1.14, 1.09 1.06 1.02
+      top-2 of 8, 4,096 x 14,336                99.0% -> 3.79, 4.14 3.79 3.77
+    The routed path takes 1.0 to 1.1 times the touched share of the
+    capacity path's time, so the two meet near 0.9 of the experts;
+    `_TOUCHED_MAX` keeps a tenth of room below that."""
+    k, E, held = c.experts_per_token, c.n_experts, c.held[1]
+    capacity = expert_capacity(c, row_len)
+    if row_len == 1:
+        touched, tile = touched_share(rows, k, E), _FEW_ROWS_TILE
+        at_capacity, routed = bank_slots(
+            rows, 1, k, E, capacity, tile, held, touched)
+        takes = touched <= _TOUCHED_MAX
+    else:
+        tile = row_tile(rows * row_len * k, E)
+        at_capacity, routed = bank_slots(rows, row_len, k, E, capacity, tile, held)
+        takes = tile > 0 and 3 * routed <= 2 * at_capacity
+    if whole_bank and takes:
         return True, routed, tile
     return False, at_capacity, tile
 
@@ -380,7 +414,7 @@ def _weight_tile(tile: int, k: int, n: int) -> Tuple[int, int]:
     128: what the kernel's double buffers and f32 accumulator leave of
     the 16 MiB of VMEM a call may use."""
     tk = k if k <= 2304 else 1024
-    room = (4 if tile == 128 else 2) * 2**20 // (2 * tk)
+    room = (4 if tile <= 128 else 2) * 2**20 // (2 * tk)
     tn = max(
         (t for t in range(128, min(n, room) + 1, 128) if n % t == 0),
         default=min(n, 128),
@@ -462,6 +496,11 @@ def _take_rows_bwd(readers, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
+def _padded(x: jnp.ndarray, pad: int) -> jnp.ndarray:
+    """x (m, D) with `pad` rows of zeros below it."""
+    return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+
+
 def _routed_bank(
     tile: int,
     h: jnp.ndarray,
@@ -484,10 +523,13 @@ def _routed_bank(
     front by expert, the others behind them as one more group, the last,
     for which the bank has no weights: the grouped matmul visits no tile
     of a group beyond its weights and gives zero rows for it, forward and
-    backward (megablox's own form of a bank sharded by experts)."""
+    backward (megablox's own form of a bank sharded by experts). Where T * k
+    is no whole number of row tiles, `pad` zero rows make it one, in a last
+    group of that kind too."""
     B, S, D = h.shape
     E, k = we_gate.shape[-3], gate_idx.shape[-1]
     T = B * S
+    pad = -T * k % tile
     with jax.named_scope("moe/experts"):
         if share is not None:
             here = (gate_idx >= share) & (gate_idx < share + E)
@@ -518,13 +560,14 @@ def _routed_bank(
             )
         if share is not None:
             group_sizes = jnp.append(group_sizes, T * k - n_here)
-
-        x = _take_rows(h.reshape(T, D), src // k, dest.reshape(T, k))  # (T*k, D)
+        if pad:
+            group_sizes = jnp.append(group_sizes, pad)
+        x = _padded(_take_rows(h.reshape(T, D), src // k, dest.reshape(T, k)), pad)
         gate = _grouped_matmul(x, we_gate, group_sizes, tile)
         up = _grouped_matmul(x, we_up, group_sizes, tile)
         act = jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
         y = _grouped_matmul(act.astype(h.dtype), we_down, group_sizes, tile)
-        y = _take_rows(y, dest, src[:, None])  # (T*k, D)
+        y = _take_rows(y[: T * k] if pad else y, dest, src[:, None])  # (T*k, D)
         out = jnp.sum(
             gates.reshape(T, k, 1) * y.reshape(T, k, D).astype(jnp.float32),
             axis=1,
@@ -557,9 +600,7 @@ def _moe_mlp_routed(
         )
         gates = jnp.where(slot < expert_capacity(c, S), gate_vals, 0.0)
         tally = () if counted is None else (local_pairs(c, gate_idx, counted),)
-    tile = row_tile(
-        B // _batch_shards(mesh) * S * c.experts_per_token, c.n_experts
-    )
+    tile = plan(c, B // _batch_shards(mesh), S)[2]
     bank = functools.partial(
         _routed_bank, tile, **({"share": c.held[0]} if c.expert_share else {})
     )

@@ -465,11 +465,11 @@ class ServingEngine:
         # path is the traced path, sharded or not.
         self._attn_path = self._resolve_attn_path(config)
         self._attn_dispatch = {"pallas": 0, "lax_ragged": 0}
-        # Expert slots, window-diffable: what routing asked for against
-        # what the capacity dispatch computes, counted from the shapes at
-        # each launch (_count_expert_slots).
+        # Expert slots and launches, window-diffable, counted from the
+        # shapes at each launch (_count_expert_slots).
         self._moe_computed_slots = 0
         self._moe_routed_launches = 0
+        self._moe_expert_launches = 0
         # The (token, expert) pairs routing chose, and those that fell on
         # an expert held here: one number, counted at the launch, where the
         # bank is whole; where it is a share of the experts routed over,
@@ -1642,6 +1642,7 @@ class ServingEngine:
             "moe_routed_slots_total": self._moe_local_pairs,
             "moe_computed_slots_total": self._moe_computed_slots,
             "moe_routed_launches_total": self._moe_routed_launches,
+            "moe_expert_launches_total": self._moe_expert_launches,
             "attn_dispatch_pallas_total": self._attn_dispatch["pallas"],
             "attn_dispatch_lax_ragged_total":
                 self._attn_dispatch["lax_ragged"],
@@ -3310,7 +3311,7 @@ class ServingEngine:
         self._decode_layer_blocks += steps * layer_blocks
         self._decode_attended_blocks += steps * (layer_blocks - dead)
         self._decode_window_dead_blocks += steps * dead
-        self._count_expert_slots(steps * live, steps * self.slots, 1)
+        self._count_expert_slots(steps * live, self.slots, 1, steps)
 
     def _layer_blocks(self) -> Tuple[int, int]:
         """Over the live slots, from `_lengths_host` (no device sync): the
@@ -3339,13 +3340,17 @@ class ServingEngine:
         bank = self.params["layers"].get("we_gate")
         return self.mesh is None and not isinstance(bank, QTensor)
 
-    def _count_expert_slots(self, tokens: int, rows: int, row_len: int) -> None:
+    def _count_expert_slots(
+        self, tokens: int, rows: int, row_len: int, steps: int = 1
+    ) -> None:
         """A launch routes `tokens` valid tokens through every expert layer
-        and multiplies the slots `moe.plan` says its program does for
-        rows x row_len tokens, padding and dead rows included: the
-        capacity dispatch's experts x rows x capacity(row_len), or the
-        routed path's rows (the same function `moe.moe_mlp` dispatched on
-        when the program was traced)."""
+        and multiplies, in each of its `steps` steps, the slots `moe.plan`
+        says its program does for rows x row_len tokens, padding and dead
+        rows included: the capacity dispatch's experts x rows x
+        capacity(row_len), or the routed path's rows and row tiles (the
+        same function `moe.moe_mlp` dispatched on when the program was
+        traced). Counts the launch, and whether its expert layers took the
+        routed path."""
         c = self.config
         if c.n_experts == 0:
             return
@@ -3354,8 +3359,9 @@ class ServingEngine:
         if not c.expert_share:   # (a share's pairs: counted on the device)
             self._moe_pairs += layers * tokens * c.experts_per_token
             self._moe_local_pairs += layers * tokens * c.experts_per_token
-        self._moe_computed_slots += layers * slots
+        self._moe_computed_slots += layers * slots * steps
         self._moe_routed_launches += routed
+        self._moe_expert_launches += 1
 
     def _observe_chunk_seconds(self, chunk: "_Chunk", t_read: int) -> None:
         """Launch-to-readback wall time of the chunk whose `sync` phase
@@ -3713,6 +3719,12 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
          stats.get("moe_pairs_total", 0)),
         ("dstack_tpu_serving_moe_local_pairs_total", "counter",
          stats.get("moe_local_pairs_total", 0)),
+        # Launches whose programs pass an expert layer, and those of them
+        # whose expert layers took the routed path (moe.plan).
+        ("dstack_tpu_serving_moe_expert_launches_total", "counter",
+         stats.get("moe_expert_launches_total", 0)),
+        ("dstack_tpu_serving_moe_routed_launches_total", "counter",
+         stats.get("moe_routed_launches_total", 0)),
         ("dstack_tpu_serving_rejected_total", "counter",
          stats["rejected_total"]),
         # Speculative decoding (all zero when --spec-enable is off;

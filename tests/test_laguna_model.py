@@ -364,11 +364,14 @@ def test_engine_serves_the_share_and_counts_its_pairs_on_the_device():
     assert stats["moe_pairs_total"] == 49 * 4 * 3
     assert stats["moe_local_pairs_total"] == routed_here(c, params, fed)
     assert stats["moe_routed_slots_total"] == stats["moe_local_pairs_total"]
-    # Computed: the capacity path's held x rows x capacity, a launch: chunks
-    # of 16, 16, 9 (padded to 16) tokens, then 2 launches of 4 steps over
-    # the 2 slots, 4 expert layers each.
-    assert moe.plan(c, 1, 16) == (False, 4 * 16, 0) and moe.plan(c, 2, 1)[:2] == (False, 8)
-    assert stats["moe_computed_slots_total"] == 4 * (3 * 4 * 16 + 2 * 4 * 4 * 2)
+    # Computed: chunks of 16, 16, 9 (padded to 16) tokens on the capacity
+    # path (held x rows x capacity), then 2 launches of 4 steps over the 2
+    # slots on the routed path (their 6 pairs touch 61% of the experts: the
+    # 3 in the mean that fall here, and a 32-row tile for each of the 2 held
+    # experts expected to be touched), 4 expert layers each.
+    assert moe.plan(c, 1, 16) == (False, 4 * 16, 0) and moe.plan(c, 2, 1) == (True, 3 + 2 * 32, 32)
+    assert stats["moe_computed_slots_total"] == 4 * (3 * 4 * 16 + 2 * 4 * 67)
+    assert stats["moe_routed_launches_total"] == 2 and stats["moe_expert_launches_total"] == 5
     assert stats["layer_pattern"] == "fwwwf" and stats["kv_pool_layers"] == 5
 
 
@@ -412,6 +415,53 @@ def test_engine_serves_the_model_and_reuses_cached_head_blocks(path, request):
     # the whole bank: every pair is local, counted from shapes at the launch
     assert stats["moe_pairs_total"] == stats["moe_local_pairs_total"] == 4 * 3 * (
         stats["prefill_tokens_computed_total"] + stats["decode_slot_steps_total"])
+
+
+@pytest.mark.parametrize("held", [4, 0], ids=["share", "whole"])
+def test_decode_launches_take_the_routed_path_and_decode_the_same_tokens(held, monkeypatch):
+    """Two rows of one token, top-3 of 8: the decode launches take the routed
+    path, on experts 4..7 held or on the whole bank, and the engine decodes
+    the tokens it decodes held to the capacity path (`moe.plan` patched);
+    the launch counters say which path each launch took."""
+    c, params = model("float32")
+    if held:
+        c, params = share_of(c, params, 4, held)
+    assert moe.plan(c, 2, 1)[0] and not moe.plan(c, 1, 16)[0]
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(0, c.vocab_size, n).tolist() for n in (21, 30)]
+
+    def serve():
+        traced = []
+        monkeypatch.setattr(
+            moe, "_routed_bank",
+            lambda *a, _f=moe._routed_bank, **kw: traced.append(a[1].shape) or _f(*a, **kw),
+        )
+        engine = ServingEngine(c, params, slots=2, max_len=128, kv_block_size=4,
+                               prefill_chunk_tokens=16)
+        try:
+            outs = [engine.submit(p, max_new_tokens=7, temperature=0.0) for p in prompts]
+            got = []
+            for out in outs:
+                tokens = []
+                while (tok := out.get(timeout=300)) is not None:
+                    assert not isinstance(tok, BaseException), tok
+                    tokens.append(int(tok))
+                got.append(tokens)
+            return got, engine.stats(), traced
+        finally:
+            engine.close()
+            monkeypatch.undo()
+
+    got, stats, traced = serve()
+    assert set(traced) == {(2, 1, c.d_model)}
+    decode = stats["decode_steps_total"] // 4
+    assert decode and stats["moe_routed_launches_total"] == decode
+    assert stats["moe_expert_launches_total"] == stats["prefill_chunks_total"] + decode
+    monkeypatch.setattr(moe, "plan", lambda c, rows, row_len, whole=True: (
+        False, c.held[1] * rows * moe.expert_capacity(c, row_len), 0))
+    want, stats, traced = serve()
+    assert not traced and stats["moe_routed_launches_total"] == 0
+    assert got == want and all(len(t) == 7 for t in got)
 
 
 # -- the share ties to the model ------------------------------------------------------
@@ -491,10 +541,17 @@ def test_the_whole_bank_held_is_the_layer_it_was():
 
 def test_plan_and_the_slots_of_a_share():
     """What the cell's launches multiply, by hand: a decode launch of 16
-    rows takes the capacity path over the 128 experts held (one slot a row
-    and expert); a 512-token chunk the routed path, 5,120 pairs of which
-    half fall here in the mean, and a 128-row tile an expert held."""
-    assert moe.plan(CUT, 16, 1) == (False, 128 * 16 * 1, 0)
+    rows takes the routed path (its 160 pairs touch 47.1% of the experts:
+    80 fall here in the mean, and a 32-row tile for each of the 60 experts
+    held that they are expected to touch) where the capacity path would
+    multiply every expert held (one slot a row and expert); a 512-token
+    chunk the routed path, 5,120 pairs of which half fall here in the
+    mean, and a 128-row tile an expert held."""
+    assert moe.touched_share(16, 10, 256) == pytest.approx(0.4714, abs=1e-4)
+    assert moe.plan(CUT, 16, 1) == (True, 80 + 60 * 32, 32)
+    assert moe.plan(CUT, 16, 1, whole_bank=False) == (False, 128 * 16 * 1, 32)
+    assert moe.bank_slots(16, 1, 10, 256, 1, 32, 128, moe.touched_share(16, 10, 256)) == (
+        128 * 16, 80 + 60 * 32)
     assert moe.row_tile(512 * 10, 256) == 128
     assert moe.plan(CUT, 1, 512) == (True, 5120 * 128 // 256 + 128 * 128, 128)
     assert moe.bank_slots(1, 512, 10, 256, 512, 128, 128) == (128 * 512, 2560 + 16384)

@@ -376,34 +376,127 @@ def test_routed_path_with_an_expert_that_receives_no_row():
         assert _rel_l2(g_c[k], g_r[k]) < 0.02
 
 
-# (rows on a device, tokens a row, experts, top-k, capacity factor) of the
-# launches the benchmark's cells make -> the formulation moe.plan answers
-# and the slots it multiplies (PERF.md section 3).
+_BANK = ("we_gate", "we_up", "we_down")
+
+
+@pytest.mark.parametrize("rows", [16, 5], ids=["16-rows", "5-rows-padded"])
+@pytest.mark.parametrize("held", [0, 32], ids=["whole", "share"])
+class TestFewRowsOfOneToken:
+    """A decode launch's expert layer on the routed path: rows of one token,
+    top-2 of 64 experts (all held, or experts 32..63), the routed rows padded
+    to a whole row tile of 32 where they are fewer (5 x 2 = 10)."""
+
+    def _case(self, rows, held):
+        c = CFG.with_(n_experts=64, experts_per_token=2, capacity_factor=32.0,
+                      experts_held=held, experts_first=64 - held if held else 0)
+        p = _block_params(c, jax.random.PRNGKey(31 + rows))
+        p = {w: v for w, v in p.items() if w == "router" or w in _BANK}
+        if held:
+            assert c.expert_share and p["we_gate"].shape[0] == held
+        h = jax.random.normal(
+            jax.random.PRNGKey(32), (rows, 1, c.d_model), jnp.float32
+        ).astype(jnp.bfloat16)
+        return c, p, h
+
+    def test_equals_the_capacity_path(self, rows, held):
+        c, p, h = self._case(rows, held)
+        assert moe.plan(c, rows, 1)[0] and moe.plan(c, rows, 1)[2] == 32
+        out_c, aux_c = moe._moe_mlp_capacity(c, h, p)
+        out_r, aux_r = moe._moe_mlp_routed(c, h, p)
+        np.testing.assert_allclose(
+            np.asarray(out_c, np.float32), np.asarray(out_r, np.float32),
+            rtol=2e-2, atol=2e-2,
+        )
+        assert _rel_l2(out_c, out_r) < 0.01 and float(aux_c) == float(aux_r)
+
+    def test_an_expert_no_row_chose_is_not_read(self, rows, held):
+        """Its weights made NaN: the routed path gives the same bits (the
+        grouped matmul visits no tile of an empty group), the capacity path
+        NaN (it multiplies every expert's slots, empty or not)."""
+        c, p, h = self._case(rows, held)
+        _, gate_idx, _, _, _ = moe.route_assignments(c, h, p["router"])
+        first, n = c.held
+        chosen = set(np.asarray(gate_idx).ravel().tolist())
+        idle = jnp.asarray([e for e in range(n) if first + e not in chosen])
+        assert 0 < len(idle) < n
+        poisoned = {**p, **{w: p[w].at[idle].set(jnp.nan) for w in _BANK}}
+        clean, _ = moe._moe_mlp_routed(c, h, p)
+        out, _ = moe._moe_mlp_routed(c, h, poisoned)
+        assert bool(jnp.all(out == clean)) and bool(jnp.all(jnp.isfinite(clean)))
+        assert not bool(jnp.all(jnp.isfinite(moe._moe_mlp_capacity(c, h, poisoned)[0])))
+
+
+# (rows on a device, tokens a row, experts, top-k, capacity factor, experts
+# held: 0 = all) of the launches the benchmark's cells make -> the
+# formulation moe.plan answers and the slots it multiplies (PERF.md section
+# 3). A decode launch's 16 rows touch 1 - (1 - k / E) ** 16 of the experts:
+# 99.0% (rollout), 88.2% (mellum), 64.4% (the latent cell), 47.1% (laguna);
+# routed, it multiplies its rows and a 32-row tile for each expert touched.
 _LAUNCHES = {
-    "train row, 4,096 tokens, top-2 of 8": ((1, 4096, 8, 2, 4.0), True, 8192 + 8 * 512),
-    "latent cell, 512-token chunk, top-4 of 64": ((1, 512, 64, 4, 16.0), True, 2048 + 64 * 128),
-    "mellum, 512-token chunk, top-8 of 64": ((1, 512, 64, 8, 8.0), True, 4096 + 64 * 128),
-    "rollout, 128-token chunk, top-2 of 8": ((1, 128, 8, 2, 4.0), False, 8 * 128),
-    "rollout, decode launch, 16 rows": ((16, 1, 8, 2, 4.0), False, 8 * 16),
-    "latent cell, decode launch, 16 rows": ((16, 1, 64, 4, 16.0), False, 64 * 16),
-    "mellum, decode launch, 16 rows": ((16, 1, 64, 8, 8.0), False, 64 * 16),
+    "train row, 4,096 tokens, top-2 of 8": ((1, 4096, 8, 2, 4.0, 0), True, 8192 + 8 * 512),
+    "latent cell, 512-token chunk, top-4 of 64": ((1, 512, 64, 4, 16.0, 0), True, 2048 + 64 * 128),
+    "mellum, 512-token chunk, top-8 of 64": ((1, 512, 64, 8, 8.0, 0), True, 4096 + 64 * 128),
+    "rollout, 128-token chunk, top-2 of 8": ((1, 128, 8, 2, 4.0, 0), False, 8 * 128),
+    "rollout, decode launch, 16 rows": ((16, 1, 8, 2, 4.0, 0), False, 8 * 16),
+    "latent cell, decode launch, 16 rows": ((16, 1, 64, 4, 16.0, 0), True, 64 + 41 * 32),
+    "mellum, decode launch, 16 rows": ((16, 1, 64, 8, 8.0, 0), False, 64 * 16),
+    "laguna, decode launch, 16 rows, 128 of 256 held":
+        ((16, 1, 256, 10, 25.6, 128), True, 80 + 60 * 32),
+    "top-10 of 256, decode launch, 16 rows, whole bank":
+        ((16, 1, 256, 10, 25.6, 0), True, 160 + 121 * 32),
+    "laguna, 512-token chunk, 128 of 256 held":
+        ((1, 512, 256, 10, 25.6, 128), True, 2560 + 128 * 128),
 }
+
+
+def _config(E, k, cf, held):
+    return CFG.with_(n_experts=E, experts_per_token=k, capacity_factor=cf,
+                     experts_held=held)
 
 
 @pytest.mark.parametrize("launch", sorted(_LAUNCHES))
 def test_the_rule_at_the_cells_launch_shapes(launch):
-    (rows, row_len, E, k, cf), routed, slots = _LAUNCHES[launch]
-    c = CFG.with_(n_experts=E, experts_per_token=k, capacity_factor=cf)
+    (rows, row_len, E, k, cf, held), routed, slots = _LAUNCHES[launch]
+    c = _config(E, k, cf, held)
     assert moe.plan(c, rows, row_len)[:2] == (routed, slots)
     # A bank that is not whole on the device keeps the capacity path.
-    capacity = E * rows * expert_capacity(c, row_len)
+    capacity = c.held[1] * rows * expert_capacity(c, row_len)
     assert moe.plan(c, rows, row_len, whole_bank=False)[:2] == (False, capacity)
+
+
+def _rule_before_few_rows(c, rows, row_len, whole_bank):
+    """`moe.plan` as it was before it routed a launch of few rows of one
+    token: routed only where it multiplies at most two thirds of the slots."""
+    k, E = c.experts_per_token, c.n_experts
+    tile = moe.row_tile(rows * row_len * k, E)
+    at_capacity, routed = moe.bank_slots(
+        rows, row_len, k, E, expert_capacity(c, row_len), tile, c.held[1])
+    if whole_bank and tile and 3 * routed <= 2 * at_capacity:
+        return True, routed, tile
+    return False, at_capacity, tile
+
+
+@pytest.mark.parametrize("bank", sorted({
+    (E, k, cf, held) for (_, _, E, k, cf, held), _, _ in _LAUNCHES.values()}))
+def test_calls_of_more_than_one_token_a_row_keep_the_rule_they_had(bank):
+    """Only a launch of one token a row on a whole bank can change answer:
+    train rows, prefill chunks and speculation's verify rows keep it, and a
+    bank that is not whole keeps the capacity path at any shape."""
+    c = _config(*bank)
+    for rows in (1, 2, 4, 16, 128):
+        for row_len in (2, 5, 16, 128, 512, 4096):
+            for whole in (True, False):
+                assert moe.plan(c, rows, row_len, whole) == _rule_before_few_rows(
+                    c, rows, row_len, whole), (rows, row_len, whole)
+        assert moe.plan(c, rows, 1, False)[:2] == _rule_before_few_rows(c, rows, 1, False)[:2]
+        assert not moe.plan(c, rows, 1, False)[0]
 
 
 def test_engine_counts_the_slots_its_programs_multiply(monkeypatch):
     """A 512-token prefill chunk of an 8-expert model takes the routed path,
-    the short last chunk and every decode launch the capacity path: the
-    engine's counters read `moe.plan`, the function `moe_mlp` dispatched
+    the short last chunk the capacity path, and every decode launch the
+    routed path again (its 2 rows of top-2 of 8 touch 44% of the experts):
+    the engine's counters read `moe.plan`, the function `moe_mlp` dispatched
     on, and the tokens are those of an engine held to the capacity path."""
     from dstack_tpu.workloads.serving import ServingEngine
 
@@ -435,14 +528,17 @@ def test_engine_counts_the_slots_its_programs_multiply(monkeypatch):
             monkeypatch.undo()
 
     tokens, stats, traced = serve()
-    assert set(traced) == {(1, 512, c.d_model)}  # the long chunk, and only it
+    # the long chunk and the decode launches; not the short chunk
+    assert set(traced) == {(1, 512, c.d_model), (2, 1, c.d_model)}
     layers, k, E = c.n_layers, c.experts_per_token, c.n_experts
-    assert stats["moe_routed_launches_total"] == 1
-    decode = stats["decode_steps_total"] * 2  # rows a step: the slots
+    decode = stats["decode_steps_total"] // 4  # launches of 4 steps
+    assert stats["moe_expert_launches_total"] == stats["prefill_chunks_total"] + decode == 2 + decode
+    assert stats["moe_routed_launches_total"] == 1 + decode
+    assert moe.plan(c, 2, 1) == (True, 2 * k + 4 * 32, 32)  # 3.5 experts of 8 touched
     assert stats["moe_computed_slots_total"] == layers * (
         (512 * k + E * 128)  # the routed chunk: its rows and a tile an expert
         + E * 128            # 88 tokens padded to 128, at capacity 128
-        + E * decode         # capacity 1 a row
+        + stats["decode_steps_total"] * (2 * k + 4 * 32)  # rows, a tile an expert touched
     )
     assert stats["moe_routed_slots_total"] == layers * k * (
         600 + stats["decode_slot_steps_total"]
@@ -452,4 +548,5 @@ def test_engine_counts_the_slots_its_programs_multiply(monkeypatch):
         False, c.n_experts * rows * expert_capacity(c, row_len), 0))
     at_capacity, stats, traced = serve()
     assert not traced and stats["moe_routed_launches_total"] == 0
+    assert stats["moe_expert_launches_total"] == 2 + stats["decode_steps_total"] // 4
     assert tokens == at_capacity

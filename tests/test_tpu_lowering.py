@@ -479,13 +479,19 @@ SHARE_CFG = CFG.with_(
 
 
 @pytest.mark.parametrize("name", ["decode_steps", "chunk_prefill"])
-def test_a_share_of_the_bank_moves_no_pool_slab_or_bank(name, v5e, no_compile_cache):
+def test_a_share_of_the_bank_moves_no_pool_slab_or_bank(name, v5e, no_compile_cache, monkeypatch):
     """The paged programs of a model whose stacks of `wq`, `wo` and the gate
     go by layer kind, scanned one period a step (here ONE step, which XLA
     unrolls), with a share of the expert bank and the pair counters in the
     carry: nothing of the pool's, a slab's or one layer's bank's size is
-    copied or cut out, and the counters come back beside the tokens."""
+    copied or cut out, and the counters come back beside the tokens. The
+    decode program's 8 rows touch 66% of the experts: moe.plan routes its
+    expert layers (their grouped matmul compiled as the TPU's kernel)."""
     cfg = SHARE_CFG
+    routed = name == "decode_steps" and moe.plan(cfg, SLOTS, 1)[0]
+    assert routed == (name == "decode_steps")
+    if v5e is not None:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, args = _paged_program(name, cfg, "pallas" if v5e is not None else "lax_ragged")
     if v5e is not None:
         args = jax.tree.map(
@@ -506,7 +512,22 @@ def test_a_share_of_the_bank_moves_no_pool_slab_or_bank(name, v5e, no_compile_ca
     assert not moved, moved
     if v5e is not None:
         one_bank = math.prod(bank[1:]) * jnp.dtype(cfg.activation_dtype).itemsize
-        assert compiled.memory_analysis().temp_size_in_bytes < one_bank
+        scratch = compiled.memory_analysis().temp_size_in_bytes
+        if routed:
+            # Its scratch against the same program held to the capacity path:
+            # what routing adds (38 of 101 MB here, the attention weights'
+            # re-layouts that the compiler no longer keeps in the core's own
+            # memory beside the kernels; 2.3 MB in the laguna cell's decode
+            # program, 0.3112 against 0.3090 GiB) is no layer's bank.
+            with monkeypatch.context() as held:
+                held.setattr(moe, "plan", lambda c, rows, row_len, whole=True: (
+                    False, c.held[1] * rows * moe.expert_capacity(c, row_len), 0))
+                fn_c, _ = _paged_program(name, cfg, "pallas")
+                at_capacity = fn_c.lower(*args).compile()
+            assert at_capacity.memory_analysis().temp_size_in_bytes < one_bank
+            assert scratch < at_capacity.memory_analysis().temp_size_in_bytes + one_bank // 2
+        else:
+            assert scratch < one_bank
 
 
 # State-space layers beside attention layers (one period mmam, twice), 10
@@ -683,6 +704,61 @@ def test_long_chunk_program_multiplies_only_routed_rows(kind, v5e, no_compile_ca
         assert not cut_out, cut_out
 
 
+def test_decode_program_of_a_share_reads_only_the_routed_experts(v5e, no_compile_cache, monkeypatch):
+    """The decode program of a device that holds 128 of 256 experts, top-10
+    (the laguna cell's bank, narrow experts and two layers): its 12 rows of
+    one token touch 38% of the experts, so moe.plan routes every expert
+    layer. No array of the capacity dispatch's expert slots (E x B x C x
+    D_model or x d_ff, E the experts held) is left, so no einsum over the
+    experts held; the grouped matmul's kernels stand in its place, reading
+    each layer's experts in place in the stacked bank. The same reading
+    finds the slots in the program held to the capacity path. Compiled for
+    the v5e where libtpu describes one."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows = 12  # (at 8 or 16 an E x B x d_model array is as large as wk or wq)
+    monkeypatch.setitem(globals(), "SLOTS", rows)
+    monkeypatch.setitem(globals(), "POOL_BLOCKS", rows * MAX_BLOCKS)
+    cfg = CFG.with_(
+        n_layers=2, n_experts=256, experts_per_token=10, capacity_factor=25.6,
+        experts_held=128, d_ff=384, vocab_size=2048, remat=False,
+    )
+    E, C = cfg.held[1], moe.expert_capacity(cfg, 1)
+    assert moe.plan(cfg, rows, 1) == (True, 60 + 49 * 32, 32)
+    slot_sizes = {E * rows * C * cfg.d_model, E * rows * C * cfg.d_ff}
+    impl = "pallas" if v5e is not None else "lax_ragged"
+    fn, args = _paged_program("decode_steps", cfg, impl)
+    assert not slot_sizes & {math.prod(a.shape) for a in jax.tree.leaves(args)}
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert not slot_sizes & _array_sizes(text)
+    assert "tpu_custom_call" in text
+
+    with monkeypatch.context() as held:
+        held.setattr(moe, "plan", lambda c, rows, row_len, whole=True: (
+            False, E * rows * moe.expert_capacity(c, row_len), 0))
+        fn_c, _ = _paged_program("decode_steps", cfg, impl)
+        at_capacity = fn_c.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert slot_sizes <= _array_sizes(at_capacity)
+    assert text.count("tpu_custom_call") > at_capacity.count("tpu_custom_call")
+
+    if v5e is not None:
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e), args
+        )
+        executable = fn.lower(*args).compile()
+        compiled = executable.as_text()
+        assert not slot_sizes & _array_sizes(compiled)
+        one_bank = E * cfg.d_model * cfg.d_ff
+        cut_out = [
+            f"{m.group(2)} makes a layer's bank [{m.group(1)}]"
+            for m in _PRODUCES.finditer(compiled)
+            if math.prod(int(d) for d in m.group(1).split(",")) == one_bank
+            and m.group(2) not in ("parameter", "get-tuple-element", "bitcast")
+        ]
+        assert not cut_out, cut_out
+        # ... and its scratch holds no layer's bank either (bf16)
+        assert executable.memory_analysis().temp_size_in_bytes < one_bank
+
+
 def test_sharded_train_step_lowers_with_the_routed_bank(monkeypatch):
     """fsdp=4, one 2,048-token row a device, 8 experts top-2 at the
     no-drop factor: the rule takes the routed path, the bank runs under
@@ -758,6 +834,12 @@ _PARENT_OF_PR31 = {
     ("tiny-window", "decode_steps"): "c112128a7170a2c1",
     ("tiny-window", "chunk_prefill"): "664264e23acfa788",
 }
+
+# A decode launch whose rows touch at most `moe._TOUCHED_MAX` of the experts
+# takes the routed path (moe.plan): the test's 4 rows of top-2 of 8 touch
+# 68%, so the latent model's decode program is now the routed one (its chunk
+# program and every other program above lower as they did).
+_PARENT_OF_PR31[("tiny-latent", "decode_steps")] = "06e03e5d82ac607b"
 
 
 @pytest.mark.parametrize("preset,name", sorted(_PARENT_OF_PR31))
