@@ -169,6 +169,8 @@ METRICS: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "dstack_tpu_serving_moe_experts_published": ("gauge", ()),
     "dstack_tpu_serving_moe_local_pairs_total": ("counter", ()),
     "dstack_tpu_serving_moe_pairs_total": ("counter", ()),
+    # Of those pairs, the ones whose token took the router's skip choice.
+    "dstack_tpu_serving_moe_skip_pairs_total": ("counter", ()),
     # Launches whose programs pass an expert layer, and those of them whose
     # expert layers took the routed path (workloads/moe.py: `plan`).
     "dstack_tpu_serving_moe_expert_launches_total": ("counter", ()),

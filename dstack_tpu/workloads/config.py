@@ -15,8 +15,11 @@ import jax.numpy as jnp
 _DTYPE = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
 # The kinds a `layer_types` entry may name, as published configs spell them
-# (MAMBA is the `jamba` model type's word for its state-space layers).
+# (MAMBA is the `jamba` model type's word for its state-space layers, HYBRID
+# the `zaya` type's for a layer of compressed convolutional attention and then
+# an expert sublayer: full attention as far as a window or a cache goes).
 FULL, SLIDING, MAMBA = "full_attention", "sliding_attention", "mamba"
+HYBRID = "hybrid"
 
 
 def period_of(kinds: Tuple[str, ...]) -> Tuple[str, ...]:
@@ -220,6 +223,20 @@ class ModelConfig:
     # pairs that fall on experts held elsewhere add nothing here (moe.py).
     experts_held: int = 0
     experts_first: int = 0
+    # Compressed convolutional attention (CCA, arXiv:2510.04476; the `zaya`
+    # model type), on at cca_time0 > 0: queries and keys are projected to
+    # their heads' widths, then mixed over time by two causal convolutions of
+    # `cca_time0` and `cca_time1` taps (transformer.cca_project); half of a
+    # KV head's value comes from the token before. A slot carries the
+    # convolutions' inputs of its last tokens from program to program
+    # (`cca_tail_width`). It comes with the router of the same model type:
+    # `router_hidden_size` > 0 scores the experts AND a skip choice
+    # through an MLP of that width over a projection carried from layer to
+    # layer (transformer.depth_router; `expert_skip`), and both sublayers
+    # merge into the residual stream by learned scales (`residual_merge`).
+    cca_time0: int = 0
+    cca_time1: int = 0
+    router_hidden_size: int = 0
 
     def __post_init__(self):
         set_ = lambda k, v: object.__setattr__(self, k, v)
@@ -260,10 +277,15 @@ class ModelConfig:
                     f" {self.n_layers}"
                 )
             kinds = kinds[: self.n_layers]
-            if set(kinds) - {FULL, SLIDING, MAMBA}:
+            if set(kinds) - {FULL, SLIDING, MAMBA, HYBRID}:
                 raise ValueError(
                     f"layer_types {sorted(set(kinds))}: expected {FULL!r},"
-                    f" {SLIDING!r} or {MAMBA!r}"
+                    f" {SLIDING!r}, {MAMBA!r} or {HYBRID!r}"
+                )
+            if (HYBRID in kinds) != self.cca or (self.cca and set(kinds) != {HYBRID}):
+                raise ValueError(
+                    f"a {HYBRID!r} layer is a CCA layer (cca_time0,"
+                    " cca_time1): a model has only those, or none"
                 )
             if SLIDING in kinds and self.sliding_window < 1:
                 raise ValueError("a sliding_attention layer needs sliding_window")
@@ -318,9 +340,12 @@ class ModelConfig:
                 )
         ropes = self.rope_parameters or ()
         if isinstance(ropes, dict):
+            # (a published mapping may carry a top-level `rope_type` beside
+            # its groups by kind: it names no kind)
             ropes = tuple(
                 (kind, RopeParams.of(group, self.rope_theta))
                 for kind, group in sorted(ropes.items())
+                if isinstance(group, dict)
             )
         set_("rope_parameters", tuple(ropes))
         if self.kv_lora_rank > 0 and not (
@@ -344,6 +369,25 @@ class ModelConfig:
                 f"router_score={self.router_score!r}: expected 'softmax' or"
                 " 'sigmoid'"
             )
+        if self.cca or self.router_hidden_size:
+            if not (
+                self.cca_time0 > 0 and self.cca_time1 > 0
+                and self.router_hidden_size > 0 and self.n_experts > 0
+                and self.experts_per_token == 1 and self.head_dim % 2 == 0
+                and self.n_heads % self.n_kv_heads == 0
+                and self.router_score == "softmax"
+            ) or (
+                self.latent or self.attn_gate or self.heads_per_layer
+                or self.n_dense_layers or self.n_shared_experts
+                or self.experts_held or MAMBA in kinds
+            ):
+                raise ValueError(
+                    "CCA (cca_time0, cca_time1) comes with its router"
+                    " (router_hidden_size, top-1 of softmax-scored experts and"
+                    " a skip) on a bank held whole, and with no other"
+                    " attention or MLP variant: no program runs another"
+                    " pattern"
+                )
 
     @property
     def latent(self) -> bool:
@@ -411,6 +455,57 @@ class ModelConfig:
     def window(self, kind: str) -> int:
         """Keys a query of a `kind` layer sees, itself included; 0 = all."""
         return self.sliding_window if kind == SLIDING else 0
+
+    @property
+    def cca(self) -> bool:
+        """Attention is compressed convolutional attention (`cca_time0`)."""
+        return self.cca_time0 > 0
+
+    @property
+    def expert_skip(self) -> bool:
+        """The router scores a choice of NO expert beside the experts (a
+        token that takes it adds nothing to its layer), through its MLP of
+        `router_hidden_size` (moe.route_assignments)."""
+        return self.router_hidden_size > 0
+
+    @property
+    def n_choices(self) -> int:
+        """What the router chooses among: the experts, and the skip."""
+        return self.n_experts + self.expert_skip
+
+    @property
+    def pairs_on_device(self) -> bool:
+        """Where a routed pair lands is not a function of shapes (a share of
+        the bank, or a skip): the programs count the pairs on the device
+        (kv_blocks: `moe_pairs`)."""
+        return self.expert_share or self.expert_skip
+
+    def cca_tail_width(self) -> int:
+        """Values one slot carries a CCA layer from program to program, side
+        by side in ONE row (as `state_shapes`' tail): the first convolution's
+        inputs at the last `cca_time0` - 1 tokens, the second's at the last
+        `cca_time1` - 1 (a query's and a key head's width each, all heads),
+        and the shifted half of every KV head's value at the last token —
+        the ONE place the tail's geometry is derived."""
+        qk = (self.n_heads + self.n_kv_heads) * self.head_dim
+        return ((self.cca_time0 - 1 + self.cca_time1 - 1) * qk
+                + self.n_kv_heads * self.head_dim // 2)
+
+    def cca_params(self) -> int:
+        """One CCA layer's weights beyond the attention projections: the
+        two convolutions and each query head's temperature."""
+        hd, groups = self.head_dim, self.n_heads + self.n_kv_heads
+        return (self.cca_time0 * groups * hd + self.cca_time1 * groups * hd * hd
+                + self.n_heads)
+
+    def router_params(self) -> int:
+        """One expert layer's router: a d_model x n_experts projection, or
+        with `router_hidden_size` its down-projection, depth weight, norm
+        and three layers to the `n_choices` scores."""
+        d, r = self.d_model, self.router_hidden_size
+        if not r:
+            return d * self.n_experts
+        return d * r + 1 + r + 2 * r * r + r * self.n_choices
 
     @property
     def n_state_layers(self) -> int:
@@ -517,7 +612,7 @@ class ModelConfig:
             return 3 * d * self.dense_d_ff
         return (
             3 * d * self.d_ff * (self.held[1] + self.n_shared_experts)
-            + d * self.n_experts
+            + self.router_params()
         )
 
     def mamba_matmul_params(self) -> int:
@@ -546,6 +641,14 @@ class ModelConfig:
         the router's selection bias, a buffer the gradient does not move."""
         nd = self.n_dense_layers
         heads = (1 if self.tie_embeddings else 2) * self.d_model * self.vocab_size
+        if self.cca:
+            # every leaf: attention, convolutions and temperatures, router,
+            # experts, the two norms and the two pairs of residual scales
+            return (
+                self.n_layers * (self.attn_params() + self.cca_params()
+                                 + self.mlp_params() + 6 * self.d_model)
+                + heads + self.d_model
+            )
         if self.heads_per_layer or self.attn_gate:
             return (
                 sum(map(self.attn_params, self.layer_kinds))
@@ -663,8 +766,11 @@ class ModelConfig:
         share of the bank, the part of the k that falls here in the mean."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         kinds = self.layer_types or (FULL,)
-        # (the projections of each kind's own head count, the gate's too)
+        # (the projections of each kind's own head count, the gate's too;
+        # a CCA layer's convolutions beside them)
         per_layer = 2 * sum(map(self.attn_params, kinds)) / len(kinds)
+        if self.cca:
+            per_layer += 2 * self.cca_params()
         if self.has_state_layers:
             # Mixers by kind, averaged over the layers: a state-space one
             # is its projections and, a token, the convolution's taps and
@@ -692,10 +798,10 @@ class ModelConfig:
             per_layer += 2 * head_keys * (self.head_dim + v_dim)
         if self.n_experts > 0:
             active = (
-                self.experts_per_token * self.held[1] / self.n_experts
+                self.experts_per_token * self.held[1] / self.n_choices
                 + self.n_shared_experts
             )
-            mlp = 3 * 2 * d * f * active + 2 * d * self.n_experts
+            mlp = 3 * 2 * d * f * active + 2 * self.router_params()
         else:
             mlp = 3 * 2 * d * f
         nd = self.n_dense_layers
@@ -802,6 +908,24 @@ PRESETS: Dict[str, ModelConfig] = {
                    "partial_rotary_factor": 0.5},
             SLIDING: {"rope_type": "default", "rope_theta": 100.0,
                       "partial_rotary_factor": 1},
+        },
+    ),
+    # Compressed convolutional attention (two causal convolutions of two taps
+    # over queries and keys, the value half-shifted, queries and keys on the
+    # sphere with a temperature a head, half of a head rotated) in heads of
+    # 16 on a hidden size of 64, then 8 experts top-1 beside a skip, scored
+    # by a router MLP over a projection carried from layer to layer, each
+    # sublayer merged by learned scales, a tied head: every mechanism of the
+    # `zaya` block at a size the CPU runs. capacity_factor = experts: no drop.
+    "tiny-zaya": ModelConfig(
+        vocab_size=512, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2,
+        head_size=16, d_ff=32, max_seq_len=256, remat=False, n_experts=8,
+        experts_per_token=1, capacity_factor=8.0, tie_embeddings=True,
+        layer_types=(HYBRID,) * 3, cca_time0=2, cca_time1=2,
+        router_hidden_size=32,
+        rope_parameters={
+            HYBRID: {"rope_type": "default", "rope_theta": 10000.0,
+                     "partial_rotary_factor": 0.5},
         },
     ),
     # Mixtral-shaped 8x top-2 at the 1B-active scale.

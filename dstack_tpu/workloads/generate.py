@@ -66,6 +66,11 @@ def init_cache(
     config: ModelConfig, batch: int, max_len: int, dtype=None
 ) -> KVCache:
     c = config
+    if c.cca:
+        raise ValueError(
+            "a CCA model carries its convolutions' tail beside the rows, which"
+            " this cache does not keep: the paged engine serves it"
+        )
     k_row, v_row = c.kv_row_shapes()
     shape = (c.n_attn_layers, batch, max_len)
     dtype = dtype or c.activation_dtype

@@ -71,6 +71,8 @@ from dstack_tpu.workloads.selective_scan import scan_impl, selective_scan_decode
 from dstack_tpu.workloads.transformer import (
     absorb_query,
     attn_output,
+    cca_project,
+    depth_carry,
     embed_tokens,
     final_norm,
     head_gate,
@@ -85,6 +87,7 @@ from dstack_tpu.workloads.transformer import (
     mlp_block,
     project_latent,
     project_qkv,
+    residual_merge,
     rms_norm,
     scan_layers,
 )
@@ -119,15 +122,19 @@ class PagedDecodeState(NamedTuple):
     # by side: as a second-minor axis the 3 would be padded to a tile of
     # 16, and XLA re-laid the whole pool out at each end of a chunk's
     # layer loop (AOT for the v5e, PR 33).
+    # A CCA model (ModelConfig.cca) keeps in `conv` the tail of its
+    # convolutions over time, a row a slot and layer (`cca_tail_width`), and
+    # no `ssm`.
     ssm: Optional[jnp.ndarray] = None   # (Ls, B, d_state, d_inner) float32
     conv: Optional[jnp.ndarray] = None  # (Ls, B, (d_conv - 1) * d_inner)
     # Where the expert bank here is a share of the experts routed over
-    # (ModelConfig.expert_share): the pairs real tokens were routed to, and
-    # those of them that fell on the experts held (moe.local_pairs), summed
+    # (ModelConfig.expert_share), or the router has a skip choice: the pairs
+    # real tokens were routed to, those of them that fell on the experts held
+    # and, with a skip, those that chose it (moe.local_pairs), summed
     # over layers and programs since a decode launch last handed them out
     # (it returns them beside its tokens and starts again from zero). None
     # for every other model, as above.
-    moe_pairs: Optional[jnp.ndarray] = None  # (2,) int32
+    moe_pairs: Optional[jnp.ndarray] = None  # (2,) or (3,) int32
 
 
 def init_paged_state(
@@ -156,8 +163,11 @@ def init_paged_state(
             "conv": jnp.zeros(
                 (c.n_state_layers, batch, taps * width), c.activation_dtype),
         }
-    if c.expert_share:
-        recurrent["moe_pairs"] = jnp.zeros((2,), jnp.int32)
+    if c.cca:
+        recurrent["conv"] = jnp.zeros(
+            (c.n_layers, batch, c.cca_tail_width()), c.activation_dtype)
+    if c.pairs_on_device:
+        recurrent["moe_pairs"] = jnp.zeros((2 + c.expert_skip,), jnp.int32)
     return PagedDecodeState(
         **recurrent,
         k=jnp.zeros(shape + k_row, c.activation_dtype),
@@ -482,7 +492,8 @@ def _extra_leaves(state: PagedDecodeState):
     `_layer_loop` hands back, in its order."""
     if state.ssm is not None:
         return ("ssm", "conv")
-    return ("moe_pairs",) if state.moe_pairs is not None else ()
+    return tuple(
+        name for name in ("conv", "moe_pairs") if getattr(state, name) is not None)
 
 
 def _jit_shardings(in_shardings, out_shardings):
@@ -529,14 +540,17 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     rows are the pool's slots in order (a decode step); how many of a row's
     tokens are the sequence's (a padded tail and a row that is not live move no
     state); and which rows start a sequence (from zero state, whoever held the
-    slot). Its attention layers index the KV pool by their rank among them.
+    slot). Its attention layers index the KV pool by their rank among them. A
+    CCA model hands in the same with ssm None: `conv` is its convolutions'
+    tails, layer l's at index l, and the router's carry from layer to layer
+    rides the scan beside them (it starts at zero at every position).
     """
     if recurrent is not None:
         _, _, slot, n_valid, fresh = recurrent
 
-    def rows_of(pool, m, start, shape=None):
+    def rows_of(pool, m, start, shape=None, scope="mamba/state"):
         """Layer m of a state pool at x's rows (each of `shape`), 0 at `start`."""
-        with jax.named_scope("mamba/state"):
+        with jax.named_scope(scope):
             if slot is None:
                 rows = lax.dynamic_index_in_dim(pool, m, keepdims=False)
             else:
@@ -545,8 +559,8 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             rows = rows.reshape(rows.shape[:1] + (shape or rows.shape[1:]))
             return jnp.where(start, 0, rows)
 
-    def put_rows(pool, m, rows):
-        with jax.named_scope("mamba/state"):
+    def put_rows(pool, m, rows, scope="mamba/state"):
+        with jax.named_scope(scope):
             if slot is None:
                 return lax.dynamic_update_index_in_dim(pool, rows, m, 0)
             return lax.dynamic_update_slice(
@@ -589,9 +603,11 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             c, x, p, positions, lp, safe, scale, has_lora
         )
 
-    def attend(x, p, lp, l, kp, vp, kind):
+    def attend(x, p, lp, l, kp, vp, kind, qkv=None):
         """Write layer l's rows (it is a `kind` layer), attend over the tables
-        -> the block's attention output (before the residual) and the pools."""
+        -> the block's attention output (before the residual) and the pools.
+        `qkv`: the layer's queries, keys and values where the caller made
+        them (a CCA layer)."""
         if c.latent:
             # One row a token for all heads; the absorbed query scores
             # straight against cached rows and no step up-projects them.
@@ -607,7 +623,7 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
                     latent_values=c.kv_lora_rank, scale=c.head_dim ** -0.5,
                 )
             return latent_output(c, o_lat, p), kp, vp
-        q, k, v = project(x, p, lp, kind)
+        q, k, v = qkv or project(x, p, lp, kind)
         with jax.named_scope("attn/write"):
             kp = kp.at[l, blk, off].set(k.astype(kp.dtype), mode="drop")
             vp = vp.at[l, blk, off].set(v.astype(vp.dtype), mode="drop")
@@ -623,6 +639,8 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     tallied = {} if tally is None else {"counted": tally[0]}
 
     def block(bank_stack, first, carry, layer, kind):
+        if c.cca:
+            return cca_block(bank_stack, first, carry, layer, kind), None
         if recurrent is not None:
             (p, l, lp), (own, rank) = layer
             p = {**p, **own}
@@ -669,11 +687,34 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
     # heads differ by kind (`heads_by_kind`: wq, wo and the gate a stack a
     # kind and stack of layers), every layer keeps rows and the pool's
     # index is the layer's own.
+    def cca_block(bank_stack, first, carry, layer, kind):
+        """A CCA layer: its tail's row in, queries, keys and values, the
+        attention, the tail's row out, the residual merge, the expert
+        sublayer with the router's carry."""
+        p, l, lp = layer
+        x, kp, vp, conv, count, depth = carry
+        scope = "attn/full/attn_conv"
+        tail = rows_of(conv, l, fresh[:, None], scope=scope)
+        q, k, v, tail = cca_project(c, x, p, positions, tail, n_valid)
+        out, kp, vp = attend(x, p, lp, l, kp, vp, kind, (q, k, v))
+        conv = put_rows(conv, l, tail, scope=scope)
+        with jax.named_scope("attn/out"):
+            x = residual_merge(x, out, p["res_attn"])
+        if bank_stack:
+            p, layer_kw = {**p, **bank_stack}, {"layer": l - first}
+        else:
+            layer_kw = {"partitioned": partitioned}
+        x, _, pairs, depth = moe.moe_block(
+            c, x, p, depth=depth, **layer_kw, **tallied)
+        return x, kp, vp, conv, count + pairs, depth
+
     carry, first = (x, k_pool, v_pool), 0
     if recurrent is not None:
-        carry += recurrent[:2]
+        carry += tuple(pool for pool in recurrent[:2] if pool is not None)
     if tally is not None:
         carry += (tally[1],)
+    if c.cca:
+        carry += (depth_carry(c, x),)
     for stack, mixers, kinds in zip(
         layer_stacks(params), mixer_stacks(params), c.stack_kinds
     ):
@@ -699,7 +740,7 @@ def _layer_loop(c: ModelConfig, params, x, positions, k_pool, v_pool,
             own=own,
         )
         first += n
-    return carry
+    return carry[:-1] if c.cca else carry
 
 
 def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
@@ -759,7 +800,7 @@ def make_chunk_prefill(config: ModelConfig, chunk: int, shardings=None,
         # whatever garbage their attention rows read.
         # A slot's first chunk starts from zero state inside this program,
         # whoever held the slot before; the recurrence stops at n_valid.
-        recurrent = None if state.ssm is None else (
+        recurrent = None if state.conv is None else (
             state.ssm, state.conv, slot, n_valid[None],
             ((start == 0) & (n_valid > 0))[None],
         )
@@ -878,7 +919,7 @@ def make_paged_decode_step(config: ModelConfig, steps: int = 1, shardings=None,
         aix = state.adapter_ix
         # Live slots only: a row that is not active (empty, or between two
         # of its prefill chunks) keeps its state bit for bit.
-        recurrent = None if state.ssm is None else (
+        recurrent = None if state.conv is None else (
             state.ssm, state.conv, None, state.active.astype(jnp.int32),
             jnp.zeros((B,), bool),
         )

@@ -83,9 +83,14 @@ def route_assignments(
     Slot assignment is priority-ordered: every token's first choice is
     placed before any token's second choice (GShard ordering), via one
     cumsum over the (choice-major) flattened token axis.
+
+    A router with a skip choice (`ModelConfig.expert_skip`) scores
+    `n_choices` from the last hidden layer of its MLP (h here;
+    transformer.depth_router): the last choice is no expert, held nowhere,
+    and the chosen score is the weight as it is, not renormalised.
     """
     B, S, _ = h.shape
-    E, k = c.n_experts, c.experts_per_token
+    E, k = c.n_choices, c.experts_per_token
 
     logits = jnp.einsum(
         "bsd,de->bse", h, router, preferred_element_type=jnp.float32
@@ -99,9 +104,10 @@ def route_assignments(
     else:
         _, gate_idx = lax.top_k(probs + bias, k)
         gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
-    )
+    if not c.expert_skip:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9
+        )
     if c.routed_scaling != 1.0:
         gate_vals = gate_vals * c.routed_scaling
 
@@ -135,8 +141,8 @@ def _dispatch_combine(c: ModelConfig, seq_len: int, gate_vals, slot, sel):
     """The capacity path's dispatch and combine tensors (B,S,E,C) from
     `route_assignments`' answer, over the columns of the experts held."""
     C = expert_capacity(c, seq_len)
-    if c.expert_share:
-        first, held = c.held
+    first, held = c.held
+    if sel.shape[-1] != held:   # (a share of the bank, or a skip choice)
         sel = sel[..., first:first + held]
     slot_oh = jax.nn.one_hot(slot, C, dtype=jnp.float32)  # 0-row when >= C
     dispatch = jnp.einsum("bske,bskc->bsec", sel, slot_oh)
@@ -147,12 +153,17 @@ def _dispatch_combine(c: ModelConfig, seq_len: int, gate_vals, slot, sel):
 def local_pairs(c: ModelConfig, gate_idx: jnp.ndarray, counted: jnp.ndarray):
     """-> (2,) int32: the (token, expert) pairs routing chose for the tokens
     `counted` (B,S) bool marks (padding and dead rows route too, and count
-    for nothing), and those of them that fell on an expert held here."""
+    for nothing), and those of them that fell on an expert held here; (3,)
+    with a skip choice (`ModelConfig.expert_skip`), the third those that
+    chose it."""
     first, held = c.held
     here = (gate_idx >= first) & (gate_idx < first + held)
+    skipped = [jnp.sum((gate_idx == c.n_experts) & counted[..., None])
+               ] if c.expert_skip else []
     return jnp.stack([
         jnp.sum(counted) * gate_idx.shape[-1],
         jnp.sum(here & counted[..., None]),
+        *skipped,
     ]).astype(jnp.int32)
 
 
@@ -270,7 +281,7 @@ def plan(
     The routed path takes 1.0 to 1.1 times the touched share of the
     capacity path's time, so the two meet near 0.9 of the experts;
     `_TOUCHED_MAX` keeps a tenth of room below that."""
-    k, E, held = c.experts_per_token, c.n_experts, c.held[1]
+    k, E, held = c.experts_per_token, c.n_choices, c.held[1]
     capacity = expert_capacity(c, row_len)
     if row_len == 1:
         touched, tile = touched_share(rows, k, E), _FEW_ROWS_TILE
@@ -331,10 +342,12 @@ def moe_mlp(
     partitioned: bool = False,
     layer: Optional[jnp.ndarray] = None,
     counted: Optional[jnp.ndarray] = None,
+    router_in: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, ...]:
     """The routed SwiGLU experts on a normed input h -> (out, aux_loss), and
     with `counted` (B,S) bool -> (out, aux_loss, `local_pairs` of the tokens
-    it marks).
+    it marks). `router_in`: what the router scores where it is not h (a
+    skip-choice router's last hidden layer, `route_assignments`).
 
     p carries: router (D,E) f32, we_gate/we_up (E,D,F), we_down (E,F,D).
     `plan` chooses the formulation from what this call can see: the rows
@@ -352,8 +365,8 @@ def moe_mlp(
     if layer is not None or takes_routed_path(
         c, h.shape[0], h.shape[1], p, mesh, partitioned
     ):
-        return _moe_mlp_routed(c, h, p, mesh, layer, counted)
-    return _moe_mlp_capacity(c, h, p, mesh, counted)
+        return _moe_mlp_routed(c, h, p, mesh, layer, counted, router_in)
+    return _moe_mlp_capacity(c, h, p, mesh, counted, router_in)
 
 
 def _batch_shards(mesh: Optional[Mesh]) -> int:
@@ -369,14 +382,15 @@ def _moe_mlp_capacity(
     p: Params,
     mesh: Optional[Mesh] = None,
     counted: Optional[jnp.ndarray] = None,
+    router_in: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, ...]:
     """The capacity path: dense GShard dispatch/combine tensors, every op
     a static matmul over E x C slots a row (2*E*C*D FLOPs a token each
     way for the dispatch and the combine alone), E the experts held."""
     with jax.named_scope("moe/route"):
         gate_vals, gate_idx, slot, sel, aux = route_assignments(
-            c, h, p["router"], p.get("router_bias")
-        )
+            c, h if router_in is None else router_in, p["router"],
+            p.get("router_bias"))
         dispatch, combine = _dispatch_combine(c, h.shape[1], gate_vals, slot, sel)
         tally = () if counted is None else (local_pairs(c, gate_idx, counted),)
 
@@ -582,6 +596,7 @@ def _moe_mlp_routed(
     mesh: Optional[Mesh] = None,
     layer: Optional[jnp.ndarray] = None,
     counted: Optional[jnp.ndarray] = None,
+    router_in: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, ...]:
     """The routed path: only the rows routing chose are multiplied.
 
@@ -596,13 +611,13 @@ def _moe_mlp_routed(
     B, S, _ = h.shape
     with jax.named_scope("moe/route"):
         gate_vals, gate_idx, slot, _, aux = route_assignments(
-            c, h, p["router"], p.get("router_bias")
-        )
+            c, h if router_in is None else router_in, p["router"],
+            p.get("router_bias"))
         gates = jnp.where(slot < expert_capacity(c, S), gate_vals, 0.0)
         tally = () if counted is None else (local_pairs(c, gate_idx, counted),)
     tile = plan(c, B // _batch_shards(mesh), S)[2]
     bank = functools.partial(
-        _routed_bank, tile, **({"share": c.held[0]} if c.expert_share else {})
+        _routed_bank, tile, **({"share": c.held[0]} if c.pairs_on_device else {})
     )
     weights = [p["we_gate"], p["we_up"], p["we_down"]]
     if layer is not None:
@@ -625,18 +640,28 @@ def moe_block(
     partitioned: bool = False,
     layer: Optional[jnp.ndarray] = None,
     counted: Optional[jnp.ndarray] = None,
+    depth: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, ...]:
     """Pre-norm MoE block with residual: x -> (x + moe(norm(x)), aux), and
     with `counted` -> (.., .., `local_pairs`) as `moe_mlp` gives them.
     With shared experts (`ws_*` weights) every token also passes through
-    their SwiGLU, added beside the routed sum."""
-    from dstack_tpu.workloads.transformer import _silu, linear, rms_norm
+    their SwiGLU, added beside the routed sum. A skip-choice router takes
+    its carry from the layer before (`depth`, transformer.depth_router) and
+    hands it on last, and the block merges into the stream by its
+    `res_mlp` scales: -> (x, aux, [pairs], depth)."""
+    from dstack_tpu.workloads.transformer import (
+        _silu, depth_router, linear, residual_merge, rms_norm)
     with jax.named_scope("moe/route"):
         h = rms_norm(x, p["mlp_norm"], c.norm_eps)
-    out, aux, *tally = moe_mlp(c, h, p, mesh, partitioned, layer, counted)
+    router_in = None
+    if c.expert_skip:
+        router_in, depth = depth_router(c, h, p, depth)
+    out, aux, *tally = moe_mlp(c, h, p, mesh, partitioned, layer, counted, router_in)
     if "ws_gate" in p:
         with jax.named_scope("moe/shared"):
             gate = _silu(linear(h, p["ws_gate"]))
             out = out + linear(gate * linear(h, p["ws_up"]), p["ws_down"])
     with jax.named_scope("moe/experts"):
+        if c.expert_skip:
+            return (residual_merge(x, out, p["res_mlp"]), aux, *tally, depth)
         return (x + out, aux, *tally)

@@ -351,8 +351,8 @@ class ServingEngine:
         # stats()["prefix_cache"] names the reason.
         self._prefix_cache = (
             "off: state-space layers (a cached chain lacks the recurrent"
-            " state at its end)" if config.has_state_layers
-            else "on" if prefix_cache else "off: prefix_cache=False"
+            " state at its end)" if config.has_state_layers else _CCA_OFF
+            if config.cca else "on" if prefix_cache else "off: prefix_cache=False"
         )
         self._alloc = BlockAllocator(
             self._num_blocks, kv_block_size,
@@ -476,7 +476,7 @@ class ServingEngine:
         # what the programs counted on the device (kv_blocks: `moe_pairs`),
         # read with each decode chunk's tokens.
         self._moe_pairs = 0
-        self._moe_local_pairs = 0
+        self._moe_local_pairs = self._moe_skip_pairs = 0
         self._step = make_paged_decode_step(
             config, steps=steps_per_sync, shardings=self._shardings,
             lora=self._lora is not None, attn_impl=self._attn_path,
@@ -1639,6 +1639,9 @@ class ServingEngine:
             "moe_experts_held": self.config.held[1] if self.config.n_experts else 0,
             "moe_pairs_total": self._moe_pairs,
             "moe_local_pairs_total": self._moe_local_pairs,
+            # of the pairs, those whose token took the router's skip choice
+            # (ModelConfig.expert_skip): counted on the device, as a share's
+            "moe_skip_pairs_total": self._moe_skip_pairs,
             "moe_routed_slots_total": self._moe_local_pairs,
             "moe_computed_slots_total": self._moe_computed_slots,
             "moe_routed_launches_total": self._moe_routed_launches,
@@ -3258,10 +3261,11 @@ class ServingEngine:
         clock.mark("sync")
         chunk.toks = jax.device_get(tokens)  # (B, steps_per_sync)
         chunk.still = jax.device_get(active)
-        if pairs:   # (a share of the expert bank: what the programs counted)
-            routed, here = jax.device_get(pairs[0])
+        if pairs:   # (a share of the bank, a skip: what the programs counted)
+            routed, here, *skipped = jax.device_get(pairs[0])
             self._moe_pairs += int(routed)
             self._moe_local_pairs += int(here)
+            self._moe_skip_pairs += int(sum(skipped))
         self._observe_chunk_seconds(chunk, clock.mark("fan_out"))
         if self._spec and self._spec_cooldown > 0:
             self._spec_fallback_rounds += 1
@@ -3356,7 +3360,7 @@ class ServingEngine:
             return
         layers = c.n_layers - c.n_dense_layers
         routed, slots, _ = moe.plan(c, rows, row_len, self._whole_bank)
-        if not c.expert_share:   # (a share's pairs: counted on the device)
+        if not c.pairs_on_device:
             self._moe_pairs += layers * tokens * c.experts_per_token
             self._moe_local_pairs += layers * tokens * c.experts_per_token
         self._moe_computed_slots += layers * slots * steps
@@ -3719,6 +3723,8 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
          stats.get("moe_pairs_total", 0)),
         ("dstack_tpu_serving_moe_local_pairs_total", "counter",
          stats.get("moe_local_pairs_total", 0)),
+        ("dstack_tpu_serving_moe_skip_pairs_total", "counter",
+         stats.get("moe_skip_pairs_total", 0)),
         # Launches whose programs pass an expert layer, and those of them
         # whose expert layers took the routed path (moe.plan).
         ("dstack_tpu_serving_moe_expert_launches_total", "counter",
@@ -3884,3 +3890,9 @@ def prometheus_metrics(stats: Dict[str, Any]) -> str:
             lines.append(f'{base}_sum{{{labels}}} {hist["sum"]}')
             lines.append(f'{base}_count{{{labels}}} {hist["count"]}')
     return "\n".join(lines) + "\n"
+
+
+# Why a CCA model runs with prefix reuse off (stats()["prefix_cache"]): the
+# convolutions' tail at a cached chain's end is nowhere kept (ROADMAP R5 (a)).
+_CCA_OFF = ("off: CCA layers (a cached chain lacks the convolutions' tail at"
+            " its end)")

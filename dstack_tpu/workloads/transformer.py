@@ -28,6 +28,7 @@ from jax import lax
 from dstack_tpu.workloads.attention import plain_attention
 from dstack_tpu.workloads.config import (
     FULL,
+    HYBRID,
     MAMBA,
     ModelConfig,
     RopeParams,
@@ -147,6 +148,8 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
             layers["w_gate"] = dense(keys[5], (L, D, F), D)
             layers["w_up"] = dense(keys[6], (L, D, F), D)
             layers["w_down"] = dense(keys[7], (L, F, D), F)
+        if c.cca:
+            layers.update(cca_leaves(c, jax.random.fold_in(keys[3], 7), L, dense))
         return layers
 
     def mamba(key, L):
@@ -638,12 +641,16 @@ def _block(
     attention_fn: AttentionFn,
     mesh=None,
     kind: str = FULL,
+    depth=None,
 ):
     """One decoder block of a `kind` layer -> (x, router_aux). aux is 0.0
     for dense models. A block carries experts iff its weights do
     (`router`): the leading dense layers of an expert model run the plain
-    MLP."""
+    MLP. A CCA block (`kind` HYBRID) takes the router's `depth` carry too
+    and hands it on: -> (x, router_aux, depth)."""
     b, s, _ = x.shape
+    if kind == HYBRID:
+        return cca_block(c, x, p, positions, attention_fn, mesh, depth)
     if kind == MAMBA:
         out, _, _ = mamba_mixer(c, rms_norm(x, p["attn_norm"], c.norm_eps), p)
         return mlp_block(c, x + out, p), jnp.float32(0.0)
@@ -699,11 +706,12 @@ def forward(
 
     def block(kind):
         def body(carry, layer_p):
-            x, aux = carry
+            x, aux, *depth = carry
             if isinstance(layer_p, tuple):   # (every layer's, its kind's own)
                 layer_p = {**layer_p[0], **layer_p[1]}
-            x, layer_aux = _block(c, x, layer_p, positions, attn, mesh, kind)
-            return (x, aux + layer_aux), None
+            x, layer_aux, *depth = _block(
+                c, x, layer_p, positions, attn, mesh, kind, *depth)
+            return (x, aux + layer_aux, *depth), None
 
         return apply_remat(
             body, c, tokens.shape[0] * tokens.shape[1], mesh,
@@ -711,7 +719,8 @@ def forward(
         )
 
     blocks = {kind: block(kind) for kind in set(c.layer_types or (FULL,))}
-    carry = (x, jnp.float32(0.0))
+    carry = (x, jnp.float32(0.0)) + (
+        (depth_carry(c, x),) if c.expert_skip else ())
     for stack, own, kinds in zip(
         layer_stacks(params), mixer_stacks(params), c.stack_kinds
     ):
@@ -719,7 +728,7 @@ def forward(
             lambda carry, p, kind: blocks[kind](carry, p), carry, stack,
             kinds, own=own,
         )
-    x, aux = carry
+    x, aux = carry[:2]
 
     x = final_norm(c, params, x)
     if return_hidden:
@@ -771,3 +780,166 @@ def head_gate(c: ModelConfig, x: jnp.ndarray, p: Params):
     with jax.named_scope("attn/qkv/attn_gate"):
         h = rms_norm(x, p["attn_norm"], c.norm_eps)
         return act(linear(h, p["wg"]).astype(jnp.float32))
+
+
+# Compressed convolutional attention and its router (the `zaya` block;
+# ModelConfig.cca). Each form the published configuration leaves open lives
+# in one function below, as benchmarks/reference/zaya.py writes it out.
+
+
+def cca_leaves(c: ModelConfig, key: jax.Array, L: int, dense) -> Params:
+    """L CCA layers' leaves beyond the attention projections and the bank:
+    the two convolutions (`cca_conv0` a tap and channel of every query and
+    key head, `cca_conv1` a tap and head, channels into channels), each query
+    head's temperature, the router (its down-projection, depth weight, norm
+    and three layers, the last `router`, to the `n_choices` scores, float32)
+    and the residual scales of each sublayer (row 0 on the stream, row 1 on
+    the sublayer's output; 1 at the start). The temperatures start near
+    sqrt(head_dim), where a score of two unit vectors has the spread a plain
+    head's score has; the depth weight at 0.5; the router's last layer four
+    times wider than its fan-in gives, so that seeded scores spread as a
+    trained router's do (17 nearly equal logits weigh every expert by about
+    1/17 and make a third of the positions near-ties: 0.36 of them within
+    0.002 at one, 0.06 at four, on the CPU at `tiny-zaya`'s widening)."""
+    D, R, hd = c.d_model, c.router_hidden_size, c.head_dim
+    G = c.n_heads + c.n_kv_heads
+    ks = jax.random.split(key, 7)
+    normal = lambda k, shape, fan_in: (
+        jax.random.normal(k, shape, jnp.float32) * fan_in**-0.5)
+    return {
+        "cca_conv0": dense(ks[0], (L, c.cca_time0, G * hd), c.cca_time0),
+        "cca_conv1": dense(ks[1], (L, c.cca_time1, G, hd, hd), c.cca_time1 * hd),
+        "cca_temp": jax.random.uniform(
+            ks[2], (L, c.n_heads), jnp.float32, 0.75, 1.25) * hd**0.5,
+        "router_down": normal(ks[3], (L, D, R), D),
+        "router_gamma": jnp.full((L,), 0.5, jnp.float32),
+        "router_norm": jnp.ones((L, R), jnp.float32),
+        "router_w1": normal(ks[4], (L, R, R), R),
+        "router_w2": normal(ks[5], (L, R, R), R),
+        "router": normal(ks[6], (L, R, c.n_choices), R) * 4,
+        "res_attn": jnp.ones((L, 2, D), jnp.float32),
+        "res_mlp": jnp.ones((L, 2, D), jnp.float32),
+    }
+
+
+def cca_project(c: ModelConfig, x: jnp.ndarray, p: Params,
+                positions: jnp.ndarray, tail=None, n_valid=None):
+    """A CCA layer's queries, keys and values from its block input x (B, S,
+    d) -> (q (B, S, H, hd), k, v (B, S, KV, hd), the tail after each row's
+    last VALID token (B, `cca_tail_width`)).
+
+    `tail` is what a row comes with from its earlier tokens (zero: the
+    sequence's start, as the convolutions see zeros before position 0);
+    only the first `n_valid` (B,) tokens of a row are the sequence's (None:
+    all). With n = RMSNorm(x), q~ = n Wq and k~ = n Wk (u = [q~; k~], every
+    query head and then every key head):
+      c1_t = sum_j a_j * u_{t-j}                (depthwise, cca_time0 taps)
+      c2_t = sum_j B_j^(g) c1_{t-j}             (inside a head g, cca_time1 taps)
+      q^h = c2^(q,h) + (q~^h + k~^(g(h))) / 2,  k^g = c2^(k,g) + (mean_(h in g)
+      q~^h + k~^g) / 2
+      q <- tau_h sqrt(hd) q / |q|, k <- k / |k|, then the rotary embedding
+      v_t = [n_t Wv's first half of each KV head; n_(t-1) Wv's second half]
+    c1 is rounded to the activation dtype where it is computed, so a chunk
+    boundary (the tail keeps it in that dtype) changes nothing."""
+    b, s, _ = x.shape
+    H, KV, hd = c.n_heads, c.n_kv_heads, c.head_dim
+    t0, t1, half = c.cca_time0, c.cca_time1, hd // 2
+    width, G = (H + KV) * hd, H + KV
+    f32, dt = jnp.float32, x.dtype
+    if tail is None:
+        tail = jnp.zeros((b, c.cca_tail_width()), dt)
+    if n_valid is None:
+        n_valid = jnp.full((b,), s, jnp.int32)
+    with jax.named_scope("attn/qkv"):
+        n = rms_norm(x, p["attn_norm"], c.norm_eps)
+        u = jnp.concatenate([linear(n, p["wq"]), linear(n, p["wk"])], axis=-1)
+        v = linear(n, p["wv"]).reshape(b, s, KV, hd)
+    with jax.named_scope("attn/full/attn_conv"):
+        cut = (t0 - 1) * width, (t0 + t1 - 2) * width
+        u_all = jnp.concatenate(
+            [tail[:, :cut[0]].reshape(b, t0 - 1, width).astype(dt), u], axis=1)
+        c1 = sum(
+            u_all[:, t0 - 1 - j:t0 - 1 - j + s].astype(f32)
+            * p["cca_conv0"][j].astype(f32) for j in range(t0)
+        ).astype(dt)
+        c1_all = jnp.concatenate(
+            [tail[:, cut[0]:cut[1]].reshape(b, t1 - 1, width).astype(dt), c1],
+            axis=1)
+        # (float32 operands: a product with a batch axis of bf16 into float32
+        # is no kernel XLA:CPU has; on the TPU the default precision takes
+        # the same one bf16 pass)
+        c2 = sum(
+            jnp.einsum(
+                "bsgi,gij->bsgj",
+                c1_all[:, t1 - 1 - j:t1 - 1 - j + s].reshape(b, s, G, hd).astype(f32),
+                p["cca_conv1"][j].astype(f32),
+            ) for j in range(t1)
+        )
+        q0 = u[..., :H * hd].reshape(b, s, KV, H // KV, hd).astype(f32)
+        k0 = u[..., H * hd:].reshape(b, s, KV, 1, hd).astype(f32)
+        q = c2[:, :, :H] + 0.5 * (q0 + k0).reshape(b, s, H, hd)
+        k = c2[:, :, H:] + 0.5 * (jnp.mean(q0, axis=3) + k0[:, :, :, 0])
+        q = q * lax.rsqrt(jnp.maximum(jnp.sum(q * q, -1, keepdims=True), 1e-12)) * (
+            p["cca_temp"][:, None] * hd**0.5)
+        k = k * lax.rsqrt(jnp.maximum(jnp.sum(k * k, -1, keepdims=True), 1e-12))
+        shifted = tail[:, cut[1]:].reshape(b, 1, KV, half).astype(dt)
+        v_all = jnp.concatenate([shifted, v[..., half:]], axis=1)
+        v = jnp.concatenate([v[..., :half], v_all[:, :s]], axis=-1)
+        # Input i of a convolution sits at row i + taps - 1 of its `_all`:
+        # the last taps - 1 valid ones are its rows n_valid .. n_valid +
+        # taps - 2, reaching into the tail the row came with where it has
+        # fewer (a row with no valid token keeps its tail bit for bit).
+        last = lambda rows, n: jax.vmap(
+            lambda r, at: lax.dynamic_slice_in_dim(r, at, n, axis=0)
+        )(rows, n_valid).reshape(b, -1)
+        tail = jnp.concatenate(
+            [last(u_all, t0 - 1), last(c1_all, t1 - 1),
+             last(v_all.reshape(b, s + 1, KV * half), 1)], axis=-1)
+    with jax.named_scope("attn/qkv"):
+        rope = c.rope(HYBRID)
+        q = _rope(q, positions, rope).astype(dt)
+        k = _rope(k, positions, rope).astype(dt)
+    return q, k, v, tail
+
+
+def residual_merge(x: jnp.ndarray, y: jnp.ndarray, scales) -> jnp.ndarray:
+    """A sublayer's output y into the stream x by learned scales (2, d):
+    scales[0] * x + scales[1] * y, float32."""
+    return (scales[0] * x.astype(jnp.float32)
+            + scales[1] * y.astype(jnp.float32)).astype(x.dtype)
+
+
+def depth_carry(c: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+    """The router's carry before the first layer: zero (B, S, R) float32."""
+    return jnp.zeros(x.shape[:2] + (c.router_hidden_size,), jnp.float32)
+
+
+def depth_router(c: ModelConfig, h: jnp.ndarray, p: Params, depth):
+    """The router of a skip-choice expert layer on its normed input h (B, S,
+    d) and the carry `depth` from the layer before -> (the last hidden layer
+    of its MLP (B, S, R) float32, whose product with `router` gives the
+    `n_choices` scores' logits (moe.route_assignments), the carry for the
+    next layer). r = h W_down; r^ = r + gamma r^_prev (exponential depth
+    averaging: the first layer's carry is zero); the logits z = W_3 gelu(W_2
+    gelu(W_1 RMSNorm(r^))), gelu the exact (erf) form."""
+    with jax.named_scope("moe/route"):
+        f32 = jnp.float32
+        depth = jnp.einsum("bsd,dr->bsr", h, p["router_down"],
+                           preferred_element_type=f32) + p["router_gamma"] * depth
+        z = rms_norm(depth, p["router_norm"], c.norm_eps)
+        for w in ("router_w1", "router_w2"):
+            z = jax.nn.gelu(z @ p[w], approximate=False)
+        return z, depth
+
+
+def cca_block(c: ModelConfig, x: jnp.ndarray, p: Params, positions,
+              attention_fn: AttentionFn, mesh, depth):
+    """A whole CCA block on a full sequence -> (x, router_aux, depth)."""
+    from dstack_tpu.workloads.moe import moe_block
+
+    b, s, _ = x.shape
+    q, k, v, _ = cca_project(c, x, p, positions)
+    attn = attention_fn(q, k, v).reshape(b, s, c.n_heads * c.head_dim)
+    with jax.named_scope("attn/out"):
+        x = residual_merge(x, attn_output(attn, p), p["res_attn"])
+    return moe_block(c, x, p, mesh, depth=depth)

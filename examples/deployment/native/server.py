@@ -63,6 +63,7 @@ class Engine:
                  prefill_chunk_tokens: int = 128, kv_block_size: int = 16,
                  spec_enable: bool = False, spec_max_draft: int = 4,
                  spec_draft_preset: str = "int8", kv_budget_mb: int = 0,
+                 kv_pool_blocks: int = 0,
                  role: str = "unified", mesh_model: int = 1,
                  kv_transfer_connect: str = "",
                  lora_max_adapters: int = 0, lora_rank: int = 8,
@@ -201,6 +202,7 @@ class Engine:
                 spec_draft_params=draft_params,
                 spec_draft_config=draft_config,
                 kv_budget_bytes=kv_budget_mb * (1 << 20) or None,
+                kv_pool_blocks=kv_pool_blocks or None,
                 mesh=mesh, role=role, kv_transfer=kv_transfer,
                 lora_max_adapters=lora_max_adapters, lora_rank=lora_rank,
                 trace_ring=trace_ring, trace_slow_ms=trace_slow_ms,
@@ -616,6 +618,12 @@ def main() -> None:
                         help="KV pool memory budget in MiB (0 = unlimited);"
                              " with --spec-enable the target AND drafter"
                              " pools must both fit")
+    parser.add_argument("--kv-pool-blocks", type=int, default=0,
+                        help="KV pool size in blocks of --kv-block-size"
+                             " positions (0 = every slot's whole context);"
+                             " a smaller pool sized to the traffic frees"
+                             " HBM, and a slot it cannot feed mid-decode"
+                             " fails with an error")
     parser.add_argument("--kv-host-budget-mb", type=int, default=0,
                         help="host-RAM KV tier budget in MiB (0 = no host"
                              " tier): LRU-evicted prefix-cache blocks spill"
@@ -725,6 +733,7 @@ def main() -> None:
                     spec_max_draft=args.spec_max_draft,
                     spec_draft_preset=args.spec_draft_preset,
                     kv_budget_mb=args.kv_budget_mb,
+                    kv_pool_blocks=args.kv_pool_blocks,
                     role=args.role, mesh_model=args.mesh_model,
                     kv_transfer_connect=args.kv_transfer_connect,
                     lora_max_adapters=args.lora_max_adapters,

@@ -35,7 +35,7 @@ from dstack_tpu.workloads.config import PRESETS
 from dstack_tpu.workloads.transformer import init_params
 
 PRESET_NAMES = ("tiny", "tiny-moe", "tiny-latent", "tiny-window", "tiny-mamba",
-                "tiny-laguna")
+                "tiny-laguna", "tiny-zaya")
 PROGRAM_NAMES = ("decode_steps", "chunk_prefill")
 SLOTS, CHUNK, BLOCK, STEPS = 4, 32, 16, 2
 
@@ -248,6 +248,24 @@ def test_the_head_gate_counts_with_the_projections_and_reads_apart(
     # a model without the gate has no such operation
     plain = scope_reduce.Program(hlo_proto_of(compiled_programs("tiny-window")["decode_steps"]))
     assert not [n for n, row in plain.by_name.items() if "attn_gate" in row.op_name]
+
+
+def test_the_convolutions_count_with_the_attention_and_read_apart(compiled_programs, monkeypatch):
+    """A CCA layer's convolutions over time, its QK-mean, norms, value shift
+    and tail lie under `attn/full/attn_conv`: `attn` to the component shares,
+    and a component of its own name to the reduction `readers/scope_named.py`
+    runs with the scope added; its projections stay `attn_proj`."""
+    proto = hlo_proto_of(compiled_programs("tiny-zaya")["decode_steps"])
+    program = scope_reduce.Program(proto)
+    convs = [n for n, row in program.by_name.items()
+             if row.opcode in scope_reduce.PRODUCTS and "/attn_conv/" in row.op_name]
+    assert convs and {program.component_of(n) for n in convs} == {("attn", "attn/full")}
+    monkeypatch.setattr(scope_reduce, "SCOPES", {**scope_reduce.SCOPES, "attn_conv": "attn_conv"})
+    apart = scope_reduce.Program(proto)
+    assert {apart.component_of(n) for n in convs} == {("attn_conv", "attn_conv")}
+    projections = [n for n, row in apart.by_name.items()
+                   if row.opcode in scope_reduce.PRODUCTS and "/attn/qkv/" in row.op_name]
+    assert projections and {apart.component_of(n)[0] for n in projections} == {"attn_proj"}
 
 
 def strip_metadata(text: str) -> str:

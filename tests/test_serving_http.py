@@ -243,6 +243,20 @@ def test_native_server_rejects_bad_spec_flags(tmp_path):
         assert needle in out.stderr, (flags, out.stderr[-500:])
 
 
+def test_native_server_passes_the_pool_size_to_the_engine():
+    """--kv-pool-blocks reaches the engine's own check: a pool of one block
+    cannot hold one whole request at tiny's defaults, and the server says
+    so at start-up rather than failing a request later."""
+    env = {**os.environ, "PYTHONPATH": str(REPO), "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run(
+        [sys.executable, str(SERVER), "--preset", "tiny",
+         "--port", str(free_port()), "--kv-pool-blocks", "1"],
+        env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode != 0
+    assert "kv_pool_blocks 1 must fit one max_len" in out.stderr, out.stderr[-500:]
+
+
 @pytest.mark.slow
 def test_native_server_spec_flags_and_prometheus(tmp_path):
     """--spec-enable rides through to the engine (the same 1 MiB-per-pool
