@@ -72,9 +72,11 @@ Latent pools (`latent_values` > 0, kv_blocks' pool of a latent-attention
 model): a token keeps ONE row for all heads, `(bs, 1, width)` a block, and
 its value is the first `latent_values` columns of that same row. Both
 paths then read the k pool alone — the value tile is a lane-aligned slice
-of the key tile already in VMEM, never a second fetch — and emit
-`(B, S, H * latent_values)`; every head shares the row, so the GQA
-kernel's sorting of rows by KV head has no counterpart. The caller passes `scale`
+of the key rows already in VMEM, never a second fetch — and emit
+`(B, S, H * latent_values)`. For the kernel such a pool is one KV head of
+`width`-wide rows whose every query head shares it: the same walk, with
+the value buffer the key buffer and a group sized to its products
+(`_LATENT_GROUP_POSITIONS`), not to its copies. The caller passes `scale`
 (the model's head size, not the row's width, sets it).
 
 Window layers (`window` > 0, a sliding-attention layer of a model that
@@ -176,8 +178,8 @@ def ragged_attention(
     With `latent_values` the k pool is (L, NB, bs, 1, width), q is
     (B, S, H, width), v_pool is not read and the result is
     (B, S, H*latent_values). `scale` defaults to hd ** -0.5. `window`
-    (static) makes it a sliding-attention layer's call; the latent kernel
-    has none.
+    (static) makes it a sliding-attention layer's call; a latent pool has
+    none.
     """
     if window and latent_values:
         raise ValueError("latent attention has no window layers")
@@ -192,14 +194,9 @@ def ragged_attention(
             interpret=interpret,
         )
     if impl == "pallas":
-        if latent_values:
-            return _latent_attention_pallas(
-                q, k_pool, layer, tables, valid_len, interpret=interpret,
-                latent_values=latent_values, scale=scale,
-            )
         return _ragged_attention_pallas(
             q, k_pool, v_pool, layer, tables, valid_len, interpret=interpret,
-            window=window,
+            window=window, latent_values=latent_values, scale=scale,
         )
     return _ragged_attention_lax(
         q, k_pool, v_pool, layer, tables, valid_len,
@@ -360,16 +357,27 @@ _GROUP_BYTES = 256 * 1024
 _NOWHERE = 1 << 30
 
 
+# Positions one loop step of a LATENT pool's walk attends. Its steps are
+# bound by the products, not the copies: every position's whole row (640
+# values at GLM-4.7-Flash's widths) meets few query rows (20 heads of one
+# position, a decode row), so a step's fixed cost is paid over more
+# positions in a larger group. On the chip (PERF.md section 6), a decode
+# call of 16 rows at 8,400 positions in 256-token blocks took 0.383 ms at
+# one block a group, 0.292 at two and 0.278 at four, where its products
+# alone took 0.275 at one.
+_LATENT_GROUP_POSITIONS = 1024
+
+
 def _group_blocks(block_bytes: int, table_cols: int) -> int:
     """Blocks one loop step of the paged kernel fetches and attends."""
     return max(1, min(_GROUP_BYTES // block_bytes, table_cols))
 
 
 def _accumulate_block(logits, v, acc_ref, m_ref, l_ref):
-    """One step of the streaming softmax, shared by both kernels: fold the
-    masked (TQ, cols) logits and the step's (cols, vd) values into the
-    running max, denominator and unnormalized output. With a leading axis
-    on all five it is one such step a KV head, in one batched product."""
+    """One step of the streaming softmax: fold the masked (TQ, cols) logits
+    and the step's (cols, vd) values into the running max, denominator and
+    unnormalized output. With a leading axis on all five it is one such
+    step a KV head, in one batched product."""
     blk_m = jnp.maximum(
         jnp.max(logits, axis=-1, keepdims=True), NEG_INF / 2
     )
@@ -430,22 +438,28 @@ def _paged_kernel(
     layer_ref,  # scalar prefetch: (1,) layer index into the stacked pool
     q_ref,  # (KV, TQ, hd) query rows a KV head, ordered (s, h % G)
     vlen_ref,  # (TQ, 1) valid_len of each query row, the same for every head
-    k_hbm,  # (L*NB, bs*KV, hd) the whole K pool, left in HBM
-    v_hbm,  # (L*NB, bs*KV, hd)
-    o_ref,  # (KV, TQ, hd)
-    k_buf,  # VMEM scratch (2, group*bs*KV, hd): two slots of one group
-    v_buf,  # VMEM scratch (2, group*bs*KV, hd)
-    sems,  # DMA semaphores (2, group): one per buffered block
-    acc_ref,  # VMEM scratch (KV, TQ, hd) f32
-    m_ref,  # VMEM scratch (KV, TQ, 1) f32
-    l_ref,  # VMEM scratch (KV, TQ, 1) f32
-    *,
+    *refs,
     block_size: int,
     num_pool_blocks: int,
     group: int,
     scale: float,
     window: int,
+    latent_values: int,
 ):
+    """`refs`, in order: the whole K pool and the whole V pool, `(L*NB,
+    bs*KV, hd)` each, left in HBM; the output `(KV, TQ, vd)`; VMEM scratch,
+    two slots of one group `(2, group*bs*KV, hd)` a pool; the DMA
+    semaphores `(2, group)`, one a buffered block; the f32 accumulators
+    `(KV, TQ, vd)`, `(KV, TQ, 1)`, `(KV, TQ, 1)`. A latent pool (one KV
+    head, `latent_values` > 0) comes without the V pool and its buffer: a
+    value is the leading `latent_values` columns of its key row, cut out of
+    the key buffer, so vd is `latent_values` there and hd elsewhere."""
+    if latent_values:
+        k_hbm, o_ref, k_buf, sems, acc_ref, m_ref, l_ref = refs
+        pools, v_buf = ((k_hbm, k_buf),), k_buf
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, acc_ref, m_ref, l_ref = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
     n_cols = nc_ref[b, pl.program_id(1)]
     c0 = c0_ref[b, pl.program_id(1)]
@@ -470,11 +484,11 @@ def _paged_kernel(
     def each_live_block(step, slot, fn, carry):
         """Fold `fn(col, g, copies, carry)` over the blocks of group `step`
         that lie inside the tile's live columns `c0 .. n_cols`, in table
-        order; `copies` are the block's two DMAs into place g of buffer
-        slot `slot`. The last group of a row is cut at `n_cols` here:
-        nothing past it is fetched or waited for. A loop, not `group`
-        copies of the body: tracing the copies cost every engine start
-        seconds."""
+        order; `copies` are the block's DMAs (K and V; a latent block's
+        one) into place g of buffer slot `slot`. The last group of a row
+        is cut at `n_cols` here: nothing past it is fetched or waited for.
+        A loop, not `group` copies of the body: tracing the copies cost
+        every engine start seconds."""
         first = c0 + step * group
 
         def block(col, carry):
@@ -484,13 +498,14 @@ def _paged_kernel(
             src = base + jnp.minimum(t_ref[b, col], num_pool_blocks - 1)
             rows = place(g)
             # One semaphore a buffered block, signalled by its K and its
-            # V copy: a semaphore counts bytes, so one shared by a group's
-            # copies in flight could pass a wait on parts of several.
+            # V copy (a latent block's one copy): a semaphore counts bytes,
+            # so one shared by a group's copies in flight could pass a wait
+            # on parts of several.
             return fn(col, g, [
                 pltpu.make_async_copy(
                     pool.at[src], buf.at[slot, rows], sems.at[slot, g]
                 )
-                for pool, buf in ((k_hbm, k_buf), (v_hbm, v_buf))
+                for pool, buf in pools
             ], carry)
 
         return lax.fori_loop(
@@ -521,7 +536,8 @@ def _paged_kernel(
         # masked column still meets its value row in `p.V` as 0 x value:
         # whatever the place holds (another row's block, or bits no copy
         # ever wrote, NaN among them) is cleared first. Keys need nothing:
-        # their logits are replaced, not scaled.
+        # their logits are replaced, not scaled. A latent pool's values are
+        # cut out of its key rows, so there the key buffer is the one cleared.
         def clear(g, carry):
             v_buf[slot, place(g), :] = jnp.zeros(
                 (block_rows, v_buf.shape[-1]), v_buf.dtype
@@ -547,7 +563,8 @@ def _paged_kernel(
         ) * scale  # (KV, TQ, positions)
         _accumulate_block(
             jnp.where(ok[None], logits, NEG_INF),
-            _head_rows(v_buf, slot, num_kv_heads, positions),
+            v_buf[slot, :, :latent_values][None] if latent_values
+            else _head_rows(v_buf, slot, num_kv_heads, positions),
             acc_ref, m_ref, l_ref,
         )
         return carry
@@ -587,21 +604,31 @@ def _head_tile_positions(s: int, h: int, kv: int) -> int:
     return _q_tile_positions(s, h // kv, min(_MAX_Q_ROWS, _MAX_STEP_ROWS // kv))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "window", "latent_values", "scale")
+)
 def _ragged_attention_pallas(
-    q, k_pool, v_pool, layer, tables, valid_len, *, interpret=False, window=0
+    q, k_pool, v_pool, layer, tables, valid_len, *, interpret=False, window=0,
+    latent_values=0, scale=None,
 ):
     b, s, h, hd = q.shape
     n_layers, nb, bs, kv, _ = k_pool.shape
     mb = tables.shape[1]
     n_rep = h // kv
+    vd = latent_values or hd
     if kv > 1 and (kv * k_pool.dtype.itemsize) % 4:
         raise NotImplementedError(
             f"{kv} KV heads of {k_pool.dtype} do not fill 32-bit words"
         )
     ts = _head_tile_positions(s, h, kv)
     tq = ts * n_rep
-    group = _group_blocks(bs * kv * hd * k_pool.dtype.itemsize, mb)
+    if latent_values:
+        # Read alone: its values are cut out of its key rows.
+        pools = [k_pool]
+        group = max(1, min(_LATENT_GROUP_POSITIONS // bs, mb))
+    else:
+        pools = [k_pool, v_pool]
+        group = _group_blocks(bs * kv * hd * k_pool.dtype.itemsize, mb)
 
     # No row sees past its table (the lax path's walk ends there too).
     valid_len = jnp.minimum(valid_len.astype(jnp.int32), mb * bs)
@@ -626,9 +653,14 @@ def _ragged_attention_pallas(
         block_size=bs,
         num_pool_blocks=nb,
         group=group,
-        scale=hd ** -0.5,
+        scale=hd ** -0.5 if scale is None else scale,
         window=window,
+        latent_values=latent_values,
     )
+    if latent_values:
+        name = "latent_paged_attention"
+    else:  # the trace tells kinds apart by it
+        name = "ragged_paged_attention" + "_window" * bool(window)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -637,22 +669,20 @@ def _ragged_attention_pallas(
             in_specs=[
                 pl.BlockSpec((None, kv, tq, hd), _head_tiles),
                 pl.BlockSpec((None, tq, 1), _q_rows),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((None, kv, tq, hd), _head_tiles),
+            ] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools],
+            out_specs=pl.BlockSpec((None, kv, tq, vd), _head_tiles),
             scratch_shapes=[
-                pltpu.VMEM((2, group * bs * kv, hd), k_pool.dtype),
-                pltpu.VMEM((2, group * bs * kv, hd), v_pool.dtype),
+                pltpu.VMEM((2, group * bs * kv, hd), pool.dtype) for pool in pools
+            ] + [
                 pltpu.SemaphoreType.DMA((2, group)),
-                pltpu.VMEM((kv, tq, hd), jnp.float32),
+                pltpu.VMEM((kv, tq, vd), jnp.float32),
                 pltpu.VMEM((kv, tq, 1), jnp.float32),
                 pltpu.VMEM((kv, tq, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, kv, s * n_rep, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, s * n_rep, vd), q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention" + "_window" * bool(window),  # the trace tells kinds apart by it
+        name=name,
     )(
         tables,
         n_cols,
@@ -661,131 +691,7 @@ def _ragged_attention_pallas(
         # A KV head's query rows together: (b, j, s * G + h % G).
         q.reshape(b, s, kv, n_rep, hd).swapaxes(1, 2).reshape(b, kv, s * n_rep, hd),
         jnp.repeat(valid_len, n_rep, axis=1)[:, :, None],
-        k_pool.reshape(n_layers * nb, bs * kv, hd),
-        v_pool.reshape(n_layers * nb, bs * kv, hd),
+        *(pool.reshape(n_layers * nb, bs * kv, hd) for pool in pools),
     )
-    out = out.reshape(b, kv, s, n_rep, hd).swapaxes(1, 2)
-    return out.reshape(b, s, h * hd)
-
-
-# ----------------------------------------------------- latent pallas kernel
-#
-# A latent pool keeps one row a token for all heads, so the slabs are
-#
-#   pool  (L, NB, bs, 1, W) -> (L*NB, bs, W)       one row per position
-#   q     (B, S, H, W)      -> (B, S*H, W)         rows ordered (s, h)
-#
-# and one grid step multiplies a tile of query rows against a block's bs
-# rows: every pair is a wanted pair (no group mask), and the value tile is
-# `k[:, :latent_values]`, a lane-aligned cut of the tile the step already
-# holds. W is whole 128-value lanes (ModelConfig.kv_row_shapes pads the
-# row), the query's padding columns are zero.
-
-
-def _latent_kernel(
-    t_ref,  # scalar prefetch: (B, MB) block tables in SMEM
-    nc_ref,  # scalar prefetch: (B,) table columns row b actually needs
-    layer_ref,  # scalar prefetch: (1,) layer index — the index_maps' alone
-    q_ref,  # (TQ, W) absorbed query rows, ordered (s, h)
-    vlen_ref,  # (TQ, 1) valid_len of each query row
-    k_ref,  # (bs, W) — the block the index_map resolved for step j
-    o_ref,  # (TQ, latent_values), revisited across the innermost grid axis
-    acc_ref,  # VMEM scratch (TQ, latent_values) f32
-    m_ref,  # VMEM scratch (TQ, 1) f32
-    l_ref,  # VMEM scratch (TQ, 1) f32
-    *,
-    block_size: int,
-    num_pool_blocks: int,
-    latent_values: int,
-    scale: float,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF / 2)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(j < nc_ref[b])
-    def _attend():
-        q = q_ref[...]
-        k = k_ref[...]
-        logits = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (TQ, bs)
-        pos = j * block_size + lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        ok = (pos < vlen_ref[...]) & (t_ref[b, j] < num_pool_blocks)
-        logits = jnp.where(ok, logits, NEG_INF)
-
-        _accumulate_block(logits, k[:, :latent_values], acc_ref, m_ref, l_ref)
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        o_ref[...] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        ).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("interpret", "latent_values", "scale")
-)
-def _latent_attention_pallas(
-    q, k_pool, layer, tables, valid_len, *, interpret=False,
-    latent_values, scale,
-):
-    b, s, h, w = q.shape
-    n_layers, nb, bs, _, _ = k_pool.shape
-    mb = tables.shape[1]
-    ts = _q_tile_positions(s, h)
-    tq = ts * h
-    grid = (b, s // ts, mb)
-
-    valid_len = valid_len.astype(jnp.int32)
-    n_cols = jnp.clip((jnp.max(valid_len, axis=1) + bs - 1) // bs, 1, mb)
-
-    def _table_block(bi, qi, ji, t, nc, lyr):
-        # As _ragged_attention_pallas: the gather IS the index_map.
-        live = jnp.minimum(ji, nc[bi] - 1)
-        return (lyr[0] * nb + jnp.minimum(t[bi, live], nb - 1), 0, 0)
-
-    def _q_rows(bi, qi, ji, t, nc, lyr):
-        return (bi, qi, 0)
-
-    kernel = functools.partial(
-        _latent_kernel,
-        block_size=bs,
-        num_pool_blocks=nb,
-        latent_values=latent_values,
-        scale=scale,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((None, tq, w), _q_rows),
-                pl.BlockSpec((None, tq, 1), _q_rows),
-                pl.BlockSpec((None, bs, w), _table_block),
-            ],
-            out_specs=pl.BlockSpec((None, tq, latent_values), _q_rows),
-            scratch_shapes=[
-                pltpu.VMEM((tq, latent_values), jnp.float32),
-                pltpu.VMEM((tq, 1), jnp.float32),
-                pltpu.VMEM((tq, 1), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, s * h, latent_values), q.dtype),
-        interpret=interpret,
-        name="latent_paged_attention",
-    )(
-        tables,
-        n_cols,
-        jnp.asarray(layer, jnp.int32).reshape(1),
-        q.reshape(b, s * h, w),
-        jnp.repeat(valid_len, h, axis=1)[:, :, None],
-        k_pool.reshape(n_layers * nb, bs, w),
-    )
-    return out.reshape(b, s, h * latent_values)
+    out = out.reshape(b, kv, s, n_rep, vd).swapaxes(1, 2)
+    return out.reshape(b, s, h * vd)

@@ -234,28 +234,110 @@ def test_engine_serves_a_second_request_from_the_firsts_cached_blocks():
     assert 0 < stats["moe_routed_slots_total"] < stats["moe_computed_slots_total"]
 
 
-def test_latent_kernel_is_the_lax_path():
+def _latent_inputs(seed, b, s, h, w, nb, bs, mb, ctx, n_layers=1):
+    """Slot i holds `ctx[i]` positions in blocks of its own of an
+    (n_layers, nb, bs, 1, w) pool, its table's unused columns the pad
+    sentinel; query row (i, j) is row j of the slot's last s positions (a
+    chunk's causal rows; s = 1: the decode row); a dead slot (ctx 0) sees
+    nothing."""
+    rng = np.random.default_rng(seed)
+    q = rng.standard_normal((b, s, h, w)).astype(np.float32)
+    pool = rng.standard_normal((n_layers, nb, bs, 1, w)).astype(np.float32)
+    tables = np.full((b, mb), nb, np.int32)
+    ids = iter(rng.permutation(nb).tolist())
+    for i, n in enumerate(ctx):
+        tables[i, :-(-n // bs)] = [next(ids) for _ in range(-(-n // bs))]
+    valid = np.asarray(
+        [[n - s + 1 + j if n else 0 for j in range(s)] for n in ctx], np.int32)
+    return q, pool, tables, valid
+
+
+def _latent_case(case):
+    """(q, pool, layer, tables, valid_len, latent_values, pool the lax path
+    reads) of a `test_latent_kernel_is_the_lax_path` case. A group is
+    paged_attention._LATENT_GROUP_POSITIONS = 1,024 positions, so the
+    whole of a 12-column table of 16- or 32-token blocks, and 8 blocks of
+    128 tokens."""
+    if case == "sentinels_ragged_valid_lengths_layer_1":
+        keys = jax.random.split(jax.random.PRNGKey(6), 2)
+        q = jax.random.normal(keys[0], (3, 2, 4, 128), jnp.float32)
+        pool = jax.random.normal(keys[1], (2, 12, 16, 1, 128), jnp.float32)
+        tables = jnp.asarray([[3, 5, 12, 12], [0, 1, 2, 7], [9, 12, 12, 12]], jnp.int32)
+        valid = jnp.asarray([[19, 20], [63, 64], [4, 5]], jnp.int32)
+        return q, pool, 1, tables, valid, 32, pool
+    if case == "decode_with_a_dead_row":
+        q, pool, tables, valid = _latent_inputs(1, 3, 1, 4, 256, 40, 32, 12, (330, 0, 70))
+        return q, pool, 0, tables, valid, 128, pool
+    if case == "table_ends_in_sentinels_mid_group":
+        # 3 live blocks, then sentinels the row's length reaches into: the
+        # walk fetches (clamped) and masks columns 3 and 4 of its group.
+        q, pool, tables, valid = _latent_inputs(2, 2, 1, 4, 256, 40, 32, 12, (70, 200))
+        valid[0] = 150
+        return q, pool, 0, tables, valid, 128, pool
+    if case == "chunk_of_two_query_tiles":
+        # 32 positions x 20 heads: two tiles of 16 positions (320 rows),
+        # the first ends at column 9, the second at 10.
+        q, pool, tables, valid = _latent_inputs(3, 1, 32, 20, 128, 24, 16, 12, (150,))
+        return q, pool, 0, tables, valid, 32, pool
+    if case == "group_cut_at_n_cols":
+        # 11 columns: a full group of 8 and one cut at 3, in the other
+        # slot of the buffer; beside a row whose only group is cut at 3.
+        q, pool, tables, valid = _latent_inputs(4, 2, 2, 4, 256, 40, 128, 12, (1400, 300))
+        return q, pool, 0, tables, valid, 128, pool
+    if case == "layer_1_of_3_the_others_nan":
+        # A sentinel inside the walk clamps to the layer's last block
+        # BEFORE the layer offset; clamped after it, it would read block 0
+        # of layer 2, NaN, and 0 x NaN survives the values' product.
+        q, pool, tables, valid = _latent_inputs(
+            5, 2, 1, 4, 256, 40, 32, 12, (200, 130), n_layers=3)
+        tables[0, 3] = 40
+        pool[[0, 2]] = np.nan
+        return q, pool, 1, tables, valid, 128, pool
+    assert case == "unreached_blocks_hold_nan"
+    # Every block no walk reads is NaN, and so is every buffer place no copy
+    # wrote (the interpreter starts scratch memory as NaN): a cut group's
+    # unfetched places meet the probabilities as 0 x value unless the KEY
+    # buffer, which the values are cut from, is cleared.
+    q, pool, tables, valid = _latent_inputs(6, 2, 2, 4, 256, 40, 32, 12, (100, 250))
+    clean = pool.copy()
+    read = set(tables[tables < 40].tolist())
+    pool[0, [blk for blk in range(40) if blk not in read]] = np.nan
+    return q, pool, 0, tables, valid, 128, clean
+
+
+@pytest.mark.parametrize("case", [
+    "sentinels_ragged_valid_lengths_layer_1", "decode_with_a_dead_row",
+    "table_ends_in_sentinels_mid_group", "chunk_of_two_query_tiles",
+    "group_cut_at_n_cols", "layer_1_of_3_the_others_nan",
+    "unreached_blocks_hold_nan",
+])
+def test_latent_kernel_is_the_lax_path(case):
     """The Pallas variant (interpreted here; compiled for the v5e in
-    test_tpu_lowering.py and run on it by chip_smoke.py) against the lax path:
-    sentinel table entries, ragged valid lengths, a layer inside the stack."""
-    keys = jax.random.split(jax.random.PRNGKey(6), 2)
-    q = jax.random.normal(keys[0], (3, 2, 4, 128), jnp.float32)
-    pool = jax.random.normal(keys[1], (2, 12, 16, 1, 128), jnp.float32)
-    no_v = jnp.zeros((2, 12, 16, 1, 0), jnp.float32)
-    tables = jnp.asarray([[3, 5, 12, 12], [0, 1, 2, 7], [9, 12, 12, 12]], jnp.int32)
-    valid = jnp.asarray([[19, 20], [63, 64], [4, 5]], jnp.int32)
-    kw = {"latent_values": 32, "scale": 40 ** -0.5}
-    lax_out = ragged_attention(q, pool, no_v, jnp.int32(1), tables, valid,
+    test_tpu_lowering.py and run on it by chip_smoke.py) against the lax
+    path. The kernel walks a query tile's live table columns a group of
+    blocks a step (paged_attention._paged_kernel); the cases sit where that
+    walk can go wrong."""
+    q, pool, layer, tables, valid, lv, lax_pool = _latent_case(case)
+    q, pool, lax_pool, tables, valid = map(
+        jnp.asarray, (q, pool, lax_pool, tables, valid))
+    no_v = jnp.zeros(pool.shape[:-1] + (0,), jnp.float32)
+    kw = {"latent_values": lv, "scale": 40 ** -0.5}
+    lax_out = ragged_attention(q, lax_pool, no_v, jnp.int32(layer), tables, valid,
                                impl="lax_ragged", **kw)
-    pallas_out = ragged_attention(q, pool, no_v, jnp.int32(1), tables, valid,
+    pallas_out = ragged_attention(q, pool, no_v, jnp.int32(layer), tables, valid,
                                   impl="pallas", interpret=True, **kw)
-    assert lax_out.shape == (3, 2, 4 * 32)
+    b, s, h, _ = q.shape
+    assert lax_out.shape == pallas_out.shape == (b, s, h * lv)
+    assert np.isfinite(np.asarray(pallas_out)).all()
     assert float(jnp.max(jnp.abs(lax_out - pallas_out))) < 1e-5
-    # by hand, row 2: five positions of block 9 of layer 1
-    rows = pool[1, 9, :5, 0]
-    p = jax.nn.softmax(jnp.einsum("hw,tw->ht", q[2, 1], rows) * kw["scale"], axis=-1)
-    assert float(jnp.max(jnp.abs(
-        lax_out[2, 1].reshape(4, 32) - p @ rows[:, :32]))) < 1e-5
+    dead = np.asarray(valid).max(axis=1) == 0
+    assert not np.asarray(pallas_out)[dead].any()
+    if case == "sentinels_ragged_valid_lengths_layer_1":
+        # by hand, row 2: five positions of block 9 of layer 1
+        rows = pool[1, 9, :5, 0]
+        p = jax.nn.softmax(jnp.einsum("hw,tw->ht", q[2, 1], rows) * kw["scale"], axis=-1)
+        assert float(jnp.max(jnp.abs(
+            lax_out[2, 1].reshape(4, 32) - p @ rows[:, :32]))) < 1e-5
 
 
 # -- the router ----------------------------------------------------------------

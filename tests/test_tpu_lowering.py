@@ -30,7 +30,7 @@ from dstack_tpu.workloads.config import FULL, PRESETS, SLIDING
 from dstack_tpu.workloads.paged_attention import (
     _group_blocks,
     _head_tile_positions,
-    _latent_attention_pallas,
+    _q_tile_positions,
     _ragged_attention_pallas,
 )
 from dstack_tpu.workloads.serving import ServingEngine
@@ -101,6 +101,25 @@ def _paged_args(b, s, h, slots, max_blocks):
             ((b, max_blocks), i32), ((b, s), i32)]
 
 
+# The latent kernel's calls: decode, the cell's 512-token chunk (a 320-row
+# query tile), the smallest chunk bucket.
+LATENT_SHAPES = [("decode", SLOTS, 1), ("prefill", 1, 512), ("prefill", 1, 8)]
+
+
+def _latent_args(b, s):
+    """A latent pool's call: one row a position for the query's 20 heads,
+    and a V pool of no width (the engine's; the kernel reads K alone)."""
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    return [((b, s, 20, LATENT_W), bf16),
+            ((POOL_LAYERS, POOL_BLOCKS, BLOCK, 1, LATENT_W), bf16),
+            ((POOL_LAYERS, POOL_BLOCKS, BLOCK, 1, 0), bf16), ((), i32),
+            ((b, MAX_BLOCKS), i32), ((b, s), i32)]
+
+
+def _latent_call(*args):
+    return _ragged_attention_pallas(*args, latent_values=512, scale=256 ** -0.5)
+
+
 def _flash_fwd(q, k, v):
     return fa.flash_attention(q, k, v, causal=True)
 
@@ -128,14 +147,9 @@ def _kernels():
         ))
     # The latent pool's kernel: decode, the cell's 512-token chunk (a
     # 320-row query tile), the smallest chunk bucket.
-    for kind, b, s in [("decode", SLOTS, 1), ("prefill", 1, 512), ("prefill", 1, 8)]:
+    for kind, b, s in LATENT_SHAPES:
         out.append((
-            f"latent_{kind}_b{b}_s{s}",
-            lambda *a: _latent_attention_pallas(
-                *a, latent_values=512, scale=256 ** -0.5),
-            [((b, s, 20, LATENT_W), bf16),
-             ((POOL_LAYERS, POOL_BLOCKS, BLOCK, 1, LATENT_W), bf16), ((), i32),
-             ((b, MAX_BLOCKS), i32), ((b, s), i32)],
+            f"latent_{kind}_b{b}_s{s}", _latent_call, _latent_args(b, s),
         ))
     # A window layer's call beside a full layer's at the window cell's
     # geometry: 16 decode rows, the 512-token chunk (8 query tiles of 64
@@ -237,11 +251,11 @@ def test_kernel_builds_for_tpu(name, fn, args, v5e):
 
 
 def _pallas_grids(jaxpr):
-    """The grid of every pallas_call in a jaxpr, nested calls included."""
+    """(name, grid) of every pallas_call in a jaxpr, nested calls included."""
     grids = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            grids.append(tuple(eqn.params["grid_mapping"].grid))
+            grids.append((eqn.params["name"], tuple(eqn.params["grid_mapping"].grid)))
         for sub in jax.core.jaxprs_in_params(eqn.params):
             grids += _pallas_grids(sub)
     return grids
@@ -262,8 +276,18 @@ def test_paged_kernel_grid_has_no_table_column_axis(b, s, h, slots, max_blocks):
         for shape, dt in _paged_args(b, s, h, slots, max_blocks)
     ]
     grids = _pallas_grids(jax.make_jaxpr(_ragged_attention_pallas)(*specs).jaxpr)
-    assert grids == [(b, s // _head_tile_positions(s, h, KV))]
-    assert math.prod(grids[0]) < max_blocks  # nowhere near rows x columns
+    assert grids == [("ragged_paged_attention", (b, s // _head_tile_positions(s, h, KV)))]
+    assert math.prod(grids[0][1]) < max_blocks  # nowhere near rows x columns
+
+
+@pytest.mark.parametrize("kind,b,s", LATENT_SHAPES)
+def test_latent_kernel_grid_has_no_table_column_axis(kind, b, s):
+    """The latent call walks a query tile's live columns as the GQA kernel
+    does: ONE `latent_paged_attention` call whose grid is slots x query
+    tiles, where it was slots x query tiles x table columns."""
+    specs = [jax.ShapeDtypeStruct(shape, dt) for shape, dt in _latent_args(b, s)]
+    grids = _pallas_grids(jax.make_jaxpr(_latent_call)(*specs).jaxpr)
+    assert grids == [("latent_paged_attention", (b, s // _q_tile_positions(s, 20)))]
 
 
 def _dot_generals(jaxpr):
